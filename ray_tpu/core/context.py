@@ -34,6 +34,7 @@ from .memory_store import MemoryStore
 from .object_ref import ObjectRef
 from .object_store import ShmObjectStore
 from .ref_counter import ReferenceCounter
+from .resources import tpu_process_env
 from .serialization import SerializedValue, deserialize, serialize
 from . import events as task_events
 from .task_spec import (ARG_REF, ARG_VALUE, SchedulingStrategy, TaskSpec,
@@ -2104,8 +2105,7 @@ class CoreContext:
             # (the reference sets CUDA_VISIBLE_DEVICES the same way,
             # worker.py:888).
             self.assigned_tpu_ids = list(spec.tpu_ids)
-            os.environ["TPU_VISIBLE_CHIPS"] = ",".join(
-                str(i) for i in spec.tpu_ids)
+            os.environ.update(tpu_process_env(spec.tpu_ids))
         try:
             if spec.task_type == TaskType.ACTOR_CREATION:
                 if spec.runtime_env:

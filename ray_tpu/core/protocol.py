@@ -85,7 +85,7 @@ OK = 38
 # registration over gRPC, src/ray/gcs/gcs_server gcs_node_manager)
 REGISTER_NODE = 39        # (node_resources, store_name, node_ip, session_dir)
 REGISTER_NODE_REPLY = 40  # (node_idx, session_name)
-SPAWN_WORKER = 41         # head->agent: (worker_id,)
+SPAWN_WORKER = 41         # head->agent: (worker_id, leases_tpu)
 KILL_WORKER = 42          # head->agent: (worker_id,)
 AGENT_OBJ_GET = 43        # head->agent: (oid_bin) -> (payload, meta) | error
 AGENT_OBJ_PUT = 44        # head->agent: (oid_bin, payload, meta)

@@ -66,6 +66,29 @@ _ref_cls = None  # lazy: object_ref imports back into core modules
 _NDARRAY_OOB_MIN_BYTES = 1 << 20
 
 
+_driver_platform_settled = False
+
+
+def _settle_driver_platform(jax_mod):
+    """One process per chip. A worker's platform was decided when it was
+    started (the CPU, unless its class leases chips), but a driver leases
+    nothing: rebuilding a device array it reads must not make it start the
+    TPU backend and take a chip from the workers. So a driver whose JAX has
+    no backend yet is held to the CPU here, once. A driver that already
+    computes with JAX (its backends are up) is left as it is."""
+    global _driver_platform_settled
+    from jax._src import xla_bridge  # no public name for this question
+
+    from .context import get_context_if_exists
+
+    ctx = get_context_if_exists()
+    if ctx is None:  # not in a cluster (yet): nothing to decide
+        return
+    _driver_platform_settled = True
+    if ctx.is_driver and not xla_bridge.backends_are_initialized():
+        jax_mod.config.update("jax_platforms", "cpu")
+
+
 def _rebuild_device_array(dtype, shape, f_order, buf):
     """Inverse of the jax.Array reducer: rebuild from the (possibly
     arena-backed) out-of-band buffer. The dlpack import is zero-copy
@@ -82,6 +105,8 @@ def _rebuild_device_array(dtype, shape, f_order, buf):
             import jax as jax_mod  # noqa: F811
         except ImportError:  # pragma: no cover — cpu-only consumer
             return arr
+    if not _driver_platform_settled:
+        _settle_driver_platform(jax_mod)
     try:
         return jax_mod.numpy.from_dlpack(arr)
     except (BufferError, TypeError, ValueError, RuntimeError):

@@ -22,7 +22,9 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 
 from ray_tpu.models.config import TransformerConfig
-from ray_tpu.parallel.ring import reference_attention, ring_attention
+from ray_tpu.parallel.ring import (reference_attention, ring_attention,
+                                   shard_map)
+from ray_tpu.parallel.sharding import logical_to_spec
 from ray_tpu.parallel.sharding import with_logical_constraint as _wlc
 
 Params = Dict[str, Any]
@@ -130,7 +132,7 @@ def _select_attention(cfg: TransformerConfig, mesh: Optional[Mesh]):
     if impl == "auto":
         if mesh is not None and mesh.shape.get("sequence", 1) > 1:
             impl = "ring"
-        elif jax.default_backend() not in ("cpu",):
+        elif jax.default_backend() != "cpu":
             impl = "pallas"
         else:
             impl = "xla"
@@ -144,7 +146,21 @@ def _attention(q, k, v, cfg: TransformerConfig, mesh: Optional[Mesh],
         return ring_attention(q, k, v, mesh, causal=cfg.causal)
     if impl == "pallas":
         from ray_tpu.ops import flash_attention  # lazy: pallas import cost
-        return flash_attention(q, k, v, causal=cfg.causal)
+        attn = functools.partial(flash_attention, causal=cfg.causal)
+        if mesh is None or mesh.size == 1:
+            return attn(q, k, v)
+        if mesh.shape.get("sequence", 1) > 1:
+            raise ValueError(
+                "attention_impl='pallas' keeps whole K/V per (batch, head) "
+                "and cannot shard the sequence; use 'ring' (or 'auto') on "
+                "a mesh whose sequence axis is > 1")
+        # GSPMD cannot partition a Mosaic kernel: run it per shard over
+        # the mesh axes the batch and heads logical axes map to (K/V were
+        # already expanded to n_heads, so one spec serves all three).
+        spec = logical_to_spec(("batch", None, "heads", None),
+                               mesh_axes=mesh.axis_names)
+        return shard_map(attn, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec)(q, k, v)
     return reference_attention(q, k, v, causal=cfg.causal)
 
 

@@ -10,8 +10,11 @@ This kernel is the Pallas piece of the attention stack (SURVEY.md §7.6):
     no O(T^2) tensor is ever materialized in HBM.
 Layout is [batch, seq, heads, head_dim] at the API, transposed to
 [batch*heads, seq, head_dim] for the MXU-friendly inner matmuls.
-VMEM budget: K/V for one (batch, head) stay resident — fine through T≈16k at
-head_dim 128; beyond that, fall back to ring attention across chips.
+VMEM budget: K/V for one (batch, head) stay resident, which the TPU
+compiler's scoped-VMEM limit refuses already at T=8192 with head_dim 128
+(16.04 MB of 16.00 MB, compiled for a v5e); [4, 2048, 32, 64] bf16 compiles
+and runs (tests/test_chip_compile.py, chip_smoke.py). Longer sequences need
+the sequence axis (ring attention) or the kernel redesign of ROADMAP Q1.6.
 """
 
 from __future__ import annotations

@@ -29,9 +29,8 @@ from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map as _shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from ray_tpu.parallel.ring import _CHECK_KW, _shard_map
 
 PyTree = Any
 
@@ -115,5 +114,5 @@ def pipeline_scan(body: Callable[[jax.Array, PyTree], Any],
         inner, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: P("pipeline"), staged), P()),
         out_specs=P(),
-        axis_names={"pipeline"}, **{_CHECK_KW: False})(staged, mb)
+        axis_names={"pipeline"}, check_vma=False)(staged, mb)
     return out.reshape((B,) + x.shape[1:])

@@ -18,35 +18,22 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
-import inspect
-
-try:
-    from jax import shard_map as _shard_map  # jax >= 0.8
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# The replication-check kwarg was renamed check_rep -> check_vma in jax 0.8.
-_CHECK_KW = ("check_vma" if "check_vma" in
-             inspect.signature(_shard_map).parameters else "check_rep")
+from jax import shard_map as _shard_map
+from jax.sharding import AxisType, Mesh, PartitionSpec as P
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    try:
-        # Nested use only (e.g. ring attention inside a pipeline stage
-        # body): when the ambient mesh has MANUAL axes we are inside an
-        # enclosing shard_map, and jax requires the inner shard_map to see
-        # that context mesh, not the original concrete one. A plain
-        # `jax.set_mesh` context (all-auto) must NOT override an explicit
-        # mesh argument.
-        am = jax.sharding.get_abstract_mesh()
-        if am is not None and not am.empty and any(
-                t == jax.sharding.AxisType.Manual for t in am.axis_types):
-            mesh = am
-    except Exception:
-        pass
+    # Nested use only (e.g. ring attention inside a pipeline stage body):
+    # when the ambient mesh has MANUAL axes we are inside an enclosing
+    # shard_map, and jax requires the inner shard_map to see that context
+    # mesh, not the original concrete one. A plain `jax.set_mesh` context
+    # (all-auto) must NOT override an explicit mesh argument.
+    am = jax.sharding.get_abstract_mesh()
+    if not am.empty and AxisType.Manual in am.axis_types:
+        mesh = am
     return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **{_CHECK_KW: False})
+                      check_vma=False)
+
 
 _NEG_INF = -1e30
 

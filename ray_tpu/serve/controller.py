@@ -52,6 +52,14 @@ DEPLOY_UNHEALTHY = "UNHEALTHY"
 
 _TICK_S = 0.05
 _MAX_CONSECUTIVE_START_FAILURES = 3
+# A STARTING replica's first ping waits behind placement and the user's
+# constructor, which may load weights or start an accelerator runtime
+# (9-15 s on a v5e host before any user code runs). That is not a health
+# check: `health_check_timeout_s` bounds only pings of RUNNING replicas,
+# and a start is given up only after this long (the reference never gives
+# one up; a constructor that raises or a process that dies fails the ping
+# at once).
+_REPLICA_START_TIMEOUT_S = 600.0
 # router-reported queue depths older than this are a dead/idle router's
 # leftovers, not live demand
 _ROUTER_DEPTH_TTL_S = 3.0
@@ -524,7 +532,7 @@ class ServeController:
                                    fetch_local=False)
             if not done:
                 if time.monotonic() - r.started_at > \
-                        dep.config.health_check_timeout_s:
+                        _REPLICA_START_TIMEOUT_S:
                     self._replica_failed(
                         dep, r, "replica start timed out")
                 continue
@@ -539,6 +547,9 @@ class ServeController:
             r.state = RUNNING
             dep.start_failures = 0
             now = time.monotonic()
+            # the health clock starts with the first answer, not at spawn:
+            # a slow start must not count against the first health check
+            r.last_seen = now
             # cold-start sample: placement + ctor + weights fetch
             dep.cold_starts.append(
                 (now, now - r.started_at, len(dep.replicas)))

@@ -20,6 +20,40 @@ import numpy as np
 from ray_tpu.serve.deployment import deployment
 
 
+def _replica_params(cfg, checkpoint_dir: Optional[str], seed: int):
+    """A replica's weights: the checkpoint's, or random ones from ``seed``.
+    Called before the replica's first compile, so it also places the
+    persistent compilation cache, and keeps every program in it: a
+    replica's first requests run dozens of sub-second programs (prefill
+    per bucket and group size, decode, glue), which JAX's default
+    one-second threshold would compile again in every new replica."""
+    import jax
+
+    from ray_tpu.models.transformer import init_params
+    from ray_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    if checkpoint_dir is None:
+        return init_params(jax.random.key(seed), cfg)
+    import pickle
+
+    with open(checkpoint_dir, "rb") as f:
+        return jax.tree.map(np.asarray, pickle.load(f))
+
+
+def _tpu_lease(chips: int) -> dict:
+    """``ray_actor_options`` of an LLM replica: it leases the chips it
+    computes on, so that the replica — and no other process — is the one
+    that may load the TPU library. A cluster without TPUs serves on the
+    CPU and leases none."""
+    import ray_tpu
+
+    if ray_tpu.cluster_resources().get("TPU", 0) > 0:
+        return {"num_tpus": chips}
+    return {}
+
+
 class _LLMReplica:
     """Replica body: owns params + jitted generate for one model config.
 
@@ -39,7 +73,6 @@ class _LLMReplica:
         import jax
 
         from ray_tpu.models.config import TransformerConfig, get_config
-        from ray_tpu.models.transformer import init_params
 
         cfg = (model if isinstance(model, TransformerConfig)
                else get_config(model))
@@ -60,13 +93,7 @@ class _LLMReplica:
         # concurrent sampling requests split the same key
         self._rng_lock = threading.Lock()
         self._rng = jax.random.key(seed)
-        if checkpoint_dir is not None:
-            import pickle
-
-            with open(checkpoint_dir, "rb") as f:
-                self.params = jax.tree.map(np.asarray, pickle.load(f))
-        else:
-            self.params = init_params(jax.random.key(seed), cfg)
+        self.params = _replica_params(cfg, checkpoint_dir, seed)
         self._max_bs = int(max_batch_size)
         # the batcher cap and the compiled batch shape MUST be the same
         # number, so the batcher is built per-instance from the
@@ -205,17 +232,10 @@ class _ContinuousLLMReplica:
 
         from ray_tpu.models.config import TransformerConfig, get_config
         from ray_tpu.models.engine import InferenceEngine
-        from ray_tpu.models.transformer import init_params
 
         cfg = (model if isinstance(model, TransformerConfig)
                else get_config(model))
-        if checkpoint_dir is not None:
-            import pickle
-
-            with open(checkpoint_dir, "rb") as f:
-                params = jax.tree.map(np.asarray, pickle.load(f))
-        else:
-            params = init_params(jax.random.key(seed), cfg)
+        params = _replica_params(cfg, checkpoint_dir, seed)
         mesh = None
         if tensor_parallel > 1:
             from ray_tpu.parallel import MeshSpec
@@ -247,6 +267,14 @@ class _ContinuousLLMReplica:
     def engine_stats(self) -> dict:
         return dict(self.engine.stats)
 
+    def device(self) -> dict:
+        """The device(s) this replica computes on, as JAX reports them."""
+        import jax
+
+        devices = jax.devices()
+        return {"platform": devices[0].platform,
+                "kind": devices[0].device_kind, "count": len(devices)}
+
     def __del__(self):
         eng = getattr(self, "engine", None)
         if eng is not None:
@@ -266,7 +294,9 @@ def build_continuous_llm_deployment(model="tiny", *, name: str = "llm",
     """
     dep = deployment(_ContinuousLLMReplica, name=name) \
         .options(num_replicas=num_replicas,
-                 max_concurrent_queries=max_concurrency)
+                 max_concurrent_queries=max_concurrency,
+                 ray_actor_options=_tpu_lease(
+                     replica_kwargs.get("tensor_parallel", 1)))
     return dep.bind(model, **replica_kwargs)
 
 
@@ -281,5 +311,6 @@ def build_llm_deployment(model="tiny", *, name: str = "llm",
         out = handle.remote([1, 2, 3]).result()
     """
     dep = deployment(_LLMReplica, name=name) \
-        .options(num_replicas=num_replicas)
+        .options(num_replicas=num_replicas,
+                 ray_actor_options=_tpu_lease(1))
     return dep.bind(model, **replica_kwargs)

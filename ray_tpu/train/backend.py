@@ -13,6 +13,7 @@ import dataclasses
 from typing import Dict, Optional
 
 from ray_tpu.train.worker_group import WorkerGroup
+from ray_tpu.utils.compile_cache import enable_compile_cache
 
 
 @dataclasses.dataclass
@@ -61,6 +62,16 @@ def _init_jax_distributed(coordinator_address: str, num_processes: int,
     return True
 
 
+def _place_compile_cache():
+    """On every worker before the train loop's first jit: a trainer
+    compiles few programs, so all of them are kept, and a restarted or
+    second run compiles nothing the first one compiled."""
+    import jax
+
+    enable_compile_cache()
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+
+
 def _jax_shutdown():
     import jax
 
@@ -72,6 +83,7 @@ def _jax_shutdown():
 
 class _JaxBackend(Backend):
     def on_start(self, worker_group: WorkerGroup, backend_config: JaxConfig):
+        worker_group.execute(_place_compile_cache)
         n = worker_group.num_workers
         dist = backend_config.distributed
         if dist is None:

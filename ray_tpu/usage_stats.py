@@ -68,11 +68,11 @@ def _cluster_metadata() -> dict:
         "python_version": platform.python_version(),
         "os": platform.system().lower(),
     }
-    try:  # backend info without forcing device init
-        import jax
+    try:  # the version only: a driver that leased no chip never imports jax
+        from importlib.metadata import PackageNotFoundError, version
 
-        meta["jax_version"] = jax.__version__
-    except Exception:
+        meta["jax_version"] = version("jax")
+    except PackageNotFoundError:
         pass
     return meta
 

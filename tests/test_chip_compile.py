@@ -1,0 +1,160 @@
+"""The main path's programs, compiled for a described v5e at real widths.
+
+No chip is attached here: the TPU compiler that ships with libtpu
+compiles for a *described* `v5e:2x2` topology, which refuses what the
+chip would refuse (a kernel that cannot be partitioned, a VMEM overrun, a
+program over 16 GB) at no chip time. Nothing runs, so nothing here says
+anything about results or speed — chip_smoke.py does that on the chip.
+
+Under JAX_PLATFORMS=cpu the model code would pick the Pallas interpreter
+(`_use_interpret`) or no kernel at all (`attention_impl="auto"` -> xla);
+the fixtures steer both so the compiled text really holds the Mosaic
+kernel (`tpu_custom_call`).
+"""
+
+import importlib
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from ray_tpu.models.config import llama3_1b_config
+from ray_tpu.models.training import (batch_sharding, make_init_fn,
+                                     make_optimizer, make_train_step,
+                                     state_shardings)
+
+HBM_BYTES = 16 * 1024 ** 3  # one v5e chip
+BATCH, SEQ = 4, 2048
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever libtpu raises here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a described-device compile is written to the persistent cache but
+    # cannot be read back without a chip: keep these compiles out of it
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def mosaic(monkeypatch):
+    """Lower the Mosaic kernel, not the interpreter the CPU backend picks."""
+    fa = importlib.import_module("ray_tpu.ops.flash_attention")
+    monkeypatch.setattr(fa, "_use_interpret", lambda: False)
+
+
+def _llama(**kw):
+    return llama3_1b_config(max_seq_len=SEQ, param_dtype=jnp.bfloat16,
+                            attention_impl="pallas", **kw)
+
+
+def _on(tree, sharding):
+    """ShapeDtypeStructs of ``tree`` placed by ``sharding`` (one sharding
+    or a matching pytree of them)."""
+    if not isinstance(sharding, jax.sharding.Sharding):
+        return jax.tree.map(
+            lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+            tree, sharding)
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        tree)
+
+
+def _device_bytes(compiled) -> int:
+    m = compiled.memory_analysis()
+    return m.argument_size_in_bytes + m.temp_size_in_bytes
+
+
+def test_flash_forward_and_backward_compile(one_chip, mosaic):
+    from ray_tpu.ops import flash_attention
+
+    x = jax.ShapeDtypeStruct((BATCH, SEQ, 32, 64), jnp.bfloat16,
+                             sharding=one_chip)
+    fwd = jax.jit(flash_attention).lower(x, x, x).compile()
+    assert "tpu_custom_call" in fwd.as_text()
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v).astype(jnp.float32).sum()
+
+    bwd = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(x, x, x).compile()
+    # forward + dq + dk/dv kernels
+    assert bwd.as_text().count("tpu_custom_call") >= 3
+
+
+def test_llama3_1b_train_step_fits_one_chip(one_chip, mosaic):
+    cfg = _llama()
+    tx = make_optimizer(3e-4, mu_dtype=jnp.bfloat16)
+    state = _on(jax.eval_shape(make_init_fn(cfg, tx), jax.random.key(0)),
+                one_chip)
+    batch = {"tokens": jax.ShapeDtypeStruct((BATCH, SEQ + 1), jnp.int32,
+                                            sharding=one_chip)}
+    compiled = make_train_step(cfg, tx).lower(state, batch).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
+def test_serve_programs_compile(one_chip):
+    """The serve replica's programs at chip_smoke's sizes: 8 slots,
+    prompts to 512, 64 new tokens (max_len 640 covers the issue's
+    rehearsal size), decode chunks of 4, and the weights as a replica
+    holds them (the preset's float32 master copy)."""
+    from ray_tpu.models.engine import (decode_slots, init_slot_cache,
+                                       prefill_slots)
+    from ray_tpu.models.transformer import init_params
+
+    cfg = llama3_1b_config()
+    slots, max_len = 8, 640
+    params = _on(jax.eval_shape(lambda k: init_params(k, cfg),
+                                jax.random.key(0)), one_chip)
+    cache = _on(jax.eval_shape(
+        lambda: init_slot_cache(cfg, slots, max_len)), one_chip)
+    rng = _on(jax.eval_shape(lambda: jax.random.key(0)), one_chip)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    prefill = prefill_slots.lower(params, cache, i32(8, 512), i32(8),
+                                  i32(8), rng, cfg).compile()
+    assert _device_bytes(prefill) < HBM_BYTES
+    active = jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one_chip)
+    decode = decode_slots.lower(params, cache, i32(slots), active, rng, cfg,
+                                steps=4).compile()
+    assert _device_bytes(decode) < HBM_BYTES
+
+
+def test_fsdp2_tensor2_train_step_keeps_kernel(topo, mosaic):
+    """The sharded step on four described chips: GSPMD cannot partition a
+    Mosaic kernel, so `_attention` must run it per shard (shard_map) —
+    bare, this compile raises NotImplementedError."""
+    from ray_tpu.parallel import MeshSpec
+
+    cfg = _llama()
+    tx = make_optimizer(3e-4, mu_dtype=jnp.bfloat16)
+    mesh = MeshSpec(fsdp=2, tensor=2).build(topo.devices)
+    state = _on(jax.eval_shape(make_init_fn(cfg, tx), jax.random.key(0)),
+                state_shardings(cfg, tx, mesh))
+    batch = {"tokens": jax.ShapeDtypeStruct(
+        (BATCH, SEQ + 1), jnp.int32, sharding=batch_sharding(mesh))}
+    compiled = make_train_step(cfg, tx, mesh).lower(state, batch).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "all-gather" in text and "reduce-scatter" in text
+    # ZeRO-3 x TP: each chip holds about a quarter of the train state
+    assert _device_bytes(compiled) < HBM_BYTES // 2

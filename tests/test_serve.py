@@ -84,6 +84,34 @@ class TestBasics:
             serve.run(Broken.bind(), name="broken", timeout_s=30)
         serve.delete("broken")
 
+    def test_slow_constructor_outlives_the_health_timeout(self,
+                                                          serve_session):
+        """A start is not a health check. A constructor slower than
+        `health_check_timeout_s` (10 s by default; a TPU runtime alone
+        takes that long to start) must not get its replica killed, and
+        the slow start must not count against its first health check."""
+        @serve.deployment(health_check_period_s=0.1)
+        class Slow:
+            def __init__(self):
+                time.sleep(11)
+
+            def __call__(self):
+                return "up"
+
+        h = serve.run(Slow.bind(), name="slowstart", timeout_s=90)
+        assert h.remote().result(timeout_s=30) == "up"
+        time.sleep(1.0)  # several health-check periods
+        dep = serve.status()["applications"]["slowstart"]["deployments"][
+            "Slow"]
+        assert dep["status"] == "HEALTHY"
+        assert dep["replica_states"] == {"RUNNING": 1}
+        ctrl = ray_tpu.get_actor("SERVE_CONTROLLER")
+        _, replicas, _, _ = ray_tpu.get(
+            ctrl.get_routing_snapshot.remote("slowstart", "Slow"),
+            timeout=30)
+        # the first replica started, not a retry of one that was killed
+        assert [rid for rid, *_ in replicas] == ["slowstart#Slow#0"]
+
 
 class TestScaling:
     def test_multiple_replicas_spread_load(self, serve_session):
