@@ -1,0 +1,183 @@
+"""Where the benchmark's data files are and what they mean.
+
+Everything that belongs to one configuration, one traffic mix or one
+per-layer metric is a file found BY NAME from `BENCHMARK.json`:
+
+    benchmark/configs/<config>.json          sizes as run, source, cuts
+    benchmark/traffic/<traffic>.json         kind + parameters of a mix
+    benchmark/layer_metrics/<metric>.json    layer, moves, cells, reader
+    benchmark/readers/<reader>.py            one function: evidence -> number
+
+so a later PR adds a cell or a metric by adding files and one entry, and
+edits nothing here. This module imports neither JAX nor the program: the
+driver process reads it and must stay off the chip.
+"""
+
+from __future__ import annotations
+
+import importlib.util
+import json
+import os
+import re
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+NAME_RE = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+TRAFFIC_KINDS = ("train", "open_loop", "closed_loop")
+
+
+class SpecError(ValueError):
+    """A data file is missing, malformed or names something unknown."""
+
+
+def _read_json(path: str) -> dict:
+    try:
+        with open(path) as f:
+            return json.load(f)
+    except FileNotFoundError as e:
+        raise SpecError(f"missing benchmark file {path}") from e
+
+
+def load_benchmark(root: str = ROOT) -> dict:
+    return _read_json(os.path.join(root, "BENCHMARK.json"))
+
+
+def bench_dir(root: str = ROOT) -> str:
+    return os.path.join(root, "benchmark")
+
+
+def find_cell(bench: dict, name: str) -> dict:
+    for cell in bench["workloads"]:
+        if cell["name"] == name:
+            return cell
+    raise SpecError(f"no workload {name!r} in BENCHMARK.json; have "
+                    f"{[c['name'] for c in bench['workloads']]}")
+
+
+def load_config(bench: dict, name: str, root: str = ROOT) -> dict:
+    for entry in bench["configs"]:
+        if entry["name"] == name:
+            conf = _read_json(os.path.join(root, entry["file"]))
+            conf["name"] = name
+            return conf
+    raise SpecError(f"no config {name!r} in BENCHMARK.json")
+
+
+def load_traffic(name: str, root: str = ROOT) -> dict:
+    t = _read_json(os.path.join(bench_dir(root), "traffic", name + ".json"))
+    if t.get("kind") not in TRAFFIC_KINDS:
+        raise SpecError(f"traffic {name!r}: kind must be one of "
+                        f"{TRAFFIC_KINDS}, got {t.get('kind')!r}")
+    t["name"] = name
+    return t
+
+
+def load_peaks(root: str = ROOT) -> dict:
+    return _read_json(os.path.join(bench_dir(root), "peaks.json"))
+
+
+def device_peaks(kind: str, root: str = ROOT) -> dict:
+    """Peaks of one chip of ``kind``; a device the table does not list is
+    an error, never a default."""
+    table = load_peaks(root)["devices"]
+    if kind not in table:
+        raise SpecError(f"device_kind {kind!r} is not in benchmark/"
+                        f"peaks.json (have {sorted(table)})")
+    return table[kind]
+
+
+def metrics_for(bench: dict, cell_name: str, group: str) -> list:
+    """Entries of ``group`` ('end_to_end' or 'per_layer') that the cell
+    reports: those without a `workloads` key, or that list the cell."""
+    return [m for m in bench[group]
+            if "workloads" not in m or cell_name in m["workloads"]]
+
+
+def load_layer_metric(name: str, root: str = ROOT) -> dict:
+    m = _read_json(os.path.join(bench_dir(root), "layer_metrics",
+                                name + ".json"))
+    m["name"] = name
+    return m
+
+
+def load_reader(metric: dict, root: str = ROOT):
+    """The function `read(evidence, metric) -> float | None` in
+    benchmark/readers/<reader>.py, loaded by path so that a new reader is
+    a new file and nothing else."""
+    reader = metric["reader"]
+    if not NAME_RE.match(reader):
+        raise SpecError(f"bad reader name {reader!r}")
+    path = os.path.join(bench_dir(root), "readers", reader + ".py")
+    spec = importlib.util.spec_from_file_location(
+        f"benchmark_reader_{reader.replace('.', '_').replace('-', '_')}",
+        path)
+    if spec is None or not os.path.exists(path):
+        raise SpecError(f"missing reader file {path}")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.read
+
+
+def read_layer_metrics(bench: dict, cell_name: str, evidence: dict,
+                       root: str = ROOT) -> dict:
+    """{name: {"value", "unit"}} for every per-layer metric of the cell
+    whose reader found something to read (None -> left out)."""
+    out = {}
+    for entry in metrics_for(bench, cell_name, "per_layer"):
+        metric = load_layer_metric(entry["name"], root)
+        value = load_reader(metric, root)(evidence, metric)
+        if value is not None:
+            out[entry["name"]] = {"value": float(value),
+                                  "unit": entry["unit"]}
+    return out
+
+
+# ---- configuration -> TransformerConfig ----------------------------------
+
+def transformer_fields(conf: dict) -> dict:
+    """TransformerConfig fields (plain Python values) from a config file:
+    `mapping` says which published key feeds which field."""
+    fields = {}
+    for published, field in conf["mapping"].items():
+        if published not in conf:
+            raise SpecError(f"config {conf.get('name')}: mapping names "
+                            f"{published!r}, which the file does not hold")
+        fields[field] = conf[published]
+    head_dim = conf.get("head_dim")
+    if head_dim is not None and \
+            head_dim * fields["n_heads"] != fields["d_model"]:
+        raise SpecError("TransformerConfig derives head_dim as d_model / "
+                        "n_heads; this config's head_dim differs")
+    return fields
+
+
+def build_transformer_config(conf: dict, **overrides):
+    """The program's TransformerConfig for ``conf`` (imports the program,
+    and with it JAX: call it only in a process that may hold the chip or
+    in tests). dtype names in ``overrides`` are given as strings."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models.config import TransformerConfig
+
+    fields = transformer_fields(conf)
+    for key, val in overrides.items():
+        if key in ("dtype", "param_dtype") and isinstance(val, str):
+            val = jnp.dtype(val)
+        fields[key] = val
+    return TransformerConfig(**fields)
+
+
+def seed32(seed: int) -> int:
+    """A whole-number seed of any size folded into what a JAX key and the
+    program's `seed` arguments take (below 2**31)."""
+    return int(seed) % 2_147_483_629
+
+
+def dig(obj, path: str):
+    """`dig(out, "client.ttft_ms")`: follow a dotted path through nested
+    dicts; None where a step is missing."""
+    for key in path.split("."):
+        if not isinstance(obj, dict) or key not in obj:
+            return None
+        obj = obj[key]
+    return obj

@@ -1,0 +1,134 @@
+"""Every cell's whole path at toy size on the CPU, through the functions
+`benchmark/run.py` runs on the chip: cluster, lease, weights from the seed,
+reference check, warm-up, loaded window, reduction to the last line. The
+numbers mean nothing here (a CPU is no device metric); the control flow,
+the counts and `correct` do."""
+
+import argparse
+
+import pytest
+
+import bench_paths
+import ray_tpu
+from benchmark.harness import spec
+
+BENCH = spec.load_benchmark()
+RUN = bench_paths.load_run_module()
+TINY = dict(vocab_size=512, d_model=64, n_layers=2, n_heads=4, n_kv_heads=2,
+            d_ff=128, dtype="float32")
+TOY_DEPLOYMENT = {"slots": 4, "max_concurrency": 8, "max_prompt_len": 64,
+                  "max_new_tokens": 16, "eos_id": -1, "greedy": True}
+TOY_SERVE = {
+    "deployment": TOY_DEPLOYMENT,
+    "prompt_len": {"median": 20, "sigma": 0.9, "min": 4, "max": 64},
+    "output_len": {"median": 8, "sigma": 0.7, "min": 2, "max": 16},
+    "rate_per_s": 6.0, "ramp_s": 1.5, "tail_s": 3.0, "clients": 8,
+    "pool": 24, "check": {"prompt_lens": [40, 33, 50, 64]},
+    "trace_at_s": 0.5, "trace_s": 1.0}
+TOY = {
+    "internlm2-1.8b.train-4k": {"seq_len": 64, "rows": 2},
+    "mistral-7b-v0.3.train-fsdp2tp2": {"seq_len": 64, "rows": 4},
+    "internlm2-1.8b.chat-steady": TOY_SERVE,
+    "internlm2-1.8b.batch-closed": TOY_SERVE,
+}
+
+
+@pytest.fixture(scope="module")
+def cpu_cluster():
+    ray_tpu.init(num_cpus=4, num_tpus=0)
+    yield
+    ray_tpu.shutdown()
+
+
+def _run(cell_name, trace, seconds=2.0, seed=2 ** 31 + 11):
+    # a CPU "chip" count of 1 keeps the lease check meaningful; the mesh
+    # of the sharded cell is built over the CPU's virtual devices
+    cell = dict(spec.find_cell(BENCH, cell_name), chips=1)
+    args = argparse.Namespace(seed=seed, seconds=seconds, trace=trace)
+    return RUN.run_cell(BENCH, cell, args, platform="cpu",
+                        field_overrides=TINY,
+                        traffic_overrides=TOY[cell_name])
+
+
+@pytest.mark.parametrize("cell", sorted(TOY))
+def test_cell_runs_end_to_end_at_toy_size(cpu_cluster, cell):
+    line = _run(cell, trace=0, seconds=3.0 if "chat" in cell else 2.0)
+    assert line["correct"] is True, line
+    assert line["failed"] == 0 and line["attempted"] >= 1
+    assert line["device"]["platform"] == "cpu"
+    declared = {m["name"] for m in spec.metrics_for(BENCH, cell,
+                                                    "end_to_end")}
+    assert set(line["metrics"]) == declared
+    for name, m in line["metrics"].items():
+        assert m["value"] > 0 and m["unit"]
+    if "chat" in cell:   # a fixed request count: rate x window
+        assert line["attempted"] == round(TOY_SERVE["rate_per_s"] * 3.0)
+
+
+@pytest.mark.parametrize("cell", ["internlm2-1.8b.train-4k",
+                                  "internlm2-1.8b.chat-steady"])
+def test_traced_run_reports_layer_metrics_and_refuses_a_deviceless_trace(
+        cpu_cluster, cell):
+    line = _run(cell, trace=1, seconds=3.0 if "chat" in cell else 2.0)
+    # readers that need a device trace return nothing on the CPU and are
+    # left out; those fed by counters, spans and the host clock report
+    assert "chip_worker_ready_s" in line["metrics"]
+    assert "train_step_device_ms" not in line["metrics"]
+    assert "decode_substep_ms.chat" not in line["metrics"]
+    if "chat" in cell:
+        for name in ("ttft_p50_ms", "ttft_p95_ms", "generator_late_p99_ms",
+                     "decode_occupancy.chat", "handle_rtt_p50_ms",
+                     "fetch_wait_ms_per_fetch.chat", "peak_hbm_gb.chat"):
+            assert name in line["metrics"], name
+        assert 0 < line["metrics"]["decode_occupancy.chat"]["value"] <= 100
+    declared = {m["name"] for m in spec.metrics_for(BENCH, cell,
+                                                    "per_layer")}
+    assert set(line["metrics"]) <= declared
+    # no operation ran on a TPU: such a traced run is never `correct`
+    assert line["correct"] is False and not line["device"]["busy_s"]
+    assert set(line["breakdown"]) == {"device_ops", "idle_gaps"}
+
+
+@pytest.fixture(scope="module")
+def toy_replica():
+    from benchmark.harness import serve_cell
+
+    conf = spec.load_config(BENCH, "internlm2-1.8b")
+    dep = {k: v for k, v in TOY_DEPLOYMENT.items() if k != "max_concurrency"}
+    rep = serve_cell.BenchReplica(conf, platform="cpu", field_overrides=TINY,
+                                  seed=7, **dep)
+    yield rep
+    rep.engine.shutdown()
+
+
+@pytest.mark.parametrize("hook", ["_admit_locked", "_dispatch_locked",
+                                  "_fetch_chunks", "_deliver_locked",
+                                  "_admit_group"])
+def test_a_traced_run_fails_loudly_when_an_engine_hook_is_gone(
+        toy_replica, tmp_path, hook):
+    """The host spans and `prefill_ms_per_ktok` rest on private names of
+    the engine: a rename must stop the traced run, not empty a metric."""
+    from benchmark.harness import serve_cell
+
+    assert hook in serve_cell.ENGINE_HOOKS
+    setattr(toy_replica.engine, hook, None)   # as if it were renamed
+    try:
+        with pytest.raises(RuntimeError, match=hook):
+            toy_replica.bench_trace_start(str(tmp_path))
+    finally:
+        delattr(toy_replica.engine, hook)
+    assert callable(getattr(toy_replica.engine, hook))
+
+
+@pytest.mark.parametrize("token,logits,rel_err,want", [
+    (2, [0.0, 1.0, 5.0], 0.01, True),     # the argmax
+    (1, [0.0, 1.0, 5.0], 0.01, False),    # not the argmax, clear margin
+    (1, [0.0, 4.99, 5.0], 0.01, True),    # top two within the error
+    (0, [0.0, 4.99, 5.0], 0.0, False),    # no error allowed: exact argmax
+])
+def test_served_tokens_are_held_to_the_reference_argmax(token, logits,
+                                                        rel_err, want):
+    from benchmark.harness import serve_cell
+
+    assert serve_cell._is_argmax(token, logits,
+                                 {"rel_rms_error": rel_err}) is want
