@@ -666,7 +666,6 @@ def bench_continuous(cfg, params, *, slots, max_prompt, max_new,
                 "fetches": eng.stats["fetches"],
                 "fetch_wall_s": round(eng.stats["fetch_wall_s"], 2),
                 "dispatch_wall_s": round(eng.stats["dispatch_wall_s"], 2),
-                "cap_stalls": eng.stats["cap_stalls"],
                 **_percentiles(lat)}
     finally:
         eng.shutdown()

@@ -32,14 +32,23 @@ the reference delegates to external vLLM workers for, built TPU-first:
     their logical axes (kv_heads/heads/mlp/vocab -> "tensor") and the
     cache shards on its KV-head axis; XLA propagates the TP layout
     through the same jitted step and inserts the collectives.
+  - Observability is always on: every second of the scheduler and the
+    fetcher thread goes to one named state (``engine.stats`` seconds,
+    ``jax.profiler.TraceAnnotation`` spans on the device trace's clock
+    whenever someone traces the process), a state that lasts over a
+    second while work waits is a ``slow_events`` entry, and requests are
+    stamped at submit, admit, first token and finish.
 """
 
 from __future__ import annotations
 
+import collections
 import itertools
+import logging
 import queue
 import threading
 import time
+from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass, field
 from functools import partial
 from typing import Dict, List, Optional, Sequence
@@ -54,6 +63,8 @@ from ray_tpu.models.generate import (_final_logits, _gqa_attention,
 from ray_tpu.models.transformer import (Params, ffn_block,
                                         param_logical_axes, qkv_proj,
                                         rms_norm)
+
+log = logging.getLogger(__name__)
 
 SlotCache = Dict[str, jax.Array]
 # {"k"/"v": [L, B, S, KV, hd], "pos": [B], "start": [B]} — pos[b] is slot
@@ -230,6 +241,9 @@ def decode_slots(params: Params, cache: SlotCache, tokens: jax.Array,
 
 _FINISH_EOS = "eos"
 _FINISH_LENGTH = "length"
+# one occurrence of a thread state (ordinary ones last at most ~0.1 s)
+# beyond this many seconds is a slow event: the engine's heartbeat
+_SLOW_S = 1.0
 
 
 @dataclass
@@ -242,6 +256,15 @@ class _Request:
     stream_q: Optional[queue.Queue] = None
     finish_reason: Optional[str] = None
     error: Optional[BaseException] = None
+    # stamps (``time.perf_counter``): made, taken into a prefill group,
+    # first token on the host, finished; and how it was admitted
+    t_submit: float = 0.0
+    t_admit: float = 0.0
+    t_first: float = 0.0
+    t_done: float = 0.0
+    bucket: int = 0        # P of the prefill program that admitted it
+    group: int = 0         # K of that program
+    chunks_ahead: int = 0  # undelivered decode chunks at its admission
 
     def emit(self, tok: int):
         self.tokens.append(tok)
@@ -249,6 +272,7 @@ class _Request:
             self.stream_q.put(tok)
 
     def finish(self, reason: str):
+        self.t_done = time.perf_counter()
         self.finish_reason = reason
         if self.stream_q is not None:
             self.stream_q.put(None)  # sentinel: stream closed
@@ -357,12 +381,34 @@ class InferenceEngine:
         # the whole of a step(), and submissions must not block on it)
         self._fatal: Optional[BaseException] = None
         self._death_lock = threading.Lock()
-        # running counters for benchmarking / observability
-        self.stats = {"prefills": 0, "prefill_dispatches": 0,
-                      "decode_steps": 0, "fetches": 0, "tokens_out": 0,
-                      "requests_done": 0, "fetch_wall_s": 0.0,
-                      "cap_stalls": 0, "dispatch_wall_s": 0.0}
-        self._at_cap = False
+        # running counters, always on. Plain numbers under dot-free keys,
+        # every key here from the start (readers difference all of them);
+        # each is written by one thread, slow_* by whichever was slow.
+        # The *_s keys are the thread-time ledger `_timed` fills: for the
+        # scheduler, sched_wall_s = sched_lock_wait_s + admit_wall_s
+        # (which holds prefill_dispatch_wall_s) + dispatch_wall_s +
+        # park_idle_s + park_cap_s (+ fetch_wall_s + deliver_wall_s when
+        # step() is driven inline); for the fetcher, fetcher_wall_s =
+        # fetch_idle_s + fetch_lock_wait_s + fetch_wall_s + deliver_wall_s
+        self.stats = {
+            "prefills": 0, "prefill_dispatches": 0, "decode_steps": 0,
+            "chunks_dispatched": 0, "chunks_delivered": 0, "fetches": 0,
+            "tokens_out": 0, "requests_done": 0,
+            "sched_wall_s": 0.0, "sched_lock_wait_s": 0.0,
+            "admit_wall_s": 0.0, "prefill_dispatch_wall_s": 0.0,
+            "dispatch_wall_s": 0.0, "park_idle_s": 0.0, "park_cap_s": 0.0,
+            "fetcher_wall_s": 0.0, "fetch_idle_s": 0.0,
+            "fetch_lock_wait_s": 0.0, "fetch_wall_s": 0.0,
+            "deliver_wall_s": 0.0,
+            # request stamps, summed where the work happens
+            "queue_wait_s": 0.0, "first_token_s": 0.0, "first_tokens": 0,
+            "chunks_ahead_at_admit": 0, "prefill_padded_tokens": 0,
+            "prefill_prompt_tokens": 0,
+            "slow_s": 0.0, "slow_count": 0}
+        self.slow_events: collections.deque = collections.deque(maxlen=64)
+        self.request_log: collections.deque = collections.deque(maxlen=1024)
+        self._episodes: Dict[str, dict] = {}  # thread -> its open episode
+        self._park = "idle"  # why _dispatch_locked last dispatched nothing
 
     # -------------------------------------------------------- submission
 
@@ -410,7 +456,94 @@ class InferenceEngine:
             raise ValueError("max_new_tokens must be >= 1")
         return _Request(rid=next(self._rid), prompt=prompt,
                         max_new_tokens=mnt,
-                        stream_q=queue.Queue() if stream else None)
+                        stream_q=queue.Queue() if stream else None,
+                        t_submit=time.perf_counter())
+
+    # ------------------------------------------------------ observability
+
+    @contextmanager
+    def _timed(self, key: str, span: Optional[str] = None, *,
+               heartbeat: bool = True, episode_if=None, **meta):
+        """THE timed region of the engine's threads: enters a
+        ``jax.profiler.TraceAnnotation(span, **meta)`` (about a
+        microsecond unless a profiler session is running; on the device
+        trace's own clock when one is) and adds the elapsed seconds to
+        ``stats[key]``. A spanned region is a thread state, and one
+        occurrence over ``_SLOW_S`` while work waits is a slow event
+        (``heartbeat=False``: a child whose seconds its parent's key
+        already holds). ``episode_if`` marks a wait that wakes by its own
+        timeout: back-to-back occurrences are ONE episode, counted for as
+        long as the predicate says work is waiting for this thread."""
+        t0 = time.perf_counter()
+        try:
+            with jax.profiler.TraceAnnotation(span, **meta) if span \
+                    else nullcontext():
+                yield
+        finally:
+            dt = time.perf_counter() - t0
+            self.stats[key] += dt
+            if span and heartbeat and (episode_if or dt > _SLOW_S):
+                reason = meta.get("reason")
+                self._beat(span[len("engine."):]
+                           + (f"_{reason}" if reason else ""),
+                           t0, dt, episode_if)
+
+    def _beat(self, state: str, t0: float, dt: float, episode_if):
+        """The heartbeat behind `_timed`: ``slow_events`` gets one entry
+        (and the log one warning) per occurrence or episode that passed
+        ``_SLOW_S`` while work waited; ``slow_s`` its thread-seconds (a
+        stall that blocks both threads counts on each)."""
+        thread = threading.current_thread().name
+        if episode_if is None:
+            if not self._work_waits():
+                return  # e.g. the lock held by a warm-up: idle, not stalled
+            ev = {"state": state, "t_perf": t0, "seconds": dt}
+        elif not episode_if():
+            self._episodes.pop(thread, None)  # idle, not stalled
+            return
+        else:
+            ev = self._episodes.get(thread)
+            if ev is None or ev["state"] != state:
+                ev = self._episodes[thread] = {
+                    "state": state, "t_perf": t0, "seconds": 0.0}
+            ev["seconds"] += dt
+            if ev["seconds"] <= _SLOW_S:
+                return
+        if "thread" in ev:  # an episode already reported keeps growing
+            self.stats["slow_s"] += dt
+            return
+        ev.update(thread=thread,
+                  t_wall=time.time() - (time.perf_counter() - t0),
+                  queued=self._queue.qsize(),
+                  planned_slots=sum(n > 0 for n in self._slot_left),
+                  undelivered_chunks=self._undelivered())
+        self.slow_events.append(ev)
+        self.stats["slow_count"] += 1
+        self.stats["slow_s"] += ev["seconds"]
+        log.warning("engine thread %s slow: %.2f s in state %s "
+                    "(%d queued, %d planned slots, %d undelivered chunks)",
+                    thread, ev["seconds"], state, ev["queued"],
+                    ev["planned_slots"], ev["undelivered_chunks"])
+
+    def _undelivered(self) -> int:
+        """Decode chunks dispatched whose tokens have not reached their
+        requests: queued or running on the device, or in the fetcher."""
+        return self.stats["chunks_dispatched"] \
+            - self.stats["chunks_delivered"]
+
+    def _work_waits(self) -> bool:
+        return self._undelivered() > 0 or not self._queue.empty() \
+            or any(self._slot_left)
+
+    @contextmanager
+    def _locked(self, wait_key: str):
+        """``with self._lock``, the wait for it timed as a state."""
+        with self._timed(wait_key, "engine.lock_wait"):
+            self._lock.acquire()
+        try:
+            yield
+        finally:
+            self._lock.release()
 
     # ------------------------------------------------------------- engine
 
@@ -431,19 +564,33 @@ class InferenceEngine:
         one compiled (K, P) program."""
         K = len(group)
         P = max(self._bucket(len(req.prompt)) for _, req in group)
-        toks = np.full((K, P), self.pad_id, np.int32)
-        slots = np.zeros(K, np.int32)
-        starts = np.zeros(K, np.int32)
-        for i, (slot, req) in enumerate(group):
-            toks[i, P - len(req.prompt):] = req.prompt
-            slots[i] = slot
-            starts[i] = P - len(req.prompt)
-        self.cache, first = prefill_slots(
-            self.params, self.cache, jnp.asarray(toks),
-            jnp.asarray(slots), jnp.asarray(starts),
-            self._next_rng(), self.cfg, self.greedy, self.temperature)
-        self._next_tok_dev = self._next_tok_dev.at[jnp.asarray(slots)] \
-            .set(first)
+        # stamps and their counters where the scheduler takes the group
+        # up, before a dispatch that may block on the device's queue
+        now, ahead = time.perf_counter(), self._undelivered()
+        for _, req in group:
+            req.t_admit, req.bucket, req.group = now, P, K
+            req.chunks_ahead = ahead
+            self.stats["queue_wait_s"] += now - req.t_submit
+            self.stats["prefill_prompt_tokens"] += len(req.prompt)
+        self.stats["prefill_padded_tokens"] += K * P
+        self.stats["chunks_ahead_at_admit"] += ahead
+        with self._timed("prefill_dispatch_wall_s",
+                         "engine.prefill_dispatch", heartbeat=False,
+                         K=K, P=P,
+                         rids=" ".join(str(req.rid) for _, req in group)):
+            toks = np.full((K, P), self.pad_id, np.int32)
+            slots = np.zeros(K, np.int32)
+            starts = np.zeros(K, np.int32)
+            for i, (slot, req) in enumerate(group):
+                toks[i, P - len(req.prompt):] = req.prompt
+                slots[i] = slot
+                starts[i] = P - len(req.prompt)
+            self.cache, first = prefill_slots(
+                self.params, self.cache, jnp.asarray(toks),
+                jnp.asarray(slots), jnp.asarray(starts),
+                self._next_rng(), self.cfg, self.greedy, self.temperature)
+            self._next_tok_dev = self._next_tok_dev.at[
+                jnp.asarray(slots)].set(first)
         for slot, req in group:
             self._slot_req[slot] = req
         self.stats["prefills"] += K
@@ -488,6 +635,10 @@ class InferenceEngine:
         """Record one generated token; on an eos finish, reclaim the
         slot's remaining planned occupancy (the plan is length-based and
         eos can only shorten it)."""
+        if not req.tokens:  # the request's first token
+            req.t_first = time.perf_counter()
+            self.stats["first_token_s"] += req.t_first - req.t_submit
+            self.stats["first_tokens"] += 1
         req.emit(tok)
         self.stats["tokens_out"] += 1
         reason = None
@@ -501,10 +652,17 @@ class InferenceEngine:
                 self._slot_left[slot] = 0
             self.stats["requests_done"] += 1
             req.finish(reason)
+            self.request_log.append({
+                "rid": req.rid, "prompt_len": len(req.prompt),
+                "bucket": req.bucket, "group": req.group,
+                "chunks_ahead": req.chunks_ahead,
+                "t_submit": req.t_submit, "t_admit": req.t_admit,
+                "t_first": req.t_first, "t_done": req.t_done,
+                "tokens_out": len(req.tokens)})
 
     def step(self) -> bool:
         """One engine iteration; returns True if any work was done."""
-        with self._lock:
+        with self._locked("sched_lock_wait_s"):
             return self._step_locked()
 
     def _step_locked(self) -> bool:
@@ -534,77 +692,76 @@ class InferenceEngine:
     def _admit_locked(self) -> int:
         """Admit queued prompts into planned-free slots; dispatches one
         batched prefill per power-of-two group. Returns #admitted."""
-        take: List[tuple] = []
-        for slot in range(self.slots):
-            if self._slot_left[slot] > 0:
-                continue
-            if self._slot_req[slot] is not None:
-                # planned release: dispatching for it is complete
-                self._slot_req[slot] = None
-            try:
-                req = self._queue.get_nowait()
-            except queue.Empty:
-                break
-            take.append((slot, req))
-        i = 0
-        while i < len(take):
-            K = next(k for k in self._GROUP_SIZES if k <= len(take) - i)
-            group = take[i:i + K]
-            i += K
-            try:
-                self._admit_group(group)
-            except BaseException as e:
-                # a failed prefill dispatch poisons the whole engine
-                # (device/XLA error); fail this group's waiters AND every
-                # later dequeued-but-ungrouped request here — none of
-                # them are queued or slotted anymore, so _die cannot see
-                # them and they would otherwise hang forever
-                for _slot, req in group + take[i:]:
-                    req.error = e
-                    req.finish("error")
-                raise
-            for slot, req in group:
-                # the plan includes the prefill-sampled first token; it
-                # reaches the host in the next chunk's echo column
-                self._slot_left[slot] = req.max_new_tokens
-                self._slot_new[slot] = True
-        return len(take)
+        with self._timed("admit_wall_s", "engine.admit"):
+            take: List[tuple] = []
+            for slot in range(self.slots):
+                if self._slot_left[slot] > 0:
+                    continue
+                if self._slot_req[slot] is not None:
+                    # planned release: dispatching for it is complete
+                    self._slot_req[slot] = None
+                try:
+                    req = self._queue.get_nowait()
+                except queue.Empty:
+                    break
+                take.append((slot, req))
+            i = 0
+            while i < len(take):
+                K = next(k for k in self._GROUP_SIZES if k <= len(take) - i)
+                group = take[i:i + K]
+                i += K
+                try:
+                    self._admit_group(group)
+                except BaseException as e:
+                    # a failed prefill dispatch poisons the whole engine
+                    # (device/XLA error); fail this group's waiters AND every
+                    # later dequeued-but-ungrouped request here — none of
+                    # them are queued or slotted anymore, so _die cannot see
+                    # them and they would otherwise hang forever
+                    for _slot, req in group + take[i:]:
+                        req.error = e
+                        req.finish("error")
+                    raise
+                for slot, req in group:
+                    # the plan includes the prefill-sampled first token; it
+                    # reaches the host in the next chunk's echo column
+                    self._slot_left[slot] = req.max_new_tokens
+                    self._slot_new[slot] = True
+            return len(take)
 
     def _dispatch_locked(self) -> bool:
         active_slots = [s for s in range(self.slots)
                         if self._slot_left[s] > 0]
         if not active_slots:
+            self._park = "idle"  # no planned work
             return False
         if self._fetcher is not None and \
                 len(self._inflight) >= self.max_inflight:
-            # count stall EPISODES, not the parked loop's 50ms wakeups —
-            # one fetch-bound stall would otherwise inflate the counter
-            # by however many times the loop re-polled it
-            if not self._at_cap:
-                self.stats["cap_stalls"] += 1
-                self._at_cap = True
-            return False  # dispatch-ahead cap: wait for the fetcher
-        self._at_cap = False
-        t0 = time.perf_counter()
-        width = self.decode_chunk
-        snapshot = []
-        for slot in active_slots:
-            new = self._slot_new[slot]
-            self._slot_new[slot] = False
-            take = min(self._slot_left[slot], width + (1 if new else 0))
-            snapshot.append((slot, self._slot_req[slot],
-                             0 if new else 1, take))
-            self._slot_left[slot] = max(
-                0, self._slot_left[slot] - (width + 1 if new else width))
-        active = np.zeros(self.slots, bool)
-        active[active_slots] = True
-        self.cache, toks = decode_slots(
-            self.params, self.cache, self._next_tok_dev,
-            jnp.asarray(active), self._next_rng(), self.cfg,
-            self.greedy, self.temperature, self.eos_id, steps=width)
-        self._next_tok_dev = toks[:, -1]
-        self.stats["decode_steps"] += width
-        self.stats["dispatch_wall_s"] += time.perf_counter() - t0
+            self._park = "cap"  # dispatch-ahead cap: wait for the fetcher
+            return False
+        with self._timed("dispatch_wall_s", "engine.decode_dispatch",
+                         active=len(active_slots)):
+            width = self.decode_chunk
+            snapshot = []
+            for slot in active_slots:
+                new = self._slot_new[slot]
+                self._slot_new[slot] = False
+                take = min(self._slot_left[slot],
+                           width + (1 if new else 0))
+                snapshot.append((slot, self._slot_req[slot],
+                                 0 if new else 1, take))
+                self._slot_left[slot] = max(
+                    0,
+                    self._slot_left[slot] - (width + 1 if new else width))
+            active = np.zeros(self.slots, bool)
+            active[active_slots] = True
+            self.cache, toks = decode_slots(
+                self.params, self.cache, self._next_tok_dev,
+                jnp.asarray(active), self._next_rng(), self.cfg,
+                self.greedy, self.temperature, self.eos_id, steps=width)
+            self._next_tok_dev = toks[:, -1]
+            self.stats["decode_steps"] += width
+            self.stats["chunks_dispatched"] += 1
         self._inflight.append((toks, snapshot))
         return True
 
@@ -615,25 +772,28 @@ class InferenceEngine:
         and a mid-traffic compile stalls every slot. Called outside the
         lock by the fetcher; inline
         mode calls it under the lock."""
-        t0 = time.perf_counter()
-        parts = jax.device_get([t for t, _ in pending])
-        big = parts[0] if len(parts) == 1 else np.concatenate(
-            parts, axis=1)
-        self.stats["fetches"] += 1
-        self.stats["fetch_wall_s"] += time.perf_counter() - t0
+        with self._timed("fetch_wall_s", "engine.fetch",
+                         chunks=len(pending)):
+            parts = jax.device_get([t for t, _ in pending])
+            big = parts[0] if len(parts) == 1 else np.concatenate(
+                parts, axis=1)
+            self.stats["fetches"] += 1
         return big
 
     def _deliver_locked(self, big: np.ndarray, pending) -> None:
         W = self.decode_chunk + 1
-        for i, (_toks_dev, snap) in enumerate(pending):
-            seg = big[:, i * W:(i + 1) * W]
-            for slot, req, from_col, take in snap:
-                if req.done.is_set():
-                    continue  # finished in an earlier chunk
-                for t in range(from_col, from_col + take):
-                    self._emit_to(req, slot, int(seg[slot, t]))
+        with self._timed("deliver_wall_s", "engine.deliver",
+                         chunks=len(pending)):
+            for i, (_toks_dev, snap) in enumerate(pending):
+                seg = big[:, i * W:(i + 1) * W]
+                for slot, req, from_col, take in snap:
                     if req.done.is_set():
-                        break  # rest of the row is frozen eos/junk
+                        continue  # finished in an earlier chunk
+                    for t in range(from_col, from_col + take):
+                        self._emit_to(req, slot, int(seg[slot, t]))
+                        if req.done.is_set():
+                            break  # rest of the row is frozen eos/junk
+        self.stats["chunks_delivered"] += len(pending)
 
     # ---------------------------------------------------- background loop
 
@@ -646,67 +806,72 @@ class InferenceEngine:
             return self
         self._stop.clear()
 
+        def sched_cycle():
+            try:
+                busy = self.step()
+            except BaseException as e:
+                # an error escaping step() (device/XLA failure at
+                # dispatch or fetch) kills the engine: error out every
+                # in-flight and queued request so no waiter hangs, and
+                # refuse new submissions (the loops end on _fatal)
+                self._die(e)
+                return
+            if busy:
+                self._episodes.pop(threading.current_thread().name, None)
+                return
+            # idle or at the dispatch-ahead cap: PARK until state can
+            # change (submit(), fetcher taking chunks, or delivery all
+            # set _work). A busy-spin here would eat the host core the
+            # fetcher and request threads need — measured as ~half the
+            # device sitting idle on a 1-core host.
+            self._work.clear()
+            with self._timed(f"park_{self._park}_s", "engine.park",
+                             episode_if=self._work_waits,
+                             reason=self._park):
+                self._work.wait(timeout=0.05)
+
         def loop():
-            while not self._stop.is_set():
-                if self._fatal is not None:
+            while not self._stop.is_set() and self._fatal is None:
+                with self._timed("sched_wall_s"):
+                    sched_cycle()
+
+        def fetch_cycle():
+            with self._timed("fetch_idle_s", "engine.fetch_idle",
+                             episode_if=lambda: bool(self._inflight)):
+                self._fetch_evt.wait(timeout=0.05)
+            with self._locked("fetch_lock_wait_s"):
+                if not self._inflight:
+                    self._fetch_evt.clear()
                     return
-                try:
-                    busy = self.step()
-                except BaseException as e:
-                    # an error escaping step() (device/XLA failure at
-                    # dispatch or fetch) kills the engine: error out every
-                    # in-flight and queued request so no waiter hangs, and
-                    # refuse new submissions
-                    self._die(e)
-                    return
-                if not busy:
-                    # idle or at the dispatch-ahead cap: PARK until state
-                    # can change (submit(), fetcher taking chunks, or
-                    # delivery all set _work). A busy-spin here would eat
-                    # the host core the fetcher and request threads need
-                    # — measured as ~half the device sitting idle on a
-                    # 1-core host.
-                    self._work.clear()
-                    self._work.wait(timeout=0.05)
+                # take the OLDEST chunk (delivery must advance) plus any
+                # younger chunks the device has already finished — their
+                # transfer piggybacks for free. Taking the whole backlog
+                # instead would block this cycle on the newest,
+                # just-dispatched chunk and stretch delivery latency to
+                # the backlog depth.
+                pending = [self._inflight.pop(0)]
+                while self._inflight and self._inflight[0][0].is_ready():
+                    pending.append(self._inflight.pop(0))
+            self._episodes.pop(threading.current_thread().name, None)
+            # taking the chunks made room under the dispatch cap — wake
+            # the dispatch loop BEFORE the slow transfer so it overlaps
+            # with queued execution
+            self._work.set()
+            try:
+                big = self._fetch_chunks(pending)  # blocking transfer
+                with self._locked("fetch_lock_wait_s"):
+                    self._deliver_locked(big, pending)
+            except BaseException as e:
+                self._die(e)
+                return
+            # room under the cap + possibly eos-freed slots
+            self._work.set()
 
         def fetch_loop():
-            while True:
-                if self._fatal is not None:
-                    return
-                if self._stop.is_set() and not self._inflight:
-                    return
-                self._fetch_evt.wait(timeout=0.05)
-                with self._lock:
-                    if not self._inflight:
-                        self._fetch_evt.clear()
-                        pending = []
-                    else:
-                        # take the OLDEST chunk (delivery must advance)
-                        # plus any younger chunks the device has already
-                        # finished — their transfer piggybacks for free.
-                        # Taking the whole backlog instead would block
-                        # this cycle on the newest, just-dispatched chunk
-                        # and stretch delivery latency to the backlog
-                        # depth.
-                        pending = [self._inflight.pop(0)]
-                        while self._inflight and \
-                                self._inflight[0][0].is_ready():
-                            pending.append(self._inflight.pop(0))
-                if not pending:
-                    continue
-                # taking the chunks made room under the dispatch cap —
-                # wake the dispatch loop BEFORE the slow transfer so it
-                # overlaps with queued execution
-                self._work.set()
-                try:
-                    big = self._fetch_chunks(pending)  # blocking transfer
-                    with self._lock:
-                        self._deliver_locked(big, pending)
-                except BaseException as e:
-                    self._die(e)
-                    return
-                # room under the cap + possibly eos-freed slots
-                self._work.set()
+            while self._fatal is None and not (
+                    self._stop.is_set() and not self._inflight):
+                with self._timed("fetcher_wall_s"):
+                    fetch_cycle()
 
         self._thread = threading.Thread(target=loop, name="llm-engine",
                                         daemon=True)

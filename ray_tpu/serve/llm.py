@@ -267,6 +267,31 @@ class _ContinuousLLMReplica:
     def engine_stats(self) -> dict:
         return dict(self.engine.stats)
 
+    def engine_slow_events(self) -> List[dict]:
+        """The engine's heartbeat: thread states that lasted over a
+        second while work waited (newest last, at most 64)."""
+        return [dict(ev) for ev in self.engine.slow_events]
+
+    def engine_requests(self, last: int = 100) -> List[dict]:
+        """Stamps of the ``last`` finished requests (of at most 1024)."""
+        return list(self.engine.request_log)[-int(last):]
+
+    def trace(self, seconds: float, log_dir: str) -> str:
+        """Profile this replica for ``seconds`` (only the process that
+        holds the chip can trace it): device operations and the engine's
+        ``engine.*`` spans on one clock. -> ``log_dir``, which holds
+        ``plugins/profile/<time>/*.xplane.pb``."""
+        import time
+
+        import jax
+
+        jax.profiler.start_trace(log_dir)
+        try:
+            time.sleep(seconds)
+        finally:
+            jax.profiler.stop_trace()
+        return log_dir
+
     def device(self) -> dict:
         """The device(s) this replica computes on, as JAX reports them."""
         import jax
