@@ -8,9 +8,18 @@ generation stalls the batch. This engine is the vLLM/Orca-style redesign
 the reference delegates to external vLLM workers for, built TPU-first:
 
   - The KV cache is a fixed pool of B *slots* over one contiguous
-    [L, B, S, KV, hd] array — static shapes, one compiled decode program
+    [L, B, KV, S, hd] array — static shapes, one compiled decode program
     for the life of the engine. A slot is a row; admission writes a new
     prompt's K/V into a freed row, eviction is just host bookkeeping.
+    It is stored KV-heads-outside-positions because that is the order
+    the decode contraction reads it in: stored any other way, XLA
+    transposes the whole cache on the way into every chunk and back.
+  - A decode substep reads the cache once and writes only the rows that
+    change: the layer scan takes the cache as read-only input, the new
+    token attends to its own K/V as one more key column, and the B new
+    rows per layer are written in place AFTER the scan (a per-layer
+    write inside the scan makes the scan re-stack, and XLA copy, the
+    whole cache every substep).
   - Each decode step advances EVERY active slot by one token in a single
     batched program (per-row cache positions, per-row RoPE), then the
     host admits queued prompts into any slots that finished — finished
@@ -58,7 +67,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.models.config import TransformerConfig
-from ray_tpu.models.generate import (_final_logits, _gqa_attention,
+from ray_tpu.models.generate import (_final_logits, _gqa_decode_attention,
                                      _prefill_hidden)
 from ray_tpu.models.transformer import (Params, ffn_block,
                                         param_logical_axes, qkv_proj,
@@ -67,13 +76,15 @@ from ray_tpu.models.transformer import (Params, ffn_block,
 log = logging.getLogger(__name__)
 
 SlotCache = Dict[str, jax.Array]
-# {"k"/"v": [L, B, S, KV, hd], "pos": [B], "start": [B]} — pos[b] is slot
+# {"k"/"v": [L, B, KV, S, hd], "pos": [B], "start": [B]} — pos[b] is slot
 # b's next write position; start[b] its first real (non-pad) position.
+# KV-major (heads outside positions) is the layout decode attention
+# contracts over; the layout is private to this module.
 
 
 def init_slot_cache(cfg: TransformerConfig, slots: int,
                     max_len: int) -> SlotCache:
-    shape = (cfg.n_layers, slots, max_len, cfg.kv_heads, cfg.head_dim)
+    shape = (cfg.n_layers, slots, cfg.kv_heads, max_len, cfg.head_dim)
     return {"k": jnp.zeros(shape, cfg.dtype),
             "v": jnp.zeros(shape, cfg.dtype),
             "pos": jnp.zeros((slots,), jnp.int32),
@@ -83,7 +94,7 @@ def init_slot_cache(cfg: TransformerConfig, slots: int,
 def cache_logical_axes() -> Dict[str, tuple]:
     """Logical axes of the slot cache (slots axis stays unsharded —
     serving shards the model, not the batch)."""
-    kv = ("layers", None, None, "kv_heads", None)
+    kv = ("layers", None, "kv_heads", None, None)
     return {"k": kv, "v": kv, "pos": (None,), "start": (None,)}
 
 
@@ -92,6 +103,23 @@ def _sample(logits, rng, greedy: bool, temperature):
         return jnp.argmax(logits, axis=-1).astype(jnp.int32)
     return jax.random.categorical(
         rng, logits / jnp.maximum(temperature, 1e-6)).astype(jnp.int32)
+
+
+def _put_rows(cache: SlotCache, new_k: jax.Array, new_v: jax.Array,
+              slots: jax.Array, at: jax.Array):
+    """Write row i of ``new_k``/``new_v`` [L, n, KV, T, hd] into slot
+    ``slots[i]`` at positions ``at[i]`` .. ``at[i]+T``; -> (k, v). One
+    dynamic_update_slice per row (n is static: unrolled), which on a
+    donated cache moves the rows and nothing else."""
+    k, v = cache["k"], cache["v"]
+    zero = jnp.zeros((), jnp.int32)
+    for i in range(new_k.shape[1]):
+        start = (zero, slots[i], zero, at[i], zero)
+        k = jax.lax.dynamic_update_slice(
+            k, new_k[:, i:i + 1].astype(k.dtype), start)
+        v = jax.lax.dynamic_update_slice(
+            v, new_v[:, i:i + 1].astype(v.dtype), start)
+    return k, v
 
 
 @partial(jax.jit, static_argnames=("cfg", "greedy"), donate_argnums=(1,))
@@ -128,32 +156,14 @@ def prefill_slots(params: Params, cache: SlotCache, tokens: jax.Array,
     x, cK = _prefill_hidden(params, tokens, cfg, P, starts)
     last = _final_logits(params, x[:, -1:], cfg)[:, 0]  # [K, V]
     toks = _sample(last, rng, greedy, temperature)      # [K]
-    # cK["k"]: [L, K, P, KV, hd] -> row i into slot row slots[i]
-    k, v = cache["k"], cache["v"]
-    zero = jnp.zeros((), jnp.int32)
-    for i in range(K):  # K is static: unrolled row writes
-        k = jax.lax.dynamic_update_slice(
-            k, cK["k"][:, i:i + 1].astype(k.dtype),
-            (zero, slots[i], zero, zero, zero))
-        v = jax.lax.dynamic_update_slice(
-            v, cK["v"][:, i:i + 1].astype(v.dtype),
-            (zero, slots[i], zero, zero, zero))
+    # cK["k"]: [L, K, P, KV, hd] -> the cache's [L, K, KV, P, hd] (the
+    # prompt's K/V is small), then row i into slot row slots[i]
+    k, v = _put_rows(cache, cK["k"].transpose(0, 1, 3, 2, 4),
+                     cK["v"].transpose(0, 1, 3, 2, 4), slots,
+                     jnp.zeros_like(slots))
     return {"k": k, "v": v,
             "pos": cache["pos"].at[slots].set(P),
             "start": cache["start"].at[slots].set(starts)}, toks
-
-
-def _write_rows(layer_cache, kv, pos):
-    """Per-row cache write: layer_cache [B, S, KV, hd] <- kv [B, 1, KV, hd]
-    at per-row seq positions ``pos`` [B].
-
-    A one-hot select, NOT a vmapped dynamic_update_slice: per-row dynamic
-    indices lower to a scatter that falls off the TPU fast path (measured
-    ~5x decode slowdown); the select is pure elementwise bandwidth over
-    a cache the decode step already reads in full."""
-    S = layer_cache.shape[1]
-    hit = (jnp.arange(S)[None, :] == pos[:, None])[:, :, None, None]
-    return jnp.where(hit, kv.astype(layer_cache.dtype), layer_cache)
 
 
 def _decode_one(params: Params, cache: SlotCache, tokens: jax.Array,
@@ -162,33 +172,41 @@ def _decode_one(params: Params, cache: SlotCache, tokens: jax.Array,
     token) -> (cache with pos advanced, logits [B, V]).
 
     pos/RoPE/attention masks are all per-row, so slots admitted at
-    different times decode together in one program.
+    different times decode together in one program. The layer scan only
+    READS the cache and hands back each layer's new K/V row ([L,B,KV,hd],
+    a megabyte); the rows land afterwards, one in-place
+    dynamic_update_slice per slot at its own ``pos`` (on the donated,
+    loop-carried cache nothing else is moved). A ``pos`` past the end
+    clamps to the slot's own last position, which no request's plan
+    reads (`InferenceEngine._max_len`).
     """
     pos, start = cache["pos"], cache["start"]
     x = params["embed"].astype(cfg.dtype)[tokens[:, None]]  # [B, 1, d]
     positions = pos[:, None]  # [B, 1] per-row RoPE
+    S = cache["k"].shape[3]
+    kpos = jnp.arange(S)[None, :]
+    mask = (kpos >= start[:, None]) & (kpos < pos[:, None])  # [B, S]
+    dtype = cache["k"].dtype
 
     def block(x, scanned):
         lp, k_layer, v_layer = scanned
         h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
         q, k, v = qkv_proj(h, lp, cfg, positions)
-        k_layer = _write_rows(k_layer, k, pos)
-        v_layer = _write_rows(v_layer, v, pos)
-        S = k_layer.shape[1]
-        kpos = jnp.arange(S)[None, None, None, None, :]
-        mask = (kpos <= pos[:, None, None, None, None]) & \
-            (kpos >= start[:, None, None, None, None])
-        o = _gqa_attention(q, k_layer, v_layer, mask)
+        k, v = k[:, 0].astype(dtype), v[:, 0].astype(dtype)  # [B, KV, hd]
+        o = _gqa_decode_attention(q, k_layer, v_layer, k, v, mask)
         o = jnp.einsum("bthk,hkd->btd", o, lp["wo"].astype(cfg.dtype))
         x = x + o
         h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
         down, _ = ffn_block(h, lp, cfg, None)
         x = x + down
-        return x, (k_layer, v_layer)
+        return x, (k, v)
 
-    x, (k_all, v_all) = jax.lax.scan(
+    x, (k_rows, v_rows) = jax.lax.scan(
         block, x, (params["layers"], cache["k"], cache["v"]))
     logits = _final_logits(params, x, cfg)[:, 0]  # [B, V]
+    k_all, v_all = _put_rows(cache, k_rows[:, :, :, None],
+                             v_rows[:, :, :, None],
+                             jnp.arange(tokens.shape[0], dtype=jnp.int32), pos)
     return {"k": k_all, "v": v_all, "pos": pos + 1, "start": start}, logits
 
 

@@ -73,6 +73,32 @@ def _gqa_attention(q, k, v, mask):
     return o.reshape(B, T, H, hd).astype(q.dtype)
 
 
+def _gqa_decode_attention(q, k_cache, v_cache, k_new, v_new, mask):
+    """One query per row, q [B,1,H,hd], against a cache it only READS:
+    keys/values [B,KV,S,hd] (KV-major, the order this contraction walks)
+    under ``mask`` [B,S], plus the token's own ``k_new``/``v_new``
+    [B,KV,hd] as one more key column — one softmax over both, so the
+    result is what `_gqa_attention` gives once the row is written. The
+    caller rounds k_new/v_new to the cache's dtype first (attention then
+    sees the values it would have read back)."""
+    B, _, H, hd = q.shape
+    KV = k_cache.shape[1]
+    qg = q.reshape(B, KV, H // KV, hd).astype(jnp.float32)
+    s_old = jnp.einsum("bkrh,bksh->bkrs", qg,
+                       k_cache.astype(jnp.float32)) * (hd ** -0.5)
+    s_old = jnp.where(mask[:, None, None, :], s_old, _MASKED)
+    s_new = jnp.einsum("bkrh,bkh->bkr", qg,
+                       k_new.astype(jnp.float32)) * (hd ** -0.5)
+    # softmax over [s_old | s_new] without concatenating to S+1 columns
+    top = jnp.maximum(s_old.max(axis=-1), s_new)
+    e_old = jnp.exp(s_old - top[..., None])
+    e_new = jnp.exp(s_new - top)
+    o = jnp.einsum("bkrs,bksh->bkrh", e_old, v_cache.astype(jnp.float32)) \
+        + e_new[..., None] * v_new.astype(jnp.float32)[:, :, None, :]
+    o = o / (e_old.sum(axis=-1) + e_new)[..., None]
+    return o.reshape(B, 1, H, hd).astype(q.dtype)
+
+
 def _cached_attention(q, k_cache, v_cache, valid_len, start):
     """Decode attention against the full cache, masking key positions
     outside [start[b], valid_len). ``start`` [B] supports left-padded
