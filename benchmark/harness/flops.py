@@ -3,50 +3,21 @@ the benchmark so that no PR that claims a gain can change the yardstick.
 Recomputed operations (rematerialisation, the masked half of a causal
 score matrix a kernel happens to compute) never count: a program that does
 more arithmetic than required gets a lower utilisation, not a higher one.
+For sparse experts: the experts a token is routed to, plus the router.
 
-``fields`` is the TransformerConfig field dict `spec.transformer_fields`
-makes from a config file.
+What one architecture's forward pass requires per token
+(`forward_flops_per_token`, `num_params`) is in its own file,
+`benchmark/architectures/<name>.py`; here is what no architecture owns.
 """
 
 from __future__ import annotations
 
 
-def matmul_params(fields: dict) -> dict:
-    """Weights that take part in a matrix multiplication per token: the
-    embedding lookup is a gather and does no arithmetic; norms are
-    elementwise and left out."""
-    d, ff = fields["d_model"], fields["d_ff"]
-    H = fields["n_heads"]
-    KV = fields.get("n_kv_heads") or H
-    hd = d // H
-    per_layer = d * H * hd + 2 * d * KV * hd + H * hd * d + 3 * d * ff
-    return {"per_layer": per_layer, "head": d * fields["vocab_size"],
-            "total": fields["n_layers"] * per_layer
-            + d * fields["vocab_size"]}
-
-
-def num_params(fields: dict) -> int:
-    """All weights held (embedding, blocks with their two norms, final
-    norm, untied head)."""
-    d, v, L = fields["d_model"], fields["vocab_size"], fields["n_layers"]
-    mm = matmul_params(fields)
-    head = 0 if fields.get("tie_embeddings") else d * v
-    return v * d + L * (mm["per_layer"] + 2 * d) + d + head
-
-
-def forward_flops_per_token(fields: dict, seq_len: int) -> float:
-    """2 FLOPs per weight that multiplies, plus causal attention: QK^T and
-    PV are each 2*T*hd per head and query, of which causality needs half
-    (a query at position t attends t+1 keys; mean (T+1)/2)."""
-    d, L = fields["d_model"], fields["n_layers"]
-    attn = L * 2 * 2 * d * (seq_len + 1) / 2
-    return 2.0 * matmul_params(fields)["total"] + attn
-
-
-def train_flops_per_token(fields: dict, seq_len: int) -> float:
+def train_from_forward(forward_flops: float) -> float:
     """Forward plus backward: the backward pass needs twice the forward's
-    multiplications (gradients for inputs and for weights)."""
-    return 3.0 * forward_flops_per_token(fields, seq_len)
+    multiplications (gradients for inputs and for weights), whatever the
+    block."""
+    return 3.0 * forward_flops
 
 
 def flash_attention_cost(batch: int, heads: int, seq_q: int, seq_k: int,

@@ -7,6 +7,12 @@ binds it, with READ-ONLY additions: probes, the logits check, warming the
 engine's programs, and a profiler trace. Nothing about how a request is
 served changes. The driver side (`run`) builds the requests from the
 traffic file and `--seed`, paces them, and times every token at the client.
+
+A traced run reads the program's own `engine.*` spans (they are in the
+profiler's trace whenever one runs) and differences `engine.stats` over
+the traced seconds; it wraps nothing and names no method of the engine.
+The one tie to the engine's private names that is left is `bench_check`
+(the served programs return no logits).
 """
 
 from __future__ import annotations
@@ -20,20 +26,10 @@ from ray_tpu.serve.llm import _ContinuousLLMReplica
 from benchmark.harness import spec
 from benchmark.harness import traffic as traffic_gen
 
-# The engine has no spans of its own yet, so a traced run puts `bench:`
-# host spans on these methods of the live engine (and counts padded prompt
-# tokens at `_admit_group`). They are private names of models/engine.py: a
-# traced run FAILS when one is gone, it never drops the metrics that rest
-# on them silently. PERF.md lists them as the first spans for the
-# `tracing` issue to put into the program.
-ENGINE_SPANS = ("_admit_locked", "_dispatch_locked", "_fetch_chunks",
-                "_deliver_locked")
-ENGINE_HOOKS = ENGINE_SPANS + ("_admit_group",)
-
 
 class BenchReplica(_ContinuousLLMReplica):
-    def __init__(self, conf: dict, *, platform: str, field_overrides=None,
-                 **replica_kwargs):
+    def __init__(self, conf: dict, *, platform: str, root: str = spec.ROOT,
+                 field_overrides=None, **replica_kwargs):
         t0 = time.time()
         from benchmark.harness import probes
 
@@ -43,9 +39,12 @@ class BenchReplica(_ContinuousLLMReplica):
             raise RuntimeError(
                 f"expected platform {platform!r}, JAX found "
                 f"{self._bench_device['platform']!r}")
-        self._bench_fields = spec.transformer_fields(conf)
+        self._bench_conf = conf
+        self._bench_arch = spec.load_architecture(conf, root)
+        self._bench_fields = spec.transformer_fields(conf, root)
         self._bench_fields.update(field_overrides or {})
-        cfg = spec.build_transformer_config(conf, **(field_overrides or {}))
+        cfg = spec.build_transformer_config(conf, root,
+                                            **(field_overrides or {}))
         self._bench_compiles = probes.CompileCounter()
         super().__init__(cfg, **replica_kwargs)
         self._bench_init_unix = t0
@@ -66,7 +65,6 @@ class BenchReplica(_ContinuousLLMReplica):
                 "up_unix": self._bench_up_unix,
                 "weight_bytes": weights, "cache_bytes": cache,
                 "cache_shape": list(self.engine.cache["k"].shape),
-                "buckets": list(self.engine._buckets),
                 "decode_chunk": self.engine.decode_chunk,
                 "max_inflight": self.engine.max_inflight}
 
@@ -81,13 +79,15 @@ class BenchReplica(_ContinuousLLMReplica):
         eng = self.engine
         t0 = time.perf_counter()
         self._bench_compiles.mark()
-        with eng._lock:   # the engine is idle: nothing else steps it
-            eng.warmup()
-            mem = decode_slots.lower(
-                eng.params, eng.cache, eng._next_tok_dev,
-                jnp.ones(eng.slots, bool), jax.random.key(0), eng.cfg,
-                eng.greedy, eng.temperature, eng.eos_id,
-                steps=eng.decode_chunk).compile().memory_analysis()
+        # No request has been sent yet: the engine's threads park, and
+        # without work they touch neither the cache nor the token chain,
+        # so nothing steps the engine while this does.
+        eng.warmup()
+        mem = decode_slots.lower(
+            eng.params, eng.cache, jnp.zeros(eng.slots, jnp.int32),
+            jnp.ones(eng.slots, bool), jax.random.key(0), eng.cfg,
+            eng.greedy, eng.temperature, eng.eos_id,
+            steps=eng.decode_chunk).compile().memory_analysis()
         return {"warm_s": time.perf_counter() - t0,
                 "programs": self._bench_compiles.since_mark(),
                 "program_argument_bytes": int(mem.argument_size_in_bytes),
@@ -95,7 +95,8 @@ class BenchReplica(_ContinuousLLMReplica):
 
     def bench_check(self, seed: int, lengths) -> dict:
         """`prefill_slots` then a decode step through the slot cache,
-        against the plain reference's full forward: logits compared. The
+        against the full forward of the configuration's plain reference
+        (`spec.load_architecture`): logits compared. The
         served programs return tokens only, so they are held to the
         reference through their tokens: the first token `prefill_slots`
         samples and the first one the `decode_slots` chunk program samples
@@ -107,12 +108,13 @@ class BenchReplica(_ContinuousLLMReplica):
         import jax.numpy as jnp
         import numpy as np
 
-        from benchmark.harness import reference
+        from benchmark.harness.reference import logits_agree
         from ray_tpu.models.engine import (_decode_one, decode_slots,
                                            prefill_slots)
         from ray_tpu.models.generate import _final_logits, _prefill_hidden
 
         eng, cfg, fields = self.engine, self.engine.cfg, self._bench_fields
+        arch, conf = self._bench_arch, self._bench_conf
         K = len(lengths)
         P = max(eng._bucket(n) for n in lengths)
         prompts = [traffic_gen.prompt_tokens(seed + i, n, cfg.vocab_size)
@@ -137,7 +139,7 @@ class BenchReplica(_ContinuousLLMReplica):
                 jnp.asarray(starts), jax.random.key(0), cfg, True, 1.0)
             pending = jnp.zeros(eng.slots, jnp.int32).at[slots].set(first)
             got_dec = decode_logits(eng.params, eng.cache, pending)[:K]
-            # the served chunk program, called as `_dispatch_locked` does
+            # the served chunk program, called as the scheduler calls it
             eng.cache, chunk = decode_slots(
                 eng.params, eng.cache,
                 pending.astype(eng._next_tok_dev.dtype),
@@ -150,17 +152,18 @@ class BenchReplica(_ContinuousLLMReplica):
                          "start": jnp.zeros_like(eng.cache["start"])}
         rows, ok = [], True
         for i, p in enumerate(prompts):
-            want = reference.reference_logits(
-                eng.params, p + [int(first[i])], fields, last=2)
-            pre = reference.logits_agree(got_pre[i], want[0], dtype)
-            dec = reference.logits_agree(got_dec[i], want[1], dtype)
+            want = arch.reference_logits(
+                eng.params, p + [int(first[i])], fields, conf, last=2)
+            pre = logits_agree(got_pre[i], want[0], dtype)
+            dec = logits_agree(got_dec[i], want[1], dtype)
             token_ok = _is_argmax(first[i], want[0], pre) \
                 and int(chunk[i, 0]) == int(first[i]) \
                 and _is_argmax(chunk[i, 1], want[1], dec)
             rows.append({"prompt_len": len(p), "prefill": pre,
                          "decode": dec, "served_tokens_ok": token_ok})
             ok = ok and pre["ok"] and dec["ok"] and token_ok
-        return {"ok": bool(ok), "bucket": P, "rows": rows}
+        return {"reference": spec.architecture_name(conf), "ok": bool(ok),
+                "bucket": P, "rows": rows}
 
     def bench_mark(self) -> dict:
         self._bench_compiles.mark()
@@ -181,69 +184,28 @@ class BenchReplica(_ContinuousLLMReplica):
     # ---- tracing (a traced run only) ------------------------------------
 
     def bench_trace_start(self, trace_dir: str) -> dict:
-        """Start the profiler and put `bench:` host spans around the
-        engine's own phases for as long as the trace runs (wrappers on
-        this instance's bound methods, taken off again at stop)."""
+        """Start the profiler; the engine's own `engine.*` spans land in
+        its trace. The counters are read once it has started: what the
+        engine does while it starts is not in the trace."""
         import jax
 
         from benchmark.harness import probes
 
-        eng = self.engine
-        gone = [n for n in ENGINE_HOOKS
-                if not callable(getattr(eng, n, None))]
-        if gone:
-            raise RuntimeError(
-                f"the engine no longer has {gone}: the traced run's host "
-                "spans and prefill_ms_per_ktok rest on them "
-                "(benchmark/harness/serve_cell.py ENGINE_HOOKS)")
-        ann = jax.profiler.TraceAnnotation
-        saved, padded = {}, {"tokens": 0, "dispatches": 0}
-
-        def wrap(name):
-            fn = getattr(eng, name)
-            saved[name] = fn
-
-            def spanned(*a, **kw):
-                with ann("bench:engine." + name.strip("_")):
-                    return fn(*a, **kw)
-            setattr(eng, name, spanned)
-
-        for name in ENGINE_SPANS:
-            wrap(name)
-        admit = eng._admit_group
-        saved["_admit_group"] = admit
-
-        def admit_counted(group):
-            P = max(eng._bucket(len(req.prompt)) for _, req in group)
-            padded["tokens"] += P * len(group)
-            padded["dispatches"] += 1
-            with ann("bench:engine.admit_group"):
-                return admit(group)
-        eng._admit_group = admit_counted
-        self._bench_trace = {"dir": trace_dir, "saved": saved,
-                             "padded": padded,
-                             "stats0": dict(eng.stats)}
         jax.profiler.start_trace(
-                trace_dir, profiler_options=probes.trace_options())
-        self._bench_trace["t0"] = time.perf_counter()
+            trace_dir, profiler_options=probes.trace_options())
+        self._bench_trace = {"dir": trace_dir,
+                             "stats0": dict(self.engine.stats),
+                             "t0": time.perf_counter()}
         return {"unix": time.time()}
 
     def bench_trace_stop(self) -> dict:
         import jax
 
-        tr, eng = self._bench_trace, self.engine
+        tr, now = self._bench_trace, dict(self.engine.stats)
         # counters first: stopping the profiler takes seconds, and what
         # the engine does meanwhile is not in the trace
         tr["wall_s"] = time.perf_counter() - tr["t0"]
-        tr["stats"] = {k: eng.stats[k] - tr["stats0"][k] for k in eng.stats}
-        tr["padded"] = dict(tr["padded"])
-        for name in tr["saved"]:
-            # the wrappers were instance attributes: drop them so the
-            # class's own methods are found again
-            try:
-                delattr(eng, name)
-            except AttributeError:
-                pass
+        tr["stats"] = {k: now[k] - tr["stats0"].get(k, 0) for k in now}
         jax.profiler.stop_trace()
         return {"wall_s": tr["wall_s"],
                 "stop_s": time.perf_counter() - tr["t0"] - tr["wall_s"]}
@@ -256,8 +218,10 @@ class BenchReplica(_ContinuousLLMReplica):
         red = xplane.reduce_trace(tr["dir"])
         red.pop("op_count", None)
         red["trace_wall_s"] = tr["wall_s"]
-        red["padded_prefill_tokens"] = tr["padded"]["tokens"]
-        red["prefill_dispatches"] = tr["padded"]["dispatches"]
+        # the engine's own counts, differenced over the traced seconds
+        red["padded_prefill_tokens"] = tr["stats"].get(
+            "prefill_padded_tokens", 0)
+        red["prefill_dispatches"] = tr["stats"].get("prefill_dispatches", 0)
         red["engine_in_trace"] = tr["stats"]
         return red
 
@@ -281,7 +245,8 @@ def _is_argmax(token, want_logits, agreement: dict) -> bool:
 # ---------------------------------------------------------------- driver
 
 def deploy(conf: dict, traffic: dict, seed: int, *, platform: str,
-           field_overrides=None, timeout_s: float = 900.0):
+           root: str = spec.ROOT, field_overrides=None,
+           timeout_s: float = 900.0):
     """`serve.run` of one BenchReplica; -> (handle, seconds until it
     answered, its `bench_info`)."""
     from ray_tpu import serve
@@ -294,8 +259,9 @@ def deploy(conf: dict, traffic: dict, seed: int, *, platform: str,
     app = deployment(BenchReplica, name="bench_llm").options(
         num_replicas=1, max_concurrent_queries=dep["max_concurrency"],
         ray_actor_options=_tpu_lease(1)).bind(
-            conf, platform=platform, field_overrides=field_overrides,
-            seed=spec.seed32(seed), **engine_kwargs)
+            conf, platform=platform, root=root,
+            field_overrides=field_overrides, seed=spec.seed32(seed),
+            **engine_kwargs)
     t0 = time.time()
     handle = serve.run(app, name="bench", route_prefix="/bench",
                        timeout_s=timeout_s)
@@ -435,15 +401,50 @@ def _tracer(handle, traffic: dict, trace_dir: str, t_open: float, out: dict):
     return th, stop
 
 
+class _Sleeper:
+    """A thread of this (driver) process, which never touches JAX or the
+    chip, that sleeps 10 ms at a time through the window and keeps its
+    longest gap between two wake-ups and when it was. Information only:
+    beside an engine stall it tells a machine that froze whole (the
+    sleeper stopped too) from a device queue that stopped (it did not)."""
+
+    STEP_S = 0.010
+
+    def __init__(self, t_open: float, window_s: float):
+        self.result = {"sleeps": 0, "longest_gap_s": 0.0,
+                       "longest_gap_at_s": None, "gaps_over_100ms": 0,
+                       "gaps_over_1s": 0}
+        self.thread = threading.Thread(
+            target=self._loop, args=(t_open, window_s), daemon=True,
+            name="bench-sleeper")
+        self.thread.start()
+
+    def _loop(self, t_open: float, window_s: float):
+        _sleep_until(t_open)
+        r, last = self.result, time.perf_counter()
+        while last < t_open + window_s:
+            time.sleep(self.STEP_S)
+            now = time.perf_counter()
+            gap = now - last
+            r["sleeps"] += 1
+            r["gaps_over_100ms"] += gap > 0.1
+            r["gaps_over_1s"] += gap > 1.0
+            if gap > r["longest_gap_s"]:
+                r["longest_gap_s"], r["longest_gap_at_s"] = \
+                    gap, last - t_open
+            last = now
+
+
 class _Window:
     """What both kinds of loop do around the measured window: mark the
-    replica's counters when it opens, read them when it closes, and in a
-    traced run trace a few seconds of it."""
+    replica's counters when it opens, read them when it closes, keep a
+    sleeper beside it, and in a traced run trace a few seconds of it."""
 
     def __init__(self, handle, traffic: dict, args, trace_dir, t_open: float,
                  window_s: float):
         self.extra: dict = {}
         self._tracer = None
+        self._sleeper = _Sleeper(t_open, window_s)
 
         def mark():
             _sleep_until(t_open)
@@ -463,8 +464,9 @@ class _Window:
                                    self.extra)
 
     def join(self) -> dict:
-        for t in self._threads:
+        for t in self._threads + [self._sleeper.thread]:
             t.join(timeout=60)
+        self.extra["sleeper"] = self._sleeper.result
         if self._tracer:
             threads, stop = self._tracer
             stop.set()
@@ -577,29 +579,29 @@ def reduce_closed_loop(sched: dict, records: list) -> dict:
                               if r["error"]})[:5]}
 
 
-def run(cell: dict, conf: dict, traffic: dict, args, *, platform="tpu",
-        field_overrides=None, trace_dir=None) -> dict:
+def run(cell: dict, conf: dict, traffic: dict, args, *, root: str,
+        platform="tpu", field_overrides=None, trace_dir=None) -> dict:
     """Driver side of a serve cell: deploy, warm, check, load, reduce."""
     from ray_tpu import serve
 
     try:
-        return _run(cell, conf, traffic, args, platform, field_overrides,
+        return _run(conf, traffic, args, root, platform, field_overrides,
                     trace_dir)
     finally:
         serve.shutdown()
 
 
-def _run(cell, conf, traffic, args, platform, field_overrides, trace_dir):
+def _run(conf, traffic, args, root, platform, field_overrides, trace_dir):
     out: dict = {}
     handle, info = deploy(conf, traffic, args.seed, platform=platform,
-                          field_overrides=field_overrides)
+                          root=root, field_overrides=field_overrides)
     out["info"] = info
     out["chip_worker_ready_s"] = info["chip_worker_ready_s"]
     out["warm"] = call(handle, "bench_warm")
     chk = traffic["check"]
     out["check"] = call(handle, "bench_check", spec.seed32(args.seed),
                         chk["prompt_lens"])
-    vocab = spec.transformer_fields(conf)["vocab_size"]
+    vocab = spec.transformer_fields(conf, root)["vocab_size"]
     if field_overrides and "vocab_size" in field_overrides:
         vocab = field_overrides["vocab_size"]
     out["repeat"] = repeat_check(handle, vocab, spec.seed32(args.seed))
@@ -616,6 +618,12 @@ def _run(cell, conf, traffic, args, platform, field_overrides, trace_dir):
             raise RuntimeError("the traced window failed: "
                                + out["trace_error"])
         out["trace"] = call(handle, "bench_trace_reduce")
+    # the engine's heartbeat, every run: what stalled, on which thread,
+    # for how long and when (`at_s`: seconds from the window's opening;
+    # past the window it is the tail or a traced run's trace reduction)
+    out["slow_events"] = [
+        dict(ev, at_s=ev["t_wall"] - out["window_open_unix"])
+        for ev in call(handle, "engine_slow_events")]
     return out
 
 

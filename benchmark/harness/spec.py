@@ -7,6 +7,7 @@ per-layer metric is a file found BY NAME from `BENCHMARK.json`:
     benchmark/traffic/<traffic>.json         kind + parameters of a mix
     benchmark/layer_metrics/<metric>.json    layer, moves, cells, reader
     benchmark/readers/<reader>.py            one function: evidence -> number
+    benchmark/architectures/<name>.py        plain reference + required work
 
 so a later PR adds a cell or a metric by adding files and one entry, and
 edits nothing here. This module imports neither JAX nor the program: the
@@ -15,6 +16,7 @@ driver process reads it and must stay off the chip.
 
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 import os
@@ -24,6 +26,10 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 NAME_RE = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 TRAFFIC_KINDS = ("train", "open_loop", "closed_loop")
+# the block a config file means when it names no `architecture`
+DEFAULT_ARCHITECTURE = "dense_gqa"
+ARCHITECTURE_INTERFACE = ("reference_logits", "forward_flops_per_token",
+                          "num_params")
 
 
 class SpecError(ValueError):
@@ -100,22 +106,49 @@ def load_layer_metric(name: str, root: str = ROOT) -> dict:
     return m
 
 
+@functools.lru_cache(maxsize=None)
+def _load_module(kind: str, name: str, root: str):
+    """benchmark/<kind>/<name>.py as a module, loaded by path so that a
+    new one is a new file and nothing else (once per process: a
+    reference keeps its jitted block between calls)."""
+    if not isinstance(name, str) or not NAME_RE.match(name):
+        raise SpecError(f"bad name {name!r} for a file under "
+                        f"benchmark/{kind}/")
+    path = os.path.join(bench_dir(root), kind, name + ".py")
+    if not os.path.exists(path):
+        raise SpecError(f"missing file {path}")
+    modspec = importlib.util.spec_from_file_location(
+        f"benchmark_{kind}_{re.sub(r'[^A-Za-z0-9_]', '_', name)}", path)
+    mod = importlib.util.module_from_spec(modspec)
+    modspec.loader.exec_module(mod)
+    return mod
+
+
 def load_reader(metric: dict, root: str = ROOT):
     """The function `read(evidence, metric) -> float | None` in
-    benchmark/readers/<reader>.py, loaded by path so that a new reader is
-    a new file and nothing else."""
-    reader = metric["reader"]
-    if not NAME_RE.match(reader):
-        raise SpecError(f"bad reader name {reader!r}")
-    path = os.path.join(bench_dir(root), "readers", reader + ".py")
-    spec = importlib.util.spec_from_file_location(
-        f"benchmark_reader_{reader.replace('.', '_').replace('-', '_')}",
-        path)
-    if spec is None or not os.path.exists(path):
-        raise SpecError(f"missing reader file {path}")
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    benchmark/readers/<reader>.py."""
+    return _load_module("readers", metric["reader"], root).read
+
+
+def architecture_name(conf: dict) -> str:
+    return conf.get("architecture", DEFAULT_ARCHITECTURE)
+
+
+def load_architecture(conf: dict, root: str = ROOT):
+    """The module benchmark/architectures/<name>.py that ``conf`` names
+    under `architecture` (absent: the dense GQA block): the plain
+    reference that decides `correct` for the configuration and the work
+    its forward pass requires (benchmark/README.md gives the
+    interface). The driver process loads it for the counts, so the
+    module imports JAX only inside the functions that compute."""
+    name = architecture_name(conf)
+    mod = _load_module("architectures", name, root)
+    lacks = [f for f in ARCHITECTURE_INTERFACE
+             if not callable(getattr(mod, f, None))]
+    if lacks:
+        raise SpecError(f"architecture {name!r} ({mod.__file__}) lacks "
+                        f"{lacks}")
+    return mod
 
 
 def read_layer_metrics(bench: dict, cell_name: str, evidence: dict,
@@ -134,24 +167,35 @@ def read_layer_metrics(bench: dict, cell_name: str, evidence: dict,
 
 # ---- configuration -> TransformerConfig ----------------------------------
 
-def transformer_fields(conf: dict) -> dict:
+def transformer_fields(conf: dict, root: str = ROOT) -> dict:
     """TransformerConfig fields (plain Python values) from a config file:
-    `mapping` says which published key feeds which field."""
-    fields = {}
-    for published, field in conf["mapping"].items():
-        if published not in conf:
-            raise SpecError(f"config {conf.get('name')}: mapping names "
-                            f"{published!r}, which the file does not hold")
-        fields[field] = conf[published]
+    `mapping` says which published key feeds which field, unless the
+    configuration's architecture module brings a `fields(conf)` of its
+    own (published keys that do not map one to one)."""
+    own = getattr(load_architecture(conf, root), "fields", None)
+    if own is not None:
+        fields = dict(own(conf))
+        carried = "head_dim" in fields
+    else:
+        fields = {}
+        for published, field in conf["mapping"].items():
+            if published not in conf:
+                raise SpecError(
+                    f"config {conf.get('name')}: mapping names "
+                    f"{published!r}, which the file does not hold")
+            fields[field] = conf[published]
+        carried = "head_dim" in conf["mapping"]
     head_dim = conf.get("head_dim")
-    if head_dim is not None and \
+    if head_dim is not None and not carried and \
             head_dim * fields["n_heads"] != fields["d_model"]:
-        raise SpecError("TransformerConfig derives head_dim as d_model / "
-                        "n_heads; this config's head_dim differs")
+        raise SpecError(
+            f"config {conf.get('name')}: head_dim {head_dim} is not "
+            "d_model / n_heads and nothing carries it to a field, so the "
+            "program would derive another")
     return fields
 
 
-def build_transformer_config(conf: dict, **overrides):
+def build_transformer_config(conf: dict, root: str = ROOT, **overrides):
     """The program's TransformerConfig for ``conf`` (imports the program,
     and with it JAX: call it only in a process that may hold the chip or
     in tests). dtype names in ``overrides`` are given as strings."""
@@ -159,7 +203,7 @@ def build_transformer_config(conf: dict, **overrides):
 
     from ray_tpu.models.config import TransformerConfig
 
-    fields = transformer_fields(conf)
+    fields = transformer_fields(conf, root)
     for key, val in overrides.items():
         if key in ("dtype", "param_dtype") and isinstance(val, str):
             val = jnp.dtype(val)

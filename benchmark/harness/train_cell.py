@@ -13,6 +13,8 @@ from __future__ import annotations
 import math
 import time
 
+from benchmark.harness import spec
+
 
 def _make_batch(seed: int, step: int, rows: int, seq: int, vocab: int):
     import numpy as np
@@ -24,16 +26,18 @@ def _make_batch(seed: int, step: int, rows: int, seq: int, vocab: int):
                                    dtype=np.int32)}
 
 
-def check_against_reference(params, cfg, fields, mesh, seed: int,
-                            rows: int, seq: int) -> dict:
+def check_against_reference(params, cfg, fields, conf, arch, mesh,
+                            seed: int, rows: int, seq: int) -> dict:
     """The program's forward and loss on ``rows`` seeded rows of ``seq``
-    tokens against the plain float32 reference, row by row."""
+    tokens against the plain float32 reference of the configuration's
+    architecture (``arch``, from `spec.load_architecture`), row by row."""
     import functools
 
     import jax
     import jax.numpy as jnp
 
-    from benchmark.harness import reference
+    from benchmark.harness.reference import (LOSS_ABS_TOL, logits_agree,
+                                             reference_loss)
     from ray_tpu.models.transformer import forward, loss_fn
 
     batch = _make_batch(seed ^ 0x5EED, 0, rows, seq, cfg.vocab_size)
@@ -45,17 +49,17 @@ def check_against_reference(params, cfg, fields, mesh, seed: int,
     dtype = jnp.dtype(cfg.dtype).name
     worst, ref_losses = None, []
     for r in range(rows):
-        want = reference.reference_logits(params, tokens[r, :-1], fields)
-        res = reference.logits_agree(got_logits[r], want, dtype)
-        ref_losses.append(float(reference.reference_loss(
-            want, tokens[r, 1:])))
+        want = arch.reference_logits(params, tokens[r, :-1], fields, conf)
+        res = logits_agree(got_logits[r], want, dtype)
+        ref_losses.append(float(reference_loss(want, tokens[r, 1:])))
         if worst is None or res["rel_rms_error"] > worst["rel_rms_error"]:
             worst = res
         del want
     del got_logits
     ref_loss = sum(ref_losses) / rows
-    loss_tol = reference.LOSS_ABS_TOL[dtype]
-    return {"logits": worst, "loss": got_loss, "reference_loss": ref_loss,
+    loss_tol = LOSS_ABS_TOL[dtype]
+    return {"reference": spec.architecture_name(conf),
+            "logits": worst, "loss": got_loss, "reference_loss": ref_loss,
             "loss_abs_diff": abs(got_loss - ref_loss),
             "loss_tolerance": loss_tol, "rows": rows, "seq": seq,
             "ok": bool(worst["ok"] and math.isfinite(got_loss)
@@ -69,7 +73,7 @@ def train_loop(config):
     import jax
     import jax.numpy as jnp
 
-    from benchmark.harness import probes, spec, xplane
+    from benchmark.harness import probes, xplane
     from ray_tpu import train
     from ray_tpu.models.training import (init_train_state, make_optimizer,
                                          make_train_step, state_shardings)
@@ -86,10 +90,11 @@ def train_loop(config):
                            f"found {device['count']}")
     traffic, conf, seed = config["traffic"], config["conf"], config["seed"]
     rows, seq = traffic["rows"], traffic["seq_len"]
-    fields = spec.transformer_fields(conf)
+    root = config["root"]
+    fields = spec.transformer_fields(conf, root)
     fields.update(config.get("field_overrides") or {})
     cfg = spec.build_transformer_config(
-        conf, max_seq_len=seq, param_dtype=traffic["param_dtype"],
+        conf, root, max_seq_len=seq, param_dtype=traffic["param_dtype"],
         attention_impl=traffic["attention_impl"],
         **(config.get("field_overrides") or {}))
     tx = make_optimizer(traffic["learning_rate"],
@@ -113,8 +118,8 @@ def train_loop(config):
         params = jax.jit(lambda k: init_params(k, cfg), out_shardings=
                          state_shardings(cfg, tx, mesh)["params"])(key)
     out["check"] = check_against_reference(
-        params, cfg, fields, mesh, seed, check["rows"],
-        check.get("seq_len", seq))
+        params, cfg, fields, conf, spec.load_architecture(conf, root), mesh,
+        seed, check["rows"], check.get("seq_len", seq))
     del params
     out["check_s"] = time.perf_counter() - t0
 
@@ -202,8 +207,8 @@ def train_loop(config):
     train.report(out)
 
 
-def run(cell: dict, conf: dict, traffic: dict, args, *, platform="tpu",
-        field_overrides=None, trace_dir=None) -> dict:
+def run(cell: dict, conf: dict, traffic: dict, args, *, root: str,
+        platform="tpu", field_overrides=None, trace_dir=None) -> dict:
     """Driver side: one JaxTrainer run of `train_loop`; returns what the
     loop reported plus `chip_worker_ready_s`."""
     from ray_tpu.train import JaxTrainer, ScalingConfig
@@ -213,7 +218,7 @@ def run(cell: dict, conf: dict, traffic: dict, args, *, platform="tpu",
     result = JaxTrainer(
         train_loop,
         train_loop_config=dict(
-            conf=conf, traffic=traffic, seed=args.seed,
+            conf=conf, traffic=traffic, seed=args.seed, root=root,
             seconds=args.seconds, platform=platform, chips=cell["chips"],
             field_overrides=field_overrides,
             trace_dir=trace_dir if args.trace else None),

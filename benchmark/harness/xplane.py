@@ -1,7 +1,9 @@
 """Reduction of a JAX profiler trace (`.xplane.pb`) to what the per-layer
 metrics read: device busy and idle time, time per operation (self time, so
 a `while` does not count its body twice), collective time no compute
-covers, and the longest idle gaps named by the host span they fall in.
+covers, and the longest idle gaps named by the host span they fall in
+(the benchmark's `bench:` spans around the train loop, the serving
+engine's own `engine.*` spans).
 
 Pure functions over (name, start_ns, duration_ns) tuples, plus one loader
 that needs nothing but JAX (`jax.profiler.ProfileData`). Only the process
@@ -23,7 +25,8 @@ Event = Tuple[str, int, int]         # (name, start_ns, duration_ns)
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OP_LINE = "XLA Ops"
 MODULE_LINE = "XLA Modules"
-SPAN_PREFIX = "bench:"               # the benchmark's own host spans
+BENCH_SPAN = "bench:"                # the benchmark's own host spans
+SPAN_PREFIXES = (BENCH_SPAN, "engine.")   # ... and the serving engine's
 COLLECTIVE = re.compile(
     r"^(all-gather|all-reduce|reduce-scatter|all-to-all|"
     r"collective-permute|collective-broadcast)")
@@ -116,7 +119,8 @@ def reduce_planes(planes: Dict[str, Dict[str, List[Event]]], *,
 
     Device planes are those named `/device:TPU:<n>`; their `XLA Ops` line
     holds one event per executed HLO instruction. Host planes contribute
-    the spans whose names start with `bench:`. ``window`` (ns) restricts
+    the benchmark's spans (`bench:<name>`, named without the prefix) and
+    the program's (`engine.<state>`). ``window`` (ns) restricts
     everything to the traced steady window; default: from the first to the
     last device event.
     """
@@ -131,10 +135,10 @@ def reduce_planes(planes: Dict[str, Dict[str, List[Event]]], *,
                for ev in (l.get(MODULE_LINE) or l[OP_LINE])]
         window = (min(ev[1] for ev in evs), max(ev[1] + ev[2] for ev in evs))
     lo, hi = window
-    spans = sorted((s, s + d, n[len(SPAN_PREFIX):])
+    spans = sorted((s, s + d, n.removeprefix(BENCH_SPAN))
                    for p, lines in planes.items() if p not in dev
                    for evs in lines.values() for n, s, d in evs
-                   if n.startswith(SPAN_PREFIX))
+                   if n.startswith(SPAN_PREFIXES))
     host_spans: Dict[str, Dict[str, float]] = {}
     for s, e, name in spans:
         if e > lo and s < hi:
@@ -187,13 +191,13 @@ def reduce_planes(planes: Dict[str, Dict[str, List[Event]]], *,
             gaps.items(), key=lambda kv: -kv[1])[:top]],
         "modules": {k: {"count": len(v) / n, "seconds": sum(v) / 1e9 / n}
                     for k, v in modules.items()},
-        # the benchmark's own host spans inside the window, by name
+        # the host spans inside the window, by name
         "host_spans": host_spans,
     }
 
 
 def _owner(spans, s: int, e: int) -> str:
-    """Name of the innermost `bench:` host span covering most of [s, e)."""
+    """Name of the innermost host span covering most of [s, e)."""
     best, best_cover, best_len = UNATTRIBUTED, 0, None
     for ss, se, name in spans:
         if ss >= e:
@@ -233,8 +237,8 @@ def find_xplane(trace_dir: str) -> Optional[str]:
 
 def load_planes(path: str) -> Dict[str, Dict[str, List[Event]]]:
     """Planes of an `.xplane.pb` (or `.xplane.pb.gz`) as plain tuples. Of
-    host planes only the `bench:` spans are kept (a host plane holds every
-    Python frame)."""
+    host planes only the `bench:` and `engine.` spans are kept (a host
+    plane holds every Python frame)."""
     from jax.profiler import ProfileData
 
     if path.endswith(".gz"):
@@ -253,7 +257,7 @@ def load_planes(path: str) -> Dict[str, Dict[str, List[Event]]]:
                 continue
             evs = [(ev.name, int(ev.start_ns), int(ev.duration_ns))
                    for ev in line.events
-                   if is_dev or ev.name.startswith(SPAN_PREFIX)]
+                   if is_dev or ev.name.startswith(SPAN_PREFIXES)]
             if evs:
                 lines.setdefault(line.name, []).extend(evs)
         if lines:

@@ -75,7 +75,7 @@ def run_cell(bench: dict, cell: dict, args, *, root: str = spec.ROOT,
         shutil.rmtree(trace_dir, ignore_errors=True)
         os.makedirs(trace_dir, exist_ok=True)
     mod = train_cell if kind == "train" else serve_cell
-    out = mod.run(cell, conf, traffic, args, platform=platform,
+    out = mod.run(cell, conf, traffic, args, root=root, platform=platform,
                   field_overrides=field_overrides, trace_dir=trace_dir)
     device = out["device"] if kind == "train" else out["info"]["device"]
     if platform == "tpu":
@@ -98,7 +98,7 @@ def run_cell(bench: dict, cell: dict, args, *, root: str = spec.ROOT,
         failed = out["client"]["failed"]
         peak = out["counters"]["memory_peak_bytes"]
         compilations = out["counters"]["compilations"]
-    fields = spec.transformer_fields(conf)
+    fields = spec.transformer_fields(conf, root)
     fields.update(field_overrides or {})
     _info(cell=cell["name"], device=device, checks=checks,
           compilations_in_window=compilations,
@@ -107,7 +107,8 @@ def run_cell(bench: dict, cell: dict, args, *, root: str = spec.ROOT,
           **{k: out[k] for k in (
               "compile_s", "check_s", "program_argument_bytes",
               "program_temp_bytes", "state_bytes", "steps", "window_s",
-              "warm", "info", "repeat", "n_requests_sent") if k in out},
+              "warm", "info", "repeat", "n_requests_sent", "slow_events",
+              "sleeper") if k in out},
           first_token_ms={
               "mean": stats.mean(out["client"]["ttft_ms"]),
               **{f"p{q}": stats.percentile(out["client"]["ttft_ms"], q)
@@ -133,7 +134,8 @@ def run_cell(bench: dict, cell: dict, args, *, root: str = spec.ROOT,
         trace = out.get("trace") or {}
         evidence = {"cell": cell, "conf": conf, "traffic": traffic,
                     "fields": fields, "peaks": peaks, "out": out,
-                    "trace": trace, "end_to_end": e2e, "kind": kind}
+                    "trace": trace, "end_to_end": e2e, "kind": kind,
+                    "root": root}
         line["metrics"] = spec.read_layer_metrics(bench, cell["name"],
                                                   evidence, root)
         dev_line["busy_s"] = trace.get("busy_s")

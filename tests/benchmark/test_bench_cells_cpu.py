@@ -41,19 +41,23 @@ def cpu_cluster():
 
 
 def _run(cell_name, trace, seconds=2.0, seed=2 ** 31 + 11):
+    """-> (last line, the information line) of one toy-size run."""
     # a CPU "chip" count of 1 keeps the lease check meaningful; the mesh
     # of the sharded cell is built over the CPU's virtual devices
     cell = dict(spec.find_cell(BENCH, cell_name), chips=1)
     args = argparse.Namespace(seed=seed, seconds=seconds, trace=trace)
-    return RUN.run_cell(BENCH, cell, args, platform="cpu",
-                        field_overrides=TINY,
-                        traffic_overrides=TOY[cell_name])
+    return bench_paths.run_cell_with_info(
+        RUN, BENCH, cell, args, platform="cpu", field_overrides=TINY,
+        traffic_overrides=TOY[cell_name])
 
 
 @pytest.mark.parametrize("cell", sorted(TOY))
 def test_cell_runs_end_to_end_at_toy_size(cpu_cluster, cell):
-    line = _run(cell, trace=0, seconds=3.0 if "chat" in cell else 2.0)
+    line, info = _run(cell, trace=0,
+                      seconds=3.0 if "chat" in cell else 2.0)
     assert line["correct"] is True, line
+    # the check names the architecture module that judged the cell
+    assert info["check"]["reference"] == "dense_gqa" and info["check"]["ok"]
     assert line["failed"] == 0 and line["attempted"] >= 1
     assert line["device"]["platform"] == "cpu"
     declared = {m["name"] for m in spec.metrics_for(BENCH, cell,
@@ -63,13 +67,22 @@ def test_cell_runs_end_to_end_at_toy_size(cpu_cluster, cell):
         assert m["value"] > 0 and m["unit"]
     if "chat" in cell:   # a fixed request count: rate x window
         assert line["attempted"] == round(TOY_SERVE["rate_per_s"] * 3.0)
+    if "train" not in cell:
+        # every serve run, traced or not, prints the engine's heartbeat
+        # and the sleeper that ran beside the window (10 ms a sleep)
+        assert info["slow_events"] == []
+        sleeper = info["sleeper"]
+        assert sleeper["sleeps"] >= 50
+        assert 0.010 <= sleeper["longest_gap_s"] < 2.0
+        assert 0 <= sleeper["longest_gap_at_s"] <= 3.0
+        assert sleeper["gaps_over_1s"] == 0
 
 
 @pytest.mark.parametrize("cell", ["internlm2-1.8b.train-4k",
                                   "internlm2-1.8b.chat-steady"])
 def test_traced_run_reports_layer_metrics_and_refuses_a_deviceless_trace(
         cpu_cluster, cell):
-    line = _run(cell, trace=1, seconds=3.0 if "chat" in cell else 2.0)
+    line, _ = _run(cell, trace=1, seconds=3.0 if "chat" in cell else 2.0)
     # readers that need a device trace return nothing on the CPU and are
     # left out; those fed by counters, spans and the host clock report
     assert "chip_worker_ready_s" in line["metrics"]
@@ -87,37 +100,6 @@ def test_traced_run_reports_layer_metrics_and_refuses_a_deviceless_trace(
     # no operation ran on a TPU: such a traced run is never `correct`
     assert line["correct"] is False and not line["device"]["busy_s"]
     assert set(line["breakdown"]) == {"device_ops", "idle_gaps"}
-
-
-@pytest.fixture(scope="module")
-def toy_replica():
-    from benchmark.harness import serve_cell
-
-    conf = spec.load_config(BENCH, "internlm2-1.8b")
-    dep = {k: v for k, v in TOY_DEPLOYMENT.items() if k != "max_concurrency"}
-    rep = serve_cell.BenchReplica(conf, platform="cpu", field_overrides=TINY,
-                                  seed=7, **dep)
-    yield rep
-    rep.engine.shutdown()
-
-
-@pytest.mark.parametrize("hook", ["_admit_locked", "_dispatch_locked",
-                                  "_fetch_chunks", "_deliver_locked",
-                                  "_admit_group"])
-def test_a_traced_run_fails_loudly_when_an_engine_hook_is_gone(
-        toy_replica, tmp_path, hook):
-    """The host spans and `prefill_ms_per_ktok` rest on private names of
-    the engine: a rename must stop the traced run, not empty a metric."""
-    from benchmark.harness import serve_cell
-
-    assert hook in serve_cell.ENGINE_HOOKS
-    setattr(toy_replica.engine, hook, None)   # as if it were renamed
-    try:
-        with pytest.raises(RuntimeError, match=hook):
-            toy_replica.bench_trace_start(str(tmp_path))
-    finally:
-        delattr(toy_replica.engine, hook)
-    assert callable(getattr(toy_replica.engine, hook))
 
 
 @pytest.mark.parametrize("token,logits,rel_err,want", [

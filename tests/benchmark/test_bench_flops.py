@@ -1,4 +1,7 @@
-"""FLOP and byte functions against counts made by hand."""
+"""FLOP and byte functions against counts made by hand: the dense GQA
+block's own counts (`benchmark/architectures/dense_gqa.py`, found as a
+config that names no architecture finds it) and what no architecture owns
+(`benchmark/harness/flops.py`)."""
 
 import pytest
 
@@ -7,6 +10,7 @@ from benchmark.harness import flops, spec
 
 BENCH = spec.load_benchmark()
 PEAKS = spec.device_peaks("TPU v5 lite")
+DENSE = spec.load_architecture({})
 
 
 def _fields(name, layers=None):
@@ -24,17 +28,18 @@ def _fields(name, layers=None):
 def test_parameter_counts_at_published_depth(name, layers, per_layer,
                                              total_params):
     f = _fields(name, layers)
-    mm = flops.matmul_params(f)
+    mm = DENSE.matmul_params(f)
     assert mm["per_layer"] == per_layer
     assert mm["head"] == f["d_model"] * f["vocab_size"]
-    assert flops.num_params(f) == total_params
+    assert DENSE.num_params(f, {}) == total_params
 
 
 @pytest.mark.parametrize("name", ["internlm2-1.8b", "mistral-7b-v0.3"])
 def test_num_params_agrees_with_the_programs_own_count(name):
     conf = spec.load_config(BENCH, name)
     cfg = spec.build_transformer_config(conf)
-    assert flops.num_params(spec.transformer_fields(conf)) == cfg.num_params
+    assert spec.load_architecture(conf).num_params(
+        spec.transformer_fields(conf), conf) == cfg.num_params
 
 
 @pytest.mark.parametrize("seq", [1, 2048, 4096])
@@ -45,9 +50,9 @@ def test_forward_flops_by_hand_on_a_one_layer_model(seq):
     matmul = 2 * (576 + 80)
     # per query: QK^T and PV, 2 * hd(4) * heads(2) each per key, (seq+1)/2
     attn = 2 * (2 * 4 * 2) * (seq + 1) / 2
-    assert flops.forward_flops_per_token(f, seq) == pytest.approx(
-        matmul + attn)
-    assert flops.train_flops_per_token(f, seq) == pytest.approx(
+    forward = DENSE.forward_flops_per_token(f, {}, seq)
+    assert forward == pytest.approx(matmul + attn)
+    assert flops.train_from_forward(forward) == pytest.approx(
         3 * (matmul + attn))
 
 

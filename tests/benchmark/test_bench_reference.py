@@ -1,4 +1,6 @@
-"""The plain float32 reference against the program, at toy size on the CPU:
+"""The dense GQA block's plain float32 reference
+(`benchmark/architectures/dense_gqa.py`) against the program, at toy size
+on the CPU:
 `models/transformer.py`'s forward and loss, and prefill-then-decode through
 the engine's slot cache. The same comparison runs at published widths on
 the chip inside every cell."""
@@ -9,9 +11,11 @@ import numpy as np
 import pytest
 
 import bench_paths  # noqa: F401
-from benchmark.harness import reference
+from benchmark.harness import reference, spec
 from ray_tpu.models.config import TransformerConfig
 from ray_tpu.models.transformer import forward, init_params, loss_fn
+
+DENSE = spec.load_architecture({})   # what a config naming none gets
 
 SHAPES = {
     "gqa": dict(n_heads=4, n_kv_heads=2),
@@ -48,7 +52,7 @@ def test_forward_agrees_with_the_reference(shape, seq):
     params = init_params(jax.random.key(1), cfg)
     toks = _tokens(cfg, seq)
     got = forward(params, jnp.asarray(toks)[None], cfg)[0]
-    want = reference.reference_logits(params, toks, _fields(cfg))
+    want = DENSE.reference_logits(params, toks, _fields(cfg), {})
     res = reference.logits_agree(got, want, "float32")
     assert res["ok"], res
 
@@ -60,7 +64,7 @@ def test_loss_agrees_with_the_reference(shape):
     toks = _tokens(cfg, 41)
     got, _ = loss_fn(params, {"tokens": jnp.asarray(toks)[None]}, cfg)
     want = reference.reference_loss(
-        reference.reference_logits(params, toks[:-1], _fields(cfg)),
+        DENSE.reference_logits(params, toks[:-1], _fields(cfg), {}),
         toks[1:])
     assert abs(float(got) - float(want)) <= \
         reference.LOSS_ABS_TOL["float32"]
@@ -71,8 +75,9 @@ def test_last_positions_are_the_tail_of_the_full_logits(last):
     cfg = _cfg("gqa")
     params = init_params(jax.random.key(3), cfg)
     toks = _tokens(cfg, 19)
-    full = reference.reference_logits(params, toks, _fields(cfg))
-    tail = reference.reference_logits(params, toks, _fields(cfg), last=last)
+    full = DENSE.reference_logits(params, toks, _fields(cfg), {})
+    tail = DENSE.reference_logits(params, toks, _fields(cfg), {},
+                                  last=last)
     np.testing.assert_array_equal(np.asarray(full[-last:]),
                                   np.asarray(tail))
 
@@ -84,7 +89,7 @@ def test_bf16_compute_where_float32_is_stated_fails(shape):
     cfg32, cfg16 = _cfg(shape), _cfg(shape, dtype=jnp.bfloat16)
     params = init_params(jax.random.key(4), cfg32)
     toks = _tokens(cfg32, 33)
-    want = reference.reference_logits(params, toks, _fields(cfg32))
+    want = DENSE.reference_logits(params, toks, _fields(cfg32), {})
     got16 = forward(params, jnp.asarray(toks)[None], cfg16)[0]
     assert not reference.logits_agree(got16, want, "float32")["ok"]
     # ... while bf16 stated as bf16 passes its own, looser bound
@@ -139,8 +144,9 @@ def test_prefill_then_decode_through_the_slot_cache(shape, lengths):
     pending = jnp.zeros(slots, jnp.int32).at[rows].set(first)
     _, got_dec = _decode_one(params, cache, pending, cfg)
     for i, p in enumerate(prompts):
-        want = reference.reference_logits(
-            params, [int(t) for t in p] + [int(first[i])], fields, last=2)
+        want = DENSE.reference_logits(
+            params, [int(t) for t in p] + [int(first[i])], fields, {},
+            last=2)
         assert reference.logits_agree(got_pre[i], want[0], "float32")["ok"]
         assert reference.logits_agree(got_dec[i], want[1], "float32")["ok"]
         assert int(first[i]) == int(np.argmax(np.asarray(want[0])))

@@ -169,3 +169,74 @@ def test_peaks_table_names_its_source_and_the_v5e():
     v5e = peaks["devices"]["TPU v5 lite"]
     assert v5e["bf16_flops_per_s"] == 197e12
     assert v5e["hbm_bytes_per_s"] == 819e9
+
+
+# ---- an architecture is a file ---------------------------------------------
+
+def test_every_config_names_an_architecture_file_with_the_interface():
+    for entry in BENCH["configs"]:
+        conf = spec.load_config(BENCH, entry["name"])
+        # the two dense configurations name none and get the dense block
+        assert "architecture" not in conf
+        assert spec.architecture_name(conf) == "dense_gqa"
+        mod = spec.load_architecture(conf)
+        assert os.path.basename(mod.__file__) == "dense_gqa.py"
+        for fn in spec.ARCHITECTURE_INTERFACE:
+            assert callable(getattr(mod, fn))
+        assert mod is spec.load_architecture(conf)   # once per process
+
+
+def test_an_architecture_file_without_the_interface_is_refused(tmp_path):
+    d = tmp_path / "benchmark" / "architectures"
+    d.mkdir(parents=True)
+    (d / "half.py").write_text(
+        "def reference_logits(params, tokens, fields, conf, last=0):\n"
+        "    return None\n")
+    with pytest.raises(spec.SpecError, match="forward_flops_per_token"):
+        spec.load_architecture({"architecture": "half"}, str(tmp_path))
+
+
+def test_loading_an_architecture_imports_no_jax():
+    """The driver process loads the module for its counts and must stay
+    off JAX (`run.py` refuses a driver that imported it)."""
+    import subprocess
+    import sys
+
+    code = ("import sys; sys.path.insert(0, %r); "
+            "from benchmark.harness import spec; "
+            "b = spec.load_benchmark(); "
+            "c = spec.load_config(b, 'internlm2-1.8b'); "
+            "a = spec.load_architecture(c); "
+            "f = spec.transformer_fields(c); "
+            "print(a.forward_flops_per_token(f, c, 4096), "
+            "a.num_params(f, c), 'jax' in sys.modules)"
+            % bench_paths.REPO)
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=60, check=True).stdout.split()
+    assert out[2] == "False" and float(out[0]) > 1e9 and int(out[1]) > 1e9
+
+
+_HEADS = {"hidden_size": 64, "num_attention_heads": 4, "name": "t",
+          "mapping": {"hidden_size": "d_model",
+                      "num_attention_heads": "n_heads"}}
+
+
+@pytest.mark.parametrize("head_dim,carried,ok", [
+    (16, False, True),    # what the program derives: nothing to carry
+    (32, True, True),     # not hidden / heads, but the mapping carries it
+    (32, False, False),   # ... and nothing does: the program would differ
+    (None, False, True)])
+def test_a_head_dim_is_refused_only_where_nothing_carries_it(head_dim,
+                                                             carried, ok):
+    conf = dict(_HEADS, mapping=dict(_HEADS["mapping"]))
+    if head_dim is not None:
+        conf["head_dim"] = head_dim
+    if carried:
+        conf["mapping"]["head_dim"] = "head_dim"
+    if not ok:
+        with pytest.raises(spec.SpecError, match="head_dim 32"):
+            spec.transformer_fields(conf)
+        return
+    fields = spec.transformer_fields(conf)
+    assert fields["d_model"] == 64 and fields["n_heads"] == 4
+    assert fields.get("head_dim") == (32 if carried else None)

@@ -224,23 +224,26 @@ def test_train_mfu_reader_is_tokens_of_the_traced_steps_over_the_trace(
     """The reader's arithmetic at the cells' real sizes, on hand-made
     evidence: required FLOPs per token x (steps x rows x seq_len) over
     the trace's own window, over chips x the peaks table's bf16 peak."""
-    from benchmark.harness import flops, spec
+    from benchmark.harness import spec
 
     bench = spec.load_benchmark()
     c = spec.find_cell(bench, cell)
     traffic = spec.load_traffic(c["traffic"])
-    fields = spec.transformer_fields(spec.load_config(bench, c["config"]))
+    conf = spec.load_config(bench, c["config"])
+    fields = spec.transformer_fields(conf)
     peaks = spec.device_peaks("TPU v5 lite")
     read = spec.load_reader({"reader": "train_mfu"})
     got = read({"trace": {"window_s": window_s},
                 "out": {"trace_steps": steps}, "traffic": traffic,
-                "fields": fields, "peaks": peaks, "cell": c}, {})
+                "fields": fields, "conf": conf, "peaks": peaks, "cell": c},
+               {})
     if lo is None:
         assert got is None
         return
     assert lo < got < hi
     tokens = steps * traffic["rows"] * traffic["seq_len"]
-    by_hand = 100.0 * flops.train_flops_per_token(
-        fields, traffic["seq_len"]) * tokens / window_s \
+    dense = spec.load_architecture({})   # the dense count, by name
+    by_hand = 100.0 * 3.0 * dense.forward_flops_per_token(
+        fields, conf, traffic["seq_len"]) * tokens / window_s \
         / (c["chips"] * peaks["bf16_flops_per_s"])
     assert got == pytest.approx(by_hand, rel=1e-9)
