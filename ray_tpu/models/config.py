@@ -44,13 +44,18 @@ class TransformerConfig:
     # Microbatches for pipeline parallelism (mesh pipeline axis > 1);
     # None -> 2 * n_stages. Bubble fraction is (S-1)/(M+S-1).
     pipeline_microbatches: Optional[int] = None
-    # Mixture-of-Experts FFN (models/moe.py): 0 = dense. Experts shard over
-    # the `expert` mesh axis; top-k routing with renormalized combine
-    # weights; capacity C = ceil(T*k/E * capacity_factor).
+    # Mixture-of-Experts FFN (models/moe.py): 0 = dense, else the number
+    # of experts, each a SwiGLU of width d_ff. Dropless top-k routing over
+    # a softmax of all experts; the k kept weights are renormalised to sum
+    # to 1 (Mixtral) or left as they are (OLMoE: moe_norm_topk=False).
+    # Experts shard over the `expert` mesh axis.
     moe_experts: int = 0
     moe_top_k: int = 2
-    moe_capacity_factor: float = 1.25
+    moe_norm_topk: bool = True
     moe_aux_weight: float = 0.01
+    # RMSNorm (with a learned gain) over the whole q and the whole k
+    # projection, before the split into heads and before RoPE (OLMoE).
+    qk_norm: bool = False
 
     @property
     def kv_heads(self) -> int:
@@ -65,9 +70,12 @@ class TransformerConfig:
     def num_params(self) -> int:
         d, v, L = self.d_model, self.vocab_size, self.n_layers
         hd, H, KV, ff = self.head_dim, self.n_heads, self.kv_heads, self.d_ff
+        E = self.moe_experts
+        ffn = E * 3 * d * ff + d * E if E else 3 * d * ff   # experts+router
         per_layer = (d * H * hd + 2 * d * KV * hd + H * hd * d  # attn
-                     + 3 * d * ff                               # swiglu
-                     + 2 * d)                                   # norms
+                     + ffn                                      # swiglu(s)
+                     + 2 * d                                    # norms
+                     + (H * hd + KV * hd if self.qk_norm else 0))
         head = 0 if self.tie_embeddings else d * v
         return v * d + L * per_layer + d + head
 

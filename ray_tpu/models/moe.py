@@ -1,22 +1,35 @@
-"""Mixture-of-Experts FFN with expert parallelism over the ``expert`` axis.
+"""Mixture-of-Experts FFN: dropless top-k routing with a sorted dispatch.
 
 Absent from the reference (SURVEY.md §2.3 marks EP as greenfield-mandatory).
-TPU-first design: GShard/Switch-style *dense* dispatch — routing becomes two
-einsums against a one-hot capacity tensor, so the whole layer is static-shaped
-matmuls the MXU likes, and sharding the expert-major tensors over the
-``expert`` mesh axis makes XLA insert the canonical all-to-all pair around
-the expert FFN (no ragged ops, no host loops).
+One path, four stages, each under a `jax.named_scope` a profile groups by:
 
-Routing: top-k (default 2) with combine weights renormalized to sum to 1
-(Mixtral-style). With all experts initialized identically the layer is then
-numerically EQUAL to the dense FFN it replaces — the parity tests exploit
-this. Tokens overflowing an expert's capacity C = ceil(T*k/E * factor) are
-dropped (contribute zero), the standard Switch behavior.
+  moe.route     router logits in float32 at the highest matmul precision,
+                softmax over ALL experts, the k largest probabilities kept
+                (renormalised to sum to 1 when `cfg.moe_norm_topk`:
+                Mixtral; left as they are when not: OLMoE)
+  moe.dispatch  the N*k (token, expert) assignments ordered by expert (a
+                stable sort), group sizes by a count per expert, the
+                tokens' rows gathered into that order
+  moe.experts   SwiGLU per expert as three grouped matmuls over the ragged
+                groups (`jax.lax.ragged_dot`)
+  moe.combine   rows gathered back into token order and summed over the k
+                slots, weighted by the routing weights, in float32
+
+No token is ever dropped, whatever the imbalance, and no shape depends on
+the routing: every buffer is [N*k, ...] or smaller (no [.., E, capacity]
+tensor). Both permutations are gathers in the forward AND the backward
+pass (the transpose of a gather by a permutation is the gather by its
+inverse), so the step holds no scatter-add of rows.
+
+With all experts initialised identically and `moe_norm_topk` the layer is
+numerically EQUAL to the dense FFN it replaces: the parity tests exploit
+this. The expert weights keep their `experts` logical axis (sharded over
+the `expert` mesh axis); how fast that is is not settled here.
 """
 
 from __future__ import annotations
 
-import math
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -58,59 +71,105 @@ def init_moe_params(rng: jax.Array, cfg) -> Params:
     }
 
 
-def moe_ffn(h: jax.Array, lp: Params, cfg, mesh: Optional[Mesh] = None
-            ) -> Tuple[jax.Array, jax.Array]:
-    """One MoE FFN layer. h: [B, T, d] -> (out [B, T, d], aux_loss scalar).
+# ---- the two permutations, gathers both ways --------------------------------
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _dispatch_rows(k, x, order, inv):
+    """x [N, d] -> [N*k, d]: row i is the token of assignment order[i]
+    (assignment a belongs to token a // k). Backward: the rows gathered
+    back by ``inv`` (the inverse permutation) and summed over the k slots
+    of each token."""
+    return x[order // k]
+
+
+def _dispatch_fwd(k, x, order, inv):
+    return x[order // k], inv
+
+
+def _dispatch_bwd(k, inv, g):
+    return g[inv].reshape(-1, k, g.shape[-1]).sum(axis=1), None, None
+
+
+_dispatch_rows.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _permute_rows(x, idx, inv):
+    """x[idx] for a permutation ``idx`` whose inverse is ``inv``; backward
+    is the gather by ``inv``."""
+    return x[idx]
+
+
+def _permute_fwd(x, idx, inv):
+    return x[idx], inv
+
+
+def _permute_bwd(inv, g):
+    return g[inv], None, None
+
+
+_permute_rows.defvjp(_permute_fwd, _permute_bwd)
+
+
+def route(x: jax.Array, router: jax.Array, cfg):
+    """x [N, d], router [d, E] -> (probs [N, E], top_p [N, k], top_i
+    [N, k]), all in float32 at the highest matmul precision (2048 x 64 a
+    token costs nothing, and a routing choice then differs from a float32
+    reference's only on a true near-tie)."""
+    logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_p, top_i = jax.lax.top_k(probs, cfg.moe_top_k)
+    if cfg.moe_norm_topk:
+        top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+    return probs, top_p, top_i
+
+
+def moe_layer(h: jax.Array, lp: Params, cfg, mesh: Optional[Mesh] = None
+              ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """One MoE FFN layer. h: [B, T, d] -> (out [B, T, d], stats).
 
     lp: per-layer params {router [d,E], w_gate/w_up [E,d,f], w_down [E,f,d]}.
-    aux_loss is the Switch load-balance term E * sum_e f_e * p_e (1.0 when
-    perfectly balanced); weight it into the train loss via
-    cfg.moe_aux_weight.
+    stats["aux"] is the load-balance term E * sum_e f_e * p_e with f_e the
+    assignments to expert e per token (summing to k over the experts) and
+    p_e the mean router probability: k at perfect balance; weight it into
+    the train loss via cfg.moe_aux_weight. stats["load"] is the largest
+    group over the mean group (1.0 at perfect balance).
     """
     B, T, d = h.shape
     E, k = cfg.moe_experts, cfg.moe_top_k
-    C = max(1, math.ceil(T * k / E * cfg.moe_capacity_factor))
+    N = B * T
     dtype = h.dtype
+    x = h.reshape(N, d)
 
-    logits = jnp.einsum("btd,de->bte", h.astype(jnp.float32),
-                        lp["router"].astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)  # [B,T,E] float32
+    with jax.named_scope("moe.route"):
+        probs, top_p, top_i = route(x, lp["router"], cfg)
 
-    top_p, top_i = jax.lax.top_k(probs, k)  # [B,T,k]
-    top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+    with jax.named_scope("moe.dispatch"):
+        expert_of = top_i.reshape(N * k)
+        order = jnp.argsort(expert_of, stable=True)      # by expert
+        inv = jnp.argsort(order)                         # its inverse
+        group_sizes = jnp.sum(
+            expert_of[:, None] == jnp.arange(E, dtype=expert_of.dtype),
+            axis=0, dtype=jnp.int32)                     # [E]
+        xs = _dispatch_rows(k, x, order, inv)            # [N*k, d]
 
-    # Flatten the k routing slots into a priority-ordered stream per batch
-    # row; earlier tokens (and within a token, higher-probability slots)
-    # claim capacity first.
-    oh = jax.nn.one_hot(top_i, E, dtype=jnp.float32)     # [B,T,k,E]
-    oh = oh.reshape(B, T * k, E)                          # [B,S,E]
-    pos = jnp.cumsum(oh, axis=1) - 1.0                    # slot within expert
-    in_cap = (pos < C) * oh                               # [B,S,E]
-    slot = jax.nn.one_hot(pos.astype(jnp.int32), C,
-                          dtype=jnp.float32) * in_cap[..., None]  # [B,S,E,C]
+    with jax.named_scope("moe.experts"):
+        gate = jax.lax.ragged_dot(xs, lp["w_gate"].astype(dtype),
+                                  group_sizes)
+        up = jax.lax.ragged_dot(xs, lp["w_up"].astype(dtype), group_sizes)
+        act = jax.nn.silu(gate) * up                     # [N*k, f]
+        out = jax.lax.ragged_dot(act, lp["w_down"].astype(dtype),
+                                 group_sizes)            # [N*k, d]
 
-    # dispatch: [B,S,E,C] x [B,S,d] -> [E,B,C,d] (all-to-all over `expert`)
-    hk = jnp.broadcast_to(h[:, :, None, :], (B, T, k, d)).reshape(B, T * k, d)
-    xin = jnp.einsum("bsec,bsd->ebcd", slot.astype(dtype), hk)
-    xin = _wlc(xin, ("experts", "batch", None, "embed"), mesh=mesh)
-
-    # expert FFN (SwiGLU), expert-major so E shards over the expert axis
-    gate = jnp.einsum("ebcd,edf->ebcf", xin, lp["w_gate"].astype(dtype))
-    up = jnp.einsum("ebcd,edf->ebcf", xin, lp["w_up"].astype(dtype))
-    act = jax.nn.silu(gate) * up
-    act = _wlc(act, ("experts", "batch", None, "mlp"), mesh=mesh)
-    out = jnp.einsum("ebcf,efd->ebcd", act, lp["w_down"].astype(dtype))
-
-    # combine: weight each claimed slot by its (renormalized) router prob
-    combine = slot * top_p.reshape(B, T * k, 1, 1).astype(jnp.float32)
-    y = jnp.einsum("ebcd,bsec->bsd", out.astype(jnp.float32), combine)
-    y = y.reshape(B, T, k, d).sum(axis=2).astype(dtype)
+    with jax.named_scope("moe.combine"):
+        back = _permute_rows(out, inv, order).reshape(N, k, d)
+        y = jnp.einsum("nkd,nk->nd", back.astype(jnp.float32), top_p)
+        y = y.astype(dtype).reshape(B, T, d)
     y = _wlc(y, ("batch", "seq", "embed"), mesh=mesh)
 
-    # Switch aux loss: fraction of tokens dispatched to e (top-1 slot) times
-    # mean router prob for e, scaled by E — 1.0 at perfect balance.
-    top1 = jax.nn.one_hot(top_i[..., 0], E, dtype=jnp.float32)
-    frac = top1.reshape(-1, E).mean(axis=0)
-    mean_p = probs.reshape(-1, E).mean(axis=0)
-    aux = E * jnp.sum(frac * mean_p)
-    return y, aux
+    per_expert = group_sizes.astype(jnp.float32)
+    aux = E * jnp.sum(per_expert / N * probs.mean(axis=0))
+    load = per_expert.max() * E / (N * k)
+    return y, {"aux": aux, "load": load}
+

@@ -67,19 +67,27 @@ def _is_axes(x):
 def state_shardings(cfg: TransformerConfig, tx, mesh: Mesh, rules=None):
     """Sharding pytree for the whole TrainState.
 
-    Optimizer moments mirror param shapes, so shardings are propagated by
-    shape-matching against the params tree (ZeRO: moments shard exactly like
-    their params). Anything unmatched (step counts, scalars) is replicated.
+    Optimizer moments mirror the params tree, so a leaf whose tree path ends
+    in a parameter's path, with that parameter's shape, is sharded exactly
+    like it (ZeRO: moments shard like their params). Anything else (step
+    counts, scalars, a factored moment) is replicated.
     """
     init = make_init_fn(cfg, tx)
     shapes = jax.eval_shape(init, jax.random.key(0))
-    p_axes = param_logical_axes(cfg)
-    by_shape = {}
-    for leaf, ax in zip(jax.tree.leaves(shapes["params"]),
-                        jax.tree.leaves(p_axes, is_leaf=_is_axes)):
-        by_shape[leaf.shape] = logical_sharding(mesh, ax, rules)
+    flat = jax.tree_util.tree_flatten_with_path
+    axes = dict(flat(param_logical_axes(cfg), is_leaf=_is_axes)[0])
+    of_param = {path: (leaf.shape, logical_sharding(mesh, axes[path], rules))
+                for path, leaf in flat(shapes["params"])[0]}
     repl = NamedSharding(mesh, P())
-    return jax.tree.map(lambda s: by_shape.get(s.shape, repl), shapes)
+
+    def pick(path, leaf):
+        for n in range(1, len(path) + 1):
+            shape, sharding = of_param.get(path[-n:], (None, None))
+            if shape == leaf.shape:
+                return sharding
+        return repl
+
+    return jax.tree_util.tree_map_with_path(pick, shapes)
 
 
 def batch_sharding(mesh: Mesh, rules=None):

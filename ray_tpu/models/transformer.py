@@ -42,6 +42,9 @@ def param_logical_axes(cfg: TransformerConfig) -> Params:
         "wo": ("layers", "heads", "qkv_dim", "embed"),
         "mlp_norm": ("layers", "embed"),
     }
+    if cfg.qk_norm:
+        # gains over the flattened (heads x head_dim) projection: replicated
+        lay.update({"q_norm": ("layers", None), "k_norm": ("layers", None)})
     if cfg.moe_experts:
         from ray_tpu.models.moe import moe_param_logical_axes
 
@@ -82,6 +85,9 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> Params:
         "wo": normal(next(k), (L, H, hd, d), out_scale),
         "mlp_norm": jnp.ones((L, d), pd),
     }
+    if cfg.qk_norm:
+        lay.update({"q_norm": jnp.ones((L, H * hd), pd),
+                    "k_norm": jnp.ones((L, KV * hd), pd)})
     if cfg.moe_experts:
         from ray_tpu.models.moe import init_moe_params
 
@@ -165,28 +171,41 @@ def _attention(q, k, v, cfg: TransformerConfig, mesh: Optional[Mesh],
 
 
 def qkv_proj(h, lp, cfg: TransformerConfig, positions):
-    """Q/K/V projections + RoPE — the single definition shared by the
-    training forward and the KV-cache inference path (models/generate),
-    so a numeric change (e.g. QK-norm) lands in both."""
+    """Q/K/V projections (+ q/k norms) + RoPE — the single definition
+    shared by the training forward and the KV-cache inference paths
+    (models/generate, models/engine), so a numeric change lands in all."""
     q = jnp.einsum("btd,dhk->bthk", h, lp["wq"].astype(cfg.dtype))
     k = jnp.einsum("btd,dhk->bthk", h, lp["wk"].astype(cfg.dtype))
     v = jnp.einsum("btd,dhk->bthk", h, lp["wv"].astype(cfg.dtype))
+    if cfg.qk_norm:  # over the whole projection, all heads together
+        B, T = h.shape[:2]
+        q = rms_norm(q.reshape(B, T, -1), lp["q_norm"],
+                     cfg.rms_eps).reshape(q.shape)
+        k = rms_norm(k.reshape(B, T, -1), lp["k_norm"],
+                     cfg.rms_eps).reshape(k.shape)
     return (_rope(q, positions, cfg.rope_theta),
             _rope(k, positions, cfg.rope_theta), v)
 
 
-def ffn_block(h, lp, cfg: TransformerConfig, mesh: Optional[Mesh] = None):
-    """SwiGLU (or MoE) FFN -> (down, aux); shared by train + inference."""
-    if cfg.moe_experts:
-        from ray_tpu.models.moe import moe_ffn
+def _no_moe_stats():
+    zero = jnp.zeros((), jnp.float32)
+    return {"aux": zero, "load": zero}
 
-        return moe_ffn(h, lp, cfg, mesh)
+
+def ffn_block(h, lp, cfg: TransformerConfig, mesh: Optional[Mesh] = None):
+    """SwiGLU (or MoE) FFN -> (down, stats); shared by train + inference.
+    stats: {"aux": load-balance loss, "load": largest expert group over
+    the mean group}, zeros for a dense layer."""
+    if cfg.moe_experts:
+        from ray_tpu.models.moe import moe_layer
+
+        return moe_layer(h, lp, cfg, mesh)
     gate = jnp.einsum("btd,df->btf", h, lp["w_gate"].astype(cfg.dtype))
     up = jnp.einsum("btd,df->btf", h, lp["w_up"].astype(cfg.dtype))
     ff = jax.nn.silu(gate) * up
     ff = _wlc(ff, ("batch", "seq", "mlp"), mesh=mesh)
     down = jnp.einsum("btf,fd->btd", ff, lp["w_down"].astype(cfg.dtype))
-    return down, jnp.zeros((), jnp.float32)
+    return down, _no_moe_stats()
 
 
 def lm_head(params: Params, x, cfg: TransformerConfig,
@@ -205,8 +224,10 @@ def forward(params: Params, tokens: jax.Array, cfg: TransformerConfig,
             mesh: Optional[Mesh] = None, return_aux: bool = False):
     """tokens [B, T] int32 -> logits [B, T, vocab] float32.
 
-    With ``return_aux=True`` returns (logits, aux) where aux is the summed
-    MoE load-balance loss (0.0 for dense or pipelined execution)."""
+    With ``return_aux=True`` returns (logits, stats): ``aux`` the MoE
+    load-balance loss averaged over the layers, ``load`` the largest
+    expert group over the mean group in the worst layer (both 0.0 for
+    dense or pipelined execution)."""
     B, T = tokens.shape
     x = params["embed"].astype(cfg.dtype)[tokens]  # [B, T, d]
     x = _wlc(x, ("batch", "seq", "embed"), mesh=mesh)
@@ -225,12 +246,12 @@ def forward(params: Params, tokens: jax.Array, cfg: TransformerConfig,
         x = x + _wlc(o, ("batch", "seq", "embed"), mesh=mesh)
 
         h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-        down, aux = ffn_block(h, lp, cfg, mesh)
+        down, stats = ffn_block(h, lp, cfg, mesh)
         x = x + _wlc(down, ("batch", "seq", "embed"), mesh=mesh)
-        # aux (MoE load-balance loss) rides the scan's per-layer outputs;
-        # the pipelined path drops it (pipeline stages emit activations
-        # only) — acceptable: aux is a regularizer, not the model output.
-        return x, aux
+        # the MoE stats ride the scan's per-layer outputs; the pipelined
+        # path drops them (pipeline stages emit activations only) —
+        # acceptable: aux is a regularizer, not the model output.
+        return x, stats
 
     body = block
     if cfg.remat:
@@ -239,7 +260,7 @@ def forward(params: Params, tokens: jax.Array, cfg: TransformerConfig,
             if getattr(cfg, "remat_policy", "nothing") == "dots"
             else jax.checkpoint_policies.nothing_saveable)
         body = jax.checkpoint(body, policy=policy)
-    aux = jnp.zeros((), jnp.float32)
+    stats = _no_moe_stats()
     if mesh is not None and mesh.shape.get("pipeline", 1) > 1:
         # GPipe-style microbatched stages over the pipeline mesh axis; the
         # same block body, numerically identical to the plain scan
@@ -249,12 +270,13 @@ def forward(params: Params, tokens: jax.Array, cfg: TransformerConfig,
         x = pipeline_scan(body, x, params["layers"], mesh,
                           cfg.pipeline_microbatches)
     else:
-        x, layer_aux = jax.lax.scan(
+        x, per_layer = jax.lax.scan(
             lambda c, lp: body(c, lp), x, params["layers"])
-        aux = layer_aux.sum()
+        stats = {"aux": per_layer["aux"].mean(),
+                 "load": per_layer["load"].max()}
 
     logits = lm_head(params, x, cfg, mesh)
-    return (logits, aux) if return_aux else logits
+    return (logits, stats) if return_aux else logits
 
 
 def loss_fn(params: Params, batch: Dict[str, jax.Array],
@@ -268,7 +290,7 @@ def loss_fn(params: Params, batch: Dict[str, jax.Array],
         toks = batch["tokens"]
         inputs, targets = toks[:, :-1], toks[:, 1:]
         mask = None
-    logits, aux = forward(params, inputs, cfg, mesh, return_aux=True)
+    logits, stats = forward(params, inputs, cfg, mesh, return_aux=True)
     logz = jax.nn.logsumexp(logits, axis=-1)
     gold = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
     nll = logz - gold
@@ -279,7 +301,8 @@ def loss_fn(params: Params, batch: Dict[str, jax.Array],
         loss = nll.mean()
     metrics = {"loss": loss, "perplexity": jnp.exp(loss)}
     if cfg.moe_experts:
-        metrics["moe_aux"] = aux
-        loss = loss + cfg.moe_aux_weight * aux
+        metrics["moe_aux"] = stats["aux"]
+        metrics["moe_load_max_over_mean"] = stats["load"]
+        loss = loss + cfg.moe_aux_weight * stats["aux"]
         metrics["total_loss"] = loss
     return loss, metrics

@@ -183,3 +183,42 @@ def test_fsdp2_tensor2_train_step_keeps_kernel(topo, mosaic):
     assert "all-gather" in text and "reduce-scatter" in text
     # ZeRO-3 x TP: each chip holds about a quarter of the train state
     assert _device_bytes(compiled) < HBM_BYTES // 2
+
+
+def test_olmoe_train_step_fits_one_chip_without_a_capacity_tensor(
+        one_chip, mosaic):
+    """The cell `olmoe-1b-7b.train-4k` as the benchmark runs it (its
+    config file's depth, 4 x 4096, bf16 weights and moments): the step
+    compiles for one chip (the compiler refuses a program over the chip's
+    memory, so a compile that returns fits), its expert matmuls are the
+    grouped `ragged-dot` kernels, and no buffer has the [.., E, C] shape
+    of a capacity-bound dispatch or anything near its size."""
+    from benchmark.harness import spec
+
+    bench = spec.load_benchmark()
+    conf = spec.load_config(bench, "olmoe-1b-7b")
+    traffic = spec.load_traffic("train-4k")
+    rows, seq = traffic["rows"], traffic["seq_len"]
+    cfg = spec.build_transformer_config(
+        conf, max_seq_len=seq, param_dtype=traffic["param_dtype"],
+        attention_impl="pallas")
+    assert (cfg.moe_experts, cfg.moe_top_k, cfg.d_ff) == (64, 8, 1024)
+    assert cfg.qk_norm and not cfg.moe_norm_topk
+    tx = make_optimizer(traffic["learning_rate"],
+                        mu_dtype=jnp.dtype(traffic["mu_dtype"]))
+    state = _on(jax.eval_shape(make_init_fn(cfg, tx), jax.random.key(0)),
+                one_chip)
+    batch = {"tokens": jax.ShapeDtypeStruct((rows, seq + 1), jnp.int32,
+                                            sharding=one_chip)}
+    text = make_train_step(cfg, tx).lower(state, batch).compile().as_text()
+    assert "ragged-dot" in text and "tpu_custom_call" in text
+    E, k = cfg.moe_experts, cfg.moe_top_k
+    capacity = -(-seq * k // E) * 5 // 4          # the old C at factor 1.25
+    largest = rows * seq * cfg.vocab_size         # the float32 logits
+    for m in re.finditer(r"\b\w+\[([\d,]+)\]", text):
+        dims = [int(d) for d in m.group(1).split(",")]
+        assert (E, capacity) not in zip(dims, dims[1:]), m.group(0)
+        n = 1
+        for d in dims:
+            n *= d
+        assert n <= largest, m.group(0)

@@ -153,11 +153,11 @@ class TestMoE:
     def test_identical_experts_match_dense_ffn(self):
         """With every expert set to the same weights and combine weights
         renormalized, the MoE layer must equal the dense FFN exactly
-        (capacity high enough that nothing drops)."""
+        (dropless: there is no capacity to overflow)."""
         import jax, jax.numpy as jnp, numpy as np
-        from ray_tpu.models.moe import moe_ffn
+        from ray_tpu.models.moe import moe_layer
 
-        cfg = self._cfg(moe_capacity_factor=8.0)
+        cfg = self._cfg()
         d, ff, E = cfg.d_model, cfg.d_ff, cfg.moe_experts
         key = jax.random.key(0)
         wg = jax.random.normal(key, (d, ff)) * 0.1
@@ -170,28 +170,31 @@ class TestMoE:
             "w_down": jnp.broadcast_to(wd, (E, ff, d)),
         }
         h = jax.random.normal(jax.random.key(4), (2, 8, d))
-        out, aux = moe_ffn(h, lp, cfg)
+        out, stats = moe_layer(h, lp, cfg)
         dense = jnp.einsum(
             "btf,fd->btd",
             jax.nn.silu(jnp.einsum("btd,df->btf", h, wg))
             * jnp.einsum("btd,df->btf", h, wu), wd)
         np.testing.assert_allclose(out, dense, atol=1e-5)
-        assert float(aux) > 0
+        assert float(stats["aux"]) > 0
 
     def test_expert_parallel_sharded_matches_unsharded(self):
         import jax, jax.numpy as jnp, numpy as np
-        from ray_tpu.models.moe import init_moe_params, moe_ffn
+        from ray_tpu.models.moe import init_moe_params, moe_layer
         from ray_tpu.parallel import make_mesh
 
         cfg = self._cfg(n_layers=1)
         params = init_moe_params(jax.random.key(0), cfg)
         lp = jax.tree.map(lambda p: p[0], params)  # layer 0
         h = jax.random.normal(jax.random.key(1), (4, 8, cfg.d_model))
-        ref, aux_ref = moe_ffn(h, lp, cfg)
+        ref, stats_ref = moe_layer(h, lp, cfg)
         mesh = make_mesh(expert=4, fsdp=2)
-        out, aux = jax.jit(lambda h, lp: moe_ffn(h, lp, cfg, mesh))(h, lp)
+        out, stats = jax.jit(
+            lambda h, lp: moe_layer(h, lp, cfg, mesh))(h, lp)
         np.testing.assert_allclose(ref, out, atol=1e-5)
-        np.testing.assert_allclose(float(aux_ref), float(aux), atol=1e-5)
+        for name in ("aux", "load"):
+            np.testing.assert_allclose(float(stats_ref[name]),
+                                       float(stats[name]), atol=1e-5)
 
     def test_moe_transformer_trains_and_routes(self):
         """End-to-end: MoE transformer loss decreases and aux loss is
