@@ -28,7 +28,10 @@ class TransformerConfig:
     rope_theta: float = 500_000.0
     rms_eps: float = 1e-5
     dtype: jnp.dtype = jnp.bfloat16        # activation/compute dtype
-    param_dtype: jnp.dtype = jnp.float32   # master weights
+    # the trainer's master weights and a checkpoint's dtype; a serving
+    # replica holds each leaf as the forward reads it
+    # (transformer.serving_params: `dtype`, the head float32)
+    param_dtype: jnp.dtype = jnp.float32
     tie_embeddings: bool = False
     # False -> bidirectional (encoder / BERT-class) attention; the same
     # blocks, RoPE, and loss_fn (inputs/targets/mask form = MLM) apply.

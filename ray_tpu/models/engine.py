@@ -71,7 +71,7 @@ from ray_tpu.models.generate import (_final_logits, _gqa_decode_attention,
                                      _prefill_hidden)
 from ray_tpu.models.transformer import (Params, ffn_block,
                                         param_logical_axes, qkv_proj,
-                                        rms_norm)
+                                        rms_norm, serving_params)
 
 log = logging.getLogger(__name__)
 
@@ -303,7 +303,10 @@ class InferenceEngine:
     ``step()`` is one engine iteration: admit queued prompts into free
     slots (prefill), then advance every active slot one token (decode).
     ``serve_forever`` runs steps on a background thread; ``submit`` /
-    ``submit_stream`` are thread-safe entry points.
+    ``submit_stream`` are thread-safe entry points. The weights are held
+    in the dtype the programs read them in (`serving_params`: ``cfg.dtype``,
+    the head and an MoE router float32; converted once here), not in the
+    dtype they were given in.
     """
 
     def __init__(self, params: Params, cfg: TransformerConfig, *,
@@ -347,18 +350,15 @@ class InferenceEngine:
             b *= 2
         self._buckets.append(self.max_prompt_len)
 
+        shardings = None
+        self.cache = init_slot_cache(cfg, self.slots, self._max_len)
         if mesh is not None:
             from ray_tpu.parallel.sharding import shard_array, tree_shardings
 
             shardings = tree_shardings(mesh, param_logical_axes(cfg))
-            params = jax.tree.map(
-                lambda x, s: jax.device_put(x, s), params, shardings)
-            cache = init_slot_cache(cfg, self.slots, self._max_len)
             self.cache = {k: shard_array(mesh, v, cache_logical_axes()[k])
-                          for k, v in cache.items()}
-        else:
-            self.cache = init_slot_cache(cfg, self.slots, self._max_len)
-        self.params = params
+                          for k, v in self.cache.items()}
+        self.params = serving_params(params, cfg, shardings)
 
         self._rng = jax.random.key(seed)
         self._step_i = itertools.count()

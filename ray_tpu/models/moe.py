@@ -111,6 +111,11 @@ def _permute_bwd(inv, g):
 _permute_rows.defvjp(_permute_fwd, _permute_bwd)
 
 
+# the leaves `route` reads through ``.astype(float32)``: a serving replica
+# keeps them float32 (transformer.read_in_float32 / serving_params)
+READ_IN_FLOAT32 = ("router",)
+
+
 def route(x: jax.Array, router: jax.Array, cfg):
     """x [N, d], router [d, E] -> (probs [N, E], top_p [N, k], top_i
     [N, k]), all in float32 at the highest matmul precision (2048 x 64 a

@@ -7,8 +7,9 @@ TPU-first design choices:
   - Every parameter and activation carries *logical* axis names; actual
     sharding comes from `ray_tpu.parallel.sharding` rules, so the same model
     runs DP, FSDP, TP, and ring-CP unchanged.
-  - Compute in bfloat16 on the MXU, master params float32, loss/softmax
-    accumulation float32.
+  - Compute in bfloat16 on the MXU, loss/softmax accumulation float32;
+    the trainer's master params are float32 (`param_dtype`), a serving
+    replica holds them as the forward reads them (`serving_params`).
   - `jax.checkpoint` on the scanned block trades FLOPs for HBM (remat).
 """
 
@@ -107,6 +108,32 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> Params:
     if not cfg.tie_embeddings:
         params["lm_head"] = normal(next(k), (d, v), in_scale)
     return params
+
+
+def serving_params(params: Params, cfg: TransformerConfig,
+                   shardings: Optional[Params] = None) -> Params:
+    """``params`` as a serving replica holds them: every leaf in the dtype
+    the forward reads it in, so no program converts a weight it reads in
+    ``cfg.dtype`` (the ``.astype(cfg.dtype)`` at each use is the same
+    rounding, made once here). The leaves read through
+    ``.astype(float32)`` (`read_in_float32`: the vocabulary head, the MoE
+    router) stay float32. A leaf already in its dtype is returned as it
+    is: with ``cfg.dtype`` float32, a float32 tree comes back untouched.
+    Converted leaf by leaf (after its ``device_put`` where ``shardings``
+    gives one, which the conversion keeps), so a second whole tree stands
+    beside the first only as long as the caller keeps the first.
+    """
+    in_float32 = read_in_float32(cfg)
+
+    def held(path, x, sharding=None):
+        want = jnp.dtype(jnp.float32 if path[-1].key in in_float32
+                         else cfg.dtype)
+        x = jnp.asarray(x) if sharding is None \
+            else jax.device_put(x, sharding)
+        return x if x.dtype == want else x.astype(want)
+
+    return jax.tree_util.tree_map_with_path(
+        held, params, *(() if shardings is None else (shardings,)))
 
 
 # ---- building blocks -------------------------------------------------------
@@ -216,6 +243,19 @@ def lm_head(params: Params, x, cfg: TransformerConfig,
     logits = jnp.einsum("btd,dv->btv", x.astype(jnp.float32),
                         head.astype(jnp.float32))
     return _wlc(logits, ("batch", "seq", "vocab"), mesh=mesh)
+
+
+def read_in_float32(cfg: TransformerConfig) -> tuple:
+    """Names of the leaves the forward reads through ``.astype(float32)``
+    and not through ``.astype(cfg.dtype)``: `lm_head`'s matrix (the
+    embedding table where it is tied) and `moe.route`'s. A serving replica
+    holds these in float32 and every other leaf in ``cfg.dtype``
+    (`serving_params`); tests/test_serving_params.py holds the list to
+    what the forward does."""
+    from ray_tpu.models.moe import READ_IN_FLOAT32
+
+    head = "embed" if cfg.tie_embeddings else "lm_head"
+    return (head,) + (READ_IN_FLOAT32 if cfg.moe_experts else ())
 
 
 # ---- forward ---------------------------------------------------------------

@@ -215,6 +215,9 @@ class _ContinuousLLMReplica:
     finished sequence's slot on the very next step — one long generation
     no longer stalls its batchmates (the vLLM-style redesign, TPU-first:
     static slot shapes, one compiled decode program, on-device sampling).
+    The engine holds the weights as its programs read them: ``cfg.dtype``
+    (a float32 checkpoint is rounded once at deploy, not in every
+    program) but for the float32 vocabulary head and MoE router.
 
     ``tensor_parallel`` > 1 shards the model over that many local devices
     (a `num_tpus=N`-class replica): params/cache carry tensor-axis
@@ -311,6 +314,9 @@ def build_continuous_llm_deployment(model="tiny", *, name: str = "llm",
                                     max_concurrency: int = 32,
                                     **replica_kwargs):
     """-> an Application whose replicas continuously batch generations.
+    Each replica holds its weights in the model's compute dtype
+    (``cfg.dtype``; the vocabulary head and an MoE router in float32),
+    whatever dtype the checkpoint was saved in.
 
     ``max_concurrency`` lifts the replica's query cap (and with it the
     actor's thread cap) so many callers can block in ``__call__`` while

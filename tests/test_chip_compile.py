@@ -114,16 +114,18 @@ def test_llama3_1b_train_step_fits_one_chip(one_chip, mosaic):
 def test_serve_programs_compile(one_chip):
     """The serve replica's programs at chip_smoke's sizes: 8 slots,
     prompts to 512, 64 new tokens (max_len 640 covers the issue's
-    rehearsal size), decode chunks of 4, and the weights as a replica
-    holds them (the preset's float32 master copy)."""
+    rehearsal size), decode chunks of 4, and the weights as the engine
+    holds them (`serving_params`: the preset's compute dtype, the
+    vocabulary head float32)."""
     from ray_tpu.models.engine import (decode_slots, init_slot_cache,
                                        prefill_slots)
-    from ray_tpu.models.transformer import init_params
+    from ray_tpu.models.transformer import init_params, serving_params
 
     cfg = llama3_1b_config()
     slots, max_len = 8, 640
-    params = _on(jax.eval_shape(lambda k: init_params(k, cfg),
-                                jax.random.key(0)), one_chip)
+    params = _on(jax.eval_shape(
+        lambda k: serving_params(init_params(k, cfg), cfg),
+        jax.random.key(0)), one_chip)
     cache = _on(jax.eval_shape(
         lambda: init_slot_cache(cfg, slots, max_len)), one_chip)
     rng = _on(jax.eval_shape(lambda: jax.random.key(0)), one_chip)
@@ -138,17 +140,55 @@ def test_serve_programs_compile(one_chip):
     decode = decode_slots.lower(params, cache, i32(slots), active, rng, cfg,
                                 steps=4).compile()
     assert _device_bytes(decode) < HBM_BYTES
-    # A decode substep reads the cache once and writes only the rows that
-    # change, in place: the chunk's temporaries are the bf16 copy of the
-    # weights and small change, and no instruction copies, selects over or
-    # scatters into a whole-cache-sized result (a per-layer write inside
-    # the layer scan, or a cache stored in another order than attention
-    # reads it, brings exactly those back: 2/3 of decode time on the chip)
-    weights = sum(x.size for x in jax.tree.leaves(params)) * 2
+    # The programs read the weights as they are held: no instruction
+    # converts a weight but the float32 vocabulary head, which `lm_head`
+    # reads through `.astype(float32)` and the MXU rounds once a program
+    # (a tree held in another dtype than the forward reads brings back
+    # one convert of every matrix per PROGRAM: a third of a decode chunk
+    # on the chip, 13.8 ms of every prefill), and no temporary is
+    # weight-sized but the head's bf16 copy (525 MB; the smallest stacked
+    # matrix is 33 MB, the float32 tree's bf16 copy 2.5 GB): decode's are
+    # small change, the prefill's its own activations, the float32 scores
+    # and probabilities of [8 x 512] rows first (268 MB each).
+    head = params["embed" if cfg.tie_embeddings else "lm_head"]
+    assert head.dtype == jnp.float32
+    head_copy = head.size * 2
     cache_bytes = 2 * cache["k"].size * cache["k"].dtype.itemsize
-    assert decode.memory_analysis().temp_size_in_bytes \
-        < weights + 0.1 * cache_bytes
+    scores = 8 * cfg.n_heads * 512 * 512 * 4
+    for program, room in ((decode, 16 << 20), (prefill, 3 * scores)):
+        assert _weight_converts(program.as_text(), params,
+                                but=head.shape) == []
+        assert program.memory_analysis().temp_size_in_bytes \
+            < 0.1 * cache_bytes + head_copy + room
+    # A decode substep reads the cache once and writes only the rows that
+    # change, in place: no instruction copies, selects over or scatters
+    # into a whole-cache-sized result (a per-layer write inside the layer
+    # scan, or a cache stored in another order than attention reads it,
+    # brings exactly those back: 2/3 of decode time on the chip)
     assert _whole_cache_ops(decode.as_text(), cache["k"].shape) == []
+
+
+def _weight_converts(hlo: str, params, but=()) -> list:
+    """`name = f32|bf16[...] convert(...)` lines of compiled text whose
+    result has the dimensions of a weight matrix: a whole leaf, or one
+    layer of a stacked one (in any order, 1s dropped). ``but``: the one
+    shape let through."""
+    shapes = set()
+    for path, leaf in jax.tree_util.tree_leaves_with_path(params):
+        dims = tuple(sorted(d for d in leaf.shape if d > 1))
+        shapes.add(dims)
+        if path[0].key == "layers":
+            shapes.add(tuple(sorted(d for d in leaf.shape[1:] if d > 1)))
+    shapes = {s for s in shapes if len(s) >= 2}  # matrices, not gains
+    shapes.discard(tuple(sorted(but)))
+    found = []
+    for m in re.finditer(
+            r"^\s*(?:ROOT )?(\S+) = \w+\[([\d,]+)\]\S* convert\(", hlo, re.M):
+        dims = tuple(sorted(int(d) for d in m.group(2).split(",")
+                            if int(d) > 1))
+        if dims in shapes:
+            found.append(f"{m.group(1)}: [{m.group(2)}]")
+    return found
 
 
 def _whole_cache_ops(hlo: str, cache_shape) -> list:
