@@ -612,7 +612,7 @@ def _ring_collective(arr: np.ndarray, st: _GroupState, op: str,
     slice eagerly.
 
     Per-rank traffic ~2·(R-1)/R·nbytes, none of it through the
-    coordinator or the driver (counter-asserted in BENCH_dp_r18).
+    coordinator or the driver.
     """
     m = _m()
     t_setup = time.monotonic()
@@ -844,8 +844,7 @@ def _rendezvous_allreduce(arr: np.ndarray, st: _GroupState, op: str,
     as they land (O(1) payloads held) and hands every rank the result —
     O(R·nbytes) through the coordinator's node per operation. The
     pre-exchange baseline the ring exists to beat, preserved as the
-    zero-object-plane escape hatch (transport="rendezvous") and the
-    bench_pipeline collective phase's A."""
+    zero-object-plane escape hatch (transport="rendezvous")."""
     m = _m()
     t0 = time.monotonic()
     out = _run("allreduce", st.name, np.ascontiguousarray(arr), op=op,

@@ -123,8 +123,7 @@ class Config:
     # ndarray consumers alias arena memory outright — the store's
     # borrow-pin ledger keeps the arena slice alive while any such view
     # is (see ``ShmObjectStore.get_frames(pin_borrows=True)``). False
-    # restores the pre-r13 in-band pickle path (the A/B control for
-    # bench_device_path.py).
+    # restores the pre-r13 in-band pickle path (the A/B control).
     serialization_device_zero_copy: bool = True
 
     # --- speculative arg prefetch (r13) ---
@@ -265,8 +264,7 @@ class Config:
     # the OBJECT PLANE — each rank put()s its chunks into its local
     # arena and peers pull them store-to-store (striped pulls, r13
     # typed zero-copy reducer; neither the coordinator actor nor the
-    # driver ever carries payload bytes, counter-asserted in
-    # BENCH_dp_r18.json), with sized payloads riding a chunked ring
+    # driver ever carries payload bytes), with sized payloads riding a chunked ring
     # reduce-scatter+allgather (~2·(R-1)/R·nbytes per rank, per-hop
     # pulls warmed ahead of the fold) and small payloads a
     # halving-doubling tree (log2 R hops) on power-of-two worlds.
@@ -312,9 +310,9 @@ class Config:
     # than it saves). The default is deliberately high: inline args
     # already ride the r8 zero-copy wire one hop, so by-ref only wins
     # once the payload is large enough to amortize the extra arena hop
-    # and per-object control traffic — the ingress A/B in
-    # SERVE_BENCH_r14.json measured by-ref LOSING on loopback below
-    # ~16 MiB (0.34x rps at 2 MiB, 0.87x at 16 MiB). Lower it (e.g.
+    # and per-object control traffic — a round-14 ingress A/B on a CPU
+    # host's loopback measured by-ref LOSING below ~16 MiB (0.34x rps
+    # at 2 MiB, 0.87x at 16 MiB). Lower it (e.g.
     # 512 KiB) when replicas sit behind a paced/real network link or
     # when the same payload fans out to many replicas (broadcast +
     # prefetch regimes, where by-ref wins). <= 0 disables the by-ref

@@ -151,7 +151,7 @@ class Dataset:
         # Executed as a dedicated block op seeded by (seed, block index):
         # a per-task Random(seed) would replay the identical sequence in
         # every block (the closure is re-unpickled per worker), correlating
-        # draws across blocks (round-1 ADVICE, low).
+        # draws across blocks.
         rng_seed = seed if seed is not None else int(time.time())
         return self._with_op(MapBlocks(
             name=f"random_sample({fraction})", kind="random_sample",

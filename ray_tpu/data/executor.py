@@ -241,7 +241,7 @@ def _det_hash(value) -> int:
 
     Python's builtin hash() is salted per process (PYTHONHASHSEED), so two
     workers would route the same key to different partitions — silently
-    duplicating groups (round-1 ADVICE, high). crc32 over the pickled key is
+    duplicating groups. crc32 over the pickled key is
     stable across interpreters for the plain-data keys groupby supports.
     """
     import pickle
@@ -870,8 +870,7 @@ class StreamingExecutor:
 
         def zip_all(n_left, *blocks):
             # n_left is passed explicitly: the two sides may have different
-            # block counts, so halving len(blocks) mis-assigns blocks
-            # (round-1 ADVICE, medium).
+            # block counts, so halving len(blocks) mis-assigns blocks.
             left = BlockAccessor(BlockAccessor.concat(
                 list(blocks[:n_left]))).to_pylist()
             right = BlockAccessor(BlockAccessor.concat(

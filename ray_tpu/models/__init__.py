@@ -11,10 +11,6 @@ from ray_tpu.models.config import (
     tiny_config,
 )
 from ray_tpu.models.mlm import mask_tokens
-# NOTE: the generate() function itself is not re-exported — it would
-# shadow the ray_tpu.models.generate submodule; use
-# ``from ray_tpu.models.generate import generate``.
-from ray_tpu.models.generate import decode_step, init_cache, prefill
 from ray_tpu.models.transformer import (
     forward,
     init_params,
@@ -35,7 +31,6 @@ __all__ = [
     "gpt2_small_config", "llama3_8b_config", "llama3_70b_config",
     "bert_base_config", "mask_tokens",
     "forward", "init_params", "loss_fn", "param_logical_axes",
-    "prefill", "decode_step", "init_cache",
     "make_optimizer", "make_train_step", "make_eval_step",
     "init_train_state", "state_shardings", "batch_sharding",
 ]

@@ -2,8 +2,8 @@
 
 The reference ships no LLM definitions (its model zoo is RLlib's small
 policy nets, rllib/models/ — SURVEY.md §2.4); the flagship LLM family here
-serves the north-star workloads in BASELINE.json (GPT-2-small data-parallel,
-Llama-3-8B FSDP pretrain, Llama-3-8B serving).
+serves the north-star workloads (GPT-2-small data-parallel, Llama-3-8B FSDP
+pretrain, Llama-3-8B serving).
 """
 
 from __future__ import annotations
@@ -82,12 +82,6 @@ class TransformerConfig:
         head = 0 if self.tie_embeddings else d * v
         return v * d + L * per_layer + d + head
 
-    def flops_per_token(self, seq_len: Optional[int] = None) -> float:
-        """Approximate training FLOPs/token: 6*N + attention quadratic term."""
-        s = seq_len or self.max_seq_len
-        attn = 12 * self.n_layers * self.d_model * s  # fwd+bwd qk^T and av
-        return 6.0 * self.num_params + attn
-
 
 # ---- presets ---------------------------------------------------------------
 
@@ -139,7 +133,7 @@ def bert_base_config(**kw) -> TransformerConfig:
     as the decoders but ``causal=False``; train with ``loss_fn`` in its
     inputs/targets/mask form (= masked-language-model objective, see
     models.mlm). Ref analog: the reference's BERT-base data-parallel
-    TorchTrainer benchmark config (BASELINE.md)."""
+    TorchTrainer benchmark config."""
     # d_ff=2048 keeps the 3-matrix SwiGLU FFN at BERT's 2-matrix-GELU
     # parameter budget (3*768*2048 ≈ 2*768*3072), so the preset stays
     # a 110M-class model

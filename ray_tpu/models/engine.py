@@ -123,20 +123,6 @@ def _put_rows(cache: SlotCache, new_k: jax.Array, new_v: jax.Array,
 
 
 @partial(jax.jit, static_argnames=("cfg", "greedy"), donate_argnums=(1,))
-def prefill_slot(params: Params, cache: SlotCache, tokens: jax.Array,
-                 slot: jax.Array, start: jax.Array, rng: jax.Array,
-                 cfg: TransformerConfig, greedy: bool = True,
-                 temperature: float = 1.0):
-    """Run the prompt ``tokens`` [1, P] (left-padded to its bucket, first
-    real token at ``start``) and write its K/V into slot row ``slot``;
-    -> (cache, first sampled token []). One compiled program per bucket P.
-    """
-    cache, toks = prefill_slots(params, cache, tokens, slot[None],
-                                start[None], rng, cfg, greedy, temperature)
-    return cache, toks[0]
-
-
-@partial(jax.jit, static_argnames=("cfg", "greedy"), donate_argnums=(1,))
 def prefill_slots(params: Params, cache: SlotCache, tokens: jax.Array,
                   slots: jax.Array, starts: jax.Array, rng: jax.Array,
                   cfg: TransformerConfig, greedy: bool = True,
@@ -228,7 +214,7 @@ def decode_slots(params: Params, cache: SlotCache, tokens: jax.Array,
     input column lets the pipelined host loop learn prefill-sampled
     first tokens from the same fetch (the token chain itself never
     leaves the device). Rows whose input is ``eos_id`` or that hit it
-    mid-chunk freeze on-device (keep emitting eos, like generate());
+    mid-chunk freeze on-device (keep emitting eos);
     inactive slots compute junk into a position the next real write or
     prefill overwrites, their positions don't advance, and the host
     ignores their samples.
@@ -298,7 +284,7 @@ class _Request:
 
 
 class InferenceEngine:
-    """Slot scheduler over ``prefill_slot``/``decode_slots``.
+    """Slot scheduler over ``prefill_slots``/``decode_slots``.
 
     ``step()`` is one engine iteration: admit queued prompts into free
     slots (prefill), then advance every active slot one token (decode).
@@ -315,7 +301,7 @@ class InferenceEngine:
                  temperature: float = 1.0, eos_id: int = -1,
                  pad_id: int = 0, mesh=None, seed: int = 0,
                  min_bucket: int = 16, decode_chunk: int = 4,
-                 fetch_every: int = 1, max_inflight: int = 6):
+                 max_inflight: int = 6):
         self.cfg = cfg
         self.slots = int(slots)
         self.max_prompt_len = int(max_prompt_len)
@@ -328,12 +314,6 @@ class InferenceEngine:
         # multi-step scheduling: decode_chunk substeps per dispatch (one
         # host round-trip per chunk); admission happens between chunks
         self.decode_chunk = max(1, int(decode_chunk))
-        # fetch batching (inline step() mode): accumulate this many
-        # dispatched chunks, then concatenate their token outputs ON
-        # DEVICE and fetch once. Under serve_forever the dedicated
-        # fetcher thread self-paces instead (drain everything pending
-        # per cycle) and this knob is unused.
-        self.fetch_every = max(1, int(fetch_every))
         # pipelined mode: how many dispatched-but-unfetched decode chunks
         # may exist before the dispatch loop waits for the fetcher.
         # A device->host fetch OVERLAPS with queued execution, so the
@@ -380,9 +360,9 @@ class InferenceEngine:
         # .at[slot].set) — the host never syncs to keep the chain going
         self._next_tok_dev = jnp.zeros(self.slots, jnp.int32)
         # dispatched-but-unfetched chunks: [(toks_dev [B, K+1],
-        # [(slot, request, emit_from_col)])] — fetched together (one
-        # device-side concat, one transfer) once fetch_every have
-        # accumulated, or when the engine runs out of dispatchable work
+        # [(slot, request, emit_from_col, take)])] — inline step() fetches
+        # them in the step that dispatched them, the fetcher thread as the
+        # device finishes them (one transfer for all that are ready)
         self._inflight: List[tuple] = []
         self._work = threading.Event()  # set when there may be work
         self._lock = threading.Lock()   # guards step() vs concurrent step()
@@ -692,14 +672,13 @@ class InferenceEngine:
         # 2) dispatch one full-width decode chunk (async) when there is
         #    planned work and (pipelined mode) fetch headroom.
         dispatched = self._dispatch_locked()
-        # 3) delivery. Inline mode fetches here (one device-side concat +
-        #    ONE transfer per fetch_every chunks); pipelined mode hands
-        #    the accumulated chunks to the fetcher thread instead, so the
-        #    dispatch loop never blocks on a device->host round trip.
+        # 3) delivery. Inline mode fetches what is in flight here;
+        #    pipelined mode hands the accumulated chunks to the fetcher
+        #    thread instead, so the dispatch loop never blocks on a
+        #    device->host round trip.
         processed = False
         if self._fetcher is None:
-            if self._inflight and (len(self._inflight) >= self.fetch_every
-                                   or not dispatched):
+            if self._inflight:
                 pending, self._inflight = self._inflight, []
                 self._deliver_locked(self._fetch_chunks(pending), pending)
                 processed = True

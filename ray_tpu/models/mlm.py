@@ -1,7 +1,7 @@
 """Masked-language-model batch preparation for encoder configs.
 
 Ref analog: the reference's BERT-base JaxTrainer/TorchTrainer pretraining
-config (BASELINE.md) — there the masking lives in the HF data collator;
+config — there the masking lives in the HF data collator;
 here it is one vectorized numpy transform that pairs with
 ``transformer.loss_fn``'s inputs/targets/mask form (loss on masked
 positions only, no target shift). BERT 80/10/10 recipe: of the selected

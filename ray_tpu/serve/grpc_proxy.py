@@ -163,7 +163,7 @@ class GrpcProxy:
                         # the whole cache every 1s refresh made the next
                         # Predict per app pay a blocking get_ingress
                         # controller RPC every second under steady
-                        # traffic (ADVICE.md finding).
+                        # traffic.
                         self._handles = {
                             a: h for a, h in self._handles.items()
                             if a in new and new[a] == old.get(a)}

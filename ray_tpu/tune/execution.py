@@ -136,8 +136,7 @@ class TuneController:
         while len(self._runners) < self._max_concurrent:
             # Resume paused trials whenever a slot frees, regardless of
             # searcher exhaustion — gating this on `not _exhausted` livelocks
-            # custom PAUSE-ing schedulers once the searcher runs dry
-            # (round-1 ADVICE, medium).
+            # custom PAUSE-ing schedulers once the searcher runs dry.
             paused = [t for t in self.trials if t.status == PAUSED]
             if paused:
                 trial = paused[0]

@@ -2,7 +2,7 @@
 
 Ref analog: python/ray/_private/ray_perf.py:93 — same metric names as the
 reference's release/release_logs/2.6.1/microbenchmark.json so results diff
-directly against BASELINE.md. Emits one JSON object to stdout.
+directly against it. Emits one JSON object to stdout.
 
 Run:  python -m ray_tpu.utils.microbenchmark [--quick]
 """

@@ -32,12 +32,13 @@ jax.config.update("jax_persistent_cache_enable_xla_caches", "all")
 
 import pytest  # noqa: E402
 
-# Module-level tier assignment: these files are dominated by JAX model
-# compiles (tens of seconds each on one core). Everything else is the
-# fast tier. Keep in sync with pytest.ini's marker docs.
+# Module-level tier assignment: the RL, tune and breadth suites, which no
+# cell of the benchmark runs (minutes of small-program compiles and
+# rollouts). Everything else is the fast tier, the tests of models/, ops/,
+# parallel/ and train/ among it: what runs on the chip is guarded by every
+# PR. Keep in sync with pytest.ini's marker docs.
 SLOW_MODULES = {
-    "test_models", "test_encoder", "test_generate", "test_engine",
-    "test_parallel", "test_train", "test_tune", "test_ops",
+    "test_tune",
     "test_rllib", "test_rllib_breadth", "test_rllib_sac",
     "test_rllib_connectors", "test_rllib_continuous",
     "test_rllib_catalog",

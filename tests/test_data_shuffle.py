@@ -406,34 +406,6 @@ def test_limit_prefix_batched(ray_start):
     assert data.range(10, parallelism=2).limit(0).count() == 0
 
 
-# ================================================== bench smoke
-
-
-def test_bench_data_smoke(tmp_path):
-    """Fast-tier CI smoke of bench_data.py (--smoke: tiny sizes, one
-    pair, unpaced): the shuffle phase runs end-to-end in a subprocess
-    and writes a well-formed artifact with A/B pairs."""
-    import json
-    import os
-    import subprocess
-    import sys
-
-    out = tmp_path / "bench_smoke.json"
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    r = subprocess.run(
-        [sys.executable, os.path.join(repo, "bench_data.py"),
-         "--smoke", "--phases", "shuffle", "--out", str(out)],
-        cwd=repo, env=env, capture_output=True, text=True, timeout=420)
-    assert r.returncode == 0, (r.stdout[-1000:], r.stderr[-2000:])
-    doc = json.loads(out.read_text())
-    assert doc["smoke"] is True
-    ph = doc["shuffle"]
-    assert len(ph["pairs"]) == 1
-    assert ph["pipe_mb_s_median"] > 0
-    assert "wall_ratio_median_of_pairs" in ph
-
-
 # ==================================================== 2-node smoke
 
 

@@ -1,8 +1,8 @@
 """Bidirectional (encoder / BERT-class) models: attention directionality,
 MLM masking, loss, and a short training-improves test.
 
-Analog of the reference's BERT-base pretraining config (BASELINE.md "Ray
-Train: GPT-2-small / BERT-base data-parallel JaxTrainer"): the same
+Analog of the reference's BERT-base pretraining config ("Ray Train:
+GPT-2-small / BERT-base data-parallel JaxTrainer"): the same
 transformer blocks run with causal=False and the MLM objective.
 """
 
@@ -112,7 +112,7 @@ class TestEncoderTrain:
         ray_tpu.shutdown()
 
     def test_bert_style_jax_trainer(self, runtime, tmp_path):
-        """The BASELINE "BERT-base data-parallel JaxTrainer" config shape:
+        """The reference's "BERT-base data-parallel JaxTrainer" config shape:
         an MLM encoder loop under the Train gang (scaled tiny)."""
         from ray_tpu import train
         from ray_tpu.train import JaxTrainer, RunConfig, ScalingConfig

@@ -2,9 +2,7 @@
 
 Decode reads the slot cache and writes only the rows that change, in
 place (one dynamic_update_slice per slot, after the layer scan). These are
-the cases that write can get wrong. In the fast tier (tests/test_engine.py
-is in conftest's slow one): small programs, and the serve path's
-correctness should guard every PR.
+the cases that write can get wrong.
 """
 
 import jax

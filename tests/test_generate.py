@@ -1,8 +1,7 @@
-"""KV-cache autoregressive generation: prefill/decode parity, padding,
-EOS semantics, and the batched Serve LLM deployment.
+"""Cached autoregressive generation: prefill/decode parity, padding,
+EOS semantics, and the continuous-batching Serve LLM deployment.
 
-Analog of the reference's serve LLM / batched-inference tests (the
-"Serve Llama-3 inference (batched)" BASELINE.json config); parity is
+Analog of the reference's serve LLM / batched-inference tests; parity is
 checked against the training-path ``transformer.forward`` the same way
 the reference checks vLLM outputs against HF generate.
 """
@@ -15,8 +14,10 @@ import numpy as np
 import pytest
 
 import ray_tpu
-from ray_tpu.models import generate as G
 from ray_tpu.models.config import tiny_config
+from ray_tpu.models.engine import (InferenceEngine, decode_slots,
+                                   init_slot_cache, prefill_slots)
+from ray_tpu.models.generate import _final_logits, _prefill_hidden
 from ray_tpu.models.transformer import forward, init_params
 
 
@@ -28,15 +29,36 @@ def tiny():
     return cfg, params
 
 
+def _generate(params, cfg, prompt, n, *, start=None, eos_id=-1, rng=None,
+              **sampling):
+    """prompt [B, P] (row b's first real token at ``start[b]``) -> the
+    ``n`` tokens each row generates: one `prefill_slots` into a fresh slot
+    cache, then one `decode_slots` chunk of n - 1 steps."""
+    B, P = prompt.shape
+    if start is None:
+        start = np.zeros(B, np.int32)
+    if rng is None:
+        rng = jax.random.key(0)
+    cache, first = prefill_slots(
+        params, init_slot_cache(cfg, B, P + n), jnp.asarray(prompt),
+        jnp.arange(B, dtype=jnp.int32), jnp.asarray(start), rng, cfg,
+        **sampling)
+    _, toks = decode_slots(params, cache, first, jnp.ones(B, bool), rng,
+                           cfg, eos_id=eos_id, steps=n - 1, **sampling)
+    return np.asarray(toks)
+
+
 class TestGenerate:
     def test_prefill_matches_forward(self, tiny):
         cfg, params = tiny
         prompt = jax.random.randint(jax.random.key(1), (2, 5), 0,
                                     cfg.vocab_size)
         lf = forward(params, prompt, cfg)
-        lp, cache = G.prefill(params, prompt, cfg, 16)
-        np.testing.assert_allclose(np.asarray(lf), np.asarray(lp),
-                                   atol=1e-4)
+        hidden, cache = _prefill_hidden(params, prompt, cfg, 16,
+                                        jnp.zeros(2, jnp.int32))
+        np.testing.assert_allclose(
+            np.asarray(lf), np.asarray(_final_logits(params, hidden, cfg)),
+            atol=1e-4)
         assert int(cache["pos"]) == 5
         assert cache["k"].shape == (cfg.n_layers, 2, 16, cfg.kv_heads,
                                     cfg.head_dim)
@@ -46,69 +68,68 @@ class TestGenerate:
         sequential argmax over the full (uncached) forward produces."""
         cfg, params = tiny
         B, P, N = 2, 5, 6
-        prompt = jax.random.randint(jax.random.key(1), (B, P), 0,
-                                    cfg.vocab_size)
-        out = G.generate(params, prompt, cfg, max_new_tokens=N)
-        seq = np.asarray(prompt)
+        prompt = np.asarray(jax.random.randint(jax.random.key(1), (B, P), 0,
+                                               cfg.vocab_size))
+        out = _generate(params, cfg, prompt, N)
+        seq = prompt
         for _ in range(N):
             logits = forward(params, jnp.asarray(seq), cfg)
             nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1))
             seq = np.concatenate([seq, nxt[:, None]], axis=1)
-        np.testing.assert_array_equal(seq, np.asarray(out))
+        np.testing.assert_array_equal(seq[:, P:], out)
 
     def test_left_padded_batch_matches_unpadded_rows(self, tiny):
         """Variable-length prompts left-padded into one batch generate
         exactly what each prompt generates alone — pad masking + RoPE's
         relative-position property make the offset invisible."""
         cfg, params = tiny
-        p1 = jax.random.randint(jax.random.key(2), (1, 3), 0,
-                                cfg.vocab_size)
-        p2 = jax.random.randint(jax.random.key(3), (1, 6), 0,
-                                cfg.vocab_size)
+        p1 = np.asarray(jax.random.randint(jax.random.key(2), (1, 3), 0,
+                                           cfg.vocab_size))
+        p2 = np.asarray(jax.random.randint(jax.random.key(3), (1, 6), 0,
+                                           cfg.vocab_size))
         N, P = 5, 6
-        solo1 = np.asarray(G.generate(params, p1, cfg,
-                                      max_new_tokens=N))[0, 3:]
-        solo2 = np.asarray(G.generate(params, p2, cfg,
-                                      max_new_tokens=N))[0, 6:]
+        solo1 = _generate(params, cfg, p1, N)[0]
+        solo2 = _generate(params, cfg, p2, N)[0]
         batch = np.zeros((2, P), np.int32)
-        batch[0, P - 3:] = np.asarray(p1)[0]
-        batch[1, :] = np.asarray(p2)[0]
-        start = jnp.asarray([P - 3, 0], jnp.int32)
-        out = np.asarray(G.generate(params, jnp.asarray(batch), cfg,
-                                    max_new_tokens=N, start=start))
-        np.testing.assert_array_equal(out[0, P:], solo1)
-        np.testing.assert_array_equal(out[1, P:], solo2)
+        batch[0, P - 3:] = p1[0]
+        batch[1, :] = p2[0]
+        out = _generate(params, cfg, batch, N,
+                        start=np.asarray([P - 3, 0], np.int32))
+        np.testing.assert_array_equal(out[0], solo1)
+        np.testing.assert_array_equal(out[1], solo2)
 
     def test_eos_freezes_sequence(self, tiny):
         cfg, params = tiny
-        prompt = jax.random.randint(jax.random.key(1), (1, 4), 0,
-                                    cfg.vocab_size)
-        free = np.asarray(G.generate(params, prompt, cfg,
-                                     max_new_tokens=4))[0, 4:]
+        prompt = np.asarray(jax.random.randint(jax.random.key(1), (1, 4), 0,
+                                               cfg.vocab_size))
+        free = _generate(params, cfg, prompt, 4)[0]
         eos = int(free[1])  # force EOS at the second generated token
-        out = np.asarray(G.generate(params, prompt, cfg,
-                                    max_new_tokens=4,
-                                    eos_id=eos))[0, 4:]
+        out = _generate(params, cfg, prompt, 4, eos_id=eos)[0]
+        assert out[0] == free[0]
         assert out[1] == eos and out[2] == eos and out[3] == eos
 
     def test_moe_model_generates(self):
         cfg = dataclasses.replace(tiny_config(), dtype=jnp.float32,
                                   param_dtype=jnp.float32, moe_experts=4)
         params = init_params(jax.random.key(0), cfg)
-        prompt = jnp.zeros((1, 3), jnp.int32)
-        out = G.generate(params, prompt, cfg, max_new_tokens=3)
-        assert out.shape == (1, 6)
+        eng = InferenceEngine(params, cfg, slots=2, max_prompt_len=8,
+                              max_new_tokens=3)
+        out = eng.generate([0, 0, 0])
+        assert len(out) == 3
+        assert all(0 <= t < cfg.vocab_size for t in out)
 
     def test_undersized_cache_rejected(self, tiny):
-        """A cache too small for prompt+new tokens must error loudly —
+        """A prompt the cache cannot hold must error loudly — a
         dynamic_update_slice would otherwise clamp writes onto the last
         slot and corrupt attention silently."""
         cfg, params = tiny
-        prompt = jnp.zeros((1, 6), jnp.int32)
+        eng = InferenceEngine(params, cfg, slots=1, max_prompt_len=4,
+                              max_new_tokens=2)
+        with pytest.raises(ValueError, match="max_prompt_len"):
+            eng.submit([0] * 6)
         with pytest.raises(ValueError, match="max_len"):
-            G.generate(params, prompt, cfg, max_new_tokens=8, max_len=10)
-        with pytest.raises(ValueError, match="max_len"):
-            G.prefill(params, prompt, cfg, 4)
+            _prefill_hidden(params, jnp.zeros((1, 6), jnp.int32), cfg, 4,
+                            jnp.zeros(1, jnp.int32))
 
     def test_encoder_config_rejected(self, tiny):
         """Autoregressive decoding over a causal=False encoder would
@@ -116,21 +137,25 @@ class TestGenerate:
         cfg, params = tiny
         enc = dataclasses.replace(cfg, causal=False)
         with pytest.raises(ValueError, match="causal"):
-            G.generate(params, jnp.zeros((1, 4), jnp.int32), enc,
-                       max_new_tokens=2)
+            _prefill_hidden(params, jnp.zeros((1, 4), jnp.int32), enc, 4,
+                            jnp.zeros(1, jnp.int32))
 
     def test_sampled_generation_respects_temperature_rng(self, tiny):
         cfg, params = tiny
-        prompt = jax.random.randint(jax.random.key(1), (2, 4), 0,
-                                    cfg.vocab_size)
-        a = G.generate(params, prompt, cfg, max_new_tokens=6,
-                       greedy=False, rng=jax.random.key(5))
-        b = G.generate(params, prompt, cfg, max_new_tokens=6,
-                       greedy=False, rng=jax.random.key(5))
-        c = G.generate(params, prompt, cfg, max_new_tokens=6,
-                       greedy=False, rng=jax.random.key(6))
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-        assert not np.array_equal(np.asarray(a), np.asarray(c))
+        prompt = np.asarray(jax.random.randint(jax.random.key(1), (2, 4), 0,
+                                               cfg.vocab_size))
+        a = _generate(params, cfg, prompt, 6, greedy=False,
+                      rng=jax.random.key(5))
+        b = _generate(params, cfg, prompt, 6, greedy=False,
+                      rng=jax.random.key(5))
+        c = _generate(params, cfg, prompt, 6, greedy=False,
+                      rng=jax.random.key(6))
+        np.testing.assert_array_equal(a, b)
+        assert not np.array_equal(a, c)
+        # a temperature near zero sharpens sampling into the argmax
+        cold = _generate(params, cfg, prompt, 6, greedy=False,
+                         temperature=1e-4, rng=jax.random.key(6))
+        np.testing.assert_array_equal(cold, _generate(params, cfg, prompt, 6))
 
 
 class TestServeLLM:
@@ -142,42 +167,6 @@ class TestServeLLM:
         yield serve
         serve.shutdown()
         ray_tpu.shutdown()
-
-    def test_llm_deployment_batches_and_generates(self, serve_rt):
-        serve = serve_rt
-        from ray_tpu.serve.llm import build_llm_deployment
-
-        app = build_llm_deployment(
-            "tiny", max_prompt_len=8, max_new_tokens=4, max_batch_size=4)
-        handle = serve.run(app, name="llm")
-        prompts = [[1, 2, 3], [4, 5], [6, 7, 8, 9]]
-        futs = [handle.remote(p) for p in prompts]
-        outs = [f.result(timeout_s=120) for f in futs]
-        for o in outs:
-            assert len(o["token_ids"]) == 4
-        # greedy generation is deterministic per prompt, batched or not
-        again = handle.remote([1, 2, 3]).result(timeout_s=120)
-        assert again["token_ids"] == outs[0]["token_ids"]
-        # oversized prompts are rejected per-request, not silently
-        # clipped (and don't poison the coalesced batch)
-        with pytest.raises(Exception, match="max_prompt_len"):
-            handle.remote(list(range(20))).result(timeout_s=120)
-
-    def test_streaming_tokens_match_batched(self, serve_rt):
-        """stream() yields the same greedy tokens one at a time that the
-        batched __call__ path returns all at once."""
-        serve = serve_rt
-        from ray_tpu.serve.llm import build_llm_deployment
-
-        app = build_llm_deployment(
-            "tiny", name="llm_s", max_prompt_len=8, max_new_tokens=4,
-            max_batch_size=4)
-        handle = serve.run(app, name="llm_s")
-        batched = handle.remote([1, 2, 3]).result(timeout_s=120)
-        gen = handle.options(method_name="stream",
-                             stream=True).remote([1, 2, 3])
-        streamed = [chunk["token_id"] for chunk in gen]
-        assert streamed == batched["token_ids"]
 
     def test_continuous_deployment_serves_concurrent_requests(self,
                                                               serve_rt):
@@ -217,18 +206,3 @@ class TestServeLLM:
                              stream=True).remote([3, 1, 4])
         streamed = [chunk["token_id"] for chunk in gen]
         assert streamed == whole["token_ids"]
-
-    def test_batcher_cap_matches_compiled_shape(self, serve_rt):
-        """max_batch_size below the @batch default (8) must still cap
-        the coalesced batch — the compiled XLA program only exists for
-        that exact shape."""
-        serve = serve_rt
-        from ray_tpu.serve.llm import build_llm_deployment
-
-        app = build_llm_deployment(
-            "tiny", name="llm2", max_prompt_len=4, max_new_tokens=2,
-            max_batch_size=2)
-        handle = serve.run(app, name="llm2")
-        futs = [handle.remote([1 + i]) for i in range(6)]
-        outs = [f.result(timeout_s=120) for f in futs]
-        assert all(len(o["token_ids"]) == 2 for o in outs)

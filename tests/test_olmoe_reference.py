@@ -232,10 +232,12 @@ def test_no_buffer_grows_with_experts_times_capacity():
 @pytest.mark.parametrize("qk_norm", [True, False])
 def test_prefill_and_decode_agree_with_the_reference_forward(qk_norm):
     """`qkv_proj` is the one definition the cache paths share: prefill on
-    a prompt and four decode steps through the cache give the logits of
-    the reference's full forward at those positions. Without the norms
+    a prompt and four decode steps through the slot cache give the logits
+    of the reference's full forward at those positions. Without the norms
     (the same weights) they must NOT agree: the norms are in effect."""
-    from ray_tpu.models import generate
+    from ray_tpu.models.engine import (_decode_one, init_slot_cache,
+                                       prefill_slots)
+    from ray_tpu.models.generate import _final_logits, _prefill_hidden
 
     conf = _conf(top_k=2, layers=2)
     cfg, fields, params, tokens = _setup(conf)
@@ -246,11 +248,14 @@ def test_prefill_and_decode_agree_with_the_reference_forward(qk_norm):
     row = tokens[0, :16]
     want = OLMOE.reference_logits(params, row, fields, conf)
     P = 12
-    logits, cache = generate.prefill(params, row[None, :P], cfg, 32)
-    errs = [_rel_rms(logits[0], want[:P])]
+    slot, start = jnp.zeros(1, jnp.int32), jnp.zeros(1, jnp.int32)
+    hidden, _ = _prefill_hidden(params, row[None, :P], cfg, P, start)
+    errs = [_rel_rms(_final_logits(params, hidden, cfg)[0], want[:P])]
+    cache, _ = prefill_slots(params, init_slot_cache(cfg, 1, 32),
+                             row[None, :P], slot, start, jax.random.key(0),
+                             cfg)
     for t in range(P, 16):
-        logits, cache = generate.decode_step(params, cache, row[None, t],
-                                             cfg)
+        cache, logits = _decode_one(params, cache, row[None, t], cfg)
         errs.append(_rel_rms(logits[0], want[t]))
     if qk_norm:
         assert max(errs) < TOL, errs
