@@ -1,6 +1,6 @@
 """Smoke run of the two flagship paths on a real TPU chip.
 
-    python chip_smoke.py            # one chip: train phase, then serve phase
+    python chip_smoke.py            # one chip: kernel, train, then serve phase
     python chip_smoke.py --chips 4  # four-chip host: one chip vs fsdp=2 x tensor=2
 
 Drives the entry points a user calls — ``ray_tpu.init()``, ``JaxTrainer``,
@@ -16,6 +16,15 @@ One process per chip: this process never imports JAX. Each phase runs in a
 worker that leases the chip and exits before the next phase starts, and
 the device description on the last line comes from the worker that held
 it. Finding no TPU is a failure, never a CPU run.
+
+The kernel phase holds `ray_tpu.ops.flash_attention` at the train cells'
+per-chip shape ([4, 4096, 16, 128] bf16, causal) to `reference_attention`
+in float32 on the same inputs: output and the three gradients, as relative
+RMS errors; then at an encoder's ([2, 197, 12, 64], no mask), a length
+that is no multiple of a tile, which only the chip's compiler can refuse.
+A builder who changes the kernel passes ``kernel_phase(kernel_files=...)``
+the parent's module file: it is held to the reference beside the tree's,
+in the same process and on the same inputs.
 
 Output: one JSON object per line; every line that carries a number names
 the ``platform``, ``device_kind`` and ``device_count`` it was taken on. The
@@ -59,6 +68,100 @@ def _require(checks: dict, phase: str):
         raise SmokeFailure(f"{phase}: failed checks {failed}")
 
 
+# ----------------------------------------------------------- kernel phase
+
+def _device_of(config) -> tuple:
+    """(first device, its description) in a worker; a platform other than
+    the one asked for is an error, never a run."""
+    import jax
+
+    devices = jax.devices()
+    dev = devices[0]
+    if dev.platform != config["platform"]:
+        raise RuntimeError(
+            f"expected platform {config['platform']!r}, JAX found "
+            f"{dev.platform!r} ({dev.device_kind})")
+    return dev, {"platform": dev.platform, "kind": dev.device_kind,
+                 "count": len(devices)}
+
+
+def _kernel_loop(config):
+    """Runs in the worker that leased the chip: `flash_attention` of the
+    tree (and of every kernel module file named beside it) against
+    `reference_attention` in float32, same inputs, output and gradients."""
+    import functools
+    import importlib
+    import importlib.util
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu import train
+    from ray_tpu.parallel import reference_attention
+
+    _, out_device = _device_of(config)
+    B, T, H, D = config["shape"]
+    keys = jax.random.split(jax.random.key(config["seed"]), 4)
+    q, k, v, do = (jax.random.normal(key, (B, T, H, D), jnp.float32).astype(
+        jnp.dtype(config["dtype"])) for key in keys)
+
+    def with_gradients(attn):
+        def run(q, k, v, do):
+            o, vjp = jax.vjp(functools.partial(
+                attn, causal=config["causal"]), q, k, v)
+            return (o,) + vjp(do)
+        return jax.jit(run)
+
+    # a row at a time: one row's float32 scores are H x T x T x 4 bytes
+    reference = with_gradients(reference_attention)
+    rows = [[np.asarray(x) for x in reference(*(
+        a[b:b + 1].astype(jnp.float32) for a in (q, k, v, do)))]
+        for b in range(B)]
+    want = [np.concatenate(parts) for parts in zip(*rows)]
+
+    modules = {"tree": importlib.import_module(
+        "ray_tpu.ops.flash_attention")}
+    for path in config["kernel_files"]:
+        spec = importlib.util.spec_from_file_location(
+            f"kernel_file_{len(modules)}", path)
+        modules[path] = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(modules[path])
+
+    def rel_rms(got, ref):
+        got = np.asarray(got, np.float32)
+        return float(np.sqrt(np.mean((got - ref) ** 2) / np.mean(ref ** 2)))
+
+    errors = {}
+    for label, module in modules.items():
+        got = with_gradients(module.flash_attention)(q, k, v, do)
+        errors[label] = {name: rel_rms(g, w) for name, g, w in
+                         zip(("o", "dq", "dk", "dv"), got, want)}
+    train.report({"device": out_device, "errors": errors})
+
+
+def kernel_phase(shape, *, platform: str, seed: int, causal: bool = True,
+                 dtype: str = "bfloat16", kernel_files=(),
+                 bound: float = 1e-2) -> dict:
+    """The attention kernel at ``shape`` ([B, T, H, D]) against the
+    float32 reference: relative RMS error of the output and of dq, dk, dv,
+    each under ``bound`` (bf16 rounding of the results alone is about
+    2e-3). Returns the device description from the worker."""
+    out = _run_leased(
+        _kernel_loop, dict(shape=list(shape), causal=causal, dtype=dtype,
+                           seed=seed, kernel_files=list(kernel_files)),
+        platform=platform)
+    dev = out["device"]
+    _emit("kernel", dev, shape=list(shape), dtype=dtype, causal=causal,
+          rel_rms_error_vs_float32_reference=out["errors"], bound=bound)
+    checks = {"platform": dev["platform"] == platform}
+    for label, errs in out["errors"].items():
+        for name, err in errs.items():
+            checks[f"{label}:{name}"] = math.isfinite(err) and err <= bound
+    _require(checks, "kernel")
+    return dev
+
+
 # ------------------------------------------------------------ train phase
 
 def _train_loop(config):
@@ -73,14 +176,9 @@ def _train_loop(config):
                                          make_optimizer, make_train_step)
 
     devices = jax.devices()
-    dev = devices[0]
-    out = {"device": {"platform": dev.platform, "kind": dev.device_kind,
-                      "count": len(devices)},
+    dev, described = _device_of(config)
+    out = {"device": described,
            "tpu_visible_chips": os.environ.get("TPU_VISIBLE_CHIPS")}
-    if dev.platform != config["platform"]:
-        raise RuntimeError(
-            f"expected platform {config['platform']!r}, JAX found "
-            f"{dev.platform!r} ({dev.device_kind})")
     B, T = config["batch"], config["seq"]
 
     def cfg_with(attention_impl):
@@ -156,23 +254,15 @@ def _train_loop(config):
     train.report(out)
 
 
-def run_train_loop(model: str, *, batch: int, seq: int, steps: int,
-                   platform: str, seed: int, chips: int = 1, mesh=None,
-                   eval_impls=("pallas", "xla"), eval_batch: int = 1,
-                   param_dtype: str = "bfloat16") -> dict:
-    """One JaxTrainer run of ``_train_loop`` in a worker that leases
-    ``chips`` TPU chips (none when ``platform`` is "cpu"); returns what
-    the loop reported, as plain Python values."""
+def _run_leased(loop, config: dict, *, platform: str, chips: int = 1) -> dict:
+    """One JaxTrainer run of ``loop`` in a worker that leases ``chips`` TPU
+    chips (none when ``platform`` is "cpu"); returns what the loop
+    reported, as plain Python values."""
     from ray_tpu.train import JaxTrainer, ScalingConfig
 
     on_tpu = platform == "tpu"
     result = JaxTrainer(
-        _train_loop,
-        train_loop_config=dict(
-            model=model, batch=batch, seq=seq, steps=steps,
-            platform=platform, seed=seed, mesh=mesh,
-            eval_impls=list(eval_impls), eval_batch=eval_batch,
-            param_dtype=param_dtype),
+        loop, train_loop_config=dict(config, platform=platform),
         scaling_config=ScalingConfig(
             num_workers=1, use_tpu=on_tpu,
             tpus_per_worker=chips if on_tpu else None),
@@ -180,6 +270,19 @@ def run_train_loop(model: str, *, batch: int, seq: int, steps: int,
     if result.error is not None:
         raise result.error
     return result.metrics
+
+
+def run_train_loop(model: str, *, batch: int, seq: int, steps: int,
+                   platform: str, seed: int, chips: int = 1, mesh=None,
+                   eval_impls=("pallas", "xla"), eval_batch: int = 1,
+                   param_dtype: str = "bfloat16") -> dict:
+    """``_train_loop`` in a worker that leases ``chips`` TPU chips."""
+    return _run_leased(
+        _train_loop,
+        dict(model=model, batch=batch, seq=seq, steps=steps, seed=seed,
+             mesh=mesh, eval_impls=list(eval_impls), eval_batch=eval_batch,
+             param_dtype=param_dtype),
+        platform=platform, chips=chips)
 
 
 def train_phase(model: str, *, batch: int, seq: int, steps: int,
@@ -410,6 +513,11 @@ def _run(args) -> dict:
             return sharded_phase(
                 MODEL, batch=4, seq=2048, steps=3, platform="tpu",
                 seed=args.seed, mesh={"fsdp": 2, "tensor": 2})
+        kernel_phase((4, 4096, 16, 128), platform="tpu", seed=args.seed)
+        _wait_chips_free(1)
+        kernel_phase((2, 197, 12, 64), causal=False, platform="tpu",
+                     seed=args.seed)
+        _wait_chips_free(1)
         train_phase(MODEL, batch=4, seq=2048, steps=5, platform="tpu",
                     seed=args.seed, first_loss_range=(11.5, 13.0))
         _wait_chips_free(1)

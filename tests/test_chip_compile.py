@@ -12,6 +12,7 @@ the fixtures steer both so the compiled text really holds the Mosaic
 kernel (`tpu_custom_call`).
 """
 
+import functools
 import importlib
 import re
 
@@ -83,20 +84,65 @@ def _device_bytes(compiled) -> int:
     return m.argument_size_in_bytes + m.temp_size_in_bytes
 
 
-def test_flash_forward_and_backward_compile(one_chip, mosaic):
+def _flash_loss(q, k, v, **kw):
     from ray_tpu.ops import flash_attention
 
-    x = jax.ShapeDtypeStruct((BATCH, SEQ, 32, 64), jnp.bfloat16,
-                             sharding=one_chip)
-    fwd = jax.jit(flash_attention).lower(x, x, x).compile()
+    return flash_attention(q, k, v, **kw).astype(jnp.float32).sum()
+
+
+@pytest.mark.parametrize("shape, kw", [
+    ((BATCH, SEQ, 32, 64), {}),  # llama3-1b, chip_smoke.py
+    ((4, 4096, 16, 128), {}),    # a chip's share in all three train cells
+    ((1, 8192, 4, 128), {}),     # K/V whole and in float32 overran VMEM here
+    ((1, 16384, 2, 128), {}),
+    # lengths that are no multiple of a tile: the block is, and T is padded
+    ((2, 197, 4, 64), dict(causal=False)),  # an encoder (ViT's 196 + 1)
+    ((2, 300, 4, 64), {}),
+    ((2, 77, 4, 64), {}),
+    ((2, 700, 4, 128), {}),  # one block of 768, the largest that is picked
+    ((1, 1000, 4, 128), dict(causal=False)),
+    ((2, 300, 4, 64), dict(block_q=100, block_k=40)),  # named, not whole tiles
+], ids=lambda x: f"T{x[1]}x{x[3]}" if isinstance(x, tuple) else
+   "-".join(f"{k}{v}" for k, v in x.items()) or "causal")
+def test_flash_forward_and_backward_compile(one_chip, mosaic, shape, kw):
+    from ray_tpu.ops import flash_attention
+
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    fwd = jax.jit(functools.partial(flash_attention, **kw)).lower(
+        x, x, x).compile()
     assert "tpu_custom_call" in fwd.as_text()
+    bwd = jax.jit(jax.grad(functools.partial(_flash_loss, **kw),
+                           argnums=(0, 1, 2))).lower(x, x, x).compile()
+    # the forward kernel and the backward kernel
+    assert bwd.as_text().count("tpu_custom_call") >= 2
 
-    def loss(q, k, v):
-        return flash_attention(q, k, v).astype(jnp.float32).sum()
 
-    bwd = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(x, x, x).compile()
-    # forward + dq + dk/dv kernels
-    assert bwd.as_text().count("tpu_custom_call") >= 3
+def _kernel_float32_converts(jaxpr, inside=False) -> list:
+    """Shapes of every `convert_element_type -> float32` inside the
+    Pallas kernels of ``jaxpr`` (their loops and branches included)."""
+    found = []
+    for eqn in jaxpr.eqns:
+        kernel = inside or eqn.primitive.name == "pallas_call"
+        if (inside and eqn.primitive.name == "convert_element_type"
+                and eqn.params["new_dtype"] == jnp.float32):
+            found.append(tuple(eqn.outvars[0].aval.shape))
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)  # ClosedJaxpr -> Jaxpr
+                if hasattr(sub, "eqns"):
+                    found += _kernel_float32_converts(sub, kernel)
+    return found
+
+
+def test_flash_operands_reach_the_mxu_as_they_arrive(mosaic):
+    """No kernel converts an operand to float32 at K/V's whole [T, d]
+    (the parent's kernels did, three operands each: VMEM and VPU time the
+    MXU then rounded away); what float32 there is, is tile-sized."""
+    T = 4096
+    x = jax.ShapeDtypeStruct((4, T, 16, 128), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(jax.grad(_flash_loss, argnums=(0, 1, 2)))(x, x, x)
+    assert str(jaxpr).count("pallas_call") >= 2
+    assert not [s for s in _kernel_float32_converts(jaxpr.jaxpr) if T in s]
 
 
 def test_llama3_1b_train_step_fits_one_chip(one_chip, mosaic):

@@ -45,6 +45,33 @@ def test_train_phase_tiny_on_cpu(cpu_cluster, capsys):
     assert line["kernel_vs_xla_abs_diff"] <= 1e-2
 
 
+def test_kernel_phase_tiny_on_cpu(cpu_cluster, capsys):
+    """The kernel phase at a tiny float32 shape, with the tree's own
+    kernel file named beside it: both are held to the reference."""
+    import ray_tpu.ops
+
+    beside = os.path.join(os.path.dirname(ray_tpu.ops.__file__),
+                          "flash_attention.py")
+    dev = chip_smoke.kernel_phase((1, 48, 2, 16), platform="cpu", seed=3,
+                                  dtype="float32", kernel_files=[beside],
+                                  bound=1e-4)
+    assert dev["platform"] == "cpu"
+    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert line["phase"] == "kernel" and line["shape"] == [1, 48, 2, 16]
+    errors = line["rel_rms_error_vs_float32_reference"]
+    assert sorted(errors) == sorted(["tree", beside])
+    assert errors["tree"] == errors[beside]
+    assert set(errors["tree"]) == {"o", "dq", "dk", "dv"}
+    assert all(0 < e <= 1e-4 for e in errors["tree"].values())
+
+
+def test_kernel_phase_fails_over_its_bound(cpu_cluster):
+    with pytest.raises(chip_smoke.SmokeFailure, match="kernel: failed"):
+        chip_smoke.kernel_phase((1, 32, 1, 8), platform="cpu", seed=0,
+                                causal=False,
+                                dtype="bfloat16", bound=1e-6)
+
+
 def test_train_phase_fails_on_wrong_platform(cpu_cluster):
     """Expecting a TPU and finding the CPU is a failure, not a CPU run."""
     with pytest.raises(Exception, match="expected platform 'tpu'"):
