@@ -23,31 +23,40 @@ def train_from_forward(forward_flops: float) -> float:
 def flash_attention_cost(batch: int, heads: int, seq_q: int, seq_k: int,
                          head_dim: int, *, causal: bool = True,
                          backward: bool = False,
-                         bytes_per_elem: int = 2) -> dict:
+                         bytes_per_elem: int = 2,
+                         v_head_dim: int | None = None) -> dict:
     """One call of the fused attention kernel family on [batch, seq,
     heads, head_dim] operands (K/V already expanded to `heads`, as the
-    caller hands them over).
+    caller hands them over). ``head_dim`` is the width of a query and a
+    key head, ``v_head_dim`` that of a value (and output) head where the
+    two differ (latent attention); absent, they are equal.
 
-    forward: QK^T and PV, 2*Tq*Tk*hd FLOPs each per (batch, head);
-    backward: recompute QK^T, then dV, dP, dQ, dK: five such products
+    forward: QK^T, 2*Tq*Tk*head_dim FLOPs per (batch, head), and PV,
+    2*Tq*Tk*v_head_dim; backward: recompute QK^T, then dV, dP (the value
+    width), dQ, dK (the query/key width): five such products
     (FlashAttention-2). Causality needs half of each. Bytes: each operand
     read once, each result written once; the float32 logsumexp / delta
     rows are counted at 4 bytes.
     """
+    vd = head_dim if v_head_dim is None else v_head_dim
     pairs = batch * heads
-    per_product = 2.0 * seq_q * seq_k * head_dim * (0.5 if causal else 1.0)
+    half = 0.5 if causal else 1.0
+    qk_product = 2.0 * seq_q * seq_k * head_dim * half
+    pv_product = 2.0 * seq_q * seq_k * vd * half
     q_elems = pairs * seq_q * head_dim
     k_elems = pairs * seq_k * head_dim
+    v_elems = pairs * seq_k * vd
+    o_elems = pairs * seq_q * vd
     rows = pairs * seq_q * 4
     if not backward:
-        flops = 2 * per_product * pairs
-        nbytes = (q_elems + 2 * k_elems) * bytes_per_elem \
-            + q_elems * bytes_per_elem + rows          # o, lse
+        flops = (qk_product + pv_product) * pairs
+        nbytes = (q_elems + k_elems + v_elems) * bytes_per_elem \
+            + o_elems * bytes_per_elem + rows          # o, lse
     else:
-        flops = 5 * per_product * pairs
-        nbytes = (2 * q_elems + 2 * k_elems) * bytes_per_elem \
-            + q_elems * bytes_per_elem + 2 * rows \
-            + (q_elems + 2 * k_elems) * bytes_per_elem  # dq, dk, dv
+        flops = (3 * qk_product + 2 * pv_product) * pairs
+        nbytes = (q_elems + o_elems + k_elems + v_elems) * bytes_per_elem \
+            + o_elems * bytes_per_elem + 2 * rows \
+            + (q_elems + k_elems + v_elems) * bytes_per_elem  # dq, dk, dv
         # reads: q, do, k, v, o (for delta), lse, delta
     return {"flops": flops, "bytes": float(nbytes)}
 

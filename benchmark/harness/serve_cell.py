@@ -101,8 +101,12 @@ class BenchReplica(_ContinuousLLMReplica):
         reference through their tokens: the first token `prefill_slots`
         samples and the first one the `decode_slots` chunk program samples
         must be the argmax of the reference's logits (where its top two
-        are further apart than the logits' own error). Runs on the
-        engine's own cache while it is idle, then resets the slot
+        are further apart than the logits' own error). The reference
+        computes with weights of its own: the float32 tree the program's
+        initialiser makes from the same seed, as the replica got it before
+        it stored it its own way (`serving_params`), never the engine's
+        tree, so a fault in how the replica holds its weights shows. Runs
+        on the engine's own cache while it is idle, then resets the slot
         bookkeeping as `warmup()` does."""
         import jax
         import jax.numpy as jnp
@@ -112,6 +116,7 @@ class BenchReplica(_ContinuousLLMReplica):
         from ray_tpu.models.engine import (_decode_one, decode_slots,
                                            prefill_slots)
         from ray_tpu.models.generate import _final_logits, _prefill_hidden
+        from ray_tpu.models.transformer import init_params
 
         eng, cfg, fields = self.engine, self.engine.cfg, self._bench_fields
         arch, conf = self._bench_arch, self._bench_conf
@@ -150,10 +155,16 @@ class BenchReplica(_ContinuousLLMReplica):
             eng.cache = {"k": eng.cache["k"], "v": eng.cache["v"],
                          "pos": jnp.zeros_like(eng.cache["pos"]),
                          "start": jnp.zeros_like(eng.cache["start"])}
+        # the replica's own weights came from this initialiser and this key
+        # (`_replica_params`); as one program, so that no leaf's random
+        # draw stands beside its scaled copy while the engine's tree and
+        # cache hold their share of the chip
+        ref_params = jax.jit(lambda k: init_params(k, cfg))(
+            jax.random.key(seed))
         rows, ok = [], True
         for i, p in enumerate(prompts):
             want = arch.reference_logits(
-                eng.params, p + [int(first[i])], fields, conf, last=2)
+                ref_params, p + [int(first[i])], fields, conf, last=2)
             pre = logits_agree(got_pre[i], want[0], dtype)
             dec = logits_agree(got_dec[i], want[1], dtype)
             token_ok = _is_argmax(first[i], want[0], pre) \

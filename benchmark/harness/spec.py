@@ -5,13 +5,16 @@ per-layer metric is a file found BY NAME from `BENCHMARK.json`:
 
     benchmark/configs/<config>.json          sizes as run, source, cuts
     benchmark/traffic/<traffic>.json         kind + parameters of a mix
-    benchmark/layer_metrics/<metric>.json    layer, moves, cells, reader
+    benchmark/layer_metrics/<metric>.json    layer, moves, reader
     benchmark/readers/<reader>.py            one function: evidence -> number
     benchmark/architectures/<name>.py        plain reference + required work
 
 so a later PR adds a cell or a metric by adding files and one entry, and
-edits nothing here. This module imports neither JAX nor the program: the
-driver process reads it and must stay off the chip.
+edits nothing here. Which cells report a metric is said in ONE place, the
+`workloads` list of its entry in `BENCHMARK.json`: a metric's file holds
+none, so a new cell joins a metric that is there by a list entry alone.
+This module imports neither JAX nor the program: the driver process reads
+it and must stay off the chip.
 """
 
 from __future__ import annotations

@@ -28,42 +28,59 @@ def _make_batch(seed: int, step: int, rows: int, seq: int, vocab: int):
 
 def check_against_reference(params, cfg, fields, conf, arch, mesh,
                             seed: int, rows: int, seq: int) -> dict:
-    """The program's forward and loss on ``rows`` seeded rows of ``seq``
-    tokens against the plain float32 reference of the configuration's
-    architecture (``arch``, from `spec.load_architecture`), row by row."""
+    """The program's forward and objective on ``rows`` seeded rows of
+    ``seq`` tokens against the plain float32 reference of the
+    configuration's architecture (``arch``, from `spec.load_architecture`),
+    row by row: the logits, the next-token cross entropy (the program's
+    `metrics["loss"]`, not the total it differentiates) and every further
+    term the architecture's optional `reference_terms` gives, each against
+    the metric of that name; the total against the weighted sum the config
+    file's `objective` states (`reference.objective_agrees`)."""
     import functools
 
     import jax
     import jax.numpy as jnp
 
-    from benchmark.harness.reference import (LOSS_ABS_TOL, logits_agree,
+    from benchmark.harness.reference import (logits_agree, objective_agrees,
                                              reference_loss)
     from ray_tpu.models.transformer import forward, loss_fn
 
     batch = _make_batch(seed ^ 0x5EED, 0, rows, seq, cfg.vocab_size)
     tokens = batch["tokens"]
     fwd = jax.jit(functools.partial(forward, cfg=cfg, mesh=mesh))
-    loss = jax.jit(lambda p, b: loss_fn(p, b, cfg, mesh)[0])
+    loss = jax.jit(lambda p, b: loss_fn(p, b, cfg, mesh))
     got_logits = fwd(params, jnp.asarray(tokens[:, :-1]))
-    got_loss = float(loss(params, {"tokens": jnp.asarray(tokens)}))
+    total, metrics = loss(params, {"tokens": jnp.asarray(tokens)})
+    total = float(total)
+    metrics = {k: float(v) for k, v in metrics.items()}
     dtype = jnp.dtype(cfg.dtype).name
-    worst, ref_losses = None, []
+    more_terms = getattr(arch, "reference_terms", None)
+    worst, ref_rows = None, []
     for r in range(rows):
         want = arch.reference_logits(params, tokens[r, :-1], fields, conf)
         res = logits_agree(got_logits[r], want, dtype)
-        ref_losses.append(float(reference_loss(want, tokens[r, 1:])))
+        ref = {"loss": float(reference_loss(want, tokens[r, 1:]))}
         if worst is None or res["rel_rms_error"] > worst["rel_rms_error"]:
             worst = res
         del want
+        if more_terms is not None:
+            ref.update({k: float(v) for k, v in more_terms(
+                params, tokens[r], fields, conf).items()})
+        ref_rows.append(ref)
     del got_logits
-    ref_loss = sum(ref_losses) / rows
-    loss_tol = LOSS_ABS_TOL[dtype]
+    reference = {k: sum(ref[k] for ref in ref_rows) / rows
+                 for k in ref_rows[0]}
+    objective = objective_agrees(total, metrics, reference,
+                                 conf.get("objective"), dtype,
+                                 getattr(arch, "TERM_ABS_TOL", None))
+    cross = objective["terms"]["loss"]
     return {"reference": spec.architecture_name(conf),
-            "logits": worst, "loss": got_loss, "reference_loss": ref_loss,
-            "loss_abs_diff": abs(got_loss - ref_loss),
-            "loss_tolerance": loss_tol, "rows": rows, "seq": seq,
-            "ok": bool(worst["ok"] and math.isfinite(got_loss)
-                       and abs(got_loss - ref_loss) <= loss_tol)}
+            "logits": worst, "loss": cross["program"],
+            "reference_loss": cross["reference"],
+            "loss_abs_diff": cross["abs_diff"],
+            "loss_tolerance": cross["tolerance"], "rows": rows, "seq": seq,
+            "objective": objective,
+            "ok": bool(worst["ok"] and objective["ok"])}
 
 
 def train_loop(config):
@@ -138,6 +155,10 @@ def train_loop(config):
     out["collectives_in_program"] = sorted(
         c for c in ("all-gather", "reduce-scatter", "all-reduce")
         if c in text)
+    # device time by `jax.named_scope`: the trace names an operation by
+    # its HLO instruction, the compiled text says which scope that
+    # instruction was traced under
+    scopes = xplane.op_scopes(text) if config.get("trace_dir") else {}
     del text
     state, metrics = step(state, first)
     losses = [float(metrics["loss"])]
@@ -150,6 +171,14 @@ def train_loop(config):
     seconds = config["seconds"]
     compiles.mark()
     pending = []          # metrics of dispatched, unfinished steps
+    in_window = []        # ... of the window's finished steps, on the host
+
+    def wait_step():
+        """The oldest dispatched step's scalars, once it has finished."""
+        done = jax.device_get(pending.pop(0))
+        in_window.append(done)
+        losses.append(float(done["loss"]))
+
     i = 1                 # batch index; 0 was the warm-up
     tracing = False
     batch = _make_batch(seed, i, rows, seq, cfg.vocab_size)
@@ -159,7 +188,7 @@ def train_loop(config):
     while True:
         if trace_steps and not tracing and done_steps >= trace_from:
             while pending:   # nothing in flight when the trace starts
-                losses.append(float(pending.pop(0)))
+                wait_step()
                 done_steps += 1
             jax.profiler.start_trace(
                 trace_dir, profiler_options=probes.trace_options())
@@ -167,18 +196,18 @@ def train_loop(config):
                 done_steps
         with ann("bench:train.dispatch"):
             state, metrics = step(state, batch)
-        pending.append(metrics["loss"])
+        pending.append(metrics)
         i += 1
         with ann("bench:train.make_batch"):
             batch = _make_batch(seed, i, rows, seq, cfg.vocab_size)
         if len(pending) > 1:   # one step always queued behind the running
             with ann("bench:train.wait_step"):
-                losses.append(float(pending.pop(0)))
+                wait_step()
             done_steps += 1
         if tracing and done_steps - s_trace0 >= trace_steps:
             with ann("bench:train.wait_step"):
                 while pending:
-                    losses.append(float(pending.pop(0)))
+                    wait_step()
                     done_steps += 1
             out["trace_wall_s"] = time.perf_counter() - t_trace0
             out["trace_steps"] = done_steps - s_trace0
@@ -187,7 +216,7 @@ def train_loop(config):
         if time.perf_counter() - t_open >= seconds and not tracing:
             break
     while pending:
-        losses.append(float(pending.pop(0)))
+        wait_step()
         done_steps += 1
     window_s = time.perf_counter() - t_open
     out["compilations_in_window"] = compiles.since_mark()
@@ -195,12 +224,20 @@ def train_loop(config):
     out["steps"] = done_steps
     out["tokens"] = done_steps * rows * seq
     out["losses"] = losses
+    # the step's own counters (whatever `loss_fn` and the step report:
+    # `loss`, `grad_norm`, an MoE block's `moe_load_max_over_mean`, ...),
+    # each as its mean over the window's steps
+    out["step_metrics"] = {
+        k: sum(float(m[k]) for m in in_window) / len(in_window)
+        for k in (in_window[0] if in_window else {})}
     out["step_counter"] = int(state["step"])
     out["memory_peak_bytes"] = probes.memory_peak_bytes()
     if trace_dir:
         red = xplane.reduce_trace(trace_dir)
         red.pop("op_count", None)
         out["trace"] = red
+        out["op_scopes"] = {k: scopes[k] for k in red.get("op_seconds", {})
+                            if k in scopes}
         # the steps the device ran inside the trace, from the trace itself
         out["trace_steps"] = xplane.module_executions(
             red, r"^jit_step")["count"]

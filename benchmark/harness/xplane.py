@@ -110,6 +110,35 @@ def op_key(name: str) -> str:
     return key
 
 
+_OP_NAME = re.compile(r'\bmetadata=\{[^}]*?\bop_name="([^"]*)"')
+
+
+def op_scopes(hlo_text: str) -> Dict[str, str]:
+    """{operation key: scope path} from a compiled program's text: the
+    `op_name` of each instruction's metadata, which holds the
+    `jax.named_scope`s the instruction was traced under
+    (`jit(step)/transpose(jvp())/while/body/.../moe.dispatch/gather`; a
+    fusion carries its root's). The trace names a device operation by its
+    instruction (`op_key`), and instruction names change with any edit of
+    the program or the compiler; scopes are names the program chose."""
+    out = {}
+    for line in hlo_text.splitlines():
+        m = _OP_NAME.search(line)
+        if m and " = " in line:
+            out[op_key(re.sub(r"^\s*ROOT\s+", "", line))] = m.group(1)
+    return out
+
+
+def scope_seconds_matching(reduced: dict, scopes: Dict[str, str],
+                           pattern: str) -> float:
+    """Self time of the operations whose scope path ``pattern`` is found
+    in (searched anywhere: a backward or rematerialised operation carries
+    its scope inside `transpose(jvp(...))` and `checkpoint` wrappers)."""
+    rx = re.compile(pattern)
+    return sum(v for k, v in reduced.get("op_seconds", {}).items()
+               if rx.search(scopes.get(k, "")))
+
+
 # ---- reduction ------------------------------------------------------------
 
 def reduce_planes(planes: Dict[str, Dict[str, List[Event]]], *,

@@ -27,6 +27,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from benchmark.harness import spec  # noqa: E402
 
+
 def _on(tree, sharding):
     import jax
 
@@ -119,7 +120,7 @@ def rehearse_serve(cell, conf, traffic, topo, args):
 
     from ray_tpu.models.engine import (InferenceEngine, decode_slots,
                                        init_slot_cache, prefill_slots)
-    from ray_tpu.models.transformer import init_params
+    from ray_tpu.models.transformer import init_params, serving_params
 
     dep = traffic["deployment"]
     slots = args.slots or dep["slots"]
@@ -127,8 +128,10 @@ def rehearse_serve(cell, conf, traffic, topo, args):
     cfg = spec.build_transformer_config(conf, **over)
     one = SingleDeviceSharding(topo.devices[0])
     max_len = dep["max_prompt_len"] + dep["max_new_tokens"]
-    params = _on(jax.eval_shape(lambda k: init_params(k, cfg),
-                                jax.random.key(0)), one)
+    # the tree as a replica holds it (bf16 but for the float32 head)
+    params = _on(jax.eval_shape(
+        lambda k: serving_params(init_params(k, cfg), cfg),
+        jax.random.key(0)), one)
     cache = _on(jax.eval_shape(
         lambda: init_slot_cache(cfg, slots, max_len)), one)
     rng = _on(jax.eval_shape(lambda: jax.random.key(0)), one)

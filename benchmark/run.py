@@ -5,7 +5,9 @@
 A new process per run: starts a local ray_tpu cluster, runs the cell in the
 worker (train) or replica (serve) that leases the chip(s), and prints ONE
 last line of JSON: `correct`, `attempted`, `failed`, `metrics`, `device`
-(and `breakdown` in a traced run). Earlier lines are information. With
+(and `breakdown` in a traced run), then `compared`: each number the
+reference check compared beside its limit, which the last line on
+standard error repeats. Earlier lines are information. With
 `--trace 0` the metrics are the cell's end-to-end metrics, timed with the
 profiler off; with `--trace 1` they are its per-layer metrics.
 
@@ -58,6 +60,33 @@ def end_to_end_values(kind: str, out: dict, setup_s: float) -> dict:
     return vals
 
 
+def compared_numbers(check: dict) -> dict:
+    """{short name: [number, its limit]} of every number the reference
+    check compared (`out["check"]` of either kind of cell): what the last
+    line carries under `compared` and the last line on standard error
+    repeats."""
+    if "logits" in check:           # a train cell
+        out = {"logits_rel_rms": [check["logits"]["rel_rms_error"],
+                                  check["logits"]["tolerance"]]}
+        objective = check["objective"]
+        for name, term in objective["terms"].items():
+            out[f"{name}_abs_diff"] = [term["abs_diff"], term["tolerance"]]
+        if "weighted_sum" in objective:
+            out["total_minus_weighted_sum_rel"] = [
+                objective["weighted_sum"]["rel_diff"],
+                objective["weighted_sum"]["tolerance"]]
+        return out
+    out = {}                        # a serve cell: the worst prompt
+    for part in ("prefill", "decode"):
+        worst = max((r[part] for r in check["rows"]),
+                    key=lambda r: r["rel_rms_error"])
+        out[f"{part}_logits_rel_rms"] = [worst["rel_rms_error"],
+                                         worst["tolerance"]]
+    out["served_tokens_not_the_references"] = [
+        sum(not r["served_tokens_ok"] for r in check["rows"]), 0]
+    return out
+
+
 def run_cell(bench: dict, cell: dict, args, *, root: str = spec.ROOT,
              platform: str = "tpu", field_overrides=None,
              traffic_overrides=None) -> dict:
@@ -107,7 +136,7 @@ def run_cell(bench: dict, cell: dict, args, *, root: str = spec.ROOT,
           **{k: out[k] for k in (
               "compile_s", "check_s", "program_argument_bytes",
               "program_temp_bytes", "state_bytes", "steps", "window_s",
-              "warm", "info", "repeat", "n_requests_sent", "slow_events",
+              "step_metrics", "warm", "info", "repeat", "n_requests_sent", "slow_events",
               "sleeper") if k in out},
           first_token_ms={
               "mean": stats.mean(out["client"]["ttft_ms"]),
@@ -146,6 +175,7 @@ def run_cell(bench: dict, cell: dict, args, *, root: str = spec.ROOT,
                               if k in declared}
         if not trace.get("busy_s"):
             line["correct"] = False  # a traced run must see the device
+    line["compared"] = compared_numbers(out["check"])
     return line
 
 
@@ -245,6 +275,8 @@ def main(argv=None) -> int:
     reaped = _reap_children()
     if line is not None:
         _info(shutdown=reaped)
+        print(json.dumps({"compared": line["compared"]}), file=sys.stderr,
+              flush=True)
         print(json.dumps(line), flush=True)
     return rc
 

@@ -65,6 +65,13 @@ def test_cell_runs_end_to_end_at_toy_size(cpu_cluster, cell):
     assert set(line["metrics"]) == declared
     for name, m in line["metrics"].items():
         assert m["value"] > 0 and m["unit"]
+    # every number the check compared, beside its limit, is the last key
+    assert list(line)[-1] == "compared"
+    assert sorted(line["compared"]) == (
+        ["logits_rel_rms", "loss_abs_diff"] if "train" in cell else
+        ["decode_logits_rel_rms", "prefill_logits_rel_rms",
+         "served_tokens_not_the_references"])
+    assert all(v <= limit for v, limit in line["compared"].values())
     if "chat" in cell:   # a fixed request count: rate x window
         assert line["attempted"] == round(TOY_SERVE["rate_per_s"] * 3.0)
     if "train" not in cell:
@@ -114,3 +121,34 @@ def test_served_tokens_are_held_to_the_reference_argmax(token, logits,
 
     assert serve_cell._is_argmax(token, logits,
                                  {"rel_rms_error": rel_err}) is want
+
+
+def test_the_serve_check_draws_its_own_reference_weights():
+    """The reference computes with the float32 tree the initialiser makes
+    from the seed, not with the tree the engine holds: a fault in how the
+    replica stores its weights (here: the final norm's gain 5% up) is a
+    fault of the program alone, and the check sees it. Until PR 31 both
+    sides read `engine.params`, and this passed."""
+    from benchmark.harness import serve_cell
+
+    conf = spec.load_config(BENCH, "internlm2-1.8b")
+    dep = {k: v for k, v in TOY_DEPLOYMENT.items() if k != "max_concurrency"}
+    rep = serve_cell.BenchReplica(conf, platform="cpu",
+                                  field_overrides=TINY, seed=7, **dep)
+    try:
+        good = rep.bench_check(7, [40, 33])
+        assert good["ok"] and good["reference"] == "dense_gqa"
+        assert all(r["prefill"]["rel_rms_error"] < 2e-4
+                   and r["decode"]["rel_rms_error"] < 2e-4
+                   for r in good["rows"])
+        held = rep.engine.params
+        rep.engine.params = dict(held, final_norm=held["final_norm"] * 1.05)
+        bad = rep.bench_check(7, [40, 33])
+        assert not bad["ok"]
+        assert all(0.04 < r["prefill"]["rel_rms_error"] < 0.06
+                   for r in bad["rows"])
+        rep.engine.params = held
+        # another seed than the replica's is another model
+        assert not rep.bench_check(8, [40])["ok"]
+    finally:
+        rep.engine.shutdown()

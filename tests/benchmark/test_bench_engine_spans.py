@@ -112,10 +112,15 @@ def test_on_the_parents_counters_a_new_metric_reads_nothing(name):
 
 
 def test_the_thirteen_are_appended_and_change_nothing_that_was_there():
+    """PR 24's thirteen, found by name: they stand together, in the order
+    they were appended in, after everything that was there before them
+    (later PRs append after them, as the contract has it)."""
     names = [m["name"] for m in BENCH["per_layer"]]
     new = NEW[CHAT] + NEW[BATCH]
-    assert len(new) == 13 and set(names[-13:]) == set(new)
-    for m in BENCH["per_layer"][-13:]:
+    first = min(names.index(n) for n in new)
+    assert len(new) == 13 and set(names[first:first + 13]) == set(new)
+    assert names[first - 1] == "peak_hbm_gb.batch"   # PR 23's last
+    for m in BENCH["per_layer"][first:first + 13]:
         chat = m["name"].endswith(".chat")
         assert m["workloads"] == [CHAT if chat else BATCH]
         assert m["moves"] == ("tpot_p50_ms" if chat

@@ -150,3 +150,66 @@ def test_prefill_then_decode_through_the_slot_cache(shape, lengths):
         assert reference.logits_agree(got_pre[i], want[0], "float32")["ok"]
         assert reference.logits_agree(got_dec[i], want[1], "float32")["ok"]
         assert int(first[i]) == int(np.argmax(np.asarray(want[0])))
+
+
+# ---- the objective, term by term ---------------------------------------------
+
+_PROGRAM = {"loss": 6.20, "aux": 2.01, "perplexity": 492.7}
+_REFERENCE = {"loss": 6.2004, "aux": 2.012}
+_WEIGHTS = {"loss": 1.0, "aux": 0.01}
+
+
+@pytest.mark.parametrize("total,program,want,weights,ok,why", [
+    (6.2201, _PROGRAM, _REFERENCE, _WEIGHTS, True, None),
+    # no weights stated: the terms are compared and the total only finite
+    (99.0, _PROGRAM, _REFERENCE, None, True, None),
+    (float("nan"), _PROGRAM, _REFERENCE, None, False, None),
+    # a term further from the reference than the bf16 tolerance, 1e-2
+    (6.2201, dict(_PROGRAM, aux=2.03), _REFERENCE, None, False, "aux"),
+    # a term of the reference that the program does not report
+    (6.2, {"loss": 6.20}, _REFERENCE, None, False, "aux"),
+    # the total is not the weighted sum of the program's own terms
+    (6.2402, _PROGRAM, _REFERENCE, _WEIGHTS, False, "weighted_sum"),
+    # a weighted term nobody compares: the reference has to give it
+    (6.2201, _PROGRAM, {"loss": 6.2004}, _WEIGHTS, False, "weighted_sum"),
+    # ... unless its weight is nought
+    (6.20, _PROGRAM, {"loss": 6.2004}, {"loss": 1.0, "aux": 0.0}, True,
+     None)])
+def test_objective_agrees_term_by_term(total, program, want, weights, ok,
+                                       why):
+    got = reference.objective_agrees(total, program, want, weights,
+                                     "bfloat16")
+    assert got["ok"] is ok
+    assert sorted(got["terms"]) == sorted(want)
+    assert ("weighted_sum" in got) == (weights is not None)
+    if why == "aux":
+        assert not got["terms"]["aux"]["ok"] and got["terms"]["loss"]["ok"]
+    if why == "weighted_sum":
+        assert not got["weighted_sum"]["ok"]
+        assert all(t["ok"] for t in got["terms"].values())
+    for term in got["terms"].values():
+        assert term["tolerance"] == reference.LOSS_ABS_TOL["bfloat16"] \
+            == 1e-2
+
+
+def test_a_further_term_takes_its_architectures_limit_the_loss_never():
+    """`TERM_ABS_TOL` of an architecture file widens (or narrows) a
+    further term's limit; the cross entropy's is `LOSS_ABS_TOL` whatever
+    the file says."""
+    program = {"loss": 6.25, "aux": 2.10}
+    want = {"loss": 6.20, "aux": 2.00}
+    own = {"aux": {"bfloat16": 0.25}, "loss": {"bfloat16": 1.0}}
+    plain = reference.objective_agrees(6.25, program, want, None, "bfloat16")
+    assert not plain["terms"]["aux"]["ok"] and not plain["terms"]["loss"]["ok"]
+    got = reference.objective_agrees(6.25, program, want, None, "bfloat16",
+                                     own)
+    assert got["terms"]["aux"]["ok"] and got["terms"]["aux"][
+        "tolerance"] == 0.25
+    assert not got["terms"]["loss"]["ok"] and got["terms"]["loss"][
+        "tolerance"] == 1e-2
+    assert not got["ok"]
+    # a limit stated for another dtype only: the table's own
+    got = reference.objective_agrees(6.2, {"loss": 6.2, "aux": 2.0}, want,
+                                     None, "float32", {"aux": {
+                                         "float32": 1e-4, "bfloat16": 0.25}})
+    assert got["ok"] and got["terms"]["aux"]["tolerance"] == 1e-4
