@@ -59,28 +59,122 @@ class TransformerConfig:
     # RMSNorm (with a learned gain) over the whole q and the whole k
     # projection, before the split into heads and before RoPE (OLMoE).
     qk_norm: bool = False
+    # Width of a query/key head. None -> d_model / n_heads, resolved when
+    # the config is built (a `dataclasses.replace` that changes d_model
+    # or n_heads passes head_dim=None and v_head_dim=None too).
+    head_dim: Optional[int] = None
+    # Latent attention (models/transformer.py:qkv_proj; `kv_lora_rank` 0 =
+    # ordinary projections): queries through a latent of `q_lora_rank`
+    # and keys/values through one of `kv_lora_rank`, each with its own
+    # RMSNorm. Of a query/key head's `head_dim`, the last `rope_head_dim`
+    # carry the rotary embedding (the key's rotary part is ONE vector a
+    # token, shared by all heads) and the rest none; a value head is
+    # `v_head_dim` wide (None -> head_dim). Scores are scaled by
+    # head_dim ** -0.5. The rotary columns of the stored latent projections
+    # are paired (2i, 2i+1), as this attention's published weights are
+    # (the ordinary projections pair by halves).
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    rope_head_dim: int = 0
+    v_head_dim: Optional[int] = None
+    # Of the `n_layers` layers of an MoE model, the first
+    # `moe_dense_layers` have a dense SwiGLU of width `moe_dense_d_ff`.
+    moe_dense_layers: int = 0
+    moe_dense_d_ff: int = 0
+    # The router (models/moe.py:route): scores are a "softmax" or a
+    # "sigmoid" of the logits; `moe_select_bias`: the k experts are chosen
+    # by score plus a per-expert bias, a leaf that takes no gradient and
+    # no optimiser update, and weighted by the unbiased scores; the kept
+    # weights (renormalised where `moe_norm_topk`) times `moe_route_scale`.
+    moe_scoring: str = "softmax"
+    moe_select_bias: bool = False
+    moe_route_scale: float = 1.0
+    # A shared expert: a dense SwiGLU of this width that every token
+    # passes, added to the routed sum (0 = none).
+    moe_shared_d_ff: int = 0
+    # One chip's share of an expert-parallel deployment: the layer holds
+    # experts [moe_first_expert, moe_first_expert + moe_held_experts) of
+    # the `moe_experts` the router scores (None = all) and computes their
+    # part of the result; what the absent experts would add is left out.
+    moe_held_experts: Optional[int] = None
+    moe_first_expert: int = 0
+    # Multi-token prediction: `mtp_layers` (0 or 1) further block(s) that
+    # predict the token after next from the main stack's last hidden
+    # state and the next token's embedding (transformer.loss_fn);
+    # `mtp_weight` x its cross entropy joins the total loss.
+    mtp_layers: int = 0
+    mtp_weight: float = 0.0
+
+    def __post_init__(self):
+        def need(ok, why):
+            if not ok:
+                raise ValueError(f"TransformerConfig: {why}")
+
+        if self.head_dim is None:
+            need(self.d_model % self.n_heads == 0,
+                 "d_model is no multiple of n_heads and no head_dim is given")
+            object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
+        if self.v_head_dim is None:
+            object.__setattr__(self, "v_head_dim", self.head_dim)
+        if self.kv_lora_rank:
+            need(self.q_lora_rank and 0 < self.rope_head_dim < self.head_dim,
+                 "latent attention needs q_lora_rank and 0 < rope_head_dim "
+                 "< head_dim")
+            need(self.kv_heads == self.n_heads and not self.qk_norm,
+                 "latent attention has one key/value head a query head and "
+                 "no q/k norms")
+        else:
+            need(self.v_head_dim == self.head_dim,
+                 "v_head_dim differs from head_dim without latent attention")
+        need(self.moe_scoring in ("softmax", "sigmoid"),
+             f"moe_scoring {self.moe_scoring!r}")
+        need(self.mtp_layers in (0, 1), "one prediction module at most")
+        need(self.moe_experts or not (self.moe_dense_layers
+                                      or self.moe_shared_d_ff),
+             "moe_dense_layers and moe_shared_d_ff belong to an MoE model")
 
     @property
     def kv_heads(self) -> int:
         return self.n_kv_heads or self.n_heads
 
     @property
-    def head_dim(self) -> int:
-        assert self.d_model % self.n_heads == 0
-        return self.d_model // self.n_heads
+    def held_experts(self) -> int:
+        return self.moe_experts if self.moe_held_experts is None \
+            else self.moe_held_experts
+
+    def _layer_params(self, moe: bool) -> int:
+        d, hd, H, KV = self.d_model, self.head_dim, self.n_heads, \
+            self.kv_heads
+        if self.kv_lora_rank:
+            q, kv, rope = self.q_lora_rank, self.kv_lora_rank, \
+                self.rope_head_dim
+            attn = (d * q + q + q * H * hd + d * (kv + rope) + kv
+                    + kv * H * (hd - rope + self.v_head_dim)
+                    + H * self.v_head_dim * d)
+        else:
+            attn = (d * H * hd + 2 * d * KV * hd + H * hd * d
+                    + (H * hd + KV * hd if self.qk_norm else 0))
+        if moe:
+            ffn = (d * self.moe_experts                        # router
+                   + (self.moe_experts if self.moe_select_bias else 0)
+                   + self.held_experts * 3 * d * self.d_ff
+                   + 3 * d * self.moe_shared_d_ff)
+        else:
+            ffn = 3 * d * (self.moe_dense_d_ff if self.moe_experts
+                           else self.d_ff)
+        return attn + ffn + 2 * d                              # + norms
 
     @property
     def num_params(self) -> int:
-        d, v, L = self.d_model, self.vocab_size, self.n_layers
-        hd, H, KV, ff = self.head_dim, self.n_heads, self.kv_heads, self.d_ff
-        E = self.moe_experts
-        ffn = E * 3 * d * ff + d * E if E else 3 * d * ff   # experts+router
-        per_layer = (d * H * hd + 2 * d * KV * hd + H * hd * d  # attn
-                     + ffn                                      # swiglu(s)
-                     + 2 * d                                    # norms
-                     + (H * hd + KV * hd if self.qk_norm else 0))
+        """Leaves of `init_params`, counted from shapes."""
+        d, v = self.d_model, self.vocab_size
+        dense = self.moe_dense_layers if self.moe_experts else self.n_layers
+        layers = dense * self._layer_params(False) \
+            + (self.n_layers - dense) * self._layer_params(True)
+        mtp = self.mtp_layers * (2 * d + 2 * d * d
+                                 + self._layer_params(bool(self.moe_experts)))
         head = 0 if self.tie_embeddings else d * v
-        return v * d + L * per_layer + d + head
+        return v * d + layers + mtp + d + head
 
 
 # ---- presets ---------------------------------------------------------------

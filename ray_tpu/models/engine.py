@@ -71,7 +71,8 @@ from ray_tpu.models.generate import (_final_logits, _gqa_decode_attention,
                                      _prefill_hidden)
 from ray_tpu.models.transformer import (Params, ffn_block,
                                         param_logical_axes, qkv_proj,
-                                        rms_norm, serving_params)
+                                        refuse_unserved, rms_norm,
+                                        serving_params)
 
 log = logging.getLogger(__name__)
 
@@ -84,6 +85,7 @@ SlotCache = Dict[str, jax.Array]
 
 def init_slot_cache(cfg: TransformerConfig, slots: int,
                     max_len: int) -> SlotCache:
+    refuse_unserved(cfg)
     shape = (cfg.n_layers, slots, cfg.kv_heads, max_len, cfg.head_dim)
     return {"k": jnp.zeros(shape, cfg.dtype),
             "v": jnp.zeros(shape, cfg.dtype),
@@ -302,6 +304,7 @@ class InferenceEngine:
                  pad_id: int = 0, mesh=None, seed: int = 0,
                  min_bucket: int = 16, decode_chunk: int = 4,
                  max_inflight: int = 6):
+        refuse_unserved(cfg)
         self.cfg = cfg
         self.slots = int(slots)
         self.max_prompt_len = int(max_prompt_len)

@@ -27,7 +27,7 @@ import jax.numpy as jnp
 
 from ray_tpu.models.config import TransformerConfig
 from ray_tpu.models.transformer import (Params, ffn_block, lm_head,
-                                        qkv_proj, rms_norm)
+                                        qkv_proj, refuse_unserved, rms_norm)
 
 # Large-finite instead of -inf for masked scores: a fully-masked query row
 # (a pad position in a left-padded batch) then softmaxes to uniform junk
@@ -99,6 +99,7 @@ def _prefill_hidden(params: Params, tokens: jax.Array,
     space (a [B,P,V] float32 logits tensor is ~2 GB for llama3-8b at
     P=512 and is pure waste on the serving hot path)."""
     B, P = tokens.shape
+    refuse_unserved(cfg)
     if max_len < P:
         raise ValueError(f"max_len={max_len} < prompt length {P}")
     if not cfg.causal:

@@ -4,9 +4,12 @@ Absent from the reference (SURVEY.md §2.3 marks EP as greenfield-mandatory).
 One path, four stages, each under a `jax.named_scope` a profile groups by:
 
   moe.route     router logits in float32 at the highest matmul precision,
-                softmax over ALL experts, the k largest probabilities kept
-                (renormalised to sum to 1 when `cfg.moe_norm_topk`:
-                Mixtral; left as they are when not: OLMoE)
+                scores a softmax or a sigmoid (`cfg.moe_scoring`) over ALL
+                experts, the k largest kept (by score plus a per-expert
+                bias that takes no gradient where `cfg.moe_select_bias`;
+                the weights are the unbiased scores), renormalised to sum
+                to 1 when `cfg.moe_norm_topk` (Mixtral; left as they are
+                when not: OLMoE) and scaled by `cfg.moe_route_scale`
   moe.dispatch  the N*k (token, expert) assignments ordered by expert (a
                 stable sort), group sizes by a count per expert, the
                 tokens' rows gathered into that order
@@ -14,6 +17,18 @@ One path, four stages, each under a `jax.named_scope` a profile groups by:
                 groups (`jax.lax.ragged_dot`)
   moe.combine   rows gathered back into token order and summed over the k
                 slots, weighted by the routing weights, in float32
+  moe.shared    where `cfg.moe_shared_d_ff`: a dense SwiGLU every token
+                passes, added to the routed sum
+
+One chip's share (`cfg.moe_held_experts` of the `cfg.moe_experts` the
+router scores, from `cfg.moe_first_expert` on): the layer routes over ALL
+experts, orders the assignments that fall on its own experts first (by
+expert) and the others after them, and computes its experts' part of the
+result; what the absent experts would add is left out. The row buffer stays
+[N*k, d], the most that can fall on the held experts, so no assignment to a
+held expert is ever dropped; the rows past the sum of the group sizes
+belong to no group: they enter as zeros and whatever `ragged_dot` leaves
+in them is replaced by zeros, forward and backward.
 
 No token is ever dropped, whatever the imbalance, and no shape depends on
 the routing: every buffer is [N*k, ...] or smaller (no [.., E, capacity]
@@ -41,34 +56,66 @@ from ray_tpu.parallel.sharding import with_logical_constraint as _wlc
 Params = Dict[str, Any]
 
 
-def moe_param_logical_axes() -> Dict[str, tuple]:
+def moe_param_logical_axes(cfg) -> Dict[str, tuple]:
     """Logical axes for one layer-stack of MoE parameters (leading layers
     axis; experts axis sharded over the ``expert`` mesh axis)."""
-    return {
+    axes = {
         "router": ("layers", "embed", "experts"),
         "w_gate": ("layers", "experts", "embed", "mlp"),
         "w_up": ("layers", "experts", "embed", "mlp"),
         "w_down": ("layers", "experts", "mlp", "embed"),
     }
+    if cfg.moe_select_bias:
+        axes["router_bias"] = ("layers", "experts")
+    if cfg.moe_shared_d_ff:
+        axes.update({"ws_gate": ("layers", "embed", "mlp"),
+                     "ws_up": ("layers", "embed", "mlp"),
+                     "ws_down": ("layers", "mlp", "embed")})
+    return axes
 
 
-def init_moe_params(rng: jax.Array, cfg) -> Params:
-    """Stacked per-layer MoE params: router [L,d,E] + expert FFNs [L,E,...]."""
-    L, d, ff, E = cfg.n_layers, cfg.d_model, cfg.d_ff, cfg.moe_experts
-    pd = cfg.param_dtype
+def init_moe_params(rng: jax.Array, cfg, n_layers=None) -> Params:
+    """Stacked per-layer MoE params: router [L,d,E] + the held experts'
+    FFNs [L,held,...] (+ the selection bias [L,E], zeros; + the shared
+    expert's FFN [L,...])."""
+    L = cfg.n_layers if n_layers is None else n_layers
+    d, ff, E, held = cfg.d_model, cfg.d_ff, cfg.moe_experts, cfg.held_experts
     k = iter(jax.random.split(rng, 8))
-
-    def normal(key, shape, scale):
-        return (jax.random.normal(key, shape, jnp.float32) * scale).astype(pd)
-
+    normal = functools.partial(scaled_normal, dtype=cfg.param_dtype)
     in_scale = d ** -0.5
-    out_scale = (2 * L) ** -0.5 * d ** -0.5 * (ff / d) ** 0.5
-    return {
+    out_scale = (2 * cfg.n_layers) ** -0.5 * d ** -0.5   # times (f/d)^0.5
+    lay = {
         "router": normal(next(k), (L, d, E), in_scale),
-        "w_gate": normal(next(k), (L, E, d, ff), in_scale),
-        "w_up": normal(next(k), (L, E, d, ff), in_scale),
-        "w_down": normal(next(k), (L, E, ff, d), out_scale),
+        "w_gate": normal(next(k), (L, held, d, ff), in_scale),
+        "w_up": normal(next(k), (L, held, d, ff), in_scale),
+        "w_down": normal(next(k), (L, held, ff, d),
+                         out_scale * (ff / d) ** 0.5),
     }
+    if cfg.moe_select_bias:
+        lay["router_bias"] = jnp.zeros((L, E), cfg.param_dtype)
+    if cfg.moe_shared_d_ff:
+        fs = cfg.moe_shared_d_ff
+        lay.update({
+            "ws_gate": normal(next(k), (L, d, fs), in_scale),
+            "ws_up": normal(next(k), (L, d, fs), in_scale),
+            "ws_down": normal(next(k), (L, fs, d),
+                              out_scale * (fs / d) ** 0.5),
+        })
+    return lay
+
+
+def scaled_normal(key, shape, scale, dtype):
+    return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dtype)
+
+
+def swiglu(h, w_gate, w_up, w_down, cfg, mesh: Optional[Mesh] = None):
+    """A dense SwiGLU: h [B, T, d] -> [B, T, d] (the shared expert here,
+    a dense layer's feed-forward in transformer.ffn_block)."""
+    gate = jnp.einsum("btd,df->btf", h, w_gate.astype(cfg.dtype))
+    up = jnp.einsum("btd,df->btf", h, w_up.astype(cfg.dtype))
+    ff = jax.nn.silu(gate) * up
+    ff = _wlc(ff, ("batch", "seq", "mlp"), mesh=mesh)
+    return jnp.einsum("btf,fd->btd", ff, w_down.astype(cfg.dtype))
 
 
 # ---- the two permutations, gathers both ways --------------------------------
@@ -113,20 +160,45 @@ _permute_rows.defvjp(_permute_fwd, _permute_bwd)
 
 # the leaves `route` reads through ``.astype(float32)``: a serving replica
 # keeps them float32 (transformer.read_in_float32 / serving_params)
-READ_IN_FLOAT32 = ("router",)
+READ_IN_FLOAT32 = ("router", "router_bias")
+# the leaves that take no gradient and no optimiser update (weight decay
+# included): `route` reads the bias under `stop_gradient`, and
+# training.make_train_step leaves them as they are. (What moves a bias in
+# training is a balancing rule outside the gradient, which is not here.)
+NO_UPDATE = ("router_bias",)
 
 
-def route(x: jax.Array, router: jax.Array, cfg):
-    """x [N, d], router [d, E] -> (probs [N, E], top_p [N, k], top_i
-    [N, k]), all in float32 at the highest matmul precision (2048 x 64 a
-    token costs nothing, and a routing choice then differs from a float32
-    reference's only on a true near-tie)."""
+def hold_no_update_leaves(updates):
+    """An optimiser's updates with zeros for the leaves NO_UPDATE names."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, u: jnp.zeros_like(u)
+        if path[-1].key in NO_UPDATE else u, updates)
+
+
+def route(x: jax.Array, router: jax.Array, cfg, bias=None):
+    """x [N, d], router [d, E], bias [E] or None -> (probs [N, E], top_p
+    [N, k], top_i [N, k]), all in float32 at the highest matmul precision
+    (2048 x 64 a token costs nothing, and a routing choice then differs
+    from a float32 reference's only on a true near-tie). ``probs`` sum to
+    1 over the experts (a sigmoid's scores divided by their sum): the
+    load-balance statistic's."""
     logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_i = jax.lax.top_k(probs, cfg.moe_top_k)
+    if cfg.moe_scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        probs = scores / jnp.sum(scores, axis=-1, keepdims=True)
+    else:
+        scores = probs = jax.nn.softmax(logits, axis=-1)
+    if bias is None:
+        top_p, top_i = jax.lax.top_k(scores, cfg.moe_top_k)
+    else:   # chosen by score + bias, weighted by the score alone
+        _, top_i = jax.lax.top_k(scores + jax.lax.stop_gradient(
+            bias.astype(jnp.float32)), cfg.moe_top_k)
+        top_p = jnp.take_along_axis(scores, top_i, axis=-1)
     if cfg.moe_norm_topk:
         top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+    if cfg.moe_route_scale != 1.0:
+        top_p = top_p * cfg.moe_route_scale
     return probs, top_p, top_i
 
 
@@ -134,30 +206,47 @@ def moe_layer(h: jax.Array, lp: Params, cfg, mesh: Optional[Mesh] = None
               ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """One MoE FFN layer. h: [B, T, d] -> (out [B, T, d], stats).
 
-    lp: per-layer params {router [d,E], w_gate/w_up [E,d,f], w_down [E,f,d]}.
+    lp: per-layer params {router [d,E], w_gate/w_up [held,d,f], w_down
+    [held,f,d]} (+ router_bias [E], ws_gate/ws_up/ws_down).
     stats["aux"] is the load-balance term E * sum_e f_e * p_e with f_e the
     assignments to expert e per token (summing to k over the experts) and
     p_e the mean router probability: k at perfect balance; weight it into
-    the train loss via cfg.moe_aux_weight. stats["load"] is the largest
-    group over the mean group (1.0 at perfect balance).
+    the train loss via cfg.moe_aux_weight (over the held experts alone
+    where a share is held: that chip's part of the sum). stats["load"] is
+    the largest group over the mean group of the held experts (1.0 at
+    perfect balance), stats["held"] the share of the N*k assignments that
+    fall on held experts (1.0 when all are held).
     """
     B, T, d = h.shape
     E, k = cfg.moe_experts, cfg.moe_top_k
+    held, first = cfg.held_experts, cfg.moe_first_expert
+    share = held < E          # some of the router's experts are not here
     N = B * T
     dtype = h.dtype
     x = h.reshape(N, d)
 
     with jax.named_scope("moe.route"):
-        probs, top_p, top_i = route(x, lp["router"], cfg)
+        probs, top_p, top_i = route(x, lp["router"], cfg,
+                                    lp.get("router_bias"))
 
     with jax.named_scope("moe.dispatch"):
         expert_of = top_i.reshape(N * k)
+        if share:
+            # held experts numbered from 0, every absent one `held`: the
+            # stable sort puts their assignments last, past every group
+            local = expert_of - first
+            expert_of = jnp.where((local >= 0) & (local < held), local,
+                                  held)
+            top_p = jnp.where(expert_of.reshape(N, k) < held, top_p, 0.0)
         order = jnp.argsort(expert_of, stable=True)      # by expert
         inv = jnp.argsort(order)                         # its inverse
         group_sizes = jnp.sum(
-            expert_of[:, None] == jnp.arange(E, dtype=expert_of.dtype),
-            axis=0, dtype=jnp.int32)                     # [E]
+            expert_of[:, None] == jnp.arange(held, dtype=expert_of.dtype),
+            axis=0, dtype=jnp.int32)                     # [held]
         xs = _dispatch_rows(k, x, order, inv)            # [N*k, d]
+        if share:
+            in_group = (jnp.arange(N * k) < group_sizes.sum())[:, None]
+            xs = jnp.where(in_group, xs, 0)
 
     with jax.named_scope("moe.experts"):
         gate = jax.lax.ragged_dot(xs, lp["w_gate"].astype(dtype),
@@ -168,13 +257,22 @@ def moe_layer(h: jax.Array, lp: Params, cfg, mesh: Optional[Mesh] = None
                                  group_sizes)            # [N*k, d]
 
     with jax.named_scope("moe.combine"):
+        if share:
+            out = jnp.where(in_group, out, 0)
         back = _permute_rows(out, inv, order).reshape(N, k, d)
         y = jnp.einsum("nkd,nk->nd", back.astype(jnp.float32), top_p)
         y = y.astype(dtype).reshape(B, T, d)
+    if cfg.moe_shared_d_ff:
+        with jax.named_scope("moe.shared"):
+            y = y + swiglu(h, lp["ws_gate"], lp["ws_up"], lp["ws_down"],
+                           cfg, mesh)
     y = _wlc(y, ("batch", "seq", "embed"), mesh=mesh)
 
     per_expert = group_sizes.astype(jnp.float32)
-    aux = E * jnp.sum(per_expert / N * probs.mean(axis=0))
-    load = per_expert.max() * E / (N * k)
-    return y, {"aux": aux, "load": load}
-
+    # the assignments on held experts: all N*k where every expert is held
+    on_held = per_expert.sum() if share else float(N * k)
+    aux = E * jnp.sum(per_expert / N
+                      * probs.mean(axis=0)[first:first + held])
+    load = per_expert.max() * held / jnp.maximum(on_held, 1.0)
+    return y, {"aux": aux, "load": load,
+               "held": jnp.asarray(on_held / (N * k), jnp.float32)}

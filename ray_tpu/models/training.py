@@ -18,6 +18,7 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_tpu.models.config import TransformerConfig
+from ray_tpu.models.moe import hold_no_update_leaves
 from ray_tpu.models.transformer import (
     init_params,
     loss_fn,
@@ -122,6 +123,8 @@ def make_train_step(cfg: TransformerConfig, tx, mesh: Optional[Mesh] = None,
         (_, metrics), grads = grad_fn(state["params"], batch)
         updates, new_opt = tx.update(grads, state["opt_state"],
                                      state["params"])
+        if cfg.moe_select_bias:   # no weight decay on a leaf held as it is
+            updates = hold_no_update_leaves(updates)
         new_params = optax.apply_updates(state["params"], updates)
         metrics = dict(metrics,
                        grad_norm=optax.global_norm(grads),

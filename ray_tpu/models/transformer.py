@@ -15,6 +15,7 @@ TPU-first design choices:
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Any, Dict, Optional
 
@@ -23,6 +24,9 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 
 from ray_tpu.models.config import TransformerConfig
+from ray_tpu.models.moe import (READ_IN_FLOAT32, init_moe_params, moe_layer,
+                                moe_param_logical_axes, scaled_normal,
+                                swiglu)
 from ray_tpu.parallel.ring import (reference_attention, ring_attention,
                                    shard_map)
 from ray_tpu.parallel.sharding import logical_to_spec
@@ -33,80 +37,134 @@ Params = Dict[str, Any]
 
 # ---- parameter structure ---------------------------------------------------
 
-def param_logical_axes(cfg: TransformerConfig) -> Params:
-    """Same-structure pytree of logical axis tuples (for shardings)."""
+def _stack_axes(cfg: TransformerConfig, moe: bool) -> Params:
     lay = {
         "attn_norm": ("layers", "embed"),
-        "wq": ("layers", "embed", "heads", "qkv_dim"),
-        "wk": ("layers", "embed", "kv_heads", "qkv_dim"),
-        "wv": ("layers", "embed", "kv_heads", "qkv_dim"),
         "wo": ("layers", "heads", "qkv_dim", "embed"),
         "mlp_norm": ("layers", "embed"),
     }
+    if cfg.kv_lora_rank:
+        lay.update({
+            "wq_a": ("layers", "embed", None),
+            "q_a_norm": ("layers", None),
+            "wq_b": ("layers", None, "heads", "qkv_dim"),
+            "wkv_a": ("layers", "embed", None),
+            "kv_a_norm": ("layers", None),
+            "wkv_b": ("layers", None, "heads", "qkv_dim"),
+        })
+    else:
+        lay.update({
+            "wq": ("layers", "embed", "heads", "qkv_dim"),
+            "wk": ("layers", "embed", "kv_heads", "qkv_dim"),
+            "wv": ("layers", "embed", "kv_heads", "qkv_dim"),
+        })
     if cfg.qk_norm:
         # gains over the flattened (heads x head_dim) projection: replicated
         lay.update({"q_norm": ("layers", None), "k_norm": ("layers", None)})
-    if cfg.moe_experts:
-        from ray_tpu.models.moe import moe_param_logical_axes
-
-        lay.update(moe_param_logical_axes())
+    if moe:
+        lay.update(moe_param_logical_axes(cfg))
     else:
         lay.update({
             "w_gate": ("layers", "embed", "mlp"),
             "w_up": ("layers", "embed", "mlp"),
             "w_down": ("layers", "mlp", "embed"),
         })
+    return lay
+
+
+def param_logical_axes(cfg: TransformerConfig) -> Params:
+    """Same-structure pytree of logical axis tuples (for shardings)."""
+    moe = bool(cfg.moe_experts)
     axes = {
         "embed": ("vocab", "embed"),
-        "layers": lay,
+        "layers": _stack_axes(cfg, moe),
         "final_norm": ("embed",),
     }
+    if moe and cfg.moe_dense_layers:
+        axes["dense_layers"] = _stack_axes(cfg, False)
+    if cfg.mtp_layers:
+        axes["mtp"] = {"h_norm": ("embed",), "e_norm": ("embed",),
+                       "proj": (None, "embed"),
+                       "layers": _stack_axes(cfg, moe)}
     if not cfg.tie_embeddings:
         axes["lm_head"] = ("embed", "vocab")
     return axes
 
 
-def init_params(rng: jax.Array, cfg: TransformerConfig) -> Params:
-    d, v, L = cfg.d_model, cfg.vocab_size, cfg.n_layers
-    hd, H, KV, ff = cfg.head_dim, cfg.n_heads, cfg.kv_heads, cfg.d_ff
+def _init_stack(k, cfg: TransformerConfig, L: int, moe: bool) -> Params:
+    """``L`` stacked layers of one kind, keys drawn from the iterator
+    ``k`` (attention first, then the FFN)."""
+    d, hd, H, KV = cfg.d_model, cfg.head_dim, cfg.n_heads, cfg.kv_heads
     pd = cfg.param_dtype
-    k = iter(jax.random.split(rng, 16))
-
-    def normal(key, shape, scale):
-        return (jax.random.normal(key, shape, jnp.float32) * scale).astype(pd)
-
-    emb_scale = d ** -0.5
+    normal = functools.partial(scaled_normal, dtype=pd)
     in_scale = d ** -0.5
-    out_scale = (2 * L) ** -0.5 * d ** -0.5  # depth-scaled residual outputs
-    lay = {
-        "attn_norm": jnp.ones((L, d), pd),
-        "wq": normal(next(k), (L, d, H, hd), in_scale),
-        "wk": normal(next(k), (L, d, KV, hd), in_scale),
-        "wv": normal(next(k), (L, d, KV, hd), in_scale),
-        "wo": normal(next(k), (L, H, hd, d), out_scale),
-        "mlp_norm": jnp.ones((L, d), pd),
-    }
+    # depth-scaled residual outputs, by the depth of the whole model
+    out_scale = (2 * cfg.n_layers) ** -0.5 * d ** -0.5
+    lay = {"attn_norm": jnp.ones((L, d), pd),
+           "mlp_norm": jnp.ones((L, d), pd)}
+    if cfg.kv_lora_rank:
+        rq, rkv, rope = cfg.q_lora_rank, cfg.kv_lora_rank, cfg.rope_head_dim
+        lay.update({
+            "wq_a": normal(next(k), (L, d, rq), in_scale),
+            "q_a_norm": jnp.ones((L, rq), pd),
+            "wq_b": normal(next(k), (L, rq, H, hd), rq ** -0.5),
+            "wkv_a": normal(next(k), (L, d, rkv + rope), in_scale),
+            "kv_a_norm": jnp.ones((L, rkv), pd),
+            # per head: the unrotated part of the key, then the value
+            "wkv_b": normal(next(k), (L, rkv, H, hd - rope + cfg.v_head_dim),
+                            rkv ** -0.5),
+        })
+    else:
+        lay.update({
+            "wq": normal(next(k), (L, d, H, hd), in_scale),
+            "wk": normal(next(k), (L, d, KV, hd), in_scale),
+            "wv": normal(next(k), (L, d, KV, hd), in_scale),
+        })
+    lay["wo"] = normal(next(k), (L, H, cfg.v_head_dim, d), out_scale)
     if cfg.qk_norm:
         lay.update({"q_norm": jnp.ones((L, H * hd), pd),
                     "k_norm": jnp.ones((L, KV * hd), pd)})
-    if cfg.moe_experts:
-        from ray_tpu.models.moe import init_moe_params
-
-        lay.update(init_moe_params(next(k), cfg))
+    if moe:
+        lay.update(init_moe_params(next(k), cfg, L))
     else:
+        ff = cfg.moe_dense_d_ff if cfg.moe_experts else cfg.d_ff
         lay.update({
             "w_gate": normal(next(k), (L, d, ff), in_scale),
             "w_up": normal(next(k), (L, d, ff), in_scale),
             "w_down": normal(next(k), (L, ff, d),
                              out_scale * (ff / d) ** 0.5),
         })
+    return lay
+
+
+def init_params(rng: jax.Array, cfg: TransformerConfig) -> Params:
+    d, v = cfg.d_model, cfg.vocab_size
+    pd = cfg.param_dtype
+    moe = bool(cfg.moe_experts)
+    k = iter(jax.random.split(rng, 16))
     params: Params = {
-        "embed": normal(next(k), (v, d), emb_scale),
-        "layers": lay,
+        # an MoE model's leading dense layers are a stack of their own
+        "layers": _init_stack(k, cfg, cfg.n_layers - cfg.moe_dense_layers,
+                              moe),
+        "embed": scaled_normal(next(k), (v, d), d ** -0.5, pd),
         "final_norm": jnp.ones((d,), pd),
     }
     if not cfg.tie_embeddings:
-        params["lm_head"] = normal(next(k), (d, v), in_scale)
+        params["lm_head"] = scaled_normal(next(k), (d, v), d ** -0.5, pd)
+    # further stacks draw from keys of their own, so the leaves above are
+    # what they were before a configuration could have these
+    if moe and cfg.moe_dense_layers:
+        params["dense_layers"] = _init_stack(
+            iter(jax.random.split(jax.random.fold_in(rng, 1), 16)), cfg,
+            cfg.moe_dense_layers, False)
+    if cfg.mtp_layers:
+        km = iter(jax.random.split(jax.random.fold_in(rng, 2), 16))
+        params["mtp"] = {
+            "h_norm": jnp.ones((d,), pd), "e_norm": jnp.ones((d,), pd),
+            # rows: the hidden state's half, then the embedding's
+            "proj": scaled_normal(next(km), (2 * d, d), (2 * d) ** -0.5, pd),
+            "layers": _init_stack(km, cfg, cfg.mtp_layers, moe),
+        }
     return params
 
 
@@ -197,10 +255,48 @@ def _attention(q, k, v, cfg: TransformerConfig, mesh: Optional[Mesh],
     return reference_attention(q, k, v, causal=cfg.causal)
 
 
+def _latent_qkv(h, lp, cfg: TransformerConfig, positions):
+    """Latent attention in its expanded (training) form: q through a
+    normed latent of `q_lora_rank`, keys and values through one of
+    `kv_lora_rank`; the last `rope_head_dim` of each query head and ONE
+    such vector a token for the keys, shared by all heads, carry the
+    rotary embedding, the rest of a head none. -> q, k [B, T, H, head_dim]
+    and v [B, T, H, v_head_dim]: ordinary multi-head attention from here
+    on. The weights are sliced, not the activations."""
+    dt, eps = cfg.dtype, cfg.rms_eps
+    rkv, rope = cfg.kv_lora_rank, cfg.rope_head_dim
+    nope = cfg.head_dim - rope
+    wq_b, wkv_b = lp["wq_b"].astype(dt), lp["wkv_b"].astype(dt)
+    wkv_a = lp["wkv_a"].astype(dt)
+    with jax.named_scope("mla.q"):
+        cq = jnp.einsum("btd,dr->btr", h, lp["wq_a"].astype(dt))
+        cq = rms_norm(cq, lp["q_a_norm"], eps)
+        q_nope = jnp.einsum("btr,rhk->bthk", cq, wq_b[..., :nope])
+        q_rope = jnp.einsum("btr,rhk->bthk", cq, wq_b[..., nope:])
+    with jax.named_scope("mla.kv"):
+        c = jnp.einsum("btd,dr->btr", h, wkv_a[:, :rkv])
+        c = rms_norm(c, lp["kv_a_norm"], eps)
+        k_nope = jnp.einsum("btr,rhk->bthk", c, wkv_b[..., :nope])
+        v = jnp.einsum("btr,rhk->bthk", c, wkv_b[..., nope:])
+        k_rope = jnp.einsum("btd,dr->btr", h, wkv_a[:, rkv:])[:, :, None]
+    with jax.named_scope("mla.rope"):
+        # stored pairs (2i, 2i+1) -> the halves (i, i + n/2) `_rope` turns
+        halves = lambda x: jnp.concatenate(  # noqa: E731
+            [x[..., 0::2], x[..., 1::2]], axis=-1)
+        q_rope = _rope(halves(q_rope), positions, cfg.rope_theta)
+        k_rope = _rope(halves(k_rope), positions, cfg.rope_theta)
+        q = jnp.concatenate([q_nope, q_rope], axis=-1)
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_rope, q_rope.shape)], axis=-1)
+    return q, k, v
+
+
 def qkv_proj(h, lp, cfg: TransformerConfig, positions):
     """Q/K/V projections (+ q/k norms) + RoPE — the single definition
     shared by the training forward and the KV-cache inference paths
     (models/generate, models/engine), so a numeric change lands in all."""
+    if cfg.kv_lora_rank:
+        return _latent_qkv(h, lp, cfg, positions)
     q = jnp.einsum("btd,dhk->bthk", h, lp["wq"].astype(cfg.dtype))
     k = jnp.einsum("btd,dhk->bthk", h, lp["wk"].astype(cfg.dtype))
     v = jnp.einsum("btd,dhk->bthk", h, lp["wv"].astype(cfg.dtype))
@@ -214,25 +310,41 @@ def qkv_proj(h, lp, cfg: TransformerConfig, positions):
             _rope(k, positions, cfg.rope_theta), v)
 
 
+def refuse_unserved(cfg: TransformerConfig):
+    """The KV-cache paths (models/generate, models/engine) hold one k and
+    one v row of `head_dim` a token and layer, scan ONE stack of layers
+    and emit one token a step: raise for a configuration that needs a
+    latent cache and the absorbed decode form, a second stack, or a
+    step of more than one token."""
+    cannot = [what for has, what in (
+        (cfg.kv_lora_rank, "latent attention (kv_lora_rank: a latent slot "
+         "cache and the absorbed decode form)"),
+        (cfg.moe_experts and cfg.moe_dense_layers,
+         "leading dense layers (moe_dense_layers: a second layer stack)"),
+        (cfg.mtp_layers, "a multi-token-prediction module (mtp_layers: a "
+         "decode step of more than one token)")) if has]
+    if cannot:
+        raise NotImplementedError(
+            "serving is not implemented for a configuration with "
+            + "; ".join(cannot) + ": it trains (models/transformer.py) "
+            "and does not serve yet")
+
+
 def _no_moe_stats():
     zero = jnp.zeros((), jnp.float32)
-    return {"aux": zero, "load": zero}
+    return {"aux": zero, "load": zero, "held": zero}
 
 
 def ffn_block(h, lp, cfg: TransformerConfig, mesh: Optional[Mesh] = None):
     """SwiGLU (or MoE) FFN -> (down, stats); shared by train + inference.
-    stats: {"aux": load-balance loss, "load": largest expert group over
-    the mean group}, zeros for a dense layer."""
-    if cfg.moe_experts:
-        from ray_tpu.models.moe import moe_layer
-
+    A layer has experts if its leaves hold a router (an MoE model's
+    leading dense layers hold none). stats: {"aux": load-balance loss,
+    "load": largest expert group over the mean group, "held": share of
+    the assignments that fall on held experts}, zeros for a dense layer."""
+    if "router" in lp:
         return moe_layer(h, lp, cfg, mesh)
-    gate = jnp.einsum("btd,df->btf", h, lp["w_gate"].astype(cfg.dtype))
-    up = jnp.einsum("btd,df->btf", h, lp["w_up"].astype(cfg.dtype))
-    ff = jax.nn.silu(gate) * up
-    ff = _wlc(ff, ("batch", "seq", "mlp"), mesh=mesh)
-    down = jnp.einsum("btf,fd->btd", ff, lp["w_down"].astype(cfg.dtype))
-    return down, _no_moe_stats()
+    return swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"], cfg,
+                  mesh), _no_moe_stats()
 
 
 def lm_head(params: Params, x, cfg: TransformerConfig,
@@ -248,75 +360,139 @@ def lm_head(params: Params, x, cfg: TransformerConfig,
 def read_in_float32(cfg: TransformerConfig) -> tuple:
     """Names of the leaves the forward reads through ``.astype(float32)``
     and not through ``.astype(cfg.dtype)``: `lm_head`'s matrix (the
-    embedding table where it is tied) and `moe.route`'s. A serving replica
+    embedding table where it is tied) and `moe.route`'s (the router, and
+    the selection bias where there is one). A serving replica
     holds these in float32 and every other leaf in ``cfg.dtype``
     (`serving_params`); tests/test_serving_params.py holds the list to
     what the forward does."""
-    from ray_tpu.models.moe import READ_IN_FLOAT32
-
     head = "embed" if cfg.tie_embeddings else "lm_head"
-    return (head,) + (READ_IN_FLOAT32 if cfg.moe_experts else ())
+    if not cfg.moe_experts:
+        return (head,)
+    return (head,) + tuple(n for n in READ_IN_FLOAT32
+                           if n != "router_bias" or cfg.moe_select_bias)
 
 
 # ---- forward ---------------------------------------------------------------
 
-def forward(params: Params, tokens: jax.Array, cfg: TransformerConfig,
-            mesh: Optional[Mesh] = None, return_aux: bool = False):
-    """tokens [B, T] int32 -> logits [B, T, vocab] float32.
-
-    With ``return_aux=True`` returns (logits, stats): ``aux`` the MoE
-    load-balance loss averaged over the layers, ``load`` the largest
-    expert group over the mean group in the worst layer (both 0.0 for
-    dense or pipelined execution)."""
-    B, T = tokens.shape
-    x = params["embed"].astype(cfg.dtype)[tokens]  # [B, T, d]
-    x = _wlc(x, ("batch", "seq", "embed"), mesh=mesh)
-    positions = jnp.arange(T)
-
-    def block(x, lp):
-        h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-        q, k, v = qkv_proj(h, lp, cfg, positions)
-        reps = cfg.n_heads // cfg.kv_heads
-        if reps > 1:  # GQA: expand kv heads to match q heads
-            k = jnp.repeat(k, reps, axis=2)
-            v = jnp.repeat(v, reps, axis=2)
-        q = _wlc(q, ("batch", "seq", "heads", None), mesh=mesh)
-        o = _attention(q, k, v, cfg, mesh, positions)
+def _block(x, lp, cfg: TransformerConfig, mesh: Optional[Mesh], positions):
+    """One decoder layer, of whichever kind ``lp`` holds: x [B, T, d] ->
+    (x, the FFN's stats)."""
+    h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
+    q, k, v = qkv_proj(h, lp, cfg, positions)
+    reps = cfg.n_heads // cfg.kv_heads
+    if reps > 1:  # GQA: expand kv heads to match q heads
+        k = jnp.repeat(k, reps, axis=2)
+        v = jnp.repeat(v, reps, axis=2)
+    q = _wlc(q, ("batch", "seq", "heads", None), mesh=mesh)
+    o = _attention(q, k, v, cfg, mesh, positions)
+    with jax.named_scope("mla.out") if cfg.kv_lora_rank \
+            else contextlib.nullcontext():
         o = jnp.einsum("bthk,hkd->btd", o, lp["wo"].astype(cfg.dtype))
-        x = x + _wlc(o, ("batch", "seq", "embed"), mesh=mesh)
+    x = x + _wlc(o, ("batch", "seq", "embed"), mesh=mesh)
 
-        h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-        down, stats = ffn_block(h, lp, cfg, mesh)
-        x = x + _wlc(down, ("batch", "seq", "embed"), mesh=mesh)
-        # the MoE stats ride the scan's per-layer outputs; the pipelined
-        # path drops them (pipeline stages emit activations only) —
-        # acceptable: aux is a regularizer, not the model output.
-        return x, stats
+    h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
+    down, stats = ffn_block(h, lp, cfg, mesh)
+    x = x + _wlc(down, ("batch", "seq", "embed"), mesh=mesh)
+    # the MoE stats ride the scan's per-layer outputs; the pipelined
+    # path drops them (pipeline stages emit activations only) —
+    # acceptable: aux is a regularizer, not the model output.
+    return x, stats
 
-    body = block
+
+def _block_body(cfg: TransformerConfig, mesh: Optional[Mesh], positions):
+    """`_block` as a scan body (x, lp) -> (x, stats), checkpointed per
+    layer where the configuration asks for it."""
+    body = functools.partial(_block, cfg=cfg, mesh=mesh, positions=positions)
     if cfg.remat:
         policy = (
             jax.checkpoint_policies.dots_with_no_batch_dims_saveable
             if getattr(cfg, "remat_policy", "nothing") == "dots"
             else jax.checkpoint_policies.nothing_saveable)
         body = jax.checkpoint(body, policy=policy)
-    stats = _no_moe_stats()
+    return body
+
+
+def _trunk(params: Params, tokens: jax.Array, cfg: TransformerConfig,
+           mesh: Optional[Mesh] = None):
+    """tokens [B, T] -> (the last layer's hidden state [B, T, d], before
+    the final norm; the expert layers' stats, one entry a layer)."""
+    B, T = tokens.shape
+    x = params["embed"].astype(cfg.dtype)[tokens]  # [B, T, d]
+    x = _wlc(x, ("batch", "seq", "embed"), mesh=mesh)
+    body = _block_body(cfg, mesh, jnp.arange(T))
     if mesh is not None and mesh.shape.get("pipeline", 1) > 1:
         # GPipe-style microbatched stages over the pipeline mesh axis; the
         # same block body, numerically identical to the plain scan
         # (parallel/pipeline.py).
         from ray_tpu.parallel.pipeline import pipeline_scan
 
+        assert "dense_layers" not in params, "one stack under a pipeline"
         x = pipeline_scan(body, x, params["layers"], mesh,
                           cfg.pipeline_microbatches)
-    else:
-        x, per_layer = jax.lax.scan(
-            lambda c, lp: body(c, lp), x, params["layers"])
-        stats = {"aux": per_layer["aux"].mean(),
-                 "load": per_layer["load"].max()}
+        return x, jax.tree.map(lambda z: z[None], _no_moe_stats())
+    if "dense_layers" in params:   # an MoE model's leading dense layers
+        x, _ = jax.lax.scan(lambda c, lp: body(c, lp), x,
+                            params["dense_layers"])
+    return jax.lax.scan(lambda c, lp: body(c, lp), x, params["layers"])
 
+
+def _model_stats(per_layer):
+    return {"aux": per_layer["aux"].mean(), "load": per_layer["load"].max(),
+            "held": per_layer["held"].mean()}
+
+
+def forward(params: Params, tokens: jax.Array, cfg: TransformerConfig,
+            mesh: Optional[Mesh] = None, return_aux: bool = False):
+    """tokens [B, T] int32 -> logits [B, T, vocab] float32.
+
+    With ``return_aux=True`` returns (logits, stats): ``aux`` the MoE
+    load-balance loss averaged over the expert layers, ``load`` the
+    largest expert group over the mean group in the worst layer, ``held``
+    the mean share of assignments on held experts (all 0.0 for dense or
+    pipelined execution)."""
+    x, per_layer = _trunk(params, tokens, cfg, mesh)
     logits = lm_head(params, x, cfg, mesh)
-    return (logits, stats) if return_aux else logits
+    return (logits, _model_stats(per_layer)) if return_aux else logits
+
+
+def _cross_entropy(logits, targets, mask):
+    logz = jax.nn.logsumexp(logits, axis=-1)
+    gold = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
+    nll = logz - gold
+    if mask is not None:
+        denom = jnp.maximum(mask.sum(), 1.0)
+        return (nll * mask).sum() / denom
+    return nll.mean()
+
+
+def _mtp_loss(params: Params, x, targets, mask, cfg: TransformerConfig,
+              mesh: Optional[Mesh]):
+    """The multi-token-prediction module (DeepSeek-V3 report, section 2.2,
+    depth 1): position i joins the main stack's last hidden state h_i
+    (BEFORE the final norm; the module norms it itself) with the embedding
+    of the NEXT token, each under its own RMSNorm, concatenated [hidden;
+    embedding] and projected 2d -> d; one more layer of the main kind;
+    the main model's final norm and head; cross entropy on the token
+    AFTER next. ``targets`` [B, T] are the next tokens, so all T positions
+    have an input and the first T - 1 a target: the block runs on T rows
+    (causal, so the last changes nothing before it) and the loss is over
+    T - 1. -> (loss, the layer's stats)."""
+    mp = params["mtp"]
+    with jax.named_scope("mtp.merge"):
+        nxt = params["embed"].astype(cfg.dtype)[targets]
+        both = jnp.concatenate([rms_norm(x, mp["h_norm"], cfg.rms_eps),
+                                rms_norm(nxt, mp["e_norm"], cfg.rms_eps)],
+                               axis=-1)
+        h = jnp.einsum("bte,ed->btd", both, mp["proj"].astype(cfg.dtype))
+        h = _wlc(h, ("batch", "seq", "embed"), mesh=mesh)
+    with jax.named_scope("mtp.block"):
+        body = _block_body(cfg, mesh, jnp.arange(x.shape[1]))
+        h, stats = body(h, jax.tree.map(lambda a: a[0], mp["layers"]))
+    with jax.named_scope("mtp.head"):
+        logits = lm_head(params, h[:, :-1], cfg, mesh)
+        loss = _cross_entropy(logits, targets[:, 1:],
+                              None if mask is None else mask[:, 1:])
+    return loss, stats
 
 
 def loss_fn(params: Params, batch: Dict[str, jax.Array],
@@ -330,19 +506,25 @@ def loss_fn(params: Params, batch: Dict[str, jax.Array],
         toks = batch["tokens"]
         inputs, targets = toks[:, :-1], toks[:, 1:]
         mask = None
-    logits, stats = forward(params, inputs, cfg, mesh, return_aux=True)
-    logz = jax.nn.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-    nll = logz - gold
-    if mask is not None:
-        denom = jnp.maximum(mask.sum(), 1.0)
-        loss = (nll * mask).sum() / denom
-    else:
-        loss = nll.mean()
+    x, per_layer = _trunk(params, inputs, cfg, mesh)
+    loss = _cross_entropy(lm_head(params, x, cfg, mesh), targets, mask)
     metrics = {"loss": loss, "perplexity": jnp.exp(loss)}
+    total = loss
+    if cfg.mtp_layers:
+        assert cfg.causal, "a token after next needs a causal model"
+        metrics["mtp_loss"], mtp_stats = _mtp_loss(params, x, targets, mask,
+                                                   cfg, mesh)
+        total = total + cfg.mtp_weight * metrics["mtp_loss"]
+        # the module's layer counts as one more layer of the model
+        per_layer = jax.tree.map(lambda a, b: jnp.append(a, b), per_layer,
+                                 mtp_stats)
     if cfg.moe_experts:
+        stats = _model_stats(per_layer)
         metrics["moe_aux"] = stats["aux"]
         metrics["moe_load_max_over_mean"] = stats["load"]
-        loss = loss + cfg.moe_aux_weight * stats["aux"]
-        metrics["total_loss"] = loss
-    return loss, metrics
+        if cfg.moe_held_experts is not None:
+            metrics["moe_held_share"] = stats["held"]
+        total = total + cfg.moe_aux_weight * stats["aux"]
+    if cfg.mtp_layers or cfg.moe_experts:
+        metrics["total_loss"] = total
+    return total, metrics

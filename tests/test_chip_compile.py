@@ -93,6 +93,8 @@ def _flash_loss(q, k, v, **kw):
 @pytest.mark.parametrize("shape, kw", [
     ((BATCH, SEQ, 32, 64), {}),  # llama3-1b, chip_smoke.py
     ((4, 4096, 16, 128), {}),    # a chip's share in all three train cells
+    # latent attention expanded: 8 rows x 20 heads, q, k and v 256 wide
+    ((8, 4096, 20, 256), {}),
     ((1, 8192, 4, 128), {}),     # K/V whole and in float32 overran VMEM here
     ((1, 16384, 2, 128), {}),
     # lengths that are no multiple of a tile: the block is, and T is padded
@@ -307,4 +309,50 @@ def test_olmoe_train_step_fits_one_chip_without_a_capacity_tensor(
         n = 1
         for d in dims:
             n *= d
+        assert n <= largest, m.group(0)
+
+
+def test_glm_train_step_fits_one_chip_at_the_depth_its_file_states(
+        one_chip, mosaic):
+    """The cell `glm-4.7-flash.train-4k-8rows` as the benchmark runs it
+    (its config file's depth, 8 x 4096, bf16 weights and moments): the
+    step compiles for one chip (a compile that returns fits), holds the
+    attention kernel and the grouped `ragged-dot` kernels, its expert
+    weights are the 8 HELD experts' and its router is 64 wide, and no
+    buffer is larger than the float32 logits over the vocabulary slice."""
+    from benchmark.harness import spec
+
+    bench = spec.load_benchmark()
+    conf = spec.load_config(bench, "glm-4.7-flash")
+    traffic = spec.load_traffic("train-4k-8rows")
+    rows, seq = traffic["rows"], traffic["seq_len"]
+    cfg = spec.build_transformer_config(
+        conf, max_seq_len=seq, param_dtype=traffic["param_dtype"],
+        attention_impl="pallas")
+    assert cfg.n_layers == conf["num_hidden_layers"] >= 5
+    assert (cfg.moe_experts, cfg.held_experts, cfg.moe_top_k) == (64, 8, 4)
+    assert (cfg.head_dim, cfg.v_head_dim, cfg.rope_head_dim) == (256, 256,
+                                                                 64)
+    tx = make_optimizer(traffic["learning_rate"],
+                        mu_dtype=jnp.dtype(traffic["mu_dtype"]))
+    shapes = jax.eval_shape(make_init_fn(cfg, tx), jax.random.key(0))
+    lay = shapes["params"]["layers"]
+    assert lay["w_gate"].shape == (cfg.n_layers - 1, 8, 2048, 1536)
+    assert lay["router"].shape == (cfg.n_layers - 1, 2048, 64)
+    assert shapes["params"]["dense_layers"]["w_gate"].shape == (1, 2048,
+                                                                10240)
+    assert shapes["params"]["embed"].shape == (19360, 2048)
+    batch = {"tokens": jax.ShapeDtypeStruct((rows, seq + 1), jnp.int32,
+                                            sharding=one_chip)}
+    text = make_train_step(cfg, tx).lower(
+        _on(shapes, one_chip), batch).compile().as_text()
+    assert "ragged-dot" in text and "tpu_custom_call" in text
+    for scope in ("mla.q", "mla.kv", "mla.rope", "mla.out", "moe.shared",
+                  "mtp.merge", "mtp.block", "mtp.head"):
+        assert scope in text, scope
+    largest = rows * seq * cfg.vocab_size         # the float32 logits
+    for m in re.finditer(r"\b\w+\[([\d,]+)\]", text):
+        n = 1
+        for d in m.group(1).split(","):
+            n *= int(d)
         assert n <= largest, m.group(0)

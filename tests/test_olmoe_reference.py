@@ -307,10 +307,10 @@ def test_train_step_reports_the_moe_counters_beside_the_loss():
 
 
 def test_the_chip_comparison_of_the_expert_layer_runs_at_toy_size():
-    """`chip_olmoe_expert_layer.py` (the published widths, bf16, on the
+    """`chip_expert_layer.py --config olmoe-1b-7b` (published widths, bf16, on the
     chip) at a toy size here: its three checks hold, and rounding the
     layer's inputs to 8-bit floats is told apart from bf16."""
-    import chip_olmoe_expert_layer as script
+    import chip_expert_layer as script
 
     conf = spec.load_config(spec.load_benchmark(), "olmoe-1b-7b")
     r = script.compare(5, conf, shape=(1, 32), **script.TOY)
