@@ -14,12 +14,19 @@ the reference delegates to external vLLM workers for, built TPU-first:
     It is stored KV-heads-outside-positions because that is the order
     the decode contraction reads it in: stored any other way, XLA
     transposes the whole cache on the way into every chunk and back.
-  - A decode substep reads the cache once and writes only the rows that
-    change: the layer scan takes the cache as read-only input, the new
-    token attends to its own K/V as one more key column, and the B new
-    rows per layer are written in place AFTER the scan (a per-layer
-    write inside the scan makes the scan re-stack, and XLA copy, the
-    whole cache every substep).
+  - A decode substep reads the cache rows its requests own and writes
+    only the rows that change: on a chip the layer scan carries the layer
+    index and hands the whole stacked cache to one Pallas kernel
+    (`ops/decode_attention.py`), which fetches, per slot, the position
+    blocks that cross ``[start, pos)`` and nothing for a slot that is not
+    active (a layer's slab sliced out for a custom call would be copied
+    first: 84 MB a layer for K and V each at 32 slots x 1280); on the CPU,
+    and for a shape the kernel does not take, the scan takes the cache as
+    read-only input and `_gqa_decode_attention` contracts over every
+    position under a mask. Either way the new token attends to its own
+    K/V as one more key column, and the B new rows per layer are written
+    in place AFTER the scan (a per-layer write inside the scan makes the
+    scan re-stack, and XLA copy, the whole cache every substep).
   - Each decode step advances EVERY active slot by one token in a single
     batched program (per-row cache positions, per-row RoPE), then the
     host admits queued prompts into any slots that finished — finished
@@ -73,6 +80,10 @@ from ray_tpu.models.transformer import (Params, ffn_block,
                                         param_logical_axes, qkv_proj,
                                         refuse_unserved, rms_norm,
                                         serving_params)
+from ray_tpu.ops.decode_attention import decode_attention, pick_block, \
+    rows_read
+from ray_tpu.parallel.ring import shard_map
+from ray_tpu.parallel.sharding import logical_to_spec
 
 log = logging.getLogger(__name__)
 
@@ -154,12 +165,49 @@ def prefill_slots(params: Params, cache: SlotCache, tokens: jax.Array,
             "start": cache["start"].at[slots].set(starts)}, toks
 
 
-def _decode_one(params: Params, cache: SlotCache, tokens: jax.Array,
-                cfg: TransformerConfig):
-    """One decode step for every slot: tokens [B] (each slot's pending
-    token) -> (cache with pos advanced, logits [B, V]).
+def _on_chip() -> bool:
+    return jax.default_backend() != "cpu"
 
-    pos/RoPE/attention masks are all per-row, so slots admitted at
+
+def _kv_block(k_cache) -> Optional[int]:
+    """The position block the decode kernel walks this cache by, or None
+    where the masked contraction runs instead: on the CPU (where it is
+    also the tests' reference), and for a shape the kernel does not take
+    (`pick_block`). Decided, as `transformer._select_attention` decides,
+    by what the code can observe."""
+    if not _on_chip():
+        return None
+    return pick_block(k_cache.shape[3], k_cache.shape[4], k_cache.dtype)
+
+
+def _kernel_attention(q, cache: SlotCache, k_new, v_new, active, layer, mesh):
+    """`decode_attention` on one layer of the whole stacked cache. GSPMD
+    cannot partition a Mosaic kernel: on a mesh it runs per shard of the
+    KV heads (heads are independent), as `transformer._attention` runs
+    the train kernel."""
+    args = (q, cache["k"], cache["v"], k_new, v_new, cache["pos"],
+            cache["start"], active, layer)
+    if mesh is None or mesh.size == 1:
+        return decode_attention(*args)
+    kv, heads, new = (
+        logical_to_spec(axes, mesh_axes=mesh.axis_names)
+        for axes in (cache_logical_axes()["k"], (None, None, "heads", None),
+                     (None, "kv_heads", None)))
+    rep = jax.sharding.PartitionSpec()
+    return shard_map(decode_attention, mesh=mesh,
+                     in_specs=(heads, kv, kv, new, new, rep, rep, rep, rep),
+                     out_specs=heads)(*args)
+
+
+def _decode_one(params: Params, cache: SlotCache, tokens: jax.Array,
+                cfg: TransformerConfig, active: Optional[jax.Array] = None,
+                mesh=None):
+    """One decode step for every slot: tokens [B] (each slot's pending
+    token) -> (cache with pos advanced, logits [B, V]). ``active`` [B]
+    bool left out reads every slot as active; a slot that is not active
+    attends to nothing it has cached (its logits are junk either way).
+
+    pos/RoPE/attention bounds are all per-row, so slots admitted at
     different times decode together in one program. The layer scan only
     READS the cache and hands back each layer's new K/V row ([L,B,KV,hd],
     a megabyte); the rows land afterwards, one in-place
@@ -171,17 +219,28 @@ def _decode_one(params: Params, cache: SlotCache, tokens: jax.Array,
     pos, start = cache["pos"], cache["start"]
     x = params["embed"].astype(cfg.dtype)[tokens[:, None]]  # [B, 1, d]
     positions = pos[:, None]  # [B, 1] per-row RoPE
-    S = cache["k"].shape[3]
-    kpos = jnp.arange(S)[None, :]
-    mask = (kpos >= start[:, None]) & (kpos < pos[:, None])  # [B, S]
+    L, _, _, S, _ = cache["k"].shape
     dtype = cache["k"].dtype
+    kernel = _kv_block(cache["k"]) is not None
+    if kernel:
+        if active is None:
+            active = jnp.ones_like(pos, bool)
+        # the kernel indexes [L, ...] itself: the scan carries the index
+        scanned = (params["layers"], jnp.arange(L))
+    else:
+        kpos = jnp.arange(S)[None, :]
+        mask = (kpos >= start[:, None]) & (kpos < pos[:, None])  # [B, S]
+        scanned = (params["layers"], cache["k"], cache["v"])
 
     def block(x, scanned):
-        lp, k_layer, v_layer = scanned
+        lp, *at = scanned  # the layer's index, or its K and V
         h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
         q, k, v = qkv_proj(h, lp, cfg, positions)
         k, v = k[:, 0].astype(dtype), v[:, 0].astype(dtype)  # [B, KV, hd]
-        o = _gqa_decode_attention(q, k_layer, v_layer, k, v, mask)
+        if kernel:
+            o = _kernel_attention(q, cache, k, v, active, *at, mesh)
+        else:
+            o = _gqa_decode_attention(q, *at, k, v, mask)
         o = jnp.einsum("bthk,hkd->btd", o, lp["wo"].astype(cfg.dtype))
         x = x + o
         h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
@@ -189,8 +248,7 @@ def _decode_one(params: Params, cache: SlotCache, tokens: jax.Array,
         x = x + down
         return x, (k, v)
 
-    x, (k_rows, v_rows) = jax.lax.scan(
-        block, x, (params["layers"], cache["k"], cache["v"]))
+    x, (k_rows, v_rows) = jax.lax.scan(block, x, scanned)
     logits = _final_logits(params, x, cfg)[:, 0]  # [B, V]
     k_all, v_all = _put_rows(cache, k_rows[:, :, :, None],
                              v_rows[:, :, :, None],
@@ -198,13 +256,13 @@ def _decode_one(params: Params, cache: SlotCache, tokens: jax.Array,
     return {"k": k_all, "v": v_all, "pos": pos + 1, "start": start}, logits
 
 
-@partial(jax.jit, static_argnames=("cfg", "greedy", "steps"),
+@partial(jax.jit, static_argnames=("cfg", "greedy", "steps", "mesh"),
          donate_argnums=(1,))
 def decode_slots(params: Params, cache: SlotCache, tokens: jax.Array,
                  active: jax.Array, rng: jax.Array,
                  cfg: TransformerConfig, greedy: bool = True,
                  temperature: float = 1.0, eos_id: int = -1,
-                 steps: int = 1):
+                 steps: int = 1, mesh=None):
     """``steps`` decode substeps for every slot in ONE compiled program:
     tokens [B] (pending sampled-but-not-decoded tokens), active [B]
     bool; -> (cache, [B, steps+1]) where column 0 echoes the INPUT
@@ -219,13 +277,14 @@ def decode_slots(params: Params, cache: SlotCache, tokens: jax.Array,
     mid-chunk freeze on-device (keep emitting eos);
     inactive slots compute junk into a position the next real write or
     prefill overwrites, their positions don't advance, and the host
-    ignores their samples.
+    ignores their samples. ``mesh``: the mesh the params and the cache are
+    sharded over, which the decode kernel needs to run per shard.
     """
     pos0 = cache["pos"]
 
     def substep(carry, step_rng):
         cache, tok, done = carry
-        cache, logits = _decode_one(params, cache, tok, cfg)
+        cache, logits = _decode_one(params, cache, tok, cfg, active, mesh)
         nxt = _sample(logits, step_rng, greedy, temperature)
         nxt = jnp.where(done, jnp.asarray(eos_id, nxt.dtype), nxt)
         done = done | (nxt == eos_id)
@@ -362,6 +421,11 @@ class InferenceEngine:
         # N's last samples (or a prefill's first sample, merged in with
         # .at[slot].set) — the host never syncs to keep the chain going
         self._next_tok_dev = jnp.zeros(self.slots, jnp.int32)
+        # what the device's cache["start"] / cache["pos"] hold for a
+        # resident slot, kept on the host for the decode_kv_rows_* counters
+        self._slot_start = np.zeros(self.slots, np.int64)
+        self._slot_pos = np.zeros(self.slots, np.int64)
+        self._kv_block = _kv_block(self.cache["k"])  # None: XLA contraction
         # dispatched-but-unfetched chunks: [(toks_dev [B, K+1],
         # [(slot, request, emit_from_col, take)])] — inline step() fetches
         # them in the step that dispatched them, the fetcher thread as the
@@ -405,6 +469,11 @@ class InferenceEngine:
             "queue_wait_s": 0.0, "first_token_s": 0.0, "first_tokens": 0,
             "chunks_ahead_at_admit": 0, "prefill_padded_tokens": 0,
             "prefill_prompt_tokens": 0,
+            # cache rows (a position of a slot, every layer and head) of
+            # the decode substeps dispatched: all there are, those decode
+            # attention fetches, and those an active slot owns
+            "decode_kv_rows_cache": 0, "decode_kv_rows_read": 0,
+            "decode_kv_rows_valid": 0,
             "slow_s": 0.0, "slow_count": 0}
         self.slow_events: collections.deque = collections.deque(maxlen=64)
         self.request_log: collections.deque = collections.deque(maxlen=1024)
@@ -594,10 +663,40 @@ class InferenceEngine:
                 jnp.asarray(slots)].set(first)
         for slot, req in group:
             self._slot_req[slot] = req
+            self._slot_start[slot] = P - len(req.prompt)
+            self._slot_pos[slot] = P
         self.stats["prefills"] += K
         self.stats["prefill_dispatches"] += 1
 
     _GROUP_SIZES = (4, 2, 1)  # compiled-prefill batch sizes, largest first
+
+    def _decode(self, active: np.ndarray):
+        """Dispatch one decode chunk for the ``active`` slots (ASYNC) and
+        chain its last samples; -> the chunk's tokens [B, chunk + 1]."""
+        # a mesh is named only where there is one, so that an unsharded
+        # engine's program is the one a caller of `decode_slots` gets
+        mesh = {} if self.mesh is None else {"mesh": self.mesh}
+        self.cache, toks = decode_slots(
+            self.params, self.cache, self._next_tok_dev,
+            jnp.asarray(active), self._next_rng(), self.cfg, self.greedy,
+            self.temperature, self.eos_id, steps=self.decode_chunk, **mesh)
+        self._next_tok_dev = toks[:, -1]
+        return toks
+
+    def _count_kv_rows(self, active_slots: List[int]):
+        """The decode_kv_rows_* counters for one chunk over these slots,
+        from the bounds the kernel itself walks by (`rows_read`)."""
+        steps = np.arange(self.decode_chunk)
+        start = self._slot_start[active_slots, None]
+        pos = self._slot_pos[active_slots, None] + steps
+        self._slot_pos[active_slots] += self.decode_chunk
+        whole = self.decode_chunk * self.slots * self._max_len
+        self.stats["decode_kv_rows_cache"] += whole
+        self.stats["decode_kv_rows_valid"] += int(np.sum(
+            np.clip(pos, None, self._max_len) - start))
+        self.stats["decode_kv_rows_read"] += whole \
+            if self._kv_block is None else rows_read(
+                start, pos, True, self._kv_block, self._max_len)
 
     def warmup(self):
         """Compile every program the serving loop can hit (per-bucket x
@@ -618,14 +717,10 @@ class InferenceEngine:
                 # group size; a mid-traffic compile stalls the loop)
                 self._next_tok_dev = self._next_tok_dev.at[
                     jnp.arange(K, dtype=jnp.int32)].set(first)
-        cache, toks = decode_slots(
-            self.params, self.cache, self._next_tok_dev,
-            jnp.ones(self.slots, bool), self._next_rng(), self.cfg,
-            self.greedy, self.temperature, self.eos_id,
-            steps=self.decode_chunk)
-        self._next_tok_dev = toks[:, -1]  # warm the last-column slice
+        self._decode(np.ones(self.slots, bool))  # and the last-column slice
         jax.block_until_ready(self._next_tok_dev)
         # reset bookkeeping: positions to zero, junk K/V is unreachable
+        cache = self.cache
         self.cache = {"k": cache["k"], "v": cache["v"],
                       "pos": jnp.zeros_like(cache["pos"]),
                       "start": jnp.zeros_like(cache["start"])}
@@ -755,11 +850,8 @@ class InferenceEngine:
                     self._slot_left[slot] - (width + 1 if new else width))
             active = np.zeros(self.slots, bool)
             active[active_slots] = True
-            self.cache, toks = decode_slots(
-                self.params, self.cache, self._next_tok_dev,
-                jnp.asarray(active), self._next_rng(), self.cfg,
-                self.greedy, self.temperature, self.eos_id, steps=width)
-            self._next_tok_dev = toks[:, -1]
+            toks = self._decode(active)
+            self._count_kv_rows(active_slots)
             self.stats["decode_steps"] += width
             self.stats["chunks_dispatched"] += 1
         self._inflight.append((toks, snapshot))

@@ -1,8 +1,9 @@
 """The building blocks of the serving programs: prompt pass and attention.
 
 `ray_tpu.models.engine` compiles its two programs from these (prefill:
-`_prefill_hidden` + `_final_logits`; decode: `_gqa_decode_attention` inside
-its own layer scan). There is no decode loop or cache of this module's own.
+`_prefill_hidden` + `_final_logits`; decode: inside its own layer scan,
+`ops/decode_attention.py`'s kernel on a chip and `_gqa_decode_attention`
+on the CPU). There is no decode loop or cache of this module's own.
 TPU-first design:
 
   - Static shapes everywhere: a prompt is left-padded to a bucket and
@@ -15,9 +16,11 @@ TPU-first design:
   - Keys/values are cached *post-RoPE* and *pre-GQA-expansion* (KV heads,
     not Q heads): memory scales with kv_heads, and the repeat to Q heads
     happens inside the attention contraction.
-  - Decode attention is a dense masked contraction over the cache — at
-    T=1 per step it is HBM-bandwidth-bound (reads the cache once), which
-    is the TPU roofline for decode; batching raises MXU utilization.
+  - Decode attention at T=1 per step is HBM-bandwidth-bound: its time is
+    the cache bytes it reads. `_gqa_decode_attention`, a dense masked
+    contraction, reads every position of every slot whatever the mask
+    says; it is the CPU path and the reference the decode kernel is held
+    to, which reads only the blocks of positions a request owns.
 """
 
 from __future__ import annotations

@@ -57,9 +57,19 @@ def one_chip(topo):
 
 @pytest.fixture
 def mosaic(monkeypatch):
-    """Lower the Mosaic kernel, not the interpreter the CPU backend picks."""
+    """Lower the Mosaic kernels, not the interpreter the CPU backend picks,
+    and let decode pick its kernel by the cache's shape as it does on a
+    chip (under the CPU backend it takes the masked contraction)."""
+    from ray_tpu.models import engine
+
     fa = importlib.import_module("ray_tpu.ops.flash_attention")
+    da = importlib.import_module("ray_tpu.ops.decode_attention")
     monkeypatch.setattr(fa, "_use_interpret", lambda: False)
+    monkeypatch.setattr(da, "_use_interpret", lambda: False)
+    monkeypatch.setattr(engine, "_on_chip", lambda: True)
+    engine.decode_slots.clear_cache()
+    yield
+    engine.decode_slots.clear_cache()
 
 
 def _llama(**kw):
@@ -159,18 +169,33 @@ def test_llama3_1b_train_step_fits_one_chip(one_chip, mosaic):
     assert _device_bytes(compiled) < HBM_BYTES
 
 
-def test_serve_programs_compile(one_chip):
-    """The serve replica's programs at chip_smoke's sizes: 8 slots,
-    prompts to 512, 64 new tokens (max_len 640 covers the issue's
-    rehearsal size), decode chunks of 4, and the weights as the engine
-    holds them (`serving_params`: the preset's compute dtype, the
-    vocabulary head float32)."""
+def _internlm2():
+    from benchmark.harness import spec
+
+    return spec.build_transformer_config(
+        spec.load_config(spec.load_benchmark(), "internlm2-1.8b"))
+
+
+@pytest.mark.parametrize("make_cfg, slots, max_len, group, kernel", [
+    # chip_smoke's sizes: 8 slots, prompts to 512, 64 new tokens (max_len
+    # 640 covers the issue's rehearsal size). A head of 64 is stored
+    # padded to 128 lanes, which the decode kernel's DMA cannot slice:
+    # this preset keeps the masked contraction
+    (llama3_1b_config, 8, 640, (8, 512), False),
+    # the serve cells' own size, InternLM2's widths (heads of 128)
+    (_internlm2, 32, 1280, (4, 1024), True),
+], ids=["llama3-1b-8x640", "internlm2-1.8b-32x1280"])
+def test_serve_programs_compile(one_chip, mosaic, make_cfg, slots, max_len,
+                                group, kernel):
+    """The serve replica's programs, decode chunks of 4, and the weights
+    as the engine holds them (`serving_params`: the preset's compute
+    dtype, the vocabulary head float32)."""
     from ray_tpu.models.engine import (decode_slots, init_slot_cache,
                                        prefill_slots)
     from ray_tpu.models.transformer import init_params, serving_params
 
-    cfg = llama3_1b_config()
-    slots, max_len = 8, 640
+    cfg = make_cfg()
+    K, P = group
     params = _on(jax.eval_shape(
         lambda k: serving_params(init_params(k, cfg), cfg),
         jax.random.key(0)), one_chip)
@@ -181,8 +206,8 @@ def test_serve_programs_compile(one_chip):
     def i32(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
 
-    prefill = prefill_slots.lower(params, cache, i32(8, 512), i32(8),
-                                  i32(8), rng, cfg).compile()
+    prefill = prefill_slots.lower(params, cache, i32(K, P), i32(K),
+                                  i32(K), rng, cfg).compile()
     assert _device_bytes(prefill) < HBM_BYTES
     active = jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one_chip)
     decode = decode_slots.lower(params, cache, i32(slots), active, rng, cfg,
@@ -197,23 +222,49 @@ def test_serve_programs_compile(one_chip):
     # weight-sized but the head's bf16 copy (525 MB; the smallest stacked
     # matrix is 33 MB, the float32 tree's bf16 copy 2.5 GB): decode's are
     # small change, the prefill's its own activations, the float32 scores
-    # and probabilities of [8 x 512] rows first (268 MB each).
+    # and probabilities of [K x P] rows first (268 MB each at 8 x 512).
     head = params["embed" if cfg.tie_embeddings else "lm_head"]
     assert head.dtype == jnp.float32
     head_copy = head.size * 2
     cache_bytes = 2 * cache["k"].size * cache["k"].dtype.itemsize
-    scores = 8 * cfg.n_heads * 512 * 512 * 4
+    scores = K * cfg.n_heads * P * P * 4
     for program, room in ((decode, 16 << 20), (prefill, 3 * scores)):
         assert _weight_converts(program.as_text(), params,
                                 but=head.shape) == []
         assert program.memory_analysis().temp_size_in_bytes \
             < 0.1 * cache_bytes + head_copy + room
-    # A decode substep reads the cache once and writes only the rows that
-    # change, in place: no instruction copies, selects over or scatters
-    # into a whole-cache-sized result (a per-layer write inside the layer
-    # scan, or a cache stored in another order than attention reads it,
-    # brings exactly those back: 2/3 of decode time on the chip)
-    assert _whole_cache_ops(decode.as_text(), cache["k"].shape) == []
+    # A decode substep writes only the rows that change, in place: no
+    # instruction copies, selects over or scatters into a whole-cache-sized
+    # result (a per-layer write inside the layer scan, or a cache stored in
+    # another order than attention reads it, brings exactly those back:
+    # 2/3 of decode time on the chip)
+    text = decode.as_text()
+    assert _whole_cache_ops(text, cache["k"].shape) == []
+    # and, where the kernel takes the cache, reads it through the kernel
+    # alone, which picks its layer itself: the one Mosaic call of the
+    # program is the named kernel, and nothing materialises a layer's
+    # [slots, KV, S, hd] slab of K or V on the way in, as a float32 copy
+    # (the contraction's) or as a slice cut out for the custom call (84 MB
+    # a layer, K and V each, at the cells' size)
+    assert ("tpu_custom_call" in text) == kernel
+    if kernel:
+        assert re.search(r"%decode_attention\S* = [^\n]*custom-call\(", text)
+        assert _layer_slab_ops(text, cache["k"].shape[1:]) == []
+
+
+def _layer_slab_ops(hlo: str, slab_shape) -> list:
+    """`name = type[...] convert|copy|dynamic-slice|fusion(...)` lines of
+    compiled text whose result has one layer's cache dimensions (in any
+    order, 1s dropped)."""
+    want = sorted(d for d in slab_shape if d > 1)
+    found = []
+    for m in re.finditer(
+            r"^\s*(?:ROOT )?(\S+) = \w+\[([\d,]+)\]\S* "
+            r"(convert|copy|dynamic-slice|fusion)\(", hlo, re.M):
+        dims = sorted(int(d) for d in m.group(2).split(",") if int(d) > 1)
+        if dims == want:
+            found.append(f"{m.group(1)}: {m.group(3)}")
+    return found
 
 
 def _weight_converts(hlo: str, params, but=()) -> list:
@@ -250,6 +301,42 @@ def _whole_cache_ops(hlo: str, cache_shape) -> list:
         if dims == sorted(cache_shape):
             found.append(f"{m.group(1)}: {m.group(3)}")
     return found
+
+
+def test_tensor2_decode_runs_the_kernel_per_shard(topo, mosaic):
+    """The serve cells' decode chunk on a `tensor=2` mesh of described
+    chips: GSPMD cannot partition a Mosaic kernel, so `_kernel_attention`
+    runs it per shard of the KV heads; each chip's call sees 4 of the 8."""
+    from ray_tpu.models.engine import (cache_logical_axes, decode_slots,
+                                       init_slot_cache)
+    from ray_tpu.models.transformer import (init_params, param_logical_axes,
+                                            serving_params)
+    from ray_tpu.parallel import MeshSpec
+    from ray_tpu.parallel.sharding import logical_sharding, tree_shardings
+
+    cfg = _internlm2()
+    slots = 32
+    mesh = MeshSpec(data=1, fsdp=1, tensor=2).build(topo.devices[:2])
+    params = _on(jax.eval_shape(
+        lambda k: serving_params(init_params(k, cfg), cfg),
+        jax.random.key(0)), tree_shardings(mesh, param_logical_axes(cfg)))
+    axes = cache_logical_axes()
+    cache = _on(jax.eval_shape(lambda: init_slot_cache(cfg, slots, 1280)),
+                {k: logical_sharding(mesh, axes[k]) for k in axes})
+    whole = logical_sharding(mesh, (None,))
+    rng = _on(jax.eval_shape(lambda: jax.random.key(0)),
+              logical_sharding(mesh, ()))
+    tokens = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=whole)
+    active = jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=whole)
+    text = decode_slots.lower(params, cache, tokens, active, rng, cfg,
+                              steps=4, mesh=mesh).compile().as_text()
+    out = re.findall(r"%decode_attention\S* = \w+\[([\d,]+)\]\S* "
+                     r"custom-call\(", text)
+    assert out == [f"{slots},{cfg.kv_heads // 2},"
+                   f"{cfg.n_heads // cfg.kv_heads},{cfg.head_dim}"]
+    assert "all-reduce" in text  # the tensor-parallel output projection
+    assert _whole_cache_ops(text, (cfg.n_layers, slots, cfg.kv_heads // 2,
+                                   1280, cfg.head_dim)) == []
 
 
 def test_fsdp2_tensor2_train_step_keeps_kernel(topo, mosaic):

@@ -2,7 +2,9 @@
 
 Decode reads the slot cache and writes only the rows that change, in
 place (one dynamic_update_slice per slot, after the layer scan). These are
-the cases that write can get wrong.
+the cases that write can get wrong, and, with the decode kernel forced on
+(the interpreter; blocks of 4 positions), the cases its walk over
+``[start, pos)`` can: `pos` advances on the device inside a chunk.
 """
 
 import jax
@@ -10,9 +12,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import ray_tpu.models.engine as engine
+import ray_tpu.ops.decode_attention as decode_attention
 from ray_tpu.models.config import tiny_config
-from ray_tpu.models.engine import (_decode_one, decode_slots,
-                                   init_slot_cache, prefill_slots)
+from ray_tpu.models.engine import (InferenceEngine, _decode_one,
+                                   decode_slots, init_slot_cache,
+                                   prefill_slots)
 from ray_tpu.models.transformer import forward, init_params
 
 # A slot is (prompt length, solo decode steps taken before the chunk,
@@ -44,14 +49,36 @@ _CHUNK_CASES = {
 }
 
 
+# the cases that run again through the kernel
+_KERNEL_CASES = ("pos_and_start_differ", "inactive_between_active",
+                 "runs_off_the_end_beside_a_parked_slot", "bfloat16")
+
+
+@pytest.fixture
+def kernel(monkeypatch):
+    """Force the decode kernel where the CPU backend picks the masked
+    contraction, walking blocks of ``block`` positions; the jitted chunk
+    is traced anew on both sides of the test."""
+    def force(block):
+        monkeypatch.setattr(decode_attention, "_BLOCK", block)
+        monkeypatch.setattr(engine, "_on_chip", lambda: True)
+    decode_slots.clear_cache()
+    yield force
+    decode_slots.clear_cache()
+
+
 def _rel_rms(got, want):
     got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
     return float(np.sqrt(np.mean((got - want) ** 2) / np.mean(want ** 2)))
 
 
-@pytest.mark.parametrize("case", list(_CHUNK_CASES))
-def test_decode_chunk_equals_the_full_forward(case):
+@pytest.mark.parametrize("case, attention", [
+    (case, "xla") for case in _CHUNK_CASES] + [
+    (case, "kernel") for case in _KERNEL_CASES])
+def test_decode_chunk_equals_the_full_forward(case, attention, kernel):
     spec = _CHUNK_CASES[case]
+    if attention == "kernel":
+        kernel(4)  # _S = 20: five blocks a slot
     dtype = spec.get("dtype", "float32")
     # the same bounds the benchmark's reference check holds a run to
     bound = {"float32": 2e-4, "bfloat16": 0.08}[dtype]
@@ -160,3 +187,66 @@ def test_decode_chunk_equals_the_full_forward(case):
         keep = slice(start0[b], min(pos0[b], _S - 1))
         assert (k1[:, b, :, keep] == k0[:, b, :, keep]).all(), b
         assert (v1[:, b, :, keep] == v0[:, b, :, keep]).all(), b
+
+
+@pytest.mark.parametrize("attention", ["xla", "kernel"])
+def test_engine_counts_the_cache_rows_decode_reads(attention, kernel):
+    """`decode_kv_rows_*` after two requests of known lengths, by hand.
+    Prompts of 5 and 11 tokens are admitted as one group padded to 16, so
+    start = 11 and 5 and pos = 16 for both; 24 positions a slot. Plans of
+    6 and 3 tokens: chunk 1 (4 substeps, pos 16..19) runs both slots,
+    chunk 2 (pos 20..23) the first alone."""
+    if attention == "kernel":
+        kernel(8)  # three blocks of 8 a slot
+    cfg = tiny_config()
+    params = init_params(jax.random.key(0), cfg)
+    eng = InferenceEngine(params, cfg, slots=2, max_prompt_len=16,
+                          max_new_tokens=8, min_bucket=8, decode_chunk=4)
+    assert eng._kv_block == (8 if attention == "kernel" else None)
+    reqs = [eng.submit(list(range(1, 6)), 6),
+            eng.submit(list(range(1, 12)), 3)]
+    while not all(r.done.is_set() for r in reqs):
+        assert eng.step()
+    assert [len(r.tokens) for r in reqs] == [6, 3]
+    assert eng.stats["decode_steps"] == 8
+    assert eng.stats["decode_kv_rows_cache"] == 2 * 4 * 2 * 24
+    valid = sum((16 + t - 11) + (16 + t - 5) for t in range(4)) \
+        + sum(20 + t - 11 for t in range(4))
+    assert eng.stats["decode_kv_rows_valid"] == valid == 118
+    # blocks of 8: slot 0 owns [11, pos): block 1 at pos 16, blocks 1-2
+    # from 17 on; slot 1 owns [5, pos): blocks 0-1 at 16, 0-2 from 17 on
+    blocks = (1 + 2 + 2 + 2) + (2 + 3 + 3 + 3) + 4 * 2
+    assert eng.stats["decode_kv_rows_read"] == (
+        blocks * 8 if attention == "kernel" else 2 * 4 * 2 * 24)
+    # and each request got the full forward's greedy tokens
+    for r in reqs:
+        seq = list(r.prompt)
+        for tok in r.tokens:
+            assert tok == int(jnp.argmax(forward(
+                params, jnp.asarray([seq], jnp.int32), cfg)[0, -1]))
+            seq.append(tok)
+
+
+def test_kernel_runs_per_shard_under_a_tensor_mesh(kernel):
+    """An engine on a `tensor=2` mesh (tiny_config has 2 KV heads, the
+    sharded axis) with the kernel forced: one KV head a shard, the same
+    greedy tokens as the unsharded masked contraction."""
+    from ray_tpu.parallel import MeshSpec
+
+    cfg = tiny_config()
+    params = init_params(jax.random.key(0), cfg)
+    prompts = [[3, 1, 4, 1, 5], [2, 7]]
+
+    def tokens(mesh):
+        eng = InferenceEngine(params, cfg, slots=2, max_prompt_len=16,
+                              max_new_tokens=8, mesh=mesh)
+        reqs = [eng.submit(p) for p in prompts]
+        while not all(r.done.is_set() for r in reqs):
+            assert eng.step()
+        return [list(r.tokens) for r in reqs], eng._kv_block
+
+    want, block = tokens(None)
+    assert block is None
+    kernel(8)
+    mesh = MeshSpec(data=1, fsdp=1, tensor=2).build(jax.devices()[:2])
+    assert tokens(mesh) == (want, 8)
