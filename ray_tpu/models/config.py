@@ -72,11 +72,34 @@ class TransformerConfig:
     # `v_head_dim` wide (None -> head_dim). Scores are scaled by
     # head_dim ** -0.5. The rotary columns of the stored latent projections
     # are paired (2i, 2i+1), as this attention's published weights are
-    # (the ordinary projections pair by halves).
+    # (the ordinary projections pair by halves). `q_lora_rank` 0 under
+    # latent attention: queries straight from the hidden state (`wq`).
+    # `nope_head_dim` states the unrotated width in place of `head_dim`
+    # (head_dim = nope_head_dim + rope_head_dim) for a configuration whose
+    # published `head_dim` is another thing.
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     rope_head_dim: int = 0
     v_head_dim: Optional[int] = None
+    nope_head_dim: Optional[int] = None
+    # False: no rotary embedding anywhere (under latent attention the
+    # `rope_head_dim` columns stay, unrotated; positions then enter the
+    # model through its recurrent layers alone).
+    use_rope: bool = True
+    # The kinds of token mixer, a period applied from the first layer on
+    # (layer i has kind i mod the period's length; the leading dense
+    # layers take theirs from the same count): "attention" (whichever the
+    # fields above configure) or "kda", gated delta-rule linear attention
+    # with a per-channel decay (ops/kda.py; transformer.kda_mixer):
+    # `kda_heads` heads of `kda_head_dim` (keys, queries and values
+    # alike), a causal depthwise convolution of `kda_conv` taps on each of
+    # q, k, v, the decay's and the output gate's projections through a
+    # width of `kda_gate_rank`.
+    mixer_period: tuple = ("attention",)
+    kda_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv: int = 4
+    kda_gate_rank: int = 0
     # Of the `n_layers` layers of an MoE model, the first
     # `moe_dense_layers` have a dense SwiGLU of width `moe_dense_d_ff`.
     moe_dense_layers: int = 0
@@ -110,6 +133,13 @@ class TransformerConfig:
             if not ok:
                 raise ValueError(f"TransformerConfig: {why}")
 
+        object.__setattr__(self, "mixer_period", tuple(self.mixer_period))
+        if self.nope_head_dim is not None:
+            width = self.nope_head_dim + self.rope_head_dim
+            need(self.kv_lora_rank and self.head_dim in (None, width),
+                 "nope_head_dim belongs to latent attention and head_dim "
+                 "is nope_head_dim + rope_head_dim")
+            object.__setattr__(self, "head_dim", width)
         if self.head_dim is None:
             need(self.d_model % self.n_heads == 0,
                  "d_model is no multiple of n_heads and no head_dim is given")
@@ -117,15 +147,28 @@ class TransformerConfig:
         if self.v_head_dim is None:
             object.__setattr__(self, "v_head_dim", self.head_dim)
         if self.kv_lora_rank:
-            need(self.q_lora_rank and 0 < self.rope_head_dim < self.head_dim,
-                 "latent attention needs q_lora_rank and 0 < rope_head_dim "
-                 "< head_dim")
+            need(0 < self.rope_head_dim < self.head_dim,
+                 "latent attention needs 0 < rope_head_dim < head_dim")
             need(self.kv_heads == self.n_heads and not self.qk_norm,
                  "latent attention has one key/value head a query head and "
                  "no q/k norms")
         else:
             need(self.v_head_dim == self.head_dim,
                  "v_head_dim differs from head_dim without latent attention")
+        period = self.mixer_period
+        need(period and set(period) <= {"attention", "kda"},
+             f"mixer_period {period!r}")
+        if "kda" in period:
+            need(self.kda_heads and self.kda_head_dim and self.kda_gate_rank
+                 and self.kda_conv >= 1 and self.causal,
+                 "a kda layer needs kda_heads, kda_head_dim, kda_gate_rank, "
+                 "kda_conv >= 1 and a causal model")
+        if len(period) > 1:
+            stack = self.n_layers - self.moe_dense_layers
+            need(stack % len(period) == 0 and not self.mtp_layers,
+                 "the stack after the leading dense layers is whole "
+                 "periods of mixer_period, and a prediction module has "
+                 "one kind of layer")
         need(self.moe_scoring in ("softmax", "sigmoid"),
              f"moe_scoring {self.moe_scoring!r}")
         need(self.mtp_layers in (0, 1), "one prediction module at most")
@@ -142,13 +185,25 @@ class TransformerConfig:
         return self.moe_experts if self.moe_held_experts is None \
             else self.moe_held_experts
 
-    def _layer_params(self, moe: bool) -> int:
+    def mixer_kind(self, layer: int) -> str:
+        return self.mixer_period[layer % len(self.mixer_period)]
+
+    def _layer_params(self, moe: bool, kind: str = "attention") -> int:
         d, hd, H, KV = self.d_model, self.head_dim, self.n_heads, \
             self.kv_heads
-        if self.kv_lora_rank:
+        if kind == "kda":
+            width, r = self.kda_heads * self.kda_head_dim, self.kda_gate_rank
+            attn = (4 * d * width                    # q, k, v, out
+                    + 3 * self.kda_conv * width      # their convolutions
+                    + 2 * (d * r + r * width)        # decay, output gate
+                    + d * self.kda_heads             # beta
+                    + self.kda_heads + width         # A_log, dt_bias
+                    + self.kda_head_dim)             # the heads' norm
+        elif self.kv_lora_rank:
             q, kv, rope = self.q_lora_rank, self.kv_lora_rank, \
                 self.rope_head_dim
-            attn = (d * q + q + q * H * hd + d * (kv + rope) + kv
+            attn = ((d * q + q + q * H * hd if q else d * H * hd)
+                    + d * (kv + rope) + kv
                     + kv * H * (hd - rope + self.v_head_dim)
                     + H * self.v_head_dim * d)
         else:
@@ -169,8 +224,8 @@ class TransformerConfig:
         """Leaves of `init_params`, counted from shapes."""
         d, v = self.d_model, self.vocab_size
         dense = self.moe_dense_layers if self.moe_experts else self.n_layers
-        layers = dense * self._layer_params(False) \
-            + (self.n_layers - dense) * self._layer_params(True)
+        layers = sum(self._layer_params(i >= dense, self.mixer_kind(i))
+                     for i in range(self.n_layers))
         mtp = self.mtp_layers * (2 * d + 2 * d * d
                                  + self._layer_params(bool(self.moe_experts)))
         head = 0 if self.tie_embeddings else d * v

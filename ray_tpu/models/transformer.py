@@ -37,17 +37,58 @@ Params = Dict[str, Any]
 
 # ---- parameter structure ---------------------------------------------------
 
-def _stack_axes(cfg: TransformerConfig, moe: bool) -> Params:
+def _stack_kinds(cfg: TransformerConfig, first: int, count: int):
+    """The mixer kinds of the ``count`` layers from layer ``first`` on, as
+    (the kinds of one repetition, repetitions): whole periods of
+    `cfg.mixer_period` where the count is whole periods (the stack is then
+    scanned a period at a time), else every layer once."""
+    kinds = tuple(cfg.mixer_kind(first + j) for j in range(count))
+    period = len(cfg.mixer_period)
+    return (kinds[:period], count // period) if count % period == 0 \
+        else (kinds, 1)
+
+
+def _per_kind(cfg: TransformerConfig, first: int, count: int, make):
+    """A stack of layers ``first`` .. ``first + count``: ``make(j, kind,
+    repetitions)`` for each kind of one repetition. One kind: what it
+    makes, as a stack has always been; several: a tuple, one entry a
+    position in the period."""
+    kinds, reps = _stack_kinds(cfg, first, count)
+    made = tuple(make(j, kind, reps) for j, kind in enumerate(kinds))
+    return made[0] if len(made) == 1 else made
+
+
+def _stack_axes(cfg: TransformerConfig, moe: bool,
+                kind: str = "attention") -> Params:
     lay = {
         "attn_norm": ("layers", "embed"),
         "wo": ("layers", "heads", "qkv_dim", "embed"),
         "mlp_norm": ("layers", "embed"),
     }
-    if cfg.kv_lora_rank:
+    if kind == "kda":
+        proj = ("layers", "embed", "heads", "qkv_dim")
+        lay.update({
+            "kda_wq": proj, "kda_wk": proj, "kda_wv": proj,
+            "kda_conv_q": ("layers", None, "heads", "qkv_dim"),
+            "kda_conv_k": ("layers", None, "heads", "qkv_dim"),
+            "kda_conv_v": ("layers", None, "heads", "qkv_dim"),
+            "kda_f_a": ("layers", "embed", None),
+            "kda_f_b": ("layers", None, "heads", "qkv_dim"),
+            "kda_g_a": ("layers", "embed", None),
+            "kda_g_b": ("layers", None, "heads", "qkv_dim"),
+            "kda_beta": ("layers", "embed", "heads"),
+            "kda_A_log": ("layers", "heads"),
+            "kda_dt_bias": ("layers", "heads", "qkv_dim"),
+            "kda_o_norm": ("layers", None),
+        })
+    elif cfg.kv_lora_rank:
         lay.update({
             "wq_a": ("layers", "embed", None),
             "q_a_norm": ("layers", None),
             "wq_b": ("layers", None, "heads", "qkv_dim"),
+        } if cfg.q_lora_rank else {
+            "wq": ("layers", "embed", "heads", "qkv_dim")})
+        lay.update({
             "wkv_a": ("layers", "embed", None),
             "kv_a_norm": ("layers", None),
             "wkv_b": ("layers", None, "heads", "qkv_dim"),
@@ -58,7 +99,7 @@ def _stack_axes(cfg: TransformerConfig, moe: bool) -> Params:
             "wk": ("layers", "embed", "kv_heads", "qkv_dim"),
             "wv": ("layers", "embed", "kv_heads", "qkv_dim"),
         })
-    if cfg.qk_norm:
+    if cfg.qk_norm and kind != "kda":
         # gains over the flattened (heads x head_dim) projection: replicated
         lay.update({"q_norm": ("layers", None), "k_norm": ("layers", None)})
     if moe:
@@ -75,25 +116,56 @@ def _stack_axes(cfg: TransformerConfig, moe: bool) -> Params:
 def param_logical_axes(cfg: TransformerConfig) -> Params:
     """Same-structure pytree of logical axis tuples (for shardings)."""
     moe = bool(cfg.moe_experts)
+    dense = cfg.moe_dense_layers   # 0 without experts
     axes = {
         "embed": ("vocab", "embed"),
-        "layers": _stack_axes(cfg, moe),
+        "layers": _per_kind(cfg, dense, cfg.n_layers - dense,
+                            lambda j, kind, n: _stack_axes(cfg, moe, kind)),
         "final_norm": ("embed",),
     }
-    if moe and cfg.moe_dense_layers:
-        axes["dense_layers"] = _stack_axes(cfg, False)
+    if dense:
+        axes["dense_layers"] = _per_kind(
+            cfg, 0, dense, lambda j, kind, n: _stack_axes(cfg, False, kind))
     if cfg.mtp_layers:
         axes["mtp"] = {"h_norm": ("embed",), "e_norm": ("embed",),
                        "proj": (None, "embed"),
-                       "layers": _stack_axes(cfg, moe)}
+                       "layers": _stack_axes(cfg, moe, cfg.mixer_kind(0))}
     if not cfg.tie_embeddings:
         axes["lm_head"] = ("embed", "vocab")
     return axes
 
 
-def _init_stack(k, cfg: TransformerConfig, L: int, moe: bool) -> Params:
+def _init_kda(k, cfg: TransformerConfig, L: int) -> Params:
+    """The leaves of ``L`` stacked KDA mixers but for `wo`. `A_log` and
+    `dt_bias` as the published implementation draws them (A uniform in
+    [1, 16); dt log-uniform in [0.001, 0.1) through the inverse softplus),
+    so that seeded decays spread over (0, 1)."""
+    d, H, hd, r = cfg.d_model, cfg.kda_heads, cfg.kda_head_dim, \
+        cfg.kda_gate_rank
+    pd = cfg.param_dtype
+    normal = functools.partial(scaled_normal, dtype=pd)
+    lay = {name: normal(next(k), (L, d, H, hd), d ** -0.5)
+           for name in ("kda_wq", "kda_wk", "kda_wv")}
+    lay.update({name: normal(next(k), (L, cfg.kda_conv, H, hd),
+                             cfg.kda_conv ** -0.5)
+                for name in ("kda_conv_q", "kda_conv_k", "kda_conv_v")})
+    for gate in ("f", "g"):     # the decay's pair, the output gate's
+        lay[f"kda_{gate}_a"] = normal(next(k), (L, d, r), d ** -0.5)
+        lay[f"kda_{gate}_b"] = normal(next(k), (L, r, H, hd), r ** -0.5)
+    lay["kda_beta"] = normal(next(k), (L, d, H), d ** -0.5)
+    lay["kda_A_log"] = jnp.log(jax.random.uniform(
+        next(k), (L, H), jnp.float32, 1.0, 16.0)).astype(pd)
+    dt = jnp.exp(jax.random.uniform(next(k), (L, H, hd), jnp.float32,
+                                    jnp.log(0.001), jnp.log(0.1)))
+    lay["kda_dt_bias"] = (dt + jnp.log(-jnp.expm1(-dt))).astype(pd)
+    lay["kda_o_norm"] = jnp.ones((L, hd), pd)
+    return lay
+
+
+def _init_stack(k, cfg: TransformerConfig, L: int, moe: bool,
+                kind: str = "attention") -> Params:
     """``L`` stacked layers of one kind, keys drawn from the iterator
-    ``k`` (attention first, then the FFN)."""
+    ``k`` (the mixer first, then the FFN)."""
     d, hd, H, KV = cfg.d_model, cfg.head_dim, cfg.n_heads, cfg.kv_heads
     pd = cfg.param_dtype
     normal = functools.partial(scaled_normal, dtype=pd)
@@ -102,12 +174,18 @@ def _init_stack(k, cfg: TransformerConfig, L: int, moe: bool) -> Params:
     out_scale = (2 * cfg.n_layers) ** -0.5 * d ** -0.5
     lay = {"attn_norm": jnp.ones((L, d), pd),
            "mlp_norm": jnp.ones((L, d), pd)}
-    if cfg.kv_lora_rank:
+    out_heads = (H, cfg.v_head_dim)
+    if kind == "kda":
+        lay.update(_init_kda(k, cfg, L))
+        out_heads = (cfg.kda_heads, cfg.kda_head_dim)
+    elif cfg.kv_lora_rank:
         rq, rkv, rope = cfg.q_lora_rank, cfg.kv_lora_rank, cfg.rope_head_dim
         lay.update({
             "wq_a": normal(next(k), (L, d, rq), in_scale),
             "q_a_norm": jnp.ones((L, rq), pd),
             "wq_b": normal(next(k), (L, rq, H, hd), rq ** -0.5),
+        } if rq else {"wq": normal(next(k), (L, d, H, hd), in_scale)})
+        lay.update({
             "wkv_a": normal(next(k), (L, d, rkv + rope), in_scale),
             "kv_a_norm": jnp.ones((L, rkv), pd),
             # per head: the unrotated part of the key, then the value
@@ -120,8 +198,8 @@ def _init_stack(k, cfg: TransformerConfig, L: int, moe: bool) -> Params:
             "wk": normal(next(k), (L, d, KV, hd), in_scale),
             "wv": normal(next(k), (L, d, KV, hd), in_scale),
         })
-    lay["wo"] = normal(next(k), (L, H, cfg.v_head_dim, d), out_scale)
-    if cfg.qk_norm:
+    lay["wo"] = normal(next(k), (L, *out_heads, d), out_scale)
+    if cfg.qk_norm and kind != "kda":
         lay.update({"q_norm": jnp.ones((L, H * hd), pd),
                     "k_norm": jnp.ones((L, KV * hd), pd)})
     if moe:
@@ -141,11 +219,22 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> Params:
     d, v = cfg.d_model, cfg.vocab_size
     pd = cfg.param_dtype
     moe = bool(cfg.moe_experts)
-    k = iter(jax.random.split(rng, 16))
+    dense = cfg.moe_dense_layers   # 0 without experts
+    n_keys = 32 if "kda" in cfg.mixer_period else 16   # a KDA layer: 14
+    k = iter(jax.random.split(rng, n_keys))
+
+    def stack(first, count, moe, own, salt):
+        """Layers ``first`` .. + ``count``. One kind: from ``own``, the
+        keys that stack has always drawn from; a position of a period:
+        from keys of its own."""
+        one = len(_stack_kinds(cfg, first, count)[0]) == 1
+        return _per_kind(cfg, first, count, lambda j, kind, n: _init_stack(
+            own if one else iter(jax.random.split(
+                jax.random.fold_in(rng, salt + j), n_keys)),
+            cfg, n, moe, kind))
     params: Params = {
         # an MoE model's leading dense layers are a stack of their own
-        "layers": _init_stack(k, cfg, cfg.n_layers - cfg.moe_dense_layers,
-                              moe),
+        "layers": stack(dense, cfg.n_layers - dense, moe, k, 16),
         "embed": scaled_normal(next(k), (v, d), d ** -0.5, pd),
         "final_norm": jnp.ones((d,), pd),
     }
@@ -153,17 +242,17 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> Params:
         params["lm_head"] = scaled_normal(next(k), (d, v), d ** -0.5, pd)
     # further stacks draw from keys of their own, so the leaves above are
     # what they were before a configuration could have these
-    if moe and cfg.moe_dense_layers:
-        params["dense_layers"] = _init_stack(
-            iter(jax.random.split(jax.random.fold_in(rng, 1), 16)), cfg,
-            cfg.moe_dense_layers, False)
+    if dense:
+        params["dense_layers"] = stack(0, dense, False, iter(
+            jax.random.split(jax.random.fold_in(rng, 1), n_keys)), 48)
     if cfg.mtp_layers:
         km = iter(jax.random.split(jax.random.fold_in(rng, 2), 16))
         params["mtp"] = {
             "h_norm": jnp.ones((d,), pd), "e_norm": jnp.ones((d,), pd),
             # rows: the hidden state's half, then the embedding's
             "proj": scaled_normal(next(km), (2 * d, d), (2 * d) ** -0.5, pd),
-            "layers": _init_stack(km, cfg, cfg.mtp_layers, moe),
+            "layers": _init_stack(km, cfg, cfg.mtp_layers, moe,
+                                  cfg.mixer_kind(0)),
         }
     return params
 
@@ -233,6 +322,13 @@ def _select_attention(cfg: TransformerConfig, mesh: Optional[Mesh]):
 def _attention(q, k, v, cfg: TransformerConfig, mesh: Optional[Mesh],
                positions):
     impl = _select_attention(cfg, mesh)
+    narrow = q.shape[-1] - v.shape[-1]
+    if narrow and impl != "xla":
+        # the kernels take one width for q, k and v: a narrower value head
+        # is filled with zero columns, which come back as zero columns
+        v = jnp.pad(v, ((0, 0),) * 3 + ((0, narrow),))
+        return _attention(q, k, v, cfg, mesh,
+                          positions)[..., :-narrow]
     if impl == "ring":
         return ring_attention(q, k, v, mesh, causal=cfg.causal)
     if impl == "pallas":
@@ -257,22 +353,28 @@ def _attention(q, k, v, cfg: TransformerConfig, mesh: Optional[Mesh],
 
 def _latent_qkv(h, lp, cfg: TransformerConfig, positions):
     """Latent attention in its expanded (training) form: q through a
-    normed latent of `q_lora_rank`, keys and values through one of
-    `kv_lora_rank`; the last `rope_head_dim` of each query head and ONE
-    such vector a token for the keys, shared by all heads, carry the
-    rotary embedding, the rest of a head none. -> q, k [B, T, H, head_dim]
-    and v [B, T, H, v_head_dim]: ordinary multi-head attention from here
-    on. The weights are sliced, not the activations."""
+    normed latent of `q_lora_rank` (or, where that is 0, straight from
+    the hidden state), keys and values through one of `kv_lora_rank`; the
+    last `rope_head_dim` of each query head and ONE such vector a token
+    for the keys, shared by all heads, carry the rotary embedding (none
+    where `use_rope` is off: the columns stay as they are), the rest of a
+    head none. -> q, k [B, T, H, head_dim] and v [B, T, H, v_head_dim]:
+    ordinary multi-head attention from here on. The weights are sliced,
+    not the activations."""
     dt, eps = cfg.dtype, cfg.rms_eps
     rkv, rope = cfg.kv_lora_rank, cfg.rope_head_dim
     nope = cfg.head_dim - rope
-    wq_b, wkv_b = lp["wq_b"].astype(dt), lp["wkv_b"].astype(dt)
+    wkv_b = lp["wkv_b"].astype(dt)
     wkv_a = lp["wkv_a"].astype(dt)
     with jax.named_scope("mla.q"):
-        cq = jnp.einsum("btd,dr->btr", h, lp["wq_a"].astype(dt))
-        cq = rms_norm(cq, lp["q_a_norm"], eps)
-        q_nope = jnp.einsum("btr,rhk->bthk", cq, wq_b[..., :nope])
-        q_rope = jnp.einsum("btr,rhk->bthk", cq, wq_b[..., nope:])
+        if cfg.q_lora_rank:
+            wq, cq = lp["wq_b"].astype(dt), rms_norm(jnp.einsum(
+                "btd,dr->btr", h, lp["wq_a"].astype(dt)), lp["q_a_norm"],
+                eps)
+        else:
+            wq, cq = lp["wq"].astype(dt), h
+        q_nope = jnp.einsum("btr,rhk->bthk", cq, wq[..., :nope])
+        q_rope = jnp.einsum("btr,rhk->bthk", cq, wq[..., nope:])
     with jax.named_scope("mla.kv"):
         c = jnp.einsum("btd,dr->btr", h, wkv_a[:, :rkv])
         c = rms_norm(c, lp["kv_a_norm"], eps)
@@ -283,8 +385,9 @@ def _latent_qkv(h, lp, cfg: TransformerConfig, positions):
         # stored pairs (2i, 2i+1) -> the halves (i, i + n/2) `_rope` turns
         halves = lambda x: jnp.concatenate(  # noqa: E731
             [x[..., 0::2], x[..., 1::2]], axis=-1)
-        q_rope = _rope(halves(q_rope), positions, cfg.rope_theta)
-        k_rope = _rope(halves(k_rope), positions, cfg.rope_theta)
+        if cfg.use_rope:
+            q_rope = _rope(halves(q_rope), positions, cfg.rope_theta)
+            k_rope = _rope(halves(k_rope), positions, cfg.rope_theta)
         q = jnp.concatenate([q_nope, q_rope], axis=-1)
         k = jnp.concatenate(
             [k_nope, jnp.broadcast_to(k_rope, q_rope.shape)], axis=-1)
@@ -306,17 +409,87 @@ def qkv_proj(h, lp, cfg: TransformerConfig, positions):
                      cfg.rms_eps).reshape(q.shape)
         k = rms_norm(k.reshape(B, T, -1), lp["k_norm"],
                      cfg.rms_eps).reshape(k.shape)
+    if not cfg.use_rope:
+        return q, k, v
     return (_rope(q, positions, cfg.rope_theta),
             _rope(k, positions, cfg.rope_theta), v)
 
 
+# ---- gated delta-rule linear attention (KDA) -----------------------------------
+
+KDA_L2_EPS = 1e-6
+
+
+def _conv_silu(x, w):
+    """A causal depthwise convolution along the row, then SiLU. x [B, T,
+    H, D]; w [taps, H, D]: y_t = sum_i w[i] x_(t - taps + 1 + i), zeros
+    before the row's first token."""
+    taps, T = w.shape[0], x.shape[1]
+    # padded and sliced as it arrives; float32 from the products on
+    xp = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0), (0, 0)))
+    w = w.astype(jnp.float32)
+    y = sum(xp[:, i:i + T].astype(jnp.float32) * w[i] for i in range(taps))
+    return jax.nn.silu(y)
+
+
+def _l2norm(x):
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True)
+                             + KDA_L2_EPS)
+
+
+def kda_mixer(h, lp, cfg: TransformerConfig):
+    """Kimi delta attention on normed rows h [B, T, d] -> [B, T, d]: q, k,
+    v each through a short causal convolution and SiLU, q and k
+    l2-normalised a head; a per-channel decay exp(-exp(A_log) x softplus(
+    W_f2 W_f1 h + dt_bias)) and a write strength sigmoid(W_beta h) a head,
+    float32; the gated delta rule over the row (ops/kda.py); the heads'
+    RMSNorm times a sigmoid gate W_g2 W_g1 h; the output projection. Each
+    part under a `jax.named_scope` a profile groups by."""
+    from ray_tpu.ops import kda
+
+    dt, f32 = cfg.dtype, jnp.float32
+
+    def low_rank(a, b, out):
+        return jnp.einsum(
+            "btr,rhk->bthk", jnp.einsum("btd,dr->btr", h, lp[a].astype(dt)),
+            lp[b].astype(dt), preferred_element_type=out)
+
+    with jax.named_scope("kda.proj"):
+        q, k, v = (jnp.einsum("btd,dhk->bthk", h, lp[name].astype(dt))
+                   for name in ("kda_wq", "kda_wk", "kda_wv"))
+    with jax.named_scope("kda.conv"):
+        q = (_l2norm(_conv_silu(q, lp["kda_conv_q"]))
+             * cfg.kda_head_dim ** -0.5).astype(dt)
+        k = _l2norm(_conv_silu(k, lp["kda_conv_k"])).astype(dt)
+        v = _conv_silu(v, lp["kda_conv_v"]).astype(dt)
+    with jax.named_scope("kda.gate"):
+        g = -jnp.exp(lp["kda_A_log"].astype(f32))[:, None] * jax.nn.softplus(
+            low_rank("kda_f_a", "kda_f_b", f32)
+            + lp["kda_dt_bias"].astype(f32))
+        beta = jax.nn.sigmoid(jnp.einsum(
+            "btd,dh->bth", h, lp["kda_beta"].astype(dt),
+            preferred_element_type=f32))
+        gate = low_rank("kda_g_a", "kda_g_b", dt)
+    o = kda.kda_scan(q, k, v, g, beta)
+    with jax.named_scope("kda.out"):
+        o = rms_norm(o, lp["kda_o_norm"], cfg.rms_eps) \
+            * jax.nn.sigmoid(gate.astype(f32)).astype(dt)
+        return jnp.einsum("bthk,hkd->btd", o, lp["wo"].astype(dt))
+
+
 def refuse_unserved(cfg: TransformerConfig):
     """The KV-cache paths (models/generate, models/engine) hold one k and
-    one v row of `head_dim` a token and layer, scan ONE stack of layers
-    and emit one token a step: raise for a configuration that needs a
-    latent cache and the absorbed decode form, a second stack, or a
-    step of more than one token."""
+    one v row of `head_dim` a token and layer, scan ONE stack of layers of
+    one kind and emit one token a step: raise for a configuration that
+    needs a latent cache and the absorbed decode form, a second stack, a
+    stack of several kinds, a recurrent state, or a step of more than one
+    token."""
     cannot = [what for has, what in (
+        (len(cfg.mixer_period) > 1, "a period of mixer kinds (mixer_period: "
+         "the engine scans one stack of one kind; ROADMAP M1)"),
+        ("kda" in cfg.mixer_period, "gated delta-rule linear attention "
+         "(kda: a recurrent state and a convolution's tail in the slot "
+         "cache; ROADMAP M6)"),
         (cfg.kv_lora_rank, "latent attention (kv_lora_rank: a latent slot "
          "cache and the absorbed decode form)"),
         (cfg.moe_experts and cfg.moe_dense_layers,
@@ -374,10 +547,8 @@ def read_in_float32(cfg: TransformerConfig) -> tuple:
 
 # ---- forward ---------------------------------------------------------------
 
-def _block(x, lp, cfg: TransformerConfig, mesh: Optional[Mesh], positions):
-    """One decoder layer, of whichever kind ``lp`` holds: x [B, T, d] ->
-    (x, the FFN's stats)."""
-    h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
+def _attention_mixer(h, lp, cfg: TransformerConfig, mesh: Optional[Mesh],
+                     positions):
     q, k, v = qkv_proj(h, lp, cfg, positions)
     reps = cfg.n_heads // cfg.kv_heads
     if reps > 1:  # GQA: expand kv heads to match q heads
@@ -387,7 +558,18 @@ def _block(x, lp, cfg: TransformerConfig, mesh: Optional[Mesh], positions):
     o = _attention(q, k, v, cfg, mesh, positions)
     with jax.named_scope("mla.out") if cfg.kv_lora_rank \
             else contextlib.nullcontext():
-        o = jnp.einsum("bthk,hkd->btd", o, lp["wo"].astype(cfg.dtype))
+        return jnp.einsum("bthk,hkd->btd", o, lp["wo"].astype(cfg.dtype))
+
+
+def _block(x, lp, cfg: TransformerConfig, mesh: Optional[Mesh], positions):
+    """One decoder layer, of whichever kinds ``lp`` holds (a KDA mixer
+    where it holds one's leaves, else attention; experts where it holds a
+    router): x [B, T, d] -> (x, the FFN's stats)."""
+    h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
+    if "kda_wq" in lp:
+        o = kda_mixer(h, lp, cfg)
+    else:
+        o = _attention_mixer(h, lp, cfg, mesh, positions)
     x = x + _wlc(o, ("batch", "seq", "embed"), mesh=mesh)
 
     h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
@@ -426,14 +608,33 @@ def _trunk(params: Params, tokens: jax.Array, cfg: TransformerConfig,
         # (parallel/pipeline.py).
         from ray_tpu.parallel.pipeline import pipeline_scan
 
-        assert "dense_layers" not in params, "one stack under a pipeline"
+        assert "dense_layers" not in params and isinstance(
+            params["layers"], dict), "one stack of one kind under a pipeline"
         x = pipeline_scan(body, x, params["layers"], mesh,
                           cfg.pipeline_microbatches)
         return x, jax.tree.map(lambda z: z[None], _no_moe_stats())
     if "dense_layers" in params:   # an MoE model's leading dense layers
-        x, _ = jax.lax.scan(lambda c, lp: body(c, lp), x,
-                            params["dense_layers"])
-    return jax.lax.scan(lambda c, lp: body(c, lp), x, params["layers"])
+        x, _ = _scan_stack(body, x, params["dense_layers"])
+    return _scan_stack(body, x, params["layers"])
+
+
+def _scan_stack(body, x, stack):
+    """``body`` over a stack of layers -> (x, the layers' stats, one
+    entry a layer). One kind: a scan over the layers. Several (a tuple,
+    one entry a position in the period): a scan over whole periods, whose
+    body is the period's blocks one after another, each checkpointed as
+    ``body`` is."""
+    if isinstance(stack, dict):
+        return jax.lax.scan(lambda c, lp: body(c, lp), x, stack)
+
+    def period(c, lps):
+        stats = []
+        for lp in lps:
+            c, st = body(c, lp)
+            stats.append(st)
+        return c, jax.tree.map(lambda *a: jnp.stack(a), *stats)
+    x, stats = jax.lax.scan(period, x, stack)
+    return x, jax.tree.map(lambda a: a.reshape(-1), stats)
 
 
 def _model_stats(per_layer):

@@ -5,5 +5,6 @@ virtual device mesh (SURVEY.md §4 strategy).
 """
 
 from ray_tpu.ops.flash_attention import flash_attention
+from ray_tpu.ops.kda import kda_scan
 
-__all__ = ["flash_attention"]
+__all__ = ["flash_attention", "kda_scan"]

@@ -443,3 +443,95 @@ def test_glm_train_step_fits_one_chip_at_the_depth_its_file_states(
         for d in m.group(1).split(","):
             n *= int(d)
         assert n <= largest, m.group(0)
+
+
+def test_flash_compiles_at_the_longest_row_a_192_wide_head_holds(one_chip,
+                                                                 mosaic):
+    """Latent attention without positions as the Kimi-Linear cell calls
+    the kernel: 2 rows x 16,384 x 32 heads, query and key 192 wide (256
+    lanes) and the 128-wide value zero-padded to 192 outside the kernel:
+    the longest whole row the kernel's resident side holds on a v5e."""
+    from ray_tpu.ops import flash_attention
+
+    x = jax.ShapeDtypeStruct((2, 16384, 32, 192), jnp.bfloat16,
+                             sharding=one_chip)
+    bwd = jax.jit(jax.grad(functools.partial(_flash_loss, scale=192 ** -0.5),
+                           argnums=(0, 1, 2))).lower(x, x, x).compile()
+    assert bwd.as_text().count("tpu_custom_call") >= 2
+
+
+def test_kda_scan_compiles_forward_and_backward_at_the_cells_shape(one_chip):
+    """The chunked gated delta rule over 2 rows x 16,384 x 32 heads of 128
+    (bf16 q, k, v; float32 decays and beta), forward and backward, for one
+    chip: a walk over chunks each way (a `while`), no triangular-solve
+    call, every operation under the scope `kda.scan`, and what the walk
+    holds stays small beside the chip."""
+    from ray_tpu.ops.kda import kda_scan
+
+    B, T, H, d = 2, 16384, 32, 128
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    args = (s((B, T, H, d), jnp.bfloat16),) * 3 + (
+        s((B, T, H, d), jnp.float32), s((B, T, H), jnp.float32))
+    compiled = jax.jit(jax.grad(
+        lambda *a: kda_scan(*a).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2, 3, 4))).lower(*args).compile()
+    text = compiled.as_text()
+    assert "kda.scan" in text and " while(" in text
+    assert "triangular" not in text.lower()
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 1024 ** 3
+
+
+def test_kimi_linear_train_step_fits_one_chip_at_the_depth_its_file_states(
+        one_chip, mosaic):
+    """The cell `kimi-linear-48b-a3b.train-16k-2rows` as the benchmark
+    runs it (its config file's depth, 2 x 16,384, bf16 weights and
+    moments): the step compiles for one chip (a compile that returns
+    fits), holds the attention kernel, the grouped `ragged-dot` kernels and
+    the scan under its scopes, its expert weights are the 8 HELD experts'
+    and its router is 256 wide, the expert stack is one stack a position
+    of the period, and no buffer is larger than the float32 logits over
+    the vocabulary slice. (One period deeper the compiler refuses it:
+    `benchmark/rehearse.py --layers`, quoted in the config file; that
+    compile takes minutes and is no test.)"""
+    from benchmark.harness import spec
+
+    bench = spec.load_benchmark()
+    conf = spec.load_config(bench, "kimi-linear-48b-a3b")
+    traffic = spec.load_traffic("train-16k-2rows")
+    rows, seq = traffic["rows"], traffic["seq_len"]
+    cfg = spec.build_transformer_config(
+        conf, max_seq_len=seq, param_dtype=traffic["param_dtype"],
+        attention_impl="pallas")
+    periods = (cfg.n_layers - 1) // 4
+    assert cfg.n_layers == conf["num_hidden_layers"] == 1 + 4 * periods >= 5
+    assert (cfg.moe_experts, cfg.held_experts, cfg.moe_top_k) == (256, 8, 8)
+    assert (cfg.head_dim, cfg.v_head_dim, cfg.rope_head_dim) == (192, 128,
+                                                                 64)
+    tx = make_optimizer(traffic["learning_rate"],
+                        mu_dtype=jnp.dtype(traffic["mu_dtype"]))
+    shapes = jax.eval_shape(make_init_fn(cfg, tx), jax.random.key(0))
+    lay = shapes["params"]["layers"]
+    assert ["kda_wq" in x for x in lay] == [True, True, False, True]
+    assert lay[0]["w_gate"].shape == (periods, 8, 2304, 1024)
+    assert lay[2]["router"].shape == (periods, 2304, 256)
+    assert lay[2]["wq"].shape == (periods, 2304, 32, 192)
+    assert lay[0]["kda_f_b"].shape == (periods, 128, 32, 128)
+    dense = shapes["params"]["dense_layers"]
+    assert dense["w_gate"].shape == (1, 2304, 9216) and "kda_wq" in dense
+    assert shapes["params"]["embed"].shape == (20480, 2304)
+    batch = {"tokens": jax.ShapeDtypeStruct((rows, seq + 1), jnp.int32,
+                                            sharding=one_chip)}
+    text = make_train_step(cfg, tx).lower(
+        _on(shapes, one_chip), batch).compile().as_text()
+    assert "ragged-dot" in text and "tpu_custom_call" in text
+    for scope in ("kda.proj", "kda.conv", "kda.gate", "kda.scan", "kda.out",
+                  "mla.q", "mla.kv", "mla.out", "moe.shared"):
+        assert scope in text, scope
+    largest = rows * seq * cfg.vocab_size         # the float32 logits
+    for m in re.finditer(r"\b\w+\[([\d,]+)\]", text):
+        n = 1
+        for d in m.group(1).split(","):
+            n *= int(d)
+        assert n <= largest, m.group(0)
