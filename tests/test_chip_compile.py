@@ -64,8 +64,9 @@ def mosaic(monkeypatch):
 
     fa = importlib.import_module("ray_tpu.ops.flash_attention")
     da = importlib.import_module("ray_tpu.ops.decode_attention")
-    monkeypatch.setattr(fa, "_use_interpret", lambda: False)
-    monkeypatch.setattr(da, "_use_interpret", lambda: False)
+    kda = importlib.import_module("ray_tpu.ops.kda")
+    for module in (fa, da, kda):
+        monkeypatch.setattr(module, "_use_interpret", lambda: False)
     monkeypatch.setattr(engine, "_on_chip", lambda: True)
     engine.decode_slots.clear_cache()
     yield
@@ -460,12 +461,27 @@ def test_flash_compiles_at_the_longest_row_a_192_wide_head_holds(one_chip,
     assert bwd.as_text().count("tpu_custom_call") >= 2
 
 
-def test_kda_scan_compiles_forward_and_backward_at_the_cells_shape(one_chip):
+def _kernels(text):
+    """[(xplane.op_key, op_name)] of the Mosaic kernels in a compiled
+    program's text: what a trace names each by, and the scopes it was
+    traced under."""
+    from benchmark.harness import xplane
+
+    scopes = xplane.op_scopes(text)
+    return [(key, scopes[key]) for key in scopes
+            if key.startswith(f"{xplane.KERNEL_TAG}:")]
+
+
+def test_kda_scan_compiles_forward_and_backward_at_the_cells_shape(one_chip,
+                                                                   mosaic):
     """The chunked gated delta rule over 2 rows x 16,384 x 32 heads of 128
     (bf16 q, k, v; float32 decays and beta), forward and backward, for one
-    chip: a walk over chunks each way (a `while`), no triangular-solve
-    call, every operation under the scope `kda.scan`, and what the walk
-    holds stays small beside the chip."""
+    chip: two Mosaic kernels whose names begin `kda` under the scope
+    `kda.scan` (what `kda_scan_roofline` and the attention metrics'
+    pattern find them by), no loop of XLA's, no triangular-solve call, no
+    transpose of an operand (the head is a column block) and no more than
+    one relayout of each of q, k, v, g and their gradients, and what is
+    held between the two stays small beside the chip."""
     from ray_tpu.ops.kda import kda_scan
 
     B, T, H, d = 2, 16384, 32, 128
@@ -478,8 +494,28 @@ def test_kda_scan_compiles_forward_and_backward_at_the_cells_shape(one_chip):
         lambda *a: kda_scan(*a).astype(jnp.float32).sum(),
         argnums=(0, 1, 2, 3, 4))).lower(*args).compile()
     text = compiled.as_text()
-    assert "kda.scan" in text and " while(" in text
-    assert "triangular" not in text.lower()
+    kernels = _kernels(text)
+    assert len(kernels) >= 2
+    for key, scope in kernels:
+        assert key.startswith("tpu_custom_call:kda") and "kda.scan" in scope
+    assert " while(" not in text and "triangular" not in text.lower()
+    # nothing of an operand's size is transposed (beta [B, T, H] alone may
+    # be). What XLA does move at that size, around the kernels: `[B, T, H,
+    # d]` and `[B, T, H x d]` are other bytes in the chip's tiled layouts,
+    # so q, k, v, g are laid out anew on the way in (`reshape`) and dq, dk,
+    # dv, dg on the way out (`copy`), one operand each and no more
+    operand = B * T * H * d
+    moved = []
+    for m in re.finditer(r"= \w+\[([\d,]+)\]\S* ([\w-]+)\(", text):
+        n = 1
+        for size in m.group(1).split(","):
+            n *= int(size)
+        if n >= operand and m.group(2) not in (
+                "parameter", "bitcast", "get-tuple-element", "custom-call",
+                "broadcast"):       # the last: this loss's constant `do`
+            moved.append((m.group(2), n))
+    assert len(moved) <= 8, moved
+    assert all(op in ("copy", "reshape") and n == operand for op, n in moved)
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * 1024 ** 3
 
 
@@ -529,6 +565,15 @@ def test_kimi_linear_train_step_fits_one_chip_at_the_depth_its_file_states(
     for scope in ("kda.proj", "kda.conv", "kda.gate", "kda.scan", "kda.out",
                   "mla.q", "mla.kv", "mla.out", "moe.shared"):
         assert scope in text, scope
+    # the scan's kernels and no others are named `kda*` and lie under
+    # `kda.scan`: `nope_mla_attention_step_share` counts every Mosaic
+    # kernel whose name does not begin `ragged-dot` or `kda`
+    kernels = _kernels(text)
+    named = {key for key, _ in kernels
+             if key.startswith("tpu_custom_call:kda")}
+    scoped = {key for key, scope in kernels if "kda.scan" in scope}
+    assert named == scoped and len(named) >= 3      # forward, remat, backward
+    assert len(kernels) > len(named)                # the attention kernels
     largest = rows * seq * cfg.vocab_size         # the float32 logits
     for m in re.finditer(r"\b\w+\[([\d,]+)\]", text):
         n = 1
