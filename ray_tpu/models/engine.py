@@ -53,7 +53,9 @@ the reference delegates to external vLLM workers for, built TPU-first:
     ``jax.profiler.TraceAnnotation`` spans on the device trace's clock
     whenever someone traces the process), a state that lasts over a
     second while work waits is a ``slow_events`` entry, and requests are
-    stamped at submit, admit, first token and finish.
+    stamped at submit, admit, first token and finish. A streamed request
+    keeps a ledger of its own from there to the pulls that take its
+    tokens (`InferenceEngine._stream`).
 """
 
 from __future__ import annotations
@@ -330,11 +332,39 @@ class _Request:
     bucket: int = 0        # P of the prefill program that admitted it
     group: int = 0         # K of that program
     chunks_ahead: int = 0  # undelivered decode chunks at its admission
+    # ``time.time()`` where the caller made the request (a serve handle's
+    # ``remote()``, in another process); None: the engine driven directly
+    t_sent: Optional[float] = None
+    # the stream's ledger (`InferenceEngine._stream`), written by the one
+    # thread that pulls the stream and read once it has ended (``closed``,
+    # set last): seconds blocked on ``stream_q`` and seconds suspended
+    # between pulls, the tokens' seconds in ``stream_q``, resumptions,
+    # those that found their token queued, tokens handed out
+    t_first_pickup: float = 0.0
+    stream_open_s: float = 0.0  # submit to the generator's end
+    stream_wait_s: float = 0.0
+    stream_held_s: float = 0.0
+    pickup_lag_s: float = 0.0
+    pulls: int = 0
+    ready_pulls: int = 0
+    stream_tokens: int = 0
+    closed: Optional[str] = None  # "done" | "abandoned" | "error"
+    log_entry: Optional[dict] = None  # its ``request_log`` entry, once done
 
-    def emit(self, tok: int):
+    def emit(self, tok: int, t_delivered: float):
         self.tokens.append(tok)
         if self.stream_q is not None:
-            self.stream_q.put(tok)
+            self.stream_q.put((tok, t_delivered))
+
+    def stream_record(self) -> dict:
+        """What an ended stream adds to its ``request_log`` entry."""
+        return {"t_first_pickup": self.t_first_pickup,
+                "stream_open_s": self.stream_open_s,
+                "stream_wait_s": self.stream_wait_s,
+                "stream_held_s": self.stream_held_s,
+                "pickup_lag_s": self.pickup_lag_s, "pulls": self.pulls,
+                "ready_pulls": self.ready_pulls,
+                "stream_tokens": self.stream_tokens, "closed": self.closed}
 
     def finish(self, reason: str):
         self.t_done = time.perf_counter()
@@ -448,7 +478,9 @@ class InferenceEngine:
         self._death_lock = threading.Lock()
         # running counters, always on. Plain numbers under dot-free keys,
         # every key here from the start (readers difference all of them);
-        # each is written by one thread, slow_* by whichever was slow.
+        # each is written by one thread, slow_* by whichever was slow, the
+        # stream_* / *pickup* / entr* keys by request threads under
+        # `_fold_lock`.
         # The *_s keys are the thread-time ledger `_timed` fills: for the
         # scheduler, sched_wall_s = sched_lock_wait_s + admit_wall_s
         # (which holds prefill_dispatch_wall_s) + dispatch_wall_s +
@@ -474,7 +506,19 @@ class InferenceEngine:
             # attention fetches, and those an active slot owns
             "decode_kv_rows_cache": 0, "decode_kv_rows_read": 0,
             "decode_kv_rows_valid": 0,
-            "slow_s": 0.0, "slow_count": 0}
+            "slow_s": 0.0, "slow_count": 0,
+            # the ended streams' ledgers (`_fold_stream`): stream_open_s =
+            # stream_wait_s + stream_held_s; pickup lag over stream_tokens
+            "stream_open_s": 0.0, "stream_wait_s": 0.0, "stream_held_s": 0.0,
+            "stream_pulls": 0, "stream_ready_pulls": 0, "stream_tokens": 0,
+            "stream_pickup_lag_s": 0.0, "first_pickup_s": 0.0,
+            "first_pickups": 0, "streams_closed": 0, "streams_abandoned": 0,
+            # caller's stamp to `_make_request`, of the requests that carry one
+            "entry_leg_s": 0.0, "entries": 0}
+        # up to `max_concurrency` request threads end streams and make
+        # requests at once and `stats[k] += x` is not atomic. NOT `_lock`:
+        # the fetcher races the scheduler for that one (ROADMAP D5)
+        self._fold_lock = threading.Lock()
         self.slow_events: collections.deque = collections.deque(maxlen=64)
         self.request_log: collections.deque = collections.deque(maxlen=1024)
         self._episodes: Dict[str, dict] = {}  # thread -> its open episode
@@ -483,9 +527,12 @@ class InferenceEngine:
     # -------------------------------------------------------- submission
 
     def submit(self, prompt: Sequence[int],
-               max_new_tokens: Optional[int] = None) -> _Request:
-        """Enqueue a prompt; returns the request (``result()`` to wait)."""
-        req = self._make_request(prompt, max_new_tokens, stream=False)
+               max_new_tokens: Optional[int] = None, *,
+               t_sent: Optional[float] = None) -> _Request:
+        """Enqueue a prompt; returns the request (``result()`` to wait).
+        ``t_sent``: ``time.time()`` where the caller made the request."""
+        req = self._make_request(prompt, max_new_tokens, stream=False,
+                                 t_sent=t_sent)
         with self._death_lock:
             self._check_alive()
             self._queue.put(req)
@@ -493,26 +540,94 @@ class InferenceEngine:
         return req
 
     def submit_stream(self, prompt: Sequence[int],
-                      max_new_tokens: Optional[int] = None):
+                      max_new_tokens: Optional[int] = None, *,
+                      t_sent: Optional[float] = None):
         """Enqueue a prompt; returns an iterator of token ids that ends
         when the sequence finishes (eos or length)."""
-        req = self._make_request(prompt, max_new_tokens, stream=True)
+        req = self._make_request(prompt, max_new_tokens, stream=True,
+                                 t_sent=t_sent)
         with self._death_lock:
             self._check_alive()
             self._queue.put(req)
         self._work.set()
+        stream = self._stream(req)
+        next(stream)  # to its first yield: from here on close() folds it
+        return stream
 
-        def gen():
+    def _stream(self, req: _Request):
+        """The generator behind `submit_stream`, and the stream's time
+        ledger: from submit to its end every second goes to ``wait``
+        (blocked on ``stream_q``: the engine has not delivered, the chip
+        paces the stream; a `serve.stream_wait` span, whose prefix keeps it
+        off the `engine.*` spans that are read as owners of device idle
+        time) or to ``held`` (suspended at ``yield``: until the first
+        pull, and from handing a token over until the next pull asks, so
+        whoever pulls paces it). A token carries the time of the delivery
+        that brought it; its pickup adds what it waited in ``stream_q``.
+        All of it accumulates on the request, which this thread alone
+        writes, and folds into ``stats`` once, however the stream ends."""
+        clock, mark, closed = time.perf_counter, req.t_submit, "abandoned"
+        try:
+            yield  # `submit_stream` stops here
             while True:
-                tok = req.stream_q.get()
-                if tok is None:
+                now = clock()  # a pull asks: ``held`` since `mark` ends
+                req.stream_held_s += now - mark
+                req.pulls += 1
+                try:
+                    item, ready, mark = req.stream_q.get_nowait(), True, now
+                except queue.Empty:
+                    ready = False
+                    with jax.profiler.TraceAnnotation("serve.stream_wait",
+                                                      rid=req.rid):
+                        item = req.stream_q.get()
+                    mark = clock()
+                    req.stream_wait_s += mark - now
+                if item is None:  # the engine's sentinel
                     if req.error is not None:
+                        closed = "error"
                         raise req.error
+                    closed = "done"
                     return
+                tok, t_delivered = item
+                if not req.stream_tokens:
+                    req.t_first_pickup = mark
+                req.stream_tokens += 1
+                req.ready_pulls += ready
+                req.pickup_lag_s += mark - t_delivered
                 yield tok
-        return gen()
+        except GeneratorExit:  # closed between two pulls
+            now = clock()
+            req.stream_held_s += now - mark
+            mark = now
+            raise
+        finally:
+            self._fold_stream(req, closed, mark)
 
-    def _make_request(self, prompt, max_new_tokens, stream: bool):
+    def _fold_stream(self, req: _Request, closed: str, t_closed: float):
+        """An ended stream's ledger into ``stats`` and into the request's
+        ``request_log`` entry. The engine may finish the request (and make
+        the entry) before or after: each side writes its own part first
+        and then reads the other's, so the later of the two fills it."""
+        req.stream_open_s, req.closed = t_closed - req.t_submit, closed
+        with self._fold_lock:
+            st = self.stats
+            st["stream_open_s"] += req.stream_open_s
+            st["stream_wait_s"] += req.stream_wait_s
+            st["stream_held_s"] += req.stream_held_s
+            st["stream_pulls"] += req.pulls
+            st["stream_ready_pulls"] += req.ready_pulls
+            st["stream_tokens"] += req.stream_tokens
+            st["stream_pickup_lag_s"] += req.pickup_lag_s
+            if req.stream_tokens:
+                st["first_pickup_s"] += req.t_first_pickup - req.t_first
+                st["first_pickups"] += 1
+            st["streams_closed"] += 1
+            st["streams_abandoned"] += closed == "abandoned"
+        if req.log_entry is not None:
+            req.log_entry.update(req.stream_record())
+
+    def _make_request(self, prompt, max_new_tokens, stream: bool,
+                      t_sent: Optional[float]):
         prompt = list(prompt)
         if not prompt:
             raise ValueError("empty prompt")
@@ -524,10 +639,15 @@ class InferenceEngine:
             else min(int(max_new_tokens), self.max_new_tokens)
         if mnt <= 0:
             raise ValueError("max_new_tokens must be >= 1")
+        if t_sent is not None:
+            # wall clock against wall clock: the caller is another process
+            with self._fold_lock:
+                self.stats["entry_leg_s"] += time.time() - t_sent
+                self.stats["entries"] += 1
         return _Request(rid=next(self._rid), prompt=prompt,
                         max_new_tokens=mnt,
                         stream_q=queue.Queue() if stream else None,
-                        t_submit=time.perf_counter())
+                        t_submit=time.perf_counter(), t_sent=t_sent)
 
     # ------------------------------------------------------ observability
 
@@ -727,15 +847,17 @@ class InferenceEngine:
         self._next_tok_dev = jnp.zeros(self.slots, jnp.int32)
         return self
 
-    def _emit_to(self, req: _Request, slot: int, tok: int):
-        """Record one generated token; on an eos finish, reclaim the
-        slot's remaining planned occupancy (the plan is length-based and
-        eos can only shorten it)."""
+    def _emit_to(self, req: _Request, slot: int, tok: int,
+                 t_delivered: float):
+        """Record one generated token of the delivery that began at
+        ``t_delivered``; on an eos finish, reclaim the slot's remaining
+        planned occupancy (the plan is length-based and eos can only
+        shorten it)."""
         if not req.tokens:  # the request's first token
             req.t_first = time.perf_counter()
             self.stats["first_token_s"] += req.t_first - req.t_submit
             self.stats["first_tokens"] += 1
-        req.emit(tok)
+        req.emit(tok, t_delivered)
         self.stats["tokens_out"] += 1
         reason = None
         if tok == self.eos_id:
@@ -748,13 +870,16 @@ class InferenceEngine:
                 self._slot_left[slot] = 0
             self.stats["requests_done"] += 1
             req.finish(reason)
-            self.request_log.append({
+            req.log_entry = {
                 "rid": req.rid, "prompt_len": len(req.prompt),
                 "bucket": req.bucket, "group": req.group,
-                "chunks_ahead": req.chunks_ahead,
+                "chunks_ahead": req.chunks_ahead, "t_sent": req.t_sent,
                 "t_submit": req.t_submit, "t_admit": req.t_admit,
                 "t_first": req.t_first, "t_done": req.t_done,
-                "tokens_out": len(req.tokens)})
+                "tokens_out": len(req.tokens)}
+            if req.closed is not None:  # abandoned before this: _fold_stream
+                req.log_entry.update(req.stream_record())
+            self.request_log.append(req.log_entry)
 
     def step(self) -> bool:
         """One engine iteration; returns True if any work was done."""
@@ -876,13 +1001,14 @@ class InferenceEngine:
         W = self.decode_chunk + 1
         with self._timed("deliver_wall_s", "engine.deliver",
                          chunks=len(pending)):
+            now = time.perf_counter()  # every token's time of delivery
             for i, (_toks_dev, snap) in enumerate(pending):
                 seg = big[:, i * W:(i + 1) * W]
                 for slot, req, from_col, take in snap:
                     if req.done.is_set():
                         continue  # finished in an earlier chunk
                     for t in range(from_col, from_col + take):
-                        self._emit_to(req, slot, int(seg[slot, t]))
+                        self._emit_to(req, slot, int(seg[slot, t]), now)
                         if req.done.is_set():
                             break  # rest of the row is frozen eos/junk
         self.stats["chunks_delivered"] += len(pending)
@@ -1018,10 +1144,11 @@ class InferenceEngine:
 
     def generate(self, prompt: Sequence[int],
                  max_new_tokens: Optional[int] = None,
-                 timeout: float = 300.0) -> List[int]:
+                 timeout: float = 300.0, *,
+                 t_sent: Optional[float] = None) -> List[int]:
         """Blocking single-prompt helper (drives steps inline if no
         background thread is running)."""
-        req = self.submit(prompt, max_new_tokens)
+        req = self.submit(prompt, max_new_tokens, t_sent=t_sent)
         if self._thread is None:
             while not req.done.is_set():
                 if not self.step():

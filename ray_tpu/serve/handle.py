@@ -10,6 +10,7 @@ through the caller.
 
 from __future__ import annotations
 
+import time
 from typing import Any, Optional
 
 import ray_tpu
@@ -136,10 +137,15 @@ class DeploymentHandle:
             self.multiplexed_model_id if multiplexed_model_id is None
             else multiplexed_model_id)
 
-    def _meta(self) -> Optional[dict]:
+    def _meta(self) -> dict:
+        """What rides with a request into the replica's request context:
+        when it was made, on this process's wall clock (an LLM replica's
+        engine counts the way to itself from it), and the model asked
+        for."""
+        meta = {"t_sent": time.time()}
         if self.multiplexed_model_id:
-            return {"multiplexed_model_id": self.multiplexed_model_id}
-        return None
+            meta["multiplexed_model_id"] = self.multiplexed_model_id
+        return meta
 
     def remote(self, *args, **kwargs):
         from .router import get_router

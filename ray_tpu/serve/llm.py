@@ -16,6 +16,7 @@ from typing import List, Optional, Sequence
 import numpy as np
 
 from ray_tpu.serve.deployment import deployment
+from ray_tpu.serve.multiplex import _request_sent_time
 
 
 def _replica_params(cfg, checkpoint_dir: Optional[str], seed: int):
@@ -109,12 +110,16 @@ class _ContinuousLLMReplica:
 
     def __call__(self, prompt: Sequence[int],
                  max_new_tokens: Optional[int] = None) -> dict:
-        toks = self.engine.generate(prompt, max_new_tokens)
+        toks = self.engine.generate(prompt, max_new_tokens,
+                                    t_sent=_request_sent_time())
         return {"token_ids": toks}
 
     def stream(self, prompt: Sequence[int],
                max_new_tokens: Optional[int] = None):
-        for tok in self.engine.submit_stream(prompt, max_new_tokens):
+        # a generator: this body, and with it the engine's submit, runs in
+        # the stream's first pull
+        for tok in self.engine.submit_stream(prompt, max_new_tokens,
+                                             t_sent=_request_sent_time()):
             yield {"token_id": tok}
 
     def engine_stats(self) -> dict:
@@ -126,13 +131,21 @@ class _ContinuousLLMReplica:
         return [dict(ev) for ev in self.engine.slow_events]
 
     def engine_requests(self, last: int = 100) -> List[dict]:
-        """Stamps of the ``last`` finished requests (of at most 1024)."""
+        """Stamps of the ``last`` finished requests (of at most 1024):
+        ``t_sent`` is the caller's wall clock at ``handle.remote()``, the
+        ``t_*`` after it this process's ``perf_counter``. A streamed
+        request's entry gains ``t_first_pickup``, ``stream_open_s`` =
+        ``stream_wait_s`` + ``stream_held_s``, ``pickup_lag_s``, ``pulls``,
+        ``ready_pulls``, ``stream_tokens`` and ``closed`` ("done",
+        "abandoned" or "error") when its stream ends: without them it is
+        a stream still open (or an answer that was not streamed)."""
         return list(self.engine.request_log)[-int(last):]
 
     def trace(self, seconds: float, log_dir: str) -> str:
         """Profile this replica for ``seconds`` (only the process that
-        holds the chip can trace it): device operations and the engine's
-        ``engine.*`` spans on one clock. -> ``log_dir``, which holds
+        holds the chip can trace it): device operations, the engine's
+        ``engine.*`` spans and the streams' ``serve.stream_wait`` on one
+        clock. -> ``log_dir``, which holds
         ``plugins/profile/<time>/*.xplane.pb``."""
         import time
 

@@ -26,8 +26,19 @@ def get_multiplexed_model_id() -> str:
     return getattr(_request_ctx, "model_id", "")
 
 
-def _set_request_model_id(model_id: str):
-    _request_ctx.model_id = model_id
+def _request_sent_time() -> Optional[float]:
+    """Inside a replica: ``time.time()`` in the caller's process when
+    ``handle.remote()`` made the CURRENT request; None for a request that
+    came another way (a proxy)."""
+    return getattr(_request_ctx, "t_sent", None)
+
+
+def _set_request_meta(meta: Optional[dict]):
+    """The request context of this thread, from a request's ``meta`` (None
+    after the request: a pooled thread must not keep the last one's)."""
+    meta = meta or {}
+    _request_ctx.model_id = meta.get("multiplexed_model_id", "")
+    _request_ctx.t_sent = meta.get("t_sent")
 
 
 class _ModelMultiplexWrapper:

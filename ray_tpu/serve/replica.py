@@ -146,7 +146,7 @@ class ServeReplica:
         worker runtime before dispatch — arena-backed zero-copy read,
         dispatch-time prefetch overlap, and the fetch shows up as the
         task's ``arg_fetch`` phase instead of hiding inside exec."""
-        from .multiplex import _set_request_model_id
+        from .multiplex import _set_request_meta
 
         # count the request BEFORE resolving by-ref payloads: fetching a
         # large kwarg over a slow link can take hundreds of ms, and a
@@ -156,12 +156,11 @@ class ServeReplica:
             self._ongoing += 1
         try:
             args, kwargs = _resolve_request_refs(args, kwargs or {})
-            _set_request_model_id(
-                (meta or {}).get("multiplexed_model_id", ""))
+            _set_request_meta(meta)
             try:
                 return self._resolve_fn(method_name)(*args, **kwargs)
             finally:
-                _set_request_model_id("")
+                _set_request_meta(None)
         finally:
             with self._lock:
                 self._ongoing -= 1
@@ -178,7 +177,7 @@ class ServeReplica:
         Positional args ride as real task args (see handle_request)."""
         from ray_tpu.core.ids import _random_bytes
 
-        from .multiplex import _set_request_model_id
+        from .multiplex import _set_request_meta
 
         # count BEFORE resolving by-ref payloads, same invariant as
         # handle_request: a replica saturated fetching large request
@@ -187,12 +186,11 @@ class ServeReplica:
             self._ongoing += 1
         try:
             args, kwargs = _resolve_request_refs(args, kwargs or {})
-            _set_request_model_id(
-                (meta or {}).get("multiplexed_model_id", ""))
+            _set_request_meta(meta)
             try:
                 result = self._resolve_fn(method_name)(*args, **kwargs)
             finally:
-                _set_request_model_id("")
+                _set_request_meta(None)
             it = iter(result)
             sid = _random_bytes(8).hex()  # pooled entropy: per-request
             with self._lock:
@@ -224,7 +222,7 @@ class ServeReplica:
         it — a larger batch would delay time-to-first-token by the whole
         batch and time out slow producers. Callers wanting fewer RPCs on
         fast streams can raise max_items."""
-        from .multiplex import _set_request_model_id
+        from .multiplex import _set_request_meta
 
         with self._lock:
             entry = self._streams.get(sid)
@@ -235,14 +233,14 @@ class ServeReplica:
         done = False
         # generator frames execute during next() — the request context
         # must be live HERE, not just in start_stream
-        _set_request_model_id(meta.get("multiplexed_model_id", ""))
+        _set_request_meta(meta)
         try:
             for _ in range(max_items):
                 items.append(next(it))
         except StopIteration:
             done = True
         finally:
-            _set_request_model_id("")
+            _set_request_meta(None)
         if done:
             with self._lock:
                 # guard against a concurrent cancel_stream having already

@@ -1,11 +1,14 @@
 """The engine's own observability (toy model, CPU): the thread-time
 ledger is complete, requests are stamped, the heartbeat tells a stall from
 an idle engine, the spans are on the profiler's clock, and `engine.stats`
-stays a flat dict of numbers (its readers difference every key)."""
+stays a flat dict of numbers (its readers difference every key). Since
+PR 40 a streamed request's ledger goes on from delivery to the pulls that
+take its tokens: every second of an open stream is `wait` or `held`."""
 
 import collections
 import glob
 import logging
+import sys
 import threading
 import time
 
@@ -45,6 +48,14 @@ def _drive(eng, reqs, steps=500):
             return
         eng.step()
     raise AssertionError("requests did not finish")
+
+
+def _run_dry(eng, steps=500):
+    """Inline mode: step until the engine has nothing left to do."""
+    for _ in range(steps):
+        if not eng.step():
+            return
+    raise AssertionError("the engine did not run dry")
 
 
 def _serve_staggered(eng, prompts=PROMPTS, gap_s=0.01):
@@ -191,7 +202,7 @@ def test_parked_at_the_cap_is_one_episode_not_one_per_wakeup(
     assert cap[0]["undelivered_chunks"] >= 1
 
 
-def _engine_events(trace_dir):
+def _engine_events(trace_dir, prefix="engine."):
     """{line id: [(name, start_ns, end_ns)]} of the `engine.*` host
     events in the newest trace under ``trace_dir``."""
     from jax.profiler import ProfileData
@@ -201,7 +212,7 @@ def _engine_events(trace_dir):
     for plane in ProfileData.from_file(path[-1]).planes:
         for i, line in enumerate(plane.lines):
             evs = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
-                   for ev in line.events if ev.name.startswith("engine.")]
+                   for ev in line.events if ev.name.startswith(prefix)]
             if evs:
                 lines[(plane.name, i)] = evs
     return lines
@@ -339,3 +350,246 @@ def test_a_lock_held_over_an_idle_engine_is_no_stall(model, monkeypatch):
              and e["thread"] == "llm-engine"]   # the fetcher may wait too
     assert len(waits) == 1
     assert waits[0]["queued"] == 1 and waits[0]["seconds"] >= 0.1
+
+
+# ---- a stream's ledger: from the engine's delivery to the pull (PR 40) -----
+
+STREAM_KEYS = ("stream_open_s", "stream_wait_s", "stream_held_s",
+               "stream_pulls", "stream_ready_pulls", "stream_tokens",
+               "stream_pickup_lag_s", "first_pickup_s", "first_pickups",
+               "streams_closed", "streams_abandoned")
+# a stream's record in `request_log` -> the key of `stats` it folds into
+FOLDED = {"stream_open_s": "stream_open_s", "stream_wait_s": "stream_wait_s",
+          "stream_held_s": "stream_held_s", "pulls": "stream_pulls",
+          "ready_pulls": "stream_ready_pulls",
+          "stream_tokens": "stream_tokens",
+          "pickup_lag_s": "stream_pickup_lag_s"}
+
+
+def _ended_streams(eng):
+    """The records of the ended streams; every one of them, and their
+    sum in `stats`, goes to the two states and nowhere else."""
+    recs = [r for r in eng.request_log if "closed" in r]
+    for r in recs:
+        assert r["stream_open_s"] == pytest.approx(
+            r["stream_wait_s"] + r["stream_held_s"], rel=1e-6, abs=1e-6)
+        assert r["stream_wait_s"] >= 0 and r["stream_held_s"] >= 0
+        assert 0 <= r["ready_pulls"] <= r["stream_tokens"] <= r["pulls"]
+    st = eng.stats
+    assert st["stream_open_s"] == pytest.approx(
+        st["stream_wait_s"] + st["stream_held_s"], rel=1e-6, abs=1e-6)
+    return recs
+
+
+def _pull_all(stream, out):
+    """Drain ``stream`` into ``out`` on a thread of its own -> the thread."""
+    def pull():
+        for tok in stream:
+            out.append(tok)
+    th = threading.Thread(target=pull, daemon=True)
+    th.start()
+    return th
+
+
+def test_a_consumer_that_comes_late_finds_every_token_waiting(model):
+    eng = _engine(model)
+    stream = eng.submit_stream(PROMPTS[0])
+    assert eng.stats["streams_closed"] == 0    # open: nothing folded yet
+    _run_dry(eng)                              # the engine runs to the end
+    assert "closed" not in eng.request_log[-1]  # done; its stream still open
+    toks = list(stream)
+    assert len(toks) == 8
+    (rec,) = _ended_streams(eng)
+    assert rec["closed"] == "done" and rec["stream_tokens"] == 8
+    # 8 tokens and the end of the stream: nine pulls, none of them waited
+    assert rec["pulls"] == 9 and rec["ready_pulls"] == 8
+    assert rec["stream_wait_s"] == 0 and rec["stream_held_s"] > 0
+    assert rec["pickup_lag_s"] > 0
+    assert rec["t_first_pickup"] >= rec["t_done"] >= rec["t_first"]
+    st = eng.stats
+    assert st["first_pickups"] == 1
+    assert st["first_pickup_s"] == pytest.approx(
+        rec["t_first_pickup"] - rec["t_first"])
+    assert st["streams_closed"] == 1 and st["streams_abandoned"] == 0
+    assert st["entries"] == 0 and rec["t_sent"] is None   # no stamp came
+
+
+def test_a_consumer_that_is_always_waiting_reads_the_reverse(model):
+    eng = _engine(model, decode_chunk=1)
+    got = []
+    th = _pull_all(eng.submit_stream(PROMPTS[1]), got)
+    for _ in range(500):    # one token a step, and a pause: the chip paces
+        if not eng.step():
+            break
+        deadline = time.monotonic() + 60
+        while len(got) < eng.stats["tokens_out"] \
+                and time.monotonic() < deadline:
+            time.sleep(0.001)
+        time.sleep(0.02)    # ... while the consumer waits for the next one
+    th.join(timeout=120)
+    assert not th.is_alive() and len(got) == 8
+    (rec,) = _ended_streams(eng)
+    # only the first delivery brings two tokens (the prefill's and one); a
+    # consumer the machine held up for a pause may find one more
+    assert rec["ready_pulls"] <= 4 and rec["pulls"] == 9
+    assert rec["stream_wait_s"] > rec["stream_held_s"]
+    assert rec["stream_wait_s"] > rec["pickup_lag_s"]
+    assert rec["stream_wait_s"] > 0.5 * rec["stream_open_s"]
+
+
+def test_an_abandoned_stream_folds_once(model):
+    eng = _engine(model)
+    never_pulled, stream = (eng.submit_stream(p) for p in PROMPTS[:2])
+    for _ in range(3):
+        eng.step()
+    first = next(stream)
+    never_pulled.close()
+    stream.close()
+    stream.close()             # closed already: nothing folds again
+    with pytest.raises(StopIteration):
+        next(stream)
+    st = eng.stats
+    assert st["streams_closed"] == st["streams_abandoned"] == 2
+    assert st["stream_tokens"] == st["first_pickups"] == 1
+    assert st["stream_pulls"] == 1
+    _run_dry(eng)              # the engine finishes both all the same
+    recs = _ended_streams(eng)
+    assert [r["closed"] for r in recs] == ["abandoned"] * 2
+    assert sorted(r["stream_tokens"] for r in recs) == [0, 1]
+    assert all(r["tokens_out"] == 8 for r in recs)
+    assert st["streams_closed"] == 2 and isinstance(first, int)
+    # a stream that ends after the engine has finished its request
+    late = eng.submit_stream(PROMPTS[2])
+    _run_dry(eng)
+    next(late)
+    late.close()
+    assert [r["closed"] for r in _ended_streams(eng)] == ["abandoned"] * 3
+    assert st["streams_abandoned"] == 3
+
+
+def test_streams_that_end_at_once_fold_to_the_sum_of_their_records(model):
+    """64 request threads end their streams in the same instant: `stats`
+    holds the sum of the 64 records (a lost update of the unlocked
+    ``stats[k] += x`` would not)."""
+    n = 64
+    eng = _engine(model).serve_forever()
+    barrier = threading.Barrier(n)
+    errors = []
+
+    def consume(i):
+        try:
+            stream = eng.submit_stream(PROMPTS[i % len(PROMPTS)])
+            for _ in range(8):
+                next(stream)
+            barrier.wait(timeout=120)
+            assert next(stream, None) is None    # the end: folds here
+        except BaseException as e:  # noqa: BLE001 — shown below
+            errors.append(e)
+            barrier.abort()
+
+    keep = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=consume, args=(i,), daemon=True)
+                   for i in range(n)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=180)
+        assert not any(t.is_alive() for t in threads) and not errors
+    finally:
+        sys.setswitchinterval(keep)
+        eng.shutdown()
+    recs = _ended_streams(eng)
+    st = eng.stats
+    assert len(recs) == st["streams_closed"] == st["first_pickups"] == n
+    for field, key in FOLDED.items():
+        assert st[key] == pytest.approx(sum(r[field] for r in recs)), key
+    assert st["stream_tokens"] == 8 * n and st["stream_pulls"] == 9 * n
+    assert st["first_pickup_s"] == pytest.approx(
+        sum(r["t_first_pickup"] - r["t_first"] for r in recs))
+    assert st["streams_abandoned"] == 0
+
+
+def test_a_whole_answer_touches_no_stream_key(model):
+    eng = _engine(model)
+    assert len(eng.generate(PROMPTS[0])) == 8
+    sent = time.time() - 0.25
+    assert len(eng.generate(PROMPTS[1], t_sent=sent)) == 8
+    st = eng.stats
+    assert all(st[k] == 0 for k in STREAM_KEYS)
+    assert st["entries"] == 1 and 0.25 <= st["entry_leg_s"] < 60
+    assert [r["t_sent"] for r in eng.request_log] == [None, sent]
+    assert not any("closed" in r for r in eng.request_log)
+    # ... and a stream fills them without making one lazily
+    keys = _flat_numbers(eng.stats)
+    assert keys >= set(STREAM_KEYS) | {"entry_leg_s", "entries"}
+    stream = eng.submit_stream(PROMPTS[2], t_sent=time.time())
+    _run_dry(eng)
+    assert len(list(stream)) == 8
+    assert _flat_numbers(eng.stats) == keys and st["entries"] == 2
+    assert st["stream_tokens"] == 8 and st["stream_pulls"] == 9
+
+
+def test_a_failed_stream_raises_and_folds_as_an_error(model, monkeypatch):
+    eng = _engine(model)
+    boom = RuntimeError("device lost")
+    monkeypatch.setattr(engine_mod, "prefill_slots",
+                        lambda *a, **k: (_ for _ in ()).throw(boom))
+    stream = eng.submit_stream(PROMPTS[0])
+    eng.serve_forever()
+    try:
+        with pytest.raises(RuntimeError, match="device lost"):
+            next(stream)
+    finally:
+        eng.shutdown()
+    st = eng.stats
+    assert st["streams_closed"] == 1 and st["streams_abandoned"] == 0
+    assert st["stream_tokens"] == 0 and st["stream_pulls"] == 1
+    assert st["stream_open_s"] == pytest.approx(
+        st["stream_wait_s"] + st["stream_held_s"], rel=1e-6, abs=1e-6)
+
+
+def test_a_waited_pull_is_a_span_on_the_profilers_clock(model, tmp_path,
+                                                        monkeypatch):
+    """`serve.stream_wait` in a trace written by the replica's `trace()`:
+    one span a pull that found nothing queued, its seconds the ledger's
+    ``stream_wait_s``, and none of it under the `engine.` prefix."""
+    from ray_tpu.serve.llm import _ContinuousLLMReplica
+
+    shape = dict(slots=2, max_prompt_len=16, max_new_tokens=4)
+    keep = jax.config.jax_persistent_cache_min_compile_time_secs
+    try:
+        rep = _ContinuousLLMReplica(model[0], **shape)
+    finally:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", keep)
+    rep.engine.warmup()
+    outs = [[] for _ in range(6)]
+
+    def traffic(_seconds):   # what `trace()` sleeps through
+        monkeypatch.undo()
+        threads = [_pull_all(rep.stream(p), out)
+                   for p, out in zip(PROMPTS, outs)]
+        for t in threads:
+            t.join(timeout=120)
+        assert not any(t.is_alive() for t in threads)
+
+    monkeypatch.setattr(time, "sleep", traffic)
+    try:
+        rep.trace(1.0, str(tmp_path))
+    finally:
+        rep.engine.shutdown()
+    assert [len(o) for o in outs] == [4] * 6
+    recs = _ended_streams(rep.engine)
+    assert len(recs) == 6 and all(r["closed"] == "done" for r in recs)
+    spans = [ev for evs in _engine_events(
+        str(tmp_path), "serve.stream_wait").values() for ev in evs]
+    # a pull either found a token, waited, or found the END queued (which
+    # no counter tells from a wait: at most one such pull a stream)
+    not_ready = sum(r["pulls"] - r["ready_pulls"] for r in recs)
+    assert 6 <= not_ready - len(recs) <= len(spans) <= not_ready
+    assert sum(e - s for _, s, e in spans) / 1e9 == pytest.approx(
+        rep.engine.stats["stream_wait_s"], rel=0.10, abs=2e-5 * len(spans))
+    owners = {ev[0] for evs in _engine_events(str(tmp_path)).values()
+              for ev in evs}
+    assert owners and not any("stream" in name for name in owners)

@@ -565,3 +565,87 @@ class TestProxyBackpressure:
                 assert json.loads(resp.read()) == i
         finally:
             conn.close()
+
+
+class TestLLMStreamLedger:
+    """PR 40: a request's way into an LLM replica's engine and its
+    stream's way out, counted inside the program. Each call that waits
+    has a time limit of its own."""
+
+    @pytest.fixture(scope="class")
+    def llm(self, rt):
+        from ray_tpu.serve.llm import build_continuous_llm_deployment
+
+        app = build_continuous_llm_deployment(
+            "tiny", name="ledger_llm", slots=2, max_prompt_len=8,
+            max_new_tokens=6)
+        handle = serve.run(app, name="ledger_llm")
+        yield handle
+        serve.shutdown()
+
+    @staticmethod
+    def _ask(handle, method, *args):
+        return handle.options(method_name=method).remote(*args) \
+            .result(timeout_s=120)
+
+    def test_the_handles_stamp_reaches_the_engine(self, llm):
+        before = self._ask(llm, "engine_stats")
+        t0 = time.time()
+        gen = llm.options(method_name="stream", stream=True).remote([3, 1, 4])
+        first = next(gen)
+        t1 = time.time()
+        rest = list(gen)
+        assert len([first] + rest) == 6
+        st = self._ask(llm, "engine_stats")
+        rec = self._ask(llm, "engine_requests", 1)[0]
+        # made here, on this process's wall clock, and before the engine
+        # had the request, which was before its first token was here
+        assert t0 <= rec["t_sent"] <= t1
+        leg = st["entry_leg_s"] - before["entry_leg_s"]
+        assert st["entries"] - before["entries"] == 1
+        assert 0 < leg < t1 - t0
+        # the stream was pulled one token a call to its end
+        assert rec["closed"] == "done" and rec["stream_tokens"] == 6
+        assert rec["pulls"] == 7 and rec["ready_pulls"] <= 6
+        assert rec["stream_open_s"] == pytest.approx(
+            rec["stream_wait_s"] + rec["stream_held_s"], rel=1e-6, abs=1e-6)
+        assert rec["stream_held_s"] > 0 and rec["pickup_lag_s"] > 0
+        assert st["streams_closed"] - before["streams_closed"] == 1
+        assert all(type(v) in (int, float) and "." not in k
+                   for k, v in st.items())
+
+    def test_a_whole_answer_is_stamped_and_opens_no_stream(self, llm):
+        before = self._ask(llm, "engine_stats")
+        out = llm.remote([2, 7], max_new_tokens=3).result(timeout_s=120)
+        assert len(out["token_ids"]) == 3
+        st = self._ask(llm, "engine_stats")
+        assert st["entries"] - before["entries"] == 1
+        assert st["entry_leg_s"] > before["entry_leg_s"]
+        assert all(st[k] == before[k] for k in st
+                   if k.startswith("stream") or "pickup" in k)
+        rec = self._ask(llm, "engine_requests", 1)[0]
+        assert rec["t_sent"] > 0 and "closed" not in rec
+
+    def test_cancel_stream_folds_an_abandoned_stream_once(self, llm):
+        before = self._ask(llm, "engine_stats")
+        gen = llm.options(method_name="stream", stream=True).remote([5, 6])
+        assert "token_id" in next(gen)
+        gen.close()     # cancel_stream on the replica closes the generator
+        gen.close()
+        deadline = time.monotonic() + 60
+        while time.monotonic() < deadline:
+            st = self._ask(llm, "engine_stats")
+            # the engine finishes the request all the same
+            if st["streams_closed"] > before["streams_closed"] \
+                    and st["requests_done"] > before["requests_done"]:
+                break
+            time.sleep(0.05)
+        assert st["streams_closed"] - before["streams_closed"] == 1
+        assert st["streams_abandoned"] - before["streams_abandoned"] == 1
+        assert st["stream_tokens"] - before["stream_tokens"] == 1
+        rec = self._ask(llm, "engine_requests", 1)[0]
+        assert rec["closed"] == "abandoned" and rec["tokens_out"] == 6
+        assert rec["stream_tokens"] == rec["pulls"] == 1
+        # ... and a stream after it is served as before
+        again = llm.options(method_name="stream", stream=True).remote([5, 6])
+        assert len(list(again)) == 6
