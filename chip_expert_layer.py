@@ -46,7 +46,9 @@ configuration brought.
   corners: ``natural`` (zeros), ``one_held`` (every token picks held expert
   0 and three absent ones: one group of N rows), ``none_held`` (four absent
   ones: no row in any group, the routed part and its gradients are exactly
-  zero), ``all_held`` (four held ones: all N*k rows of the buffer live);
+  zero), ``all_held`` (four held ones: all N*k rows of the buffer live:
+  the one case that overflows the sorted buffer's front, ``compact_path``
+  0.0; ``one_held`` fills the one-row front exactly);
   `natural` with rows and expert weights rounded to float8_e4m3 has to come
   out NOT within the tolerance. Then forward + backward of the layer at the
   cell's 8 x 4096 rows is timed in each case: what `ragged_dot` charges for
@@ -297,6 +299,7 @@ def share(seed: int, conf: dict, rows=(1, 4096), timed_rows=(8, 4096),
                        for k in sorted(dlp) if k != "router_bias"})
         line = {"held_share": float(stats["held"]),
                 "load_max_over_mean": float(stats["load"]),
+                "compact_path": float(stats["compact"]),
                 "errors": errors,
                 "bias_gradient_is_zero": not bool(
                     jnp.any(dlp["router_bias"])),

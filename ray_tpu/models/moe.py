@@ -24,11 +24,22 @@ One chip's share (`cfg.moe_held_experts` of the `cfg.moe_experts` the
 router scores, from `cfg.moe_first_expert` on): the layer routes over ALL
 experts, orders the assignments that fall on its own experts first (by
 expert) and the others after them, and computes its experts' part of the
-result; what the absent experts would add is left out. The row buffer stays
-[N*k, d], the most that can fall on the held experts, so no assignment to a
-held expert is ever dropped; the rows past the sum of the group sizes
-belong to no group: they enter as zeros and whatever `ragged_dot` leaves
-in them is replaced by zeros, forward and backward.
+result; what the absent experts would add is left out. Such a chip works
+on the FRONT of the sorted buffer: `front_rows` = FRONT_OVER_EXPECTED
+times the load it expects (N * k * held / E), a static shape. Where the
+rows that fell on the held experts fit the front (a count the dispatch
+makes anyway), every stage between the two sorts is `front` rows long:
+the gather into the buffer's order, the mask of the rows past the groups'
+sum (they belong to no group: zeros in, zeros out, forward and backward),
+the grouped matmuls, the SwiGLU. Only two gathers stay N*k INDICES long,
+and they read a front-sized source: the combine's (an assignment whose
+place is past the front has a routing weight of 0) and the dispatch's
+backward. Where the rows do not fit, the same stage runs on the whole
+[N*k, d] buffer, the most that can fall on the held experts, so no
+assignment to a held expert is ever dropped: one `lax.cond` forward and
+one backward, on the device (`_front_or_whole`; stats["compact"] says
+which way a layer went). A layer that holds every expert, or whose front
+would be the whole buffer, has no `cond`.
 
 No token is ever dropped, whatever the imbalance, and no shape depends on
 the routing: every buffer is [N*k, ...] or smaller (no [.., E, capacity]
@@ -120,42 +131,142 @@ def swiglu(h, w_gate, w_up, w_down, cfg, mesh: Optional[Mesh] = None):
 
 # ---- the two permutations, gathers both ways --------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _dispatch_rows(k, x, order, inv):
-    """x [N, d] -> [N*k, d]: row i is the token of assignment order[i]
-    (assignment a belongs to token a // k). Backward: the rows gathered
-    back by ``inv`` (the inverse permutation) and summed over the k slots
-    of each token."""
-    return x[order // k]
+def _clip(inv, rows):
+    """Places in the sorted buffer as indices into its first ``rows``."""
+    return inv if rows == inv.shape[0] else jnp.minimum(inv, rows - 1)
 
 
-def _dispatch_fwd(k, x, order, inv):
-    return x[order // k], inv
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _dispatch_rows(k, rows, x, order, inv):
+    """x [N, d] -> [rows, d], the front of the sorted buffer: row i is the
+    token of assignment order[i] (assignment a belongs to token a // k).
+    Backward: the rows gathered back by ``inv`` (the inverse permutation;
+    an assignment whose place is past the front takes nothing) and summed
+    over the k slots of each token."""
+    return x[order[:rows] // k]
 
 
-def _dispatch_bwd(k, inv, g):
-    return g[inv].reshape(-1, k, g.shape[-1]).sum(axis=1), None, None
+def _dispatch_fwd(k, rows, x, order, inv):
+    return _dispatch_rows(k, rows, x, order, inv), inv
+
+
+def _dispatch_bwd(k, rows, inv, g):
+    g = g[_clip(inv, rows)]
+    if rows < inv.shape[0]:
+        g = jnp.where((inv < rows)[:, None], g, 0)
+    return g.reshape(-1, k, g.shape[-1]).sum(axis=1), None, None
 
 
 _dispatch_rows.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
 @jax.custom_vjp
-def _permute_rows(x, idx, inv):
-    """x[idx] for a permutation ``idx`` whose inverse is ``inv``; backward
-    is the gather by ``inv``."""
-    return x[idx]
+def _combine_rows(out, top_p, inv, order):
+    """out [rows, d], the front of the sorted buffer, top_p [N, k] -> y
+    [N, d]: each token's k rows gathered back by ``inv``, weighted and
+    summed in float32. An assignment whose place is past the front reads
+    the front's last row at a weight of 0 (it fell on no held expert).
+    Backward: the cotangent's rows gathered INTO the buffer's order, as the
+    dispatch gathers the tokens', so nothing [N*k, d] is made there."""
+    rows = out.shape[0]
+    back = out[_clip(inv, rows)].reshape(top_p.shape + out.shape[-1:])
+    y = jnp.einsum("nkd,nk->nd", back.astype(jnp.float32), top_p)
+    return y.astype(out.dtype)
 
 
-def _permute_fwd(x, idx, inv):
-    return x[idx], inv
+def _combine_fwd(out, top_p, inv, order):
+    return _combine_rows(out, top_p, inv, order), (out, top_p, inv, order)
 
 
-def _permute_bwd(inv, g):
-    return g[inv], None, None
+def _combine_bwd(res, g):
+    out, top_p, inv, order = res
+    rows, front = out.shape[0], order[:out.shape[0]]
+    g_rows = g[front // top_p.shape[1]].astype(jnp.float32)   # [rows, d]
+    d_out = (top_p.reshape(-1)[front][:, None] * g_rows).astype(out.dtype)
+    d_p = jnp.sum(out.astype(jnp.float32) * g_rows, axis=-1)[
+        _clip(inv, rows)]
+    if rows < inv.shape[0]:
+        d_p = jnp.where(inv < rows, d_p, 0)
+    return d_out, d_p.reshape(top_p.shape), None, None
 
 
-_permute_rows.defvjp(_permute_fwd, _permute_bwd)
+_combine_rows.defvjp(_combine_fwd, _combine_bwd)
+
+
+# ---- the expert stage: dispatch, grouped SwiGLU, combine --------------------
+
+# the front of the sorted buffer a chip that holds a share of the experts
+# works on, as a multiple of the load it expects (N * k * held / E)
+FRONT_OVER_EXPECTED = 2
+ROW_TILE = 512      # the front is whole row tiles of the grouped matmuls
+
+
+def front_rows(assignments: int, held: int, experts: int) -> int:
+    """Rows of the sorted buffer's compact front for ``assignments`` = N*k
+    (token, expert) pairs of which ``held`` of ``experts`` fall here:
+    FRONT_OVER_EXPECTED times the expected load, in whole row tiles, never
+    more than there are assignments."""
+    expected = -(-assignments * held // experts)
+    rows = -(-FRONT_OVER_EXPECTED * expected // ROW_TILE) * ROW_TILE
+    return min(rows, assignments)
+
+
+def _expert_rows(k, rows, masked, x, order, inv, group_sizes, top_p,
+                 w_gate, w_up, w_down):
+    """x [N, d] -> y [N, d]: the held experts' SwiGLU of every token,
+    weighted by ``top_p`` [N, k] and summed over the k slots, on the first
+    ``rows`` rows of the sorted buffer (all N*k, or a front the live rows
+    fit in). ``masked``: rows past the groups' sum belong to no group."""
+    dtype = x.dtype
+    with jax.named_scope("moe.dispatch"):
+        xs = _dispatch_rows(k, rows, x, order, inv)       # [rows, d]
+        if masked:
+            in_group = (jnp.arange(rows) < group_sizes.sum())[:, None]
+            xs = jnp.where(in_group, xs, 0)
+    with jax.named_scope("moe.experts"):
+        gate = jax.lax.ragged_dot(xs, w_gate.astype(dtype), group_sizes)
+        up = jax.lax.ragged_dot(xs, w_up.astype(dtype), group_sizes)
+        act = jax.nn.silu(gate) * up                      # [rows, f]
+        out = jax.lax.ragged_dot(act, w_down.astype(dtype),
+                                 group_sizes)             # [rows, d]
+    with jax.named_scope("moe.combine"):
+        if masked:
+            out = jnp.where(in_group, out, 0)
+        return _combine_rows(out, top_p, inv, order)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _front_or_whole(k, rows, fits, x, order, inv, group_sizes, top_p,
+                    w_gate, w_up, w_down):
+    """`_expert_rows` on the front of ``rows`` rows where ``fits`` (the
+    live rows fit it), else on the whole buffer: one `lax.cond` forward and
+    one backward (each backward branch the vjp of its own forward), the
+    stage's inputs the only residuals, so neither branch pays for the
+    other's."""
+    return jax.lax.cond(
+        fits, functools.partial(_expert_rows, k, rows, True),
+        functools.partial(_expert_rows, k, order.shape[0], True),
+        x, order, inv, group_sizes, top_p, w_gate, w_up, w_down)
+
+
+def _front_or_whole_fwd(k, rows, *args):
+    return _front_or_whole(k, rows, *args), args
+
+
+def _front_or_whole_bwd(k, rows, args, g):
+    fits, x, order, inv, group_sizes, top_p, *w = args
+
+    def pull(n):
+        def stage(x, top_p, *w):
+            return _expert_rows(k, n, True, x, order, inv, group_sizes,
+                                top_p, *w)
+        return lambda g, *primals: jax.vjp(stage, *primals)[1](g)
+    dx, dp, *dw = jax.lax.cond(fits, pull(rows), pull(order.shape[0]),
+                               g, x, top_p, *w)
+    return (None, dx, None, None, None, dp, *dw)
+
+
+_front_or_whole.defvjp(_front_or_whole_fwd, _front_or_whole_bwd)
 
 
 # the leaves `route` reads through ``.astype(float32)``: a serving replica
@@ -215,14 +326,15 @@ def moe_layer(h: jax.Array, lp: Params, cfg, mesh: Optional[Mesh] = None
     where a share is held: that chip's part of the sum). stats["load"] is
     the largest group over the mean group of the held experts (1.0 at
     perfect balance), stats["held"] the share of the N*k assignments that
-    fall on held experts (1.0 when all are held).
+    fall on held experts (1.0 when all are held), stats["compact"] 1.0
+    where the stage ran on the sorted buffer's front (or there is no
+    front to miss), 0.0 where the rows overflowed it.
     """
     B, T, d = h.shape
     E, k = cfg.moe_experts, cfg.moe_top_k
     held, first = cfg.held_experts, cfg.moe_first_expert
     share = held < E          # some of the router's experts are not here
     N = B * T
-    dtype = h.dtype
     x = h.reshape(N, d)
 
     with jax.named_scope("moe.route"):
@@ -243,25 +355,18 @@ def moe_layer(h: jax.Array, lp: Params, cfg, mesh: Optional[Mesh] = None
         group_sizes = jnp.sum(
             expert_of[:, None] == jnp.arange(held, dtype=expert_of.dtype),
             axis=0, dtype=jnp.int32)                     # [held]
-        xs = _dispatch_rows(k, x, order, inv)            # [N*k, d]
-        if share:
-            in_group = (jnp.arange(N * k) < group_sizes.sum())[:, None]
-            xs = jnp.where(in_group, xs, 0)
 
-    with jax.named_scope("moe.experts"):
-        gate = jax.lax.ragged_dot(xs, lp["w_gate"].astype(dtype),
-                                  group_sizes)
-        up = jax.lax.ragged_dot(xs, lp["w_up"].astype(dtype), group_sizes)
-        act = jax.nn.silu(gate) * up                     # [N*k, f]
-        out = jax.lax.ragged_dot(act, lp["w_down"].astype(dtype),
-                                 group_sizes)            # [N*k, d]
-
-    with jax.named_scope("moe.combine"):
-        if share:
-            out = jnp.where(in_group, out, 0)
-        back = _permute_rows(out, inv, order).reshape(N, k, d)
-        y = jnp.einsum("nkd,nk->nd", back.astype(jnp.float32), top_p)
-        y = y.astype(dtype).reshape(B, T, d)
+    stage = (x, order, inv, group_sizes, top_p, lp["w_gate"], lp["w_up"],
+             lp["w_down"])
+    rows = front_rows(N * k, held, E) if share else N * k
+    if rows < N * k:      # the front where the live rows fit it
+        fits = group_sizes.sum() <= rows
+        y = _front_or_whole(k, rows, fits, *stage)
+        compact = fits.astype(jnp.float32)
+    else:
+        y = _expert_rows(k, N * k, share, *stage)
+        compact = jnp.ones((), jnp.float32)
+    y = y.reshape(B, T, d)
     if cfg.moe_shared_d_ff:
         with jax.named_scope("moe.shared"):
             y = y + swiglu(h, lp["ws_gate"], lp["ws_up"], lp["ws_down"],
@@ -274,5 +379,5 @@ def moe_layer(h: jax.Array, lp: Params, cfg, mesh: Optional[Mesh] = None
     aux = E * jnp.sum(per_expert / N
                       * probs.mean(axis=0)[first:first + held])
     load = per_expert.max() * held / jnp.maximum(on_held, 1.0)
-    return y, {"aux": aux, "load": load,
+    return y, {"aux": aux, "load": load, "compact": compact,
                "held": jnp.asarray(on_held / (N * k), jnp.float32)}

@@ -505,7 +505,7 @@ def refuse_unserved(cfg: TransformerConfig):
 
 def _no_moe_stats():
     zero = jnp.zeros((), jnp.float32)
-    return {"aux": zero, "load": zero, "held": zero}
+    return {"aux": zero, "load": zero, "held": zero, "compact": zero}
 
 
 def ffn_block(h, lp, cfg: TransformerConfig, mesh: Optional[Mesh] = None):
@@ -513,7 +513,8 @@ def ffn_block(h, lp, cfg: TransformerConfig, mesh: Optional[Mesh] = None):
     A layer has experts if its leaves hold a router (an MoE model's
     leading dense layers hold none). stats: {"aux": load-balance loss,
     "load": largest expert group over the mean group, "held": share of
-    the assignments that fall on held experts}, zeros for a dense layer."""
+    the assignments that fall on held experts, "compact": 1.0 where the
+    layer's rows fit the sorted buffer's front}, zeros for a dense layer."""
     if "router" in lp:
         return moe_layer(h, lp, cfg, mesh)
     return swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"], cfg,
@@ -639,7 +640,8 @@ def _scan_stack(body, x, stack):
 
 def _model_stats(per_layer):
     return {"aux": per_layer["aux"].mean(), "load": per_layer["load"].max(),
-            "held": per_layer["held"].mean()}
+            "held": per_layer["held"].mean(),
+            "compact": per_layer["compact"].mean()}
 
 
 def forward(params: Params, tokens: jax.Array, cfg: TransformerConfig,
@@ -725,6 +727,7 @@ def loss_fn(params: Params, batch: Dict[str, jax.Array],
         metrics["moe_load_max_over_mean"] = stats["load"]
         if cfg.moe_held_experts is not None:
             metrics["moe_held_share"] = stats["held"]
+            metrics["moe_compact_path_share"] = stats["compact"]
         total = total + cfg.moe_aux_weight * stats["aux"]
     if cfg.mtp_layers or cfg.moe_experts:
         metrics["total_loss"] = total

@@ -361,6 +361,83 @@ def test_fsdp2_tensor2_train_step_keeps_kernel(topo, mosaic):
     assert _device_bytes(compiled) < HBM_BYTES // 2
 
 
+def _computations(text) -> dict:
+    """{name: [instruction lines]} of a compiled program's text."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        m = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if m:
+            name = m.group(1)
+            comps[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            comps[name].append(line)
+    return comps
+
+
+def _grouped_matmul_conditionals(text) -> list:
+    """[(whole, front)]: for every conditional of the program whose
+    branches hold a grouped matmul, the instruction lines of its false
+    and of its true branch (`lax.cond(live <= front, ...)` in
+    models/moe.py: true is the front of the sorted buffer), each with the
+    computations it calls."""
+    comps = _computations(text)
+
+    def lines(root):
+        seen, todo = [], [root]
+        while todo:
+            c = todo.pop()
+            if c in seen or c not in comps:
+                continue
+            seen.append(c)
+            for line in comps[c]:
+                for m in re.finditer(
+                        r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)",
+                        line):
+                    todo.append(m.group(1))
+        return [line for c in seen for line in comps[c]]
+    found = []
+    for body in comps.values():
+        for line in body:
+            m = re.search(r" conditional\(.*branch_computations=\{([^}]*)\}",
+                          line)
+            if m is None:
+                continue
+            false, true = (lines(b.strip().lstrip("%"))
+                           for b in m.group(1).split(","))
+            if any("ragged-dot" in x for x in false + true):
+                found.append((false, true))
+    return found
+
+
+def _shaped(lines, *dims) -> list:
+    """The instruction lines that name an array of exactly ``dims``."""
+    tag = "[" + ",".join(str(d) for d in dims) + "]"
+    return [x.strip() for x in lines if tag in x]
+
+
+def _front_holds_no_whole_buffer(text, assignments, front, d, f, at_least):
+    """A step whose chip holds a share of the experts: each expert stage
+    is one conditional forward and one backward; the front's branch
+    multiplies ``front`` rows and holds no [N*k, f] array at all, and of
+    [N*k, d] only the two gathers that are N*k INDICES long (the combine's
+    forward, the dispatch's backward: each reads a front-sized source) with
+    the sum over the k slots that consumes each."""
+    conds = _grouped_matmul_conditionals(text)
+    assert len(conds) >= at_least, len(conds)
+    for whole, compact in conds:
+        assert _shaped(whole, assignments, f)
+        assert _shaped(compact, front, d) and _shaped(compact, front, f)
+        assert not _shaped(compact, assignments, f)
+        wide = _shaped(compact, assignments, d)
+        assert 0 < len(wide) <= 8 < len(_shaped(whole, assignments, d)), wide
+        assert all(re.search(r"moe\.(combine|dispatch)", x) for x in wide
+                   if "op_name=" in x), wide
+    for scope in ("moe.dispatch", "moe.experts", "moe.combine"):
+        assert scope in text, scope
+
+
 def test_olmoe_train_step_fits_one_chip_without_a_capacity_tensor(
         one_chip, mosaic):
     """The cell `olmoe-1b-7b.train-4k` as the benchmark runs it (its
@@ -388,6 +465,8 @@ def test_olmoe_train_step_fits_one_chip_without_a_capacity_tensor(
                                             sharding=one_chip)}
     text = make_train_step(cfg, tx).lower(state, batch).compile().as_text()
     assert "ragged-dot" in text and "tpu_custom_call" in text
+    # every expert is held: no front, no conditional around the experts
+    assert not _grouped_matmul_conditionals(text)
     E, k = cfg.moe_experts, cfg.moe_top_k
     capacity = -(-seq * k // E) * 5 // 4          # the old C at factor 1.25
     largest = rows * seq * cfg.vocab_size         # the float32 logits
@@ -438,6 +517,9 @@ def test_glm_train_step_fits_one_chip_at_the_depth_its_file_states(
     for scope in ("mla.q", "mla.kv", "mla.rope", "mla.out", "moe.shared",
                   "mtp.merge", "mtp.block", "mtp.head"):
         assert scope in text, scope
+    # the layer stack's and the prediction module's, forward and backward
+    _front_holds_no_whole_buffer(text, rows * seq * 4, 32768, 2048, 1536,
+                                 at_least=4)
     largest = rows * seq * cfg.vocab_size         # the float32 logits
     for m in re.finditer(r"\b\w+\[([\d,]+)\]", text):
         n = 1
@@ -565,6 +647,9 @@ def test_kimi_linear_train_step_fits_one_chip_at_the_depth_its_file_states(
     for scope in ("kda.proj", "kda.conv", "kda.gate", "kda.scan", "kda.out",
                   "mla.q", "mla.kv", "mla.out", "moe.shared"):
         assert scope in text, scope
+    # one a position of the period, forward and backward
+    _front_holds_no_whole_buffer(text, rows * seq * 8, 16384, 2304, 1024,
+                                 at_least=8)
     # the scan's kernels and no others are named `kda*` and lie under
     # `kda.scan`: `nope_mla_attention_step_share` counts every Mosaic
     # kernel whose name does not begin `ragged-dot` or `kda`

@@ -514,6 +514,8 @@ def test_glm_is_what_it_was_at_the_parent():
         assert g[0] == shape, leaf
         assert g[1] == pytest.approx(total, rel=1e-6, abs=1e-6), leaf
         assert g[2] == pytest.approx(mag, rel=1e-6), leaf
+    # one counter came since (PR 41): every layer's rows fit the front
+    assert got["metrics"].pop("moe_compact_path_share") == 1.0
     assert sorted(got["metrics"]) == sorted(want["metrics"])
     assert got["total"] == pytest.approx(want["total"], rel=1e-6)
     for k, v in want["metrics"].items():
