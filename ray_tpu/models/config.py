@@ -33,6 +33,13 @@ class TransformerConfig:
     # (transformer.serving_params: `dtype`, the head float32)
     param_dtype: jnp.dtype = jnp.float32
     tie_embeddings: bool = False
+    # The depth the initialiser scales the residual outputs by (each
+    # layer's output projections: (2 x depth) ** -0.5); None -> n_layers.
+    # A configuration that runs a SLICE of a deeper model (the first
+    # period of 48 layers on one pipeline stage) states the whole model's
+    # depth, so that its seeded layers weigh in the residual stream what
+    # they would there and not twelve times as much.
+    init_depth: Optional[int] = None
     # False -> bidirectional (encoder / BERT-class) attention; the same
     # blocks, RoPE, and loss_fn (inputs/targets/mask form = MLM) apply.
     causal: bool = True
@@ -86,6 +93,22 @@ class TransformerConfig:
     # `rope_head_dim` columns stay, unrotated; positions then enter the
     # model through its recurrent layers alone).
     use_rope: bool = True
+    # An elementwise output gate on ordinary attention: the softmax's
+    # output times sigmoid(W_g n), n the layer's normed input, W_g a leaf
+    # `wg` [d, heads, head_dim] of the layer, before the output projection
+    # (arXiv:2505.06708), in training, prefill and decode alike.
+    attn_output_gate: bool = False
+    # An ordinary attention layer's mixer (projections, softmax, gate,
+    # output projection: matmuls at the highest precision), the residual
+    # sum after it, the FFN's norm and the router's input in float32,
+    # whatever `dtype` is, in training, prefill and decode alike; the
+    # experts read that norm rounded to `dtype`, the K/V cache and the
+    # layer's result keep `dtype`. For a model whose FIRST layer is such
+    # a layer with routed experts: nothing upstream has rounded yet, the
+    # stream is at its smallest there, and a near-tie of the router that
+    # falls the other way costs the token a whole expert (PERF.md
+    # section 6, PR 42, has what that did to a served hybrid's logits).
+    attn_float32: bool = False
     # The kinds of token mixer, a period applied from the first layer on
     # (layer i has kind i mod the period's length; the leading dense
     # layers take theirs from the same count): "attention" (whichever the
@@ -100,6 +123,9 @@ class TransformerConfig:
     kda_head_dim: int = 0
     kda_conv: int = 4
     kda_gate_rank: int = 0
+    # True: a kda layer's write strength is 2 sigmoid(W_beta n), in (0, 2),
+    # so that I - b k k^T has an eigenvalue in (-1, 1) (False: in (0, 1)).
+    kda_allow_neg_eigval: bool = False
     # Of the `n_layers` layers of an MoE model, the first
     # `moe_dense_layers` have a dense SwiGLU of width `moe_dense_d_ff`.
     moe_dense_layers: int = 0
@@ -134,6 +160,9 @@ class TransformerConfig:
                 raise ValueError(f"TransformerConfig: {why}")
 
         object.__setattr__(self, "mixer_period", tuple(self.mixer_period))
+        for name in ("dtype", "param_dtype"):    # a data file names them
+            if isinstance(getattr(self, name), str):
+                object.__setattr__(self, name, jnp.dtype(getattr(self, name)))
         if self.nope_head_dim is not None:
             width = self.nope_head_dim + self.rope_head_dim
             need(self.kv_lora_rank and self.head_dim in (None, width),
@@ -169,6 +198,9 @@ class TransformerConfig:
                  "the stack after the leading dense layers is whole "
                  "periods of mixer_period, and a prediction module has "
                  "one kind of layer")
+        need(not ((self.attn_output_gate or self.attn_float32)
+                  and self.kv_lora_rank),
+             "the output gate and attn_float32 belong to ordinary attention")
         need(self.moe_scoring in ("softmax", "sigmoid"),
              f"moe_scoring {self.moe_scoring!r}")
         need(self.mtp_layers in (0, 1), "one prediction module at most")
@@ -187,6 +219,10 @@ class TransformerConfig:
 
     def mixer_kind(self, layer: int) -> str:
         return self.mixer_period[layer % len(self.mixer_period)]
+
+    def layers_of_kind(self, kind: str) -> int:
+        """How many of the `n_layers` layers have mixer ``kind``."""
+        return sum(self.mixer_kind(i) == kind for i in range(self.n_layers))
 
     def _layer_params(self, moe: bool, kind: str = "attention") -> int:
         d, hd, H, KV = self.d_model, self.head_dim, self.n_heads, \
@@ -208,6 +244,7 @@ class TransformerConfig:
                     + H * self.v_head_dim * d)
         else:
             attn = (d * H * hd + 2 * d * KV * hd + H * hd * d
+                    + (d * H * hd if self.attn_output_gate else 0)
                     + (H * hd + KV * hd if self.qk_norm else 0))
         if moe:
             ffn = (d * self.moe_experts                        # router

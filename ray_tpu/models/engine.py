@@ -14,6 +14,14 @@ the reference delegates to external vLLM workers for, built TPU-first:
     It is stored KV-heads-outside-positions because that is the order
     the decode contraction reads it in: stored any other way, XLA
     transposes the whole cache on the way into every chunk and back.
+  - The cache is a TREE of such leaves, each owned by one kind of token
+    mixer and stacked over the layers of that kind (`SlotCache` below):
+    keys and values for the attention layers, a float32 state and the
+    short convolutions' tail for gated delta-rule (KDA) layers. The engine
+    hands the leaves through untouched; admission writes a slot's share of
+    every leaf inside the prefill program, which is also its reset. Both
+    programs walk the layers a period of mixer kinds at a time, as
+    training does, each kind with its own prefill and one-token step.
   - A decode substep reads the cache rows its requests own and writes
     only the rows that change: on a chip the layer scan carries the layer
     index and hands the whole stacked cache to one Pallas kernel
@@ -34,7 +42,9 @@ the reference delegates to external vLLM workers for, built TPU-first:
   - Prefill is a separate program per (group size, prompt bucket) pair
     (both power-of-two, bounded compile count) whose K/V lands directly
     in the slot rows; queued prompts admit in groups of up to 4 as ONE
-    batched program, and prefills interleave with decode chunks so
+    batched program, the oldest with the oldest of its own bucket (a
+    group pads to its largest member), and prefills interleave with
+    decode chunks so
     time-to-first-token stays bounded under load.
   - Dispatch and fetch are pipelined across two threads: the scheduler
     thread admits + dispatches (cheap async calls), the fetcher thread
@@ -77,40 +87,72 @@ import numpy as np
 
 from ray_tpu.models.config import TransformerConfig
 from ray_tpu.models.generate import (_final_logits, _gqa_decode_attention,
-                                     _prefill_hidden)
-from ray_tpu.models.transformer import (Params, ffn_block,
+                                     _prefill_hidden, join_period,
+                                     layer_stacks)
+from ray_tpu.models.transformer import (Params, attention_out, ffn_block,
+                                        kda_mixer, mixer_precision,
                                         param_logical_axes, qkv_proj,
                                         refuse_unserved, rms_norm,
                                         serving_params)
 from ray_tpu.ops.decode_attention import decode_attention, pick_block, \
     rows_read
+from ray_tpu.ops.kda import kda_decode_step
 from ray_tpu.parallel.ring import shard_map
 from ray_tpu.parallel.sharding import logical_to_spec
 
 log = logging.getLogger(__name__)
 
 SlotCache = Dict[str, jax.Array]
-# {"k"/"v": [L, B, KV, S, hd], "pos": [B], "start": [B]} — pos[b] is slot
-# b's next write position; start[b] its first real (non-pad) position.
-# KV-major (heads outside positions) is the layout decode attention
-# contracts over; the layout is private to this module.
+# A tree of leaves with a slots axis B. The engine's own: "pos" [B], slot
+# b's next write position, and "start" [B], its first real (non-pad)
+# position. Every other leaf belongs to the layers of ONE mixer kind,
+# stacked over them (`init_slot_cache`), and the engine hands it through
+# untouched but for the slot-wise write of a prefill (`_put_slots`):
+#   attention  "k"/"v" [L_attn, B, KV, S, hd] — KV-major (heads outside
+#              positions), the layout decode attention contracts over
+#   kda        "kda_state" [L_kda, B, H, dk, dv] float32, the gated delta
+#              rule's state, and "kda_tail" [L_kda, B, taps - 1, 3 x H x
+#              dk], the projected rows of q, k, v the short convolutions
+#              reach back to
+# and, where layers have experts, "moe_counts" [2] float32: the held
+# experts fetched and the assignments that fell on them in the LAST decode
+# chunk (the host adds them up as it fetches the chunk's tokens).
+# A model of attention layers alone holds k, v, pos and start, as ever.
 
 
 def init_slot_cache(cfg: TransformerConfig, slots: int,
                     max_len: int) -> SlotCache:
     refuse_unserved(cfg)
-    shape = (cfg.n_layers, slots, cfg.kv_heads, max_len, cfg.head_dim)
-    return {"k": jnp.zeros(shape, cfg.dtype),
-            "v": jnp.zeros(shape, cfg.dtype),
-            "pos": jnp.zeros((slots,), jnp.int32),
-            "start": jnp.zeros((slots,), jnp.int32)}
+    cache = {}
+    n_attn, n_kda = (cfg.layers_of_kind(kind)
+                     for kind in ("attention", "kda"))
+    if n_attn:
+        shape = (n_attn, slots, cfg.kv_heads, max_len, cfg.head_dim)
+        cache.update(k=jnp.zeros(shape, cfg.dtype),
+                     v=jnp.zeros(shape, cfg.dtype))
+    if n_kda:
+        H, hd = cfg.kda_heads, cfg.kda_head_dim
+        cache.update(
+            kda_state=jnp.zeros((n_kda, slots, H, hd, hd), jnp.float32),
+            kda_tail=jnp.zeros((n_kda, slots, cfg.kda_conv - 1, 3 * H * hd),
+                               cfg.dtype))
+    if cfg.moe_experts:
+        cache["moe_counts"] = jnp.zeros((2,), jnp.float32)
+    cache.update(pos=jnp.zeros((slots,), jnp.int32),
+                 start=jnp.zeros((slots,), jnp.int32))
+    return cache
 
 
-def cache_logical_axes() -> Dict[str, tuple]:
-    """Logical axes of the slot cache (slots axis stays unsharded —
-    serving shards the model, not the batch)."""
+def cache_logical_axes(cache=None) -> Dict[str, tuple]:
+    """Logical axes of the slot cache's leaves (slots axis stays unsharded
+    — serving shards the model, not the batch): of ``cache``'s, or of the
+    four an attention-only model holds."""
     kv = ("layers", None, "kv_heads", None, None)
-    return {"k": kv, "v": kv, "pos": (None,), "start": (None,)}
+    axes = {"k": kv, "v": kv, "pos": (None,), "start": (None,),
+            "kda_state": ("layers", None, "heads", None, None),
+            "kda_tail": ("layers", None, None, None), "moe_counts": (None,)}
+    return {name: axes[name] for name in
+            (("k", "v", "pos", "start") if cache is None else cache)}
 
 
 def _sample(logits, rng, greedy: bool, temperature):
@@ -137,6 +179,18 @@ def _put_rows(cache: SlotCache, new_k: jax.Array, new_v: jax.Array,
     return k, v
 
 
+def _put_slots(leaf: jax.Array, new: jax.Array, slots: jax.Array):
+    """Row i of ``new`` [L, n, ...] over the whole of slot ``slots[i]`` of
+    a cache leaf [L, B, ...]: what a request admitted into the slot finds
+    there is its own prefill's, never the last tenant's."""
+    zero = jnp.zeros((), jnp.int32)
+    for i in range(new.shape[1]):
+        leaf = jax.lax.dynamic_update_slice(
+            leaf, new[:, i:i + 1].astype(leaf.dtype),
+            (zero, slots[i]) + (zero,) * (leaf.ndim - 2))
+    return leaf
+
+
 @partial(jax.jit, static_argnames=("cfg", "greedy"), donate_argnums=(1,))
 def prefill_slots(params: Params, cache: SlotCache, tokens: jax.Array,
                   slots: jax.Array, starts: jax.Array, rng: jax.Array,
@@ -144,7 +198,9 @@ def prefill_slots(params: Params, cache: SlotCache, tokens: jax.Array,
                   temperature: float = 1.0):
     """Batched prefill: ``tokens`` [K, P] (left-padded to one shared
     bucket, first real token of row i at ``starts[i]``) lands in cache
-    rows ``slots`` [K]; -> (cache, first sampled tokens [K]).
+    rows ``slots`` [K]; -> (cache, first sampled tokens [K]). A KDA
+    layer's state and tail of those slots are REPLACED by the prompt's
+    (computed from zero): admission is the reset.
 
     One compiled program per (K, P) pair; K is kept to a few power-of-two
     group sizes by the scheduler. Batching prefills is a dispatch-count
@@ -157,14 +213,18 @@ def prefill_slots(params: Params, cache: SlotCache, tokens: jax.Array,
     x, cK = _prefill_hidden(params, tokens, cfg, P, starts)
     last = _final_logits(params, x[:, -1:], cfg)[:, 0]  # [K, V]
     toks = _sample(last, rng, greedy, temperature)      # [K]
-    # cK["k"]: [L, K, P, KV, hd] -> the cache's [L, K, KV, P, hd] (the
-    # prompt's K/V is small), then row i into slot row slots[i]
-    k, v = _put_rows(cache, cK["k"].transpose(0, 1, 3, 2, 4),
-                     cK["v"].transpose(0, 1, 3, 2, 4), slots,
-                     jnp.zeros_like(slots))
-    return {"k": k, "v": v,
-            "pos": cache["pos"].at[slots].set(P),
-            "start": cache["start"].at[slots].set(starts)}, toks
+    new = dict(cache, pos=cache["pos"].at[slots].set(P),
+               start=cache["start"].at[slots].set(starts))
+    if "k" in cK:
+        # cK["k"]: [L, K, P, KV, hd] -> the cache's [L, K, KV, P, hd] (the
+        # prompt's K/V is small), then row i into slot row slots[i]
+        new["k"], new["v"] = _put_rows(
+            cache, cK["k"].transpose(0, 1, 3, 2, 4),
+            cK["v"].transpose(0, 1, 3, 2, 4), slots, jnp.zeros_like(slots))
+    for name in ("kda_state", "kda_tail"):
+        if name in cK:
+            new[name] = _put_slots(cache[name], cK[name], slots)
+    return new, toks
 
 
 def _on_chip() -> bool:
@@ -207,55 +267,111 @@ def _decode_one(params: Params, cache: SlotCache, tokens: jax.Array,
     """One decode step for every slot: tokens [B] (each slot's pending
     token) -> (cache with pos advanced, logits [B, V]). ``active`` [B]
     bool left out reads every slot as active; a slot that is not active
-    attends to nothing it has cached (its logits are junk either way).
+    attends to nothing it has cached and keeps its KDA state and tail as
+    they are (its logits are junk either way).
 
     pos/RoPE/attention bounds are all per-row, so slots admitted at
-    different times decode together in one program. The layer scan only
-    READS the cache and hands back each layer's new K/V row ([L,B,KV,hd],
-    a megabyte); the rows land afterwards, one in-place
-    dynamic_update_slice per slot at its own ``pos`` (on the donated,
-    loop-carried cache nothing else is moved). A ``pos`` past the end
-    clamps to the slot's own last position, which no request's plan
-    reads (`InferenceEngine._max_len`).
+    different times decode together in one program. The layers are walked
+    a period of mixer kinds at a time, as `transformer._trunk` walks them
+    (one stack a position of the period; one scan step a period), each
+    kind taking its own one-token step:
+      attention  only READS the cache and hands back the layer's new K/V
+                 row ([L,B,KV,hd], a megabyte); the rows land afterwards,
+                 one in-place dynamic_update_slice per slot at its own
+                 ``pos`` (on the donated, loop-carried cache nothing else
+                 is moved). A ``pos`` past the end clamps to the slot's
+                 own last position, which no request's plan reads
+                 (`InferenceEngine._max_len`).
+      kda        updates the slots' states where they lie: the scan
+                 carries the stacked states and the layer's index, and
+                 `kda_decode_step` reads and writes the layer's blocks of
+                 the one buffer; the tails are shifted by the token.
+    Layers with experts add what they fetched to ``moe_counts``.
     """
     pos, start = cache["pos"], cache["start"]
     x = params["embed"].astype(cfg.dtype)[tokens[:, None]]  # [B, 1, d]
     positions = pos[:, None]  # [B, 1] per-row RoPE
-    L, _, _, S, _ = cache["k"].shape
-    dtype = cache["k"].dtype
-    kernel = _kv_block(cache["k"]) is not None
-    if kernel:
-        if active is None:
-            active = jnp.ones_like(pos, bool)
-        # the kernel indexes [L, ...] itself: the scan carries the index
-        scanned = (params["layers"], jnp.arange(L))
-    else:
+    stacks = layer_stacks(params)
+    kinds = ["kda" if "kda_wq" in lp else "attention" for lp in stacks]
+    n_attn, n_kda = kinds.count("attention"), kinds.count("kda")
+    periods = jax.tree.leaves(stacks[0])[0].shape[0]
+    kernel = n_attn and _kv_block(cache["k"]) is not None
+    if active is None and (kernel or n_kda):
+        active = jnp.ones_like(pos, bool)
+    # a kernel indexes [L, ...] itself: the scan carries the period's index
+    scanned = [stacks, jnp.arange(periods)]
+    if n_attn and not kernel:
+        S, dtype = cache["k"].shape[3], cache["k"].dtype
         kpos = jnp.arange(S)[None, :]
         mask = (kpos >= start[:, None]) & (kpos < pos[:, None])  # [B, S]
-        scanned = (params["layers"], cache["k"], cache["v"])
+        scanned += [c.reshape((periods, n_attn) + c.shape[1:])
+                    for c in (cache["k"], cache["v"])]
+    elif n_attn:
+        dtype = cache["k"].dtype
 
-    def block(x, scanned):
-        lp, *at = scanned  # the layer's index, or its K and V
-        h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-        q, k, v = qkv_proj(h, lp, cfg, positions)
-        k, v = k[:, 0].astype(dtype), v[:, 0].astype(dtype)  # [B, KV, hd]
-        if kernel:
-            o = _kernel_attention(q, cache, k, v, active, *at, mesh)
-        else:
-            o = _gqa_decode_attention(q, *at, k, v, mask)
-        o = jnp.einsum("bthk,hkd->btd", o, lp["wo"].astype(cfg.dtype))
-        x = x + o
-        h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-        down, _ = ffn_block(h, lp, cfg, None)
-        x = x + down
-        return x, (k, v)
+    def layer_of(period, n, i):
+        return period if n == 1 else period * n + i
 
-    x, (k_rows, v_rows) = jax.lax.scan(block, x, scanned)
-    logits = _final_logits(params, x, cfg)[:, 0]  # [B, V]
-    k_all, v_all = _put_rows(cache, k_rows[:, :, :, None],
-                             v_rows[:, :, :, None],
-                             jnp.arange(tokens.shape[0], dtype=jnp.int32), pos)
-    return {"k": k_all, "v": v_all, "pos": pos + 1, "start": start}, logits
+    def block(carry, scanned):
+        lps, period, *kv = scanned
+        x, rows, i_attn, i_kda = carry["x"], [], 0, 0
+        for lp in lps:
+            if "kda_wq" in lp:
+                h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
+                layer = layer_of(period, n_kda, i_kda)
+                i_kda += 1
+
+                def step(*token, layer=layer):
+                    carry["kda_state"], o = kda_decode_step(
+                        carry["kda_state"], layer, *token, active)
+                    return o
+                old = jax.lax.dynamic_index_in_dim(
+                    carry["kda_tail"], layer, 0, keepdims=False)
+                o, tail = kda_mixer(h, lp, cfg, tail=old, step=step)
+                carry["kda_tail"] = jax.lax.dynamic_update_index_in_dim(
+                    carry["kda_tail"],
+                    jnp.where(active[:, None, None], tail, old), layer, 0)
+            else:
+                # a float32 mixer (`mixer_precision`) around the attention
+                # itself: the kernel keeps its own arithmetic, reads the
+                # cache's rows and hands back o in q's dtype
+                with mixer_precision(cfg, lp) as wide:
+                    x = x.astype(wide)
+                    h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
+                    q, k, v = qkv_proj(h, lp, cfg, positions)
+                k, v = k[:, 0].astype(dtype), v[:, 0].astype(dtype)
+                if kernel:          # [B, KV, hd]
+                    o = _kernel_attention(
+                        q, cache, k, v, active,
+                        layer_of(period, n_attn, i_attn), mesh)
+                else:
+                    o = _gqa_decode_attention(
+                        q, kv[0][i_attn], kv[1][i_attn], k, v, mask)
+                i_attn += 1
+                rows.append((k, v))
+                with mixer_precision(cfg, lp):
+                    o = attention_out(o, h, lp, cfg)
+            x = x + o
+            down, stats = ffn_block(
+                rms_norm(x, lp["mlp_norm"], cfg.rms_eps), lp, cfg)
+            if "router" in lp and "moe_counts" in carry:
+                assignments = x.shape[0] * x.shape[1] * cfg.moe_top_k
+                carry["moe_counts"] = carry["moe_counts"] + jnp.stack(
+                    [stats["fetched"], stats["held"] * assignments])
+            x = (x + down).astype(cfg.dtype)
+        return dict(carry, x=x), tuple(zip(*rows))
+
+    carried = {name: cache[name] for name in
+               ("kda_state", "kda_tail", "moe_counts") if name in cache}
+    carried, rows = jax.lax.scan(block, dict(carried, x=x), tuple(scanned))
+    logits = _final_logits(params, carried.pop("x"), cfg)[:, 0]  # [B, V]
+    new = dict(cache, pos=pos + 1, **carried)
+    if n_attn:
+        k_rows, v_rows = (join_period(x) for x in rows)
+        new["k"], new["v"] = _put_rows(
+            cache, k_rows[:, :, :, None], v_rows[:, :, :, None],
+            jnp.arange(tokens.shape[0], dtype=jnp.int32), pos)
+    return new, logits
 
 
 @partial(jax.jit, static_argnames=("cfg", "greedy", "steps", "mesh"),
@@ -283,6 +399,8 @@ def decode_slots(params: Params, cache: SlotCache, tokens: jax.Array,
     sharded over, which the decode kernel needs to run per shard.
     """
     pos0 = cache["pos"]
+    if "moe_counts" in cache:   # this chunk's alone
+        cache = dict(cache, moe_counts=jnp.zeros_like(cache["moe_counts"]))
 
     def substep(carry, step_rng):
         cache, tok, done = carry
@@ -299,8 +417,7 @@ def decode_slots(params: Params, cache: SlotCache, tokens: jax.Array,
     # overwritten by the next prefill/real decode at their frozen pos
     new_pos = jnp.where(active, cache["pos"],
                         pos0).astype(jnp.int32)
-    cache = {"k": cache["k"], "v": cache["v"], "pos": new_pos,
-             "start": cache["start"]}
+    cache = dict(cache, pos=new_pos)
     return cache, jnp.concatenate([tokens[:, None], toks.T], axis=1)
 
 
@@ -427,8 +544,14 @@ class InferenceEngine:
         if mesh is not None:
             from ray_tpu.parallel.sharding import shard_array, tree_shardings
 
+            if "kda" in cfg.mixer_period and mesh.size > 1:
+                raise NotImplementedError(
+                    "a KDA layer's decode kernel is not run per shard yet: "
+                    "serve such a model on one chip")
+
             shardings = tree_shardings(mesh, param_logical_axes(cfg))
-            self.cache = {k: shard_array(mesh, v, cache_logical_axes()[k])
+            axes = cache_logical_axes(self.cache)
+            self.cache = {k: shard_array(mesh, v, axes[k])
                           for k, v in self.cache.items()}
         self.params = serving_params(params, cfg, shardings)
 
@@ -455,9 +578,18 @@ class InferenceEngine:
         # resident slot, kept on the host for the decode_kv_rows_* counters
         self._slot_start = np.zeros(self.slots, np.int64)
         self._slot_pos = np.zeros(self.slots, np.int64)
-        self._kv_block = _kv_block(self.cache["k"])  # None: XLA contraction
+        # None: the XLA contraction (or no attention layer at all)
+        self._kv_block = _kv_block(self.cache["k"]) \
+            if "k" in self.cache else None
+        # what a decode substep costs by the model's shape, for the
+        # counters: KDA layers, layers with experts and what those offer
+        self._kda_layers = cfg.layers_of_kind("kda")
+        moe_layers = cfg.n_layers if cfg.moe_experts else 0
+        self._moe_calls = moe_layers * cfg.held_experts
+        self._moe_assignments = moe_layers * self.slots * cfg.moe_top_k
         # dispatched-but-unfetched chunks: [(toks_dev [B, K+1],
-        # [(slot, request, emit_from_col, take)])] — inline step() fetches
+        # [(slot, request, emit_from_col, take)], the chunk's moe_counts
+        # or None)] — inline step() fetches
         # them in the step that dispatched them, the fetcher thread as the
         # device finishes them (one transfer for all that are ready)
         self._inflight: List[tuple] = []
@@ -506,6 +638,17 @@ class InferenceEngine:
             # attention fetches, and those an active slot owns
             "decode_kv_rows_cache": 0, "decode_kv_rows_read": 0,
             "decode_kv_rows_valid": 0,
+            # of the decode substeps dispatched: a KDA layer's states
+            # updated (one unit: one active slot's state in EVERY KDA
+            # layer, read and written once), the held experts offered (a
+            # layer and substep: all it holds) and the (token, expert)
+            # assignments routed; of the chunks delivered, counted on the
+            # device and fetched with their tokens: the held experts that
+            # got a row (whose weights a substep fetched) and the
+            # assignments that fell on held experts
+            "kda_state_updates": 0, "moe_expert_calls": 0,
+            "moe_assignments": 0, "moe_expert_fetches": 0,
+            "moe_held_assignments": 0,
             "slow_s": 0.0, "slow_count": 0,
             # the ended streams' ledgers (`_fold_stream`): stream_open_s =
             # stream_wait_s + stream_held_s; pickup lag over stream_tokens
@@ -792,7 +935,9 @@ class InferenceEngine:
 
     def _decode(self, active: np.ndarray):
         """Dispatch one decode chunk for the ``active`` slots (ASYNC) and
-        chain its last samples; -> the chunk's tokens [B, chunk + 1]."""
+        chain its last samples; -> (the chunk's tokens [B, chunk + 1], its
+        ``moe_counts`` or None: a copy, the next dispatch donates the
+        cache's)."""
         # a mesh is named only where there is one, so that an unsharded
         # engine's program is the one a caller of `decode_slots` gets
         mesh = {} if self.mesh is None else {"mesh": self.mesh}
@@ -801,7 +946,8 @@ class InferenceEngine:
             jnp.asarray(active), self._next_rng(), self.cfg, self.greedy,
             self.temperature, self.eos_id, steps=self.decode_chunk, **mesh)
         self._next_tok_dev = toks[:, -1]
-        return toks
+        counts = self.cache.get("moe_counts")
+        return toks, None if counts is None else jnp.copy(counts)
 
     def _count_kv_rows(self, active_slots: List[int]):
         """The decode_kv_rows_* counters for one chunk over these slots,
@@ -840,10 +986,10 @@ class InferenceEngine:
         self._decode(np.ones(self.slots, bool))  # and the last-column slice
         jax.block_until_ready(self._next_tok_dev)
         # reset bookkeeping: positions to zero, junk K/V is unreachable
+        # (a KDA layer's state and tail are replaced at admission)
         cache = self.cache
-        self.cache = {"k": cache["k"], "v": cache["v"],
-                      "pos": jnp.zeros_like(cache["pos"]),
-                      "start": jnp.zeros_like(cache["start"])}
+        self.cache = dict(cache, pos=jnp.zeros_like(cache["pos"]),
+                          start=jnp.zeros_like(cache["start"]))
         self._next_tok_dev = jnp.zeros(self.slots, jnp.int32)
         return self
 
@@ -909,27 +1055,51 @@ class InferenceEngine:
             self._fetch_evt.set()
         return bool(admitted or dispatched or processed)
 
+    def _take_like(self, lead: "_Request", room: int) -> List["_Request"]:
+        """Take out of the queue the requests that share a prefill with
+        ``lead``: the oldest whose prompts fall in its bucket, from among
+        the queue's first `slots` entries (those that could be admitted
+        next anyway), as many as make a compiled group size with it
+        within ``room`` slots."""
+        bucket = self._bucket(len(lead.prompt))
+        with self._queue.mutex:
+            waiting = self._queue.queue
+            like = [req for req in itertools.islice(waiting, self.slots)
+                    if self._bucket(len(req.prompt)) == bucket]
+            K = next(k for k in self._GROUP_SIZES
+                     if k <= min(room, 1 + len(like)))
+            del like[K - 1:]
+            for req in like:
+                waiting.remove(req)
+        return like
+
     def _admit_locked(self) -> int:
         """Admit queued prompts into planned-free slots; dispatches one
-        batched prefill per power-of-two group. Returns #admitted."""
+        batched prefill a group. A group is the oldest queued request and
+        the oldest after it of ITS bucket, up to a compiled group size: a
+        group pads to its largest member's bucket, so a short prompt
+        beside a long one would cost the long one's tokens (at 128 slots
+        in first-come groups of four, twice the prompts' own). The oldest
+        always leads, so none waits for ever. Returns #admitted."""
         with self._timed("admit_wall_s", "engine.admit"):
-            take: List[tuple] = []
+            free: List[int] = []
             for slot in range(self.slots):
                 if self._slot_left[slot] > 0:
                     continue
                 if self._slot_req[slot] is not None:
                     # planned release: dispatching for it is complete
                     self._slot_req[slot] = None
+                free.append(slot)
+            groups: List[List[tuple]] = []
+            while free:
                 try:
-                    req = self._queue.get_nowait()
+                    lead = self._queue.get_nowait()
                 except queue.Empty:
                     break
-                take.append((slot, req))
-            i = 0
-            while i < len(take):
-                K = next(k for k in self._GROUP_SIZES if k <= len(take) - i)
-                group = take[i:i + K]
-                i += K
+                reqs = [lead] + self._take_like(lead, len(free))
+                groups.append(list(zip(free, reqs)))
+                del free[:len(reqs)]
+            for i, group in enumerate(groups):
                 try:
                     self._admit_group(group)
                 except BaseException as e:
@@ -938,16 +1108,17 @@ class InferenceEngine:
                     # later dequeued-but-ungrouped request here — none of
                     # them are queued or slotted anymore, so _die cannot see
                     # them and they would otherwise hang forever
-                    for _slot, req in group + take[i:]:
-                        req.error = e
-                        req.finish("error")
+                    for later in groups[i:]:
+                        for _slot, req in later:
+                            req.error = e
+                            req.finish("error")
                     raise
                 for slot, req in group:
                     # the plan includes the prefill-sampled first token; it
                     # reaches the host in the next chunk's echo column
                     self._slot_left[slot] = req.max_new_tokens
                     self._slot_new[slot] = True
-            return len(take)
+            return sum(len(group) for group in groups)
 
     def _dispatch_locked(self) -> bool:
         active_slots = [s for s in range(self.slots)
@@ -975,26 +1146,36 @@ class InferenceEngine:
                     self._slot_left[slot] - (width + 1 if new else width))
             active = np.zeros(self.slots, bool)
             active[active_slots] = True
-            toks = self._decode(active)
+            toks, counts = self._decode(active)
             self._count_kv_rows(active_slots)
             self.stats["decode_steps"] += width
             self.stats["chunks_dispatched"] += 1
-        self._inflight.append((toks, snapshot))
+            if self._kda_layers:
+                self.stats["kda_state_updates"] += width * len(active_slots)
+            self.stats["moe_expert_calls"] += width * self._moe_calls
+            self.stats["moe_assignments"] += width * self._moe_assignments
+        self._inflight.append((toks, snapshot, counts))
         return True
 
     def _fetch_chunks(self, pending) -> np.ndarray:
         """ONE batched host transfer for ``pending`` chunks (each
-        [B, decode_chunk+1]), concatenated on the host. Device-side
+        [B, decode_chunk+1], and its expert counts where the model has
+        experts: added to ``stats`` here), concatenated on the host. Device-side
         concat would compile a fresh program per distinct chunk count,
         and a mid-traffic compile stalls every slot. Called outside the
         lock by the fetcher; inline
         mode calls it under the lock."""
         with self._timed("fetch_wall_s", "engine.fetch",
                          chunks=len(pending)):
-            parts = jax.device_get([t for t, _ in pending])
+            parts, counts = jax.device_get(
+                ([t for t, _, _ in pending],
+                 [c for _, _, c in pending if c is not None]))
             big = parts[0] if len(parts) == 1 else np.concatenate(
                 parts, axis=1)
             self.stats["fetches"] += 1
+            for fetched, held in counts:
+                self.stats["moe_expert_fetches"] += int(fetched)
+                self.stats["moe_held_assignments"] += int(round(held))
         return big
 
     def _deliver_locked(self, big: np.ndarray, pending) -> None:
@@ -1002,7 +1183,7 @@ class InferenceEngine:
         with self._timed("deliver_wall_s", "engine.deliver",
                          chunks=len(pending)):
             now = time.perf_counter()  # every token's time of delivery
-            for i, (_toks_dev, snap) in enumerate(pending):
+            for i, (_toks_dev, snap, _counts) in enumerate(pending):
                 seg = big[:, i * W:(i + 1) * W]
                 for slot, req, from_col, take in snap:
                     if req.done.is_set():
@@ -1121,7 +1302,7 @@ class InferenceEngine:
                     failed.append(self._queue.get_nowait())
                 except queue.Empty:
                     break
-        for _, snap in self._inflight:
+        for _, snap, _ in self._inflight:
             failed.extend(req for _, req, _, _ in snap)
         self._inflight = []
         for req in failed:

@@ -10,9 +10,12 @@ TPU-first design:
     its K/V padded out to ``max_len``, so each (rows, bucket) pair is one
     compiled program.
   - The layer dimension rides the same stacked-params ``lax.scan`` as
-    training (`transformer.forward`), so depth costs one trace and the
+    training (`transformer.forward`: a period of mixer kinds a step, one
+    stack a position of the period), so depth costs one trace and the
     prompt's K/V comes back as one [L, B, S, KV, hd] array per k/v —
-    contiguous HBM, no per-layer Python lists.
+    contiguous HBM, no per-layer Python lists. What a KDA layer leaves a
+    slot comes back the same way: its final state and the last projected
+    rows its convolutions reach back to, one entry a KDA layer.
   - Keys/values are cached *post-RoPE* and *pre-GQA-expansion* (KV heads,
     not Q heads): memory scales with kv_heads, and the repeat to Q heads
     happens inside the attention contraction.
@@ -29,7 +32,8 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.config import TransformerConfig
-from ray_tpu.models.transformer import (Params, ffn_block, lm_head,
+from ray_tpu.models.transformer import (Params, attention_out, ffn_block,
+                                        kda_mixer, lm_head, mixer_precision,
                                         qkv_proj, refuse_unserved, rms_norm)
 
 # Large-finite instead of -inf for masked scores: a fully-masked query row
@@ -38,13 +42,6 @@ from ray_tpu.models.transformer import (Params, ffn_block, lm_head,
 # masked) nor read (only real positions' logits are consumed), while NaN
 # would propagate through 0*NaN in the value contraction.
 _MASKED = jnp.float32(jnp.finfo(jnp.float32).min / 2)
-
-
-def _ffn(h, lp, cfg):
-    # shared definition with the training path (transformer.ffn_block);
-    # inference drops the MoE aux loss
-    down, _ = ffn_block(h, lp, cfg, None)
-    return down
 
 
 def _gqa_attention(q, k, v, mask):
@@ -94,13 +91,34 @@ def _final_logits(params, x, cfg):
     return lm_head(params, x, cfg, None)
 
 
+def layer_stacks(params: Params) -> tuple:
+    """``params["layers"]`` as a tuple of stacks, one a position of the
+    period of mixer kinds (one stack for a model of one kind)."""
+    layers = params["layers"]
+    return (layers,) if isinstance(layers, dict) else tuple(layers)
+
+
+def join_period(parts):
+    """What the positions of ONE kind handed out of a scan over periods,
+    each [periods, ...], in layer order: [periods x positions, ...]."""
+    if len(parts) == 1:
+        return parts[0]
+    x = jnp.stack(parts, axis=1)
+    return x.reshape((-1,) + x.shape[2:])
+
+
 def _prefill_hidden(params: Params, tokens: jax.Array,
                     cfg: TransformerConfig, max_len: int,
                     start: jax.Array):
     """Prompt pass returning final HIDDEN states [B,P,d] + the filled
     cache — the caller projects only the positions it reads to vocab
     space (a [B,P,V] float32 logits tensor is ~2 GB for llama3-8b at
-    P=512 and is pure waste on the serving hot path)."""
+    P=512 and is pure waste on the serving hot path). The cache holds
+    what each mixer kind leaves a slot, stacked over the layers of that
+    kind: ``k``/``v`` [L_attn, B, max_len, KV, hd]; ``kda_state`` [L_kda,
+    B, H, dk, dv] float32 and ``kda_tail`` [L_kda, B, taps - 1, 3 x H x
+    dk]. Rows are padded on the left: a padded row is masked out of
+    attention, and writes nothing into a KDA state (`kda_mixer`)."""
     B, P = tokens.shape
     refuse_unserved(cfg)
     if max_len < P:
@@ -118,19 +136,33 @@ def _prefill_hidden(params: Params, tokens: jax.Array,
     prompt_mask = causal[None, :, None, None, :] & \
         valid[:, None, None, None, :]
 
-    def block(x, lp):
-        h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-        q, k, v = qkv_proj(h, lp, cfg, positions)
-        o = _gqa_attention(q, k, v, prompt_mask)
-        o = jnp.einsum("bthk,hkd->btd", o, lp["wo"].astype(cfg.dtype))
-        x = x + o
-        h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-        x = x + _ffn(h, lp, cfg)
-        # pad this layer's k/v out to max_len for the cache
-        pad = [(0, 0), (0, max_len - P), (0, 0), (0, 0)]
-        return x, (jnp.pad(k, pad), jnp.pad(v, pad))
+    def period(x, lps):
+        left = {}
+        for lp in lps:
+            with mixer_precision(cfg, lp) as dtype:
+                x = x.astype(dtype)
+                h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
+                if "kda_wq" in lp:
+                    o, state, tail = kda_mixer(h, lp, cfg, valid=valid)
+                    new = {"kda_state": state, "kda_tail": tail}
+                else:
+                    q, k, v = qkv_proj(h, lp, cfg, positions)
+                    o = attention_out(
+                        _gqa_attention(q, k, v, prompt_mask), h, lp, cfg)
+                    # pad this layer's k/v out to max_len for the cache
+                    pad = [(0, 0), (0, max_len - P), (0, 0), (0, 0)]
+                    new = {"k": jnp.pad(k.astype(cfg.dtype), pad),
+                           "v": jnp.pad(v.astype(cfg.dtype), pad)}
+            x = x + o
+            # inference drops the MoE aux loss
+            down, _ = ffn_block(rms_norm(x, lp["mlp_norm"], cfg.rms_eps),
+                                lp, cfg)
+            x = (x + down).astype(cfg.dtype)
+            for name, leaf in new.items():
+                left.setdefault(name, []).append(leaf)
+        return x, {name: tuple(leaves) for name, leaves in left.items()}
 
-    x, (k_all, v_all) = jax.lax.scan(block, x, params["layers"])
-    cache = {"k": k_all, "v": v_all,
-             "pos": jnp.asarray(P, jnp.int32)}
+    x, left = jax.lax.scan(period, x, layer_stacks(params))
+    cache = {name: join_period(leaves) for name, leaves in left.items()}
+    cache["pos"] = jnp.asarray(P, jnp.int32)
     return x, cache

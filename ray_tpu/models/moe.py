@@ -85,16 +85,20 @@ def moe_param_logical_axes(cfg) -> Dict[str, tuple]:
     return axes
 
 
-def init_moe_params(rng: jax.Array, cfg, n_layers=None) -> Params:
+def init_moe_params(rng: jax.Array, cfg, n_layers=None,
+                    normal=None) -> Params:
     """Stacked per-layer MoE params: router [L,d,E] + the held experts'
     FFNs [L,held,...] (+ the selection bias [L,E], zeros; + the shared
-    expert's FFN [L,...])."""
+    expert's FFN [L,...]). ``normal(key, shape, scale)`` draws a matrix
+    (left out: `scaled_normal` in ``cfg.param_dtype``)."""
     L = cfg.n_layers if n_layers is None else n_layers
     d, ff, E, held = cfg.d_model, cfg.d_ff, cfg.moe_experts, cfg.held_experts
     k = iter(jax.random.split(rng, 8))
-    normal = functools.partial(scaled_normal, dtype=cfg.param_dtype)
+    normal = normal or functools.partial(scaled_normal,
+                                         dtype=cfg.param_dtype)
     in_scale = d ** -0.5
-    out_scale = (2 * cfg.n_layers) ** -0.5 * d ** -0.5   # times (f/d)^0.5
+    out_scale = (2 * (cfg.init_depth or cfg.n_layers)) ** -0.5 \
+        * d ** -0.5                                      # times (f/d)^0.5
     lay = {
         "router": normal(next(k), (L, d, E), in_scale),
         "w_gate": normal(next(k), (L, held, d, ff), in_scale),
@@ -328,7 +332,9 @@ def moe_layer(h: jax.Array, lp: Params, cfg, mesh: Optional[Mesh] = None
     perfect balance), stats["held"] the share of the N*k assignments that
     fall on held experts (1.0 when all are held), stats["compact"] 1.0
     where the stage ran on the sorted buffer's front (or there is no
-    front to miss), 0.0 where the rows overflowed it.
+    front to miss), 0.0 where the rows overflowed it, stats["fetched"] the
+    held experts that got at least one row (the weights the grouped matmuls
+    had to fetch: what a decode step of a few rows a chip costs).
     """
     B, T, d = h.shape
     E, k = cfg.moe_experts, cfg.moe_top_k
@@ -340,6 +346,10 @@ def moe_layer(h: jax.Array, lp: Params, cfg, mesh: Optional[Mesh] = None
     with jax.named_scope("moe.route"):
         probs, top_p, top_i = route(x, lp["router"], cfg,
                                     lp.get("router_bias"))
+    # the router reads h as it comes (float32 after an `attn_float32`
+    # mixer), the experts what a layer of cfg.dtype would hand them
+    h = h.astype(cfg.dtype)
+    x = x.astype(cfg.dtype)
 
     with jax.named_scope("moe.dispatch"):
         expert_of = top_i.reshape(N * k)
@@ -380,4 +390,5 @@ def moe_layer(h: jax.Array, lp: Params, cfg, mesh: Optional[Mesh] = None
                       * probs.mean(axis=0)[first:first + held])
     load = per_expert.max() * held / jnp.maximum(on_held, 1.0)
     return y, {"aux": aux, "load": load, "compact": compact,
-               "held": jnp.asarray(on_held / (N * k), jnp.float32)}
+               "held": jnp.asarray(on_held / (N * k), jnp.float32),
+               "fetched": jnp.sum(group_sizes > 0).astype(jnp.float32)}

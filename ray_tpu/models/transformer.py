@@ -99,6 +99,8 @@ def _stack_axes(cfg: TransformerConfig, moe: bool,
             "wk": ("layers", "embed", "kv_heads", "qkv_dim"),
             "wv": ("layers", "embed", "kv_heads", "qkv_dim"),
         })
+        if cfg.attn_output_gate:
+            lay["wg"] = ("layers", "embed", "heads", "qkv_dim")
     if cfg.qk_norm and kind != "kda":
         # gains over the flattened (heads x head_dim) projection: replicated
         lay.update({"q_norm": ("layers", None), "k_norm": ("layers", None)})
@@ -135,7 +137,7 @@ def param_logical_axes(cfg: TransformerConfig) -> Params:
     return axes
 
 
-def _init_kda(k, cfg: TransformerConfig, L: int) -> Params:
+def _init_kda(k, cfg: TransformerConfig, L: int, normal) -> Params:
     """The leaves of ``L`` stacked KDA mixers but for `wo`. `A_log` and
     `dt_bias` as the published implementation draws them (A uniform in
     [1, 16); dt log-uniform in [0.001, 0.1) through the inverse softplus),
@@ -143,7 +145,6 @@ def _init_kda(k, cfg: TransformerConfig, L: int) -> Params:
     d, H, hd, r = cfg.d_model, cfg.kda_heads, cfg.kda_head_dim, \
         cfg.kda_gate_rank
     pd = cfg.param_dtype
-    normal = functools.partial(scaled_normal, dtype=pd)
     lay = {name: normal(next(k), (L, d, H, hd), d ** -0.5)
            for name in ("kda_wq", "kda_wk", "kda_wv")}
     lay.update({name: normal(next(k), (L, cfg.kda_conv, H, hd),
@@ -163,20 +164,21 @@ def _init_kda(k, cfg: TransformerConfig, L: int) -> Params:
 
 
 def _init_stack(k, cfg: TransformerConfig, L: int, moe: bool,
-                kind: str = "attention") -> Params:
+                kind: str = "attention", normal=None) -> Params:
     """``L`` stacked layers of one kind, keys drawn from the iterator
-    ``k`` (the mixer first, then the FFN)."""
+    ``k`` (the mixer first, then the FFN); ``normal`` as `init_params`
+    takes it."""
     d, hd, H, KV = cfg.d_model, cfg.head_dim, cfg.n_heads, cfg.kv_heads
     pd = cfg.param_dtype
-    normal = functools.partial(scaled_normal, dtype=pd)
+    normal = normal or functools.partial(scaled_normal, dtype=pd)
     in_scale = d ** -0.5
     # depth-scaled residual outputs, by the depth of the whole model
-    out_scale = (2 * cfg.n_layers) ** -0.5 * d ** -0.5
+    out_scale = (2 * (cfg.init_depth or cfg.n_layers)) ** -0.5 * d ** -0.5
     lay = {"attn_norm": jnp.ones((L, d), pd),
            "mlp_norm": jnp.ones((L, d), pd)}
     out_heads = (H, cfg.v_head_dim)
     if kind == "kda":
-        lay.update(_init_kda(k, cfg, L))
+        lay.update(_init_kda(k, cfg, L, normal))
         out_heads = (cfg.kda_heads, cfg.kda_head_dim)
     elif cfg.kv_lora_rank:
         rq, rkv, rope = cfg.q_lora_rank, cfg.kv_lora_rank, cfg.rope_head_dim
@@ -198,12 +200,14 @@ def _init_stack(k, cfg: TransformerConfig, L: int, moe: bool,
             "wk": normal(next(k), (L, d, KV, hd), in_scale),
             "wv": normal(next(k), (L, d, KV, hd), in_scale),
         })
+        if cfg.attn_output_gate:
+            lay["wg"] = normal(next(k), (L, d, H, hd), in_scale)
     lay["wo"] = normal(next(k), (L, *out_heads, d), out_scale)
     if cfg.qk_norm and kind != "kda":
         lay.update({"q_norm": jnp.ones((L, H * hd), pd),
                     "k_norm": jnp.ones((L, KV * hd), pd)})
     if moe:
-        lay.update(init_moe_params(next(k), cfg, L))
+        lay.update(init_moe_params(next(k), cfg, L, normal))
     else:
         ff = cfg.moe_dense_d_ff if cfg.moe_experts else cfg.d_ff
         lay.update({
@@ -215,9 +219,17 @@ def _init_stack(k, cfg: TransformerConfig, L: int, moe: bool,
     return lay
 
 
-def init_params(rng: jax.Array, cfg: TransformerConfig) -> Params:
+def init_params(rng: jax.Array, cfg: TransformerConfig,
+                normal=None) -> Params:
+    """The model's weights from ``rng``, in ``cfg.param_dtype``.
+    ``normal(key, shape, scale)`` draws a matrix: left out, a scaled
+    normal made on the spot; a serving replica passes one that puts the
+    draw off (`serve.llm.drawn_serving_params`), so that it can make and
+    convert the matrices one at a time. Gains, biases and a KDA layer's
+    decay parameters are small and made here either way."""
     d, v = cfg.d_model, cfg.vocab_size
     pd = cfg.param_dtype
+    normal = normal or functools.partial(scaled_normal, dtype=pd)
     moe = bool(cfg.moe_experts)
     dense = cfg.moe_dense_layers   # 0 without experts
     n_keys = 32 if "kda" in cfg.mixer_period else 16   # a KDA layer: 14
@@ -231,15 +243,15 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> Params:
         return _per_kind(cfg, first, count, lambda j, kind, n: _init_stack(
             own if one else iter(jax.random.split(
                 jax.random.fold_in(rng, salt + j), n_keys)),
-            cfg, n, moe, kind))
+            cfg, n, moe, kind, normal))
     params: Params = {
         # an MoE model's leading dense layers are a stack of their own
         "layers": stack(dense, cfg.n_layers - dense, moe, k, 16),
-        "embed": scaled_normal(next(k), (v, d), d ** -0.5, pd),
+        "embed": normal(next(k), (v, d), d ** -0.5),
         "final_norm": jnp.ones((d,), pd),
     }
     if not cfg.tie_embeddings:
-        params["lm_head"] = scaled_normal(next(k), (d, v), d ** -0.5, pd)
+        params["lm_head"] = normal(next(k), (d, v), d ** -0.5)
     # further stacks draw from keys of their own, so the leaves above are
     # what they were before a configuration could have these
     if dense:
@@ -250,9 +262,9 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> Params:
         params["mtp"] = {
             "h_norm": jnp.ones((d,), pd), "e_norm": jnp.ones((d,), pd),
             # rows: the hidden state's half, then the embedding's
-            "proj": scaled_normal(next(km), (2 * d, d), (2 * d) ** -0.5, pd),
+            "proj": normal(next(km), (2 * d, d), (2 * d) ** -0.5),
             "layers": _init_stack(km, cfg, cfg.mtp_layers, moe,
-                                  cfg.mixer_kind(0)),
+                                  cfg.mixer_kind(0), normal),
         }
     return params
 
@@ -420,13 +432,15 @@ def qkv_proj(h, lp, cfg: TransformerConfig, positions):
 KDA_L2_EPS = 1e-6
 
 
-def _conv_silu(x, w):
+def _conv_silu(x, w, before=None):
     """A causal depthwise convolution along the row, then SiLU. x [B, T,
     H, D]; w [taps, H, D]: y_t = sum_i w[i] x_(t - taps + 1 + i), zeros
-    before the row's first token."""
+    before the row's first token, or ``before`` [B, taps - 1, H, D], the
+    rows a decode step's slot keeps."""
     taps, T = w.shape[0], x.shape[1]
     # padded and sliced as it arrives; float32 from the products on
-    xp = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0), (0, 0)))
+    xp = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0), (0, 0))) \
+        if before is None else jnp.concatenate([before, x], axis=1)
     w = w.astype(jnp.float32)
     y = sum(xp[:, i:i + T].astype(jnp.float32) * w[i] for i in range(taps))
     return jax.nn.silu(y)
@@ -437,17 +451,32 @@ def _l2norm(x):
                              + KDA_L2_EPS)
 
 
-def kda_mixer(h, lp, cfg: TransformerConfig):
+def kda_mixer(h, lp, cfg: TransformerConfig, *, valid=None, tail=None,
+              step=None):
     """Kimi delta attention on normed rows h [B, T, d] -> [B, T, d]: q, k,
     v each through a short causal convolution and SiLU, q and k
     l2-normalised a head; a per-channel decay exp(-exp(A_log) x softplus(
-    W_f2 W_f1 h + dt_bias)) and a write strength sigmoid(W_beta h) a head,
-    float32; the gated delta rule over the row (ops/kda.py); the heads'
-    RMSNorm times a sigmoid gate W_g2 W_g1 h; the output projection. Each
-    part under a `jax.named_scope` a profile groups by."""
+    W_f2 W_f1 h + dt_bias)) and a write strength sigmoid(W_beta h) a head
+    (twice that where `cfg.kda_allow_neg_eigval`), float32; the gated
+    delta rule over the row (ops/kda.py); the heads' RMSNorm times a
+    sigmoid gate W_g2 W_g1 h; the output projection. Each part under a
+    `jax.named_scope` a profile groups by.
+
+    Training calls it as it is. Serving's prefill gives ``valid`` [B, T]
+    bool (False on a row's left padding: such a row is zero before the
+    convolution, writes nothing and decays nothing, so the first real
+    token sees a fresh row) and gets (out, the final state [B, H, dk, dv]
+    float32, the last `kda_conv - 1` projected rows of q, k, v [B, taps -
+    1, 3 x H x dk]). Serving's decode gives T = 1, the slots' ``tail`` of
+    that shape and ``step``, a function (q, k, v, g, beta) [B, H, ...] ->
+    o [B, H, dv] that advances the states (engine: `kda_decode_step` on
+    the layer's slice of the cache), and gets (out, the tail shifted by
+    this token)."""
     from ray_tpu.ops import kda
 
     dt, f32 = cfg.dtype, jnp.float32
+    B, T = h.shape[:2]
+    H, hd, taps = cfg.kda_heads, cfg.kda_head_dim, cfg.kda_conv
 
     def low_rank(a, b, out):
         return jnp.einsum(
@@ -457,11 +486,28 @@ def kda_mixer(h, lp, cfg: TransformerConfig):
     with jax.named_scope("kda.proj"):
         q, k, v = (jnp.einsum("btd,dhk->bthk", h, lp[name].astype(dt))
                    for name in ("kda_wq", "kda_wk", "kda_wv"))
+    before = (None,) * 3
+    if valid is not None:
+        q, k, v = (jnp.where(valid[:, :, None, None], x, 0)
+                   for x in (q, k, v))
+    if tail is not None:
+        before = tuple(x.reshape(B, taps - 1, H, hd)
+                       for x in jnp.split(tail, 3, axis=-1))
+    if valid is not None or tail is not None:
+        # the rows the next token's convolution reaches back to
+        kept = jnp.concatenate(
+            [(x if b is None else jnp.concatenate([b, x], axis=1)
+              ).reshape(B, -1, H * hd) for x, b in zip((q, k, v), before)],
+            axis=-1)
+        short = max(0, taps - 1 - kept.shape[1])   # a row of 1 or 2 tokens
+        kept = jnp.pad(kept, ((0, 0), (short, 0), (0, 0)))
+        kept = kept[:, kept.shape[1] - (taps - 1):]
     with jax.named_scope("kda.conv"):
-        q = (_l2norm(_conv_silu(q, lp["kda_conv_q"]))
-             * cfg.kda_head_dim ** -0.5).astype(dt)
-        k = _l2norm(_conv_silu(k, lp["kda_conv_k"])).astype(dt)
-        v = _conv_silu(v, lp["kda_conv_v"]).astype(dt)
+        q, k, v = (_conv_silu(x, lp[f"kda_conv_{c}"], *(() if b is None
+                                                       else (b,)))
+                   for x, c, b in zip((q, k, v), "qkv", before))
+        q = (_l2norm(q) * cfg.kda_head_dim ** -0.5).astype(dt)
+        k, v = _l2norm(k).astype(dt), v.astype(dt)
     with jax.named_scope("kda.gate"):
         g = -jnp.exp(lp["kda_A_log"].astype(f32))[:, None] * jax.nn.softplus(
             low_rank("kda_f_a", "kda_f_b", f32)
@@ -469,27 +515,38 @@ def kda_mixer(h, lp, cfg: TransformerConfig):
         beta = jax.nn.sigmoid(jnp.einsum(
             "btd,dh->bth", h, lp["kda_beta"].astype(dt),
             preferred_element_type=f32))
+        if cfg.kda_allow_neg_eigval:
+            beta = 2.0 * beta
+        if valid is not None:
+            g = jnp.where(valid[:, :, None, None], g, 0.0)
+            beta = jnp.where(valid[:, :, None], beta, 0.0)
         gate = low_rank("kda_g_a", "kda_g_b", dt)
-    o = kda.kda_scan(q, k, v, g, beta)
+    if step is not None:
+        o = step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0])[:, None]
+        o = o.astype(dt)
+    elif valid is not None:
+        with jax.named_scope("kda.prefill"):
+            o, state = kda.kda_scan(q, k, v, g, beta, final_state=True)
+    else:
+        o = kda.kda_scan(q, k, v, g, beta)
     with jax.named_scope("kda.out"):
         o = rms_norm(o, lp["kda_o_norm"], cfg.rms_eps) \
             * jax.nn.sigmoid(gate.astype(f32)).astype(dt)
-        return jnp.einsum("bthk,hkd->btd", o, lp["wo"].astype(dt))
+        out = jnp.einsum("bthk,hkd->btd", o, lp["wo"].astype(dt))
+    if step is not None:
+        return out, kept
+    return (out, state, kept) if valid is not None else out
 
 
 def refuse_unserved(cfg: TransformerConfig):
-    """The KV-cache paths (models/generate, models/engine) hold one k and
-    one v row of `head_dim` a token and layer, scan ONE stack of layers of
-    one kind and emit one token a step: raise for a configuration that
-    needs a latent cache and the absorbed decode form, a second stack, a
-    stack of several kinds, a recurrent state, or a step of more than one
+    """The serving paths (models/generate, models/engine) walk ONE stack of
+    layers, a period of mixer kinds at a time, hold for an attention layer
+    one k and one v row of `head_dim` a token and for a KDA layer a
+    float32 state and the convolutions' tail a slot, and emit one token a
+    step: raise for a configuration that needs a latent cache and the
+    absorbed decode form, a second stack, or a step of more than one
     token."""
     cannot = [what for has, what in (
-        (len(cfg.mixer_period) > 1, "a period of mixer kinds (mixer_period: "
-         "the engine scans one stack of one kind; ROADMAP M1)"),
-        ("kda" in cfg.mixer_period, "gated delta-rule linear attention "
-         "(kda: a recurrent state and a convolution's tail in the slot "
-         "cache; ROADMAP M6)"),
         (cfg.kv_lora_rank, "latent attention (kv_lora_rank: a latent slot "
          "cache and the absorbed decode form)"),
         (cfg.moe_experts and cfg.moe_dense_layers,
@@ -505,7 +562,8 @@ def refuse_unserved(cfg: TransformerConfig):
 
 def _no_moe_stats():
     zero = jnp.zeros((), jnp.float32)
-    return {"aux": zero, "load": zero, "held": zero, "compact": zero}
+    return {"aux": zero, "load": zero, "held": zero, "compact": zero,
+            "fetched": zero}
 
 
 def ffn_block(h, lp, cfg: TransformerConfig, mesh: Optional[Mesh] = None):
@@ -514,11 +572,12 @@ def ffn_block(h, lp, cfg: TransformerConfig, mesh: Optional[Mesh] = None):
     leading dense layers hold none). stats: {"aux": load-balance loss,
     "load": largest expert group over the mean group, "held": share of
     the assignments that fall on held experts, "compact": 1.0 where the
-    layer's rows fit the sorted buffer's front}, zeros for a dense layer."""
+    layer's rows fit the sorted buffer's front, "fetched": held experts
+    with at least one row}, zeros for a dense layer."""
     if "router" in lp:
         return moe_layer(h, lp, cfg, mesh)
-    return swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"], cfg,
-                  mesh), _no_moe_stats()
+    return swiglu(h.astype(cfg.dtype), lp["w_gate"], lp["w_up"],
+                  lp["w_down"], cfg, mesh), _no_moe_stats()
 
 
 def lm_head(params: Params, x, cfg: TransformerConfig,
@@ -548,6 +607,36 @@ def read_in_float32(cfg: TransformerConfig) -> tuple:
 
 # ---- forward ---------------------------------------------------------------
 
+@contextlib.contextmanager
+def mixer_precision(cfg: TransformerConfig, lp):
+    """The dtype layer ``lp`` computes its mixer in, as a context around
+    it: float32, with every matmul traced inside at the highest precision,
+    for an ordinary attention layer of a model with `attn_float32`;
+    ``cfg.dtype`` and nothing changed for every other layer. The caller
+    hands the mixer its input in that dtype, keeps the sum after it, the
+    FFN's norm and the router's input so, and rounds the layer's result
+    to ``cfg.dtype`` (`moe_layer` rounds what the experts read)."""
+    if cfg.attn_float32 and "kda_wq" not in lp \
+            and jnp.dtype(cfg.dtype) != jnp.float32:
+        with jax.default_matmul_precision("float32"):
+            yield jnp.float32
+    else:
+        yield cfg.dtype
+
+
+def attention_out(o, h, lp, cfg: TransformerConfig):
+    """The attention output o [B, T, H, hd] through the layer's output
+    gate, where it has one (`wg`: o . sigmoid(W_g h), h the layer's normed
+    input), and the output projection -> [B, T, d]. Shared by training,
+    prefill and decode."""
+    if "wg" in lp:
+        with jax.named_scope("attn.gate"):
+            gate = jnp.einsum("btd,dhk->bthk", h, lp["wg"].astype(cfg.dtype),
+                              preferred_element_type=jnp.float32)
+            o = o * jax.nn.sigmoid(gate).astype(o.dtype)
+    return jnp.einsum("bthk,hkd->btd", o, lp["wo"].astype(cfg.dtype))
+
+
 def _attention_mixer(h, lp, cfg: TransformerConfig, mesh: Optional[Mesh],
                      positions):
     q, k, v = qkv_proj(h, lp, cfg, positions)
@@ -559,23 +648,26 @@ def _attention_mixer(h, lp, cfg: TransformerConfig, mesh: Optional[Mesh],
     o = _attention(q, k, v, cfg, mesh, positions)
     with jax.named_scope("mla.out") if cfg.kv_lora_rank \
             else contextlib.nullcontext():
-        return jnp.einsum("bthk,hkd->btd", o, lp["wo"].astype(cfg.dtype))
+        return attention_out(o, h, lp, cfg)
 
 
 def _block(x, lp, cfg: TransformerConfig, mesh: Optional[Mesh], positions):
     """One decoder layer, of whichever kinds ``lp`` holds (a KDA mixer
     where it holds one's leaves, else attention; experts where it holds a
     router): x [B, T, d] -> (x, the FFN's stats)."""
-    h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-    if "kda_wq" in lp:
-        o = kda_mixer(h, lp, cfg)
-    else:
-        o = _attention_mixer(h, lp, cfg, mesh, positions)
+    with mixer_precision(cfg, lp) as dtype:
+        x = x.astype(dtype)
+        h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
+        if "kda_wq" in lp:
+            o = kda_mixer(h, lp, cfg)
+        else:
+            o = _attention_mixer(h, lp, cfg, mesh, positions)
     x = x + _wlc(o, ("batch", "seq", "embed"), mesh=mesh)
 
     h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
     down, stats = ffn_block(h, lp, cfg, mesh)
-    x = x + _wlc(down, ("batch", "seq", "embed"), mesh=mesh)
+    x = (x + _wlc(down, ("batch", "seq", "embed"), mesh=mesh)).astype(
+        cfg.dtype)
     # the MoE stats ride the scan's per-layer outputs; the pipelined
     # path drops them (pipeline stages emit activations only) —
     # acceptable: aux is a regularizer, not the model output.

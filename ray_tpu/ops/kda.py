@@ -3,7 +3,8 @@ Linear), chunked, as two Mosaic (Pallas TPU) kernels under one
 `jax.custom_vjp`.
 
 Per head, with q_t, k_t in R^dk, v_t in R^dv, log-decays g_t <= 0 in R^dk
-(a_t = exp(g_t)), a write strength b_t in (0, 1) and a state S in
+(a_t = exp(g_t)), a write strength b_t in (0, 1), or in (0, 2) where the model
+allows a negative eigenvalue of I - b k k^T, and a state S in
 R^{dk x dv} that starts at zero:
 
     S'  = Diag(a_t) S_{t-1}
@@ -289,23 +290,28 @@ def _heads_beta(beta_ref, P):
 
 
 def _fwd_kernel(sums_ref, level_ref, q_ref, k_ref, v_ref, g_ref, beta_ref,
-                o_ref, *rest):
+                o_ref, *rest, save, final):
     """Grid (B, H / P, steps), the steps in order: ``rest`` is the state
     scratch [P, dk, dv], before it the start states' output when the pass
-    is being differentiated."""
+    is being differentiated (``save``) or the state after the last step
+    when a caller keeps it (``final``: serving's prefill)."""
     S_ref = rest[-1]
 
     @pl.when(pl.program_id(2) == 0)
     def _():
         S_ref[...] = jnp.zeros_like(S_ref)
 
-    if len(rest) == 2:
+    if save:
         rest[0][0, :, 0] = S_ref[...]
     S_ref[...], o = _step(
         S_ref[...], q_ref[0], k_ref[0], v_ref[0], g_ref[0],
         _heads_beta(beta_ref, S_ref.shape[0]), sums=sums_ref[...],
         level=level_ref[...])
     o_ref[0] = o.astype(o_ref.dtype)
+    if final:   # the block stays put over the steps: written back once
+        @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
+        def _():
+            rest[0][0] = S_ref[...]
 
 
 def _bwd_kernel(sums_ref, level_ref, q_ref, k_ref, v_ref, g_ref, beta_ref,
@@ -351,6 +357,7 @@ def _call(kernel, name, n, R, shapes, step, extra_in, out):
         "beta": pl.BlockSpec((1, R, H), lambda b, h, i: (b, step(i), 0)),
         "state": pl.BlockSpec((1, P, 1, dk, dv),
                               lambda b, h, i: (b, h, step(i), 0, 0)),
+        "final": pl.BlockSpec((1, P, dk, dv), lambda b, h, i: (b, h, 0, 0)),
         "lanes": pl.BlockSpec((1, P, 1, 1, R),
                               lambda b, h, i: (b, h, step(i), 0, 0)),
     }
@@ -367,17 +374,22 @@ def _call(kernel, name, n, R, shapes, step, extra_in, out):
             vmem_limit_bytes=64 << 20))
 
 
-def _forward(q, k, v, g, beta, R, save):
+def _forward(q, k, v, g, beta, R, save, final=False):
     """q, k, g [B, T, H x dk], v [B, T, H x dv], beta [B, T, H], T a
     multiple of R -> [o [B, T, H x dv]] and, with ``save``, the state at
-    the start of every step, [B, H, T / R, dk, dv] float32."""
+    the start of every step, [B, H, T / R, dk, dv] float32, or, with
+    ``final``, the state after the last, [B, H, dk, dv] float32."""
     B, T, H = beta.shape
     dk, dv, n = q.shape[2] // H, v.shape[2] // H, T // R
     out = [("v", jax.ShapeDtypeStruct(v.shape, v.dtype))]
     if save:
         out.append(("state", jax.ShapeDtypeStruct((B, H, n, dk, dv),
                                                   jnp.float32)))
-    return _call(_fwd_kernel, "kda_scan_fwd", n, R, (B, H, dk, dv),
+    if final:
+        out.append(("final", jax.ShapeDtypeStruct((B, H, dk, dv),
+                                                  jnp.float32)))
+    kernel = functools.partial(_fwd_kernel, save=save, final=final)
+    return _call(kernel, "kda_scan_fwd", n, R, (B, H, dk, dv),
                  lambda i: i, [], out)(*_tables(), q, k, v, g, beta)
 
 
@@ -414,11 +426,14 @@ def _scan_bwd(R, saved, do):     # traced under the caller's scopes too
 _scan.defvjp(_scan_fwd, _scan_bwd)
 
 
-def kda_scan(q, k, v, g, beta, *, group: int = 2):
+def kda_scan(q, k, v, g, beta, *, group: int = 2, final_state: bool = False):
     """The chunked form. q, k: [B, T, H, dk] and v: [B, T, H, dv] in the
     compute dtype; g: [B, T, H, dk] float32 log-decays (<= 0); beta: [B,
     T, H] float32 -> o [B, T, H, dv] in v's dtype. Any T: rows past it are
-    padded with tokens that write nothing and decay nothing. ``group``
+    padded with tokens that write nothing and decay nothing.
+    ``final_state``: -> (o, the state after row T, [B, H, dk, dv] float32,
+    from the forward kernel's last grid step): serving's prefill, which
+    takes no gradient; the trainer's call and its kernel are unchanged. ``group``
     chunks a grid step of the kernels (the backward keeps T / (64 x group)
     states, for the layer being differentiated alone). The trainer takes
     the default: a larger step ran no faster on a v5e and its kernels
@@ -432,6 +447,106 @@ def kda_scan(q, k, v, g, beta, *, group: int = 2):
         def rows(x):    # [B, T, H, d] -> [B, T + pad, H x d]
             x = x.reshape(B, T, -1)
             return jnp.pad(x, ((0, 0), (0, pad), (0, 0))) if pad else x
-        o = _scan(rows(q), rows(k), rows(v), rows(g.astype(jnp.float32)),
-                  rows(beta.astype(jnp.float32)), R)
-        return o[:, :T].reshape(B, T, H, -1)
+        args = (rows(q), rows(k), rows(v), rows(g.astype(jnp.float32)),
+                rows(beta.astype(jnp.float32)))
+        if final_state:
+            o, S = _forward(*args, R, save=False, final=True)
+            return o[:, :T].reshape(B, T, H, -1), S
+        return _scan(*args, R)[:, :T].reshape(B, T, H, -1)
+
+
+# ---- one token a slot: serving's decode step --------------------------------
+
+def _decode_heads(H: int) -> int:
+    """Heads a grid step of the decode kernel (a state block of 16 heads is
+    1 MB: 512 steps a layer at 128 slots x 64 heads)."""
+    return next(n for n in (16, 8, 4, 2, 1) if H % n == 0)
+
+
+def _decode_kernel(layer_ref, active_ref, s_ref, a_ref, k_ref, q_ref, v_ref,
+                   b_ref, s_out, o_ref):
+    """Grid (slots, H / hb). s_ref / s_out: the slot's states of hb heads,
+    [1, 1, hb, dk, dv] of the one aliased buffer; a, k, q as COLUMNS [1, 1,
+    dk, hb] (dk on sublanes, as the state's rows are); v and the write
+    strength (repeated along the row) as rows [1, hb, dv]."""
+    del layer_ref    # the index maps read it
+    live = active_ref[pl.program_id(0)] != 0
+
+    @pl.when(live)
+    def _():
+        for j in range(s_ref.shape[2]):
+            k = k_ref[0, 0, :, j:j + 1]                       # [dk, 1]
+            S = s_ref[0, 0, j] * a_ref[0, 0, :, j:j + 1]      # S' = a . S
+            read = jnp.sum(S * k, axis=0, keepdims=True)      # S'^T k
+            S = S + k * (b_ref[0, j:j + 1] * (v_ref[0, j:j + 1] - read))
+            s_out[0, 0, j] = S
+            o_ref[0, j:j + 1] = jnp.sum(S * q_ref[0, 0, :, j:j + 1], axis=0,
+                                        keepdims=True)        # S^T q
+
+    @pl.when(jnp.logical_not(live))
+    def _():    # bit for bit what it was
+        s_out[...] = s_ref[...]
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+
+def _decode_step_kernel(state, layer, q, k, v, a, beta, active):
+    L, B, H, dk, dv = state.shape
+    hb = _decode_heads(H)
+
+    def columns(x):     # [B, H, dk] -> [B, H / hb, dk, hb]
+        return jnp.swapaxes(x.reshape(B, H // hb, hb, dk), 2, 3)
+    col = pl.BlockSpec((1, 1, dk, hb), lambda b, h, *_: (b, h, 0, 0))
+    row = pl.BlockSpec((1, hb, dv), lambda b, h, *_: (b, h, 0))
+    slab = pl.BlockSpec((1, 1, hb, dk, dv),
+                        lambda b, h, layer, active: (layer[0], b, h, 0, 0))
+    return pl.pallas_call(
+        _decode_kernel, name="kda_decode_step",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(B, H // hb),
+            in_specs=[slab, col, col, col, row, row],
+            out_specs=[slab, row]),
+        out_shape=[jax.ShapeDtypeStruct(state.shape, state.dtype),
+                   jax.ShapeDtypeStruct((B, H, dv), jnp.float32)],
+        # the states are updated where they lie (operand 2, after the two
+        # prefetched scalars)
+        input_output_aliases={2: 0},
+        interpret=_use_interpret(),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=48 << 20),
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), active.astype(jnp.int32),
+      state, columns(a), columns(k), columns(q), v,
+      jnp.broadcast_to(beta[..., None], v.shape))
+
+
+def _decode_step_xla(state, layer, q, k, v, a, beta, active):
+    S = jax.lax.dynamic_index_in_dim(state, layer, 0, keepdims=False)
+    S1 = a[..., None] * S
+    u = beta[..., None] * (v - jnp.sum(S1 * k[..., None], axis=2))
+    S1 = S1 + k[..., None] * u[:, :, None, :]
+    o = jnp.sum(S1 * q[..., None], axis=2)
+    S1 = jnp.where(active[:, None, None, None], S1, S)
+    return jax.lax.dynamic_update_index_in_dim(state, S1, layer, 0), o
+
+
+def kda_decode_step(state, layer, q, k, v, g, beta, active, *,
+                    kernel=None):
+    """The recurrence's one-token form on the slots' states, in place:
+    ``state`` [L, slots, H, dk, dv] float32 (every KDA layer's, stacked),
+    ``layer`` which of them; q, k, g [slots, H, dk], v [slots, H, dv],
+    beta [slots, H], active [slots] bool -> (state, o [slots, H, dv]
+    float32): S' = exp(g) . S, u = b (v - S'^T k), S = S' + k u^T, o = S^T
+    q, float32 throughout. A slot that is not active keeps its state bit
+    for bit (its o is junk). On a chip a Mosaic kernel (`kda_decode_step`)
+    that walks (slot, 16 heads) blocks of the one buffer, read and written
+    once where they lie (``input_output_aliases``), the layer picked by
+    the block index, as `decode_attention` picks its layer; on the CPU the
+    same step in `jax.numpy` (``kernel`` forces either, for the tests)."""
+    f32 = jnp.float32
+    q, k, v, beta = (x.astype(f32) for x in (q, k, v, beta))
+    a = jnp.exp(g.astype(f32))
+    kernel = not _use_interpret() if kernel is None else kernel
+    step = _decode_step_kernel if kernel else _decode_step_xla
+    with jax.named_scope("kda.step"):
+        return step(state, jnp.asarray(layer, jnp.int32), q, k, v, a, beta,
+                    active)

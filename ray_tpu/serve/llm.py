@@ -11,6 +11,7 @@ blocked in ``__call__`` or ``stream`` is a request in that engine's queue.
 
 from __future__ import annotations
 
+import functools
 from typing import List, Optional, Sequence
 
 import numpy as np
@@ -20,25 +21,79 @@ from ray_tpu.serve.multiplex import _request_sent_time
 
 
 def _replica_params(cfg, checkpoint_dir: Optional[str], seed: int):
-    """A replica's weights: the checkpoint's, or random ones from ``seed``.
-    Called before the replica's first compile, so it also places the
-    persistent compilation cache, and keeps every program in it: a
-    replica's first requests run dozens of sub-second programs (prefill
-    per bucket and group size, decode, glue), which JAX's default
-    one-second threshold would compile again in every new replica."""
+    """A replica's weights: the checkpoint's, or random ones from ``seed``
+    as the engine holds them (`drawn_serving_params`). Called before the
+    replica's first compile, so it also places the persistent compilation
+    cache, and keeps every program in it: a replica's first requests run
+    dozens of sub-second programs (prefill per bucket and group size,
+    decode, glue), which JAX's default one-second threshold would compile
+    again in every new replica."""
     import jax
 
-    from ray_tpu.models.transformer import init_params
     from ray_tpu.utils.compile_cache import enable_compile_cache
 
     enable_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     if checkpoint_dir is None:
-        return init_params(jax.random.key(seed), cfg)
+        return drawn_serving_params(cfg, seed)
     import pickle
 
     with open(checkpoint_dir, "rb") as f:
         return jax.tree.map(np.asarray, pickle.load(f))
+
+
+def drawn_serving_params(cfg, seed: int):
+    """``serving_params(init_params(key(seed), cfg), cfg)``, the same
+    values, made a matrix at a time: the initialiser runs with its draws
+    put off (keys, shapes and scales as it derives them), then each matrix
+    is drawn and converted to the dtype the engine holds it in by ONE
+    jitted program, shared by the matrices of one shape and scale. The
+    float32 tree never stands whole on the device: the largest float32
+    buffer alive is one leaf's. A replica of a model whose float32 copy (4
+    bytes a parameter) is larger than the chip starts by this rule, and so
+    does every other."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.transformer import init_params, read_in_float32
+
+    in_float32 = read_in_float32(cfg)
+    plan = init_params(jax.random.key(seed), cfg,
+                       normal=lambda *draw: functools.partial(_drawn, *draw))
+
+    def held(path, leaf):
+        want = jnp.dtype(jnp.float32 if path[-1].key in in_float32
+                         else cfg.dtype)
+        if callable(leaf):
+            return leaf(jnp.dtype(cfg.param_dtype), want)
+        return leaf if leaf.dtype == want else leaf.astype(want)
+
+    return jax.tree_util.tree_map_with_path(held, plan)
+
+
+@functools.lru_cache(maxsize=None)
+def _drawn_program():
+    """`moe.scaled_normal`'s arithmetic in ``made``, the dtype the
+    initialiser makes a matrix in, then as a replica holds it: one compiled
+    program a (shape, scale, dtypes). The barrier keeps the compiler from
+    folding the scale into the normal's own constant, which the
+    initialiser's separate steps cannot do either: the values are its to
+    the bit (tests/test_replica_params.py), at the cost of the one float32
+    leaf standing between the two halves. Made on first use: this module
+    imports no JAX at the top (a benchmark's driver process imports it and
+    must stay off the chip)."""
+    import jax
+    import jax.numpy as jnp
+
+    def draw(key, shape, scale, made, held):
+        x = jax.lax.optimization_barrier(
+            jax.random.normal(key, shape, jnp.float32))
+        return (x * scale).astype(made).astype(held)
+    return jax.jit(draw, static_argnums=(1, 2, 3, 4))
+
+
+def _drawn(key, shape, scale, made, held):
+    return _drawn_program()(key, shape, scale, made, held)
 
 
 def _tpu_lease(chips: int) -> dict:

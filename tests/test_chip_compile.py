@@ -18,6 +18,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -665,3 +666,114 @@ def test_kimi_linear_train_step_fits_one_chip_at_the_depth_its_file_states(
         for d in m.group(1).split(","):
             n *= int(d)
         assert n <= largest, m.group(0)
+
+
+def _sized_ops(text: str, shape, ops: str) -> list:
+    """`name = type[...] <op>(...)` lines of compiled text whose result has
+    ``shape``'s dimensions (in any order, 1s dropped), for the ops of the
+    alternation ``ops``."""
+    want = sorted(d for d in shape if d > 1)
+    found = []
+    for m in re.finditer(
+            r"^\s*(?:ROOT )?(\S+) = \w+\[([\d,]+)\]\S* (" + ops + r")\(",
+            text, re.M):
+        if sorted(int(d) for d in m.group(2).split(",")
+                  if int(d) > 1) == want:
+            found.append(f"{m.group(1)}: {m.group(3)}")
+    return found
+
+
+def test_solar_open2_serve_programs_compile_and_move_no_state(one_chip,
+                                                              mosaic):
+    """The cell `solar-open2-250b.batch-closed-128` as the benchmark runs
+    it (its config file's depth and share, 128 slots x 1,280 positions,
+    decode chunks of 4, a prefill group of 4 x 1,024): both served
+    programs compile for one chip (a compile that returns fits) with the
+    weights as the engine holds them. Decode runs the two named kernels
+    and the grouped matmuls, updates the 1.6 GB of KDA states through
+    `kda_decode_step` where they lie (aliased through the kernel and the
+    two loops: nothing copies, converts, selects over or scatters into a
+    whole-state-sized result, and nothing materialises one layer's [slots,
+    64, 128, 128] slab on the way in or out), reads keys and values
+    through `decode_attention` alone (no layer slab of the ONE attention
+    layer's cache either) and converts no weight but the head. Prefill runs
+    the scan's forward kernel under `kda.prefill` and writes the group's
+    states a slot at a time."""
+    from benchmark.harness import spec
+    from ray_tpu.models.engine import (decode_slots, init_slot_cache,
+                                       prefill_slots)
+    from ray_tpu.models.transformer import init_params, serving_params
+
+    bench = spec.load_benchmark()
+    conf = spec.load_config(bench, "solar-open2-250b")
+    dep = spec.load_traffic("batch-closed-128")["deployment"]
+    cfg = spec.build_transformer_config(conf)
+    slots = dep["slots"]
+    max_len = dep["max_prompt_len"] + dep["max_new_tokens"]
+    assert (slots, max_len, cfg.n_layers) == (128, 1280, 4)
+    assert cfg.mixer_period == ("attention", "kda", "kda", "kda")
+    assert (cfg.moe_experts, cfg.held_experts, cfg.moe_top_k) == (320, 40, 8)
+    params = _on(jax.eval_shape(
+        lambda k: serving_params(init_params(k, cfg), cfg),
+        jax.random.key(0)), one_chip)
+    lay = params["layers"]
+    assert ["kda_wq" in x for x in lay] == [False, True, True, True]
+    assert lay[0]["wg"].shape == (1, 4096, 64, 128)
+    assert lay[1]["w_gate"].shape == (1, 40, 4096, 1280)
+    assert lay[0]["router"].shape == (1, 4096, 320)
+    assert lay[0]["router"].dtype == jnp.float32
+    cache = _on(jax.eval_shape(
+        lambda: init_slot_cache(cfg, slots, max_len)), one_chip)
+    state, tail = cache["kda_state"], cache["kda_tail"]
+    assert state.shape == (3, 128, 64, 128, 128) \
+        and state.dtype == jnp.float32
+    assert tail.shape == (3, 128, 3, 3 * 8192)
+    assert cache["k"].shape == (1, 128, 8, 1280, 128)
+    rng = _on(jax.eval_shape(lambda: jax.random.key(0)), one_chip)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    active = jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one_chip)
+    decode = decode_slots.lower(params, cache, i32(slots), active, rng, cfg,
+                                steps=4).compile()
+    assert _device_bytes(decode) < HBM_BYTES
+    text = decode.as_text()
+    assert re.search(r"%kda_decode_step\S* = [^\n]*custom-call\(", text)
+    assert re.search(r"%decode_attention\S* = [^\n]*custom-call\(", text)
+    assert "ragged-dot" in text
+    for scope in ("kda.step", "kda.conv", "attn.gate", "moe.experts"):
+        assert scope in text, scope
+    moved = "copy|convert|select|scatter|dynamic-slice|fusion|transpose"
+    assert _sized_ops(text, state.shape, moved) == []
+    assert _sized_ops(text, state.shape[1:], moved) == []
+    assert _whole_cache_ops(text, cache["k"].shape) == []
+    assert _layer_slab_ops(text, cache["k"].shape[1:]) == []
+    # (a [slots, d] activation has the dimensions of the rank-128 pairs'
+    # [d, 128] matrices: those converts are rows, not weights)
+    # (of 2 M numbers or more, and not a row buffer: [slots, ...] rows
+    # have the dimensions of the rank-128 pairs' matrices and, times top-k,
+    # of `wk`; the convolutions' taps are read as float32, 32 K numbers)
+    def weights_converted(text, rows=slots):
+        shapes = {tuple(eval(c.split(": ")[1])) for c in _weight_converts(
+            text, params, but=params["lm_head"].shape)}
+        return sorted(s for s in shapes
+                      if np.prod(s) >= 2 << 20 and s[0] != rows)
+    assert weights_converted(text) == []
+    # the states are the program's largest buffer and it holds them once:
+    # all its temporaries together are smaller than they are
+    state_bytes = 4 * int(np.prod(state.shape))
+    assert decode.memory_analysis().temp_size_in_bytes < state_bytes
+
+    K, P = 4, dep["max_prompt_len"]
+    prefill = prefill_slots.lower(params, cache, i32(K, P), i32(K), i32(K),
+                                  rng, cfg).compile()
+    assert _device_bytes(prefill) < HBM_BYTES
+    text = prefill.as_text()
+    kernels = _kernels(text)
+    assert any(key.startswith("tpu_custom_call:kda_scan_fwd")
+               and "kda.prefill" in scope for key, scope in kernels)
+    assert "ragged-dot" in text
+    assert _sized_ops(text, state.shape, "copy|convert|select|scatter") == []
+    assert prefill.memory_analysis().temp_size_in_bytes < state_bytes
+    assert weights_converted(text, rows=K * P) == []
