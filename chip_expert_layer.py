@@ -55,6 +55,16 @@ configuration brought.
   the rows past the sum of the group sizes is the difference between
   ``none_held`` and ``all_held``.
 
+``--rows`` (`rows`, `holds_rows`): the grouped matmul of a row buffer one
+row tile long (`ops.grouped_matmul`, what a decode substep's expert stage
+runs) alone against `jax.lax.ragged_dot`, at the Solar cell's widths and
+held count, 512 x 4096 over 40 experts of 4096 x 1280 and the way back, on
+rows as decode has them (128 live over 31-32 touched groups) and as a small
+prefill group has them (512 live, 13 a group): the largest difference (one
+bf16 rounding of the largest output at most), milliseconds a call of each
+over a stream of calls, and the GB/s and share of the memory roofline the
+TOUCHED groups' weights cross at.
+
 The exit code is 0 only when every check holds for every seed. It is no
 benchmark: the seconds are information only. Off the TPU it refuses, unless
 ``--toy`` asks for toy widths and a few rows (what
@@ -335,6 +345,102 @@ def holds_share(kernel_out: dict, share_out: dict) -> dict:
     return checks
 
 
+ROWS_CASES = {"decode": (128, 32), "prefill64": (512, 40)}
+
+
+def _group_sizes(rng, groups, live, touched):
+    """``live`` rows over ``touched`` of ``groups`` groups, each touched
+    one holding a row at least, the untouched ones anywhere between."""
+    import numpy as np
+
+    sizes = np.zeros(groups, np.int64)
+    on = rng.choice(groups, size=touched, replace=False)
+    sizes[on] = 1 + rng.multinomial(live - touched,
+                                    np.full(touched, 1 / touched))
+    return sizes
+
+
+def _stream_ms(fn, *args, calls=50):
+    """Milliseconds a call with ``calls`` of them in flight: the device
+    runs them back to back, so the host's dispatch is not in the number."""
+    import jax
+
+    jax.block_until_ready(fn(*args))
+    times = []
+    for _ in range(3):
+        t0 = time.perf_counter()
+        outs = [fn(*args) for _ in range(calls)]
+        jax.block_until_ready(outs)
+        times.append((time.perf_counter() - t0) / calls)
+    return 1e3 * statistics.median(times)
+
+
+def rows(seed: int, conf: dict, n_rows=512, cases=None,
+         **overrides) -> dict:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark.harness import spec
+    from ray_tpu.ops.grouped_matmul import grouped_matmul_rows
+
+    cfg = spec.build_transformer_config(conf, **overrides)
+    d, f, G = cfg.d_model, cfg.d_ff, cfg.held_experts
+    rng = np.random.default_rng(spec.seed32(seed))
+    keys = jax.random.split(jax.random.key(spec.seed32(seed)), 4)
+    dev = jax.devices()[0]
+    peak = spec.device_peaks(dev.device_kind) if dev.platform == "tpu" \
+        else None
+    ragged = jax.jit(jax.lax.ragged_dot)
+    kernel = jax.jit(grouped_matmul_rows)
+    out = {"rows": n_rows, "groups": G, "cases": {}}
+    for way, (d_in, d_out, kx, kw) in {"in": (d, f, *keys[:2]),
+                                       "back": (f, d, *keys[2:])}.items():
+        w = (jax.random.normal(kw, (G, d_in, d_out)) * d_in ** -0.5).astype(
+            jnp.bfloat16)
+        for case, (live, touched) in (cases or ROWS_CASES).items():
+            sizes = _group_sizes(rng, G, live, touched)
+            in_group = (jnp.arange(n_rows) < live)[:, None]
+            xs = jnp.where(in_group, jax.random.normal(
+                kx, (n_rows, d_in)), 0).astype(jnp.bfloat16)
+            gs = jnp.asarray(sizes, jnp.int32)
+            want = jnp.where(in_group, ragged(xs, w, gs), 0).astype(
+                jnp.float32)
+            got = kernel(xs, w, gs).astype(jnp.float32)
+            line = {"live": live, "touched": touched,
+                    "largest_group": int(sizes.max()),
+                    "largest_difference": float(jnp.abs(got - want).max()),
+                    "largest_output": float(jnp.abs(want).max()),
+                    "rows_that_differ": int(jnp.sum(jnp.any(got != want,
+                                                            axis=1)))}
+            need = 2 * touched * d_in * d_out
+            for name, fn in (("ragged_dot", ragged), ("kernel", kernel)):
+                ms = _stream_ms(fn, xs, w, gs)
+                line[name + "_ms"] = ms
+                line[name + "_gb_per_s"] = need / ms / 1e6
+                if peak:
+                    line[name + "_roofline_pct"] = \
+                        100 * need / peak["hbm_bytes_per_s"] / (ms / 1e3)
+            out["cases"][f"{way}:{case}"] = line
+    return out
+
+
+def holds_rows(r: dict) -> dict:
+    """Within one rounding of `ragged_dot`: bf16 keeps 8 bits, so two
+    roundings of one float32 sum differ by 2^-8 of it at most."""
+    return {f"{name}:one_rounding_apart":
+            line["largest_difference"] <= 2 ** -8 * line["largest_output"]
+            for name, line in r["cases"].items()}
+
+
+def _rows_alone(seed, conf, toy):
+    toy = dict(n_rows=32, cases={"decode": (8, 5), "prefill64": (32, 8)},
+               d_model=128, d_ff=128, moe_experts=16, moe_held_experts=8,
+               moe_first_expert=0, moe_top_k=2) if toy else {}
+    r = rows(seed, conf, **toy)
+    return r, holds_rows(r)
+
+
 def _whole_layer(seed, conf, toy):
     r = compare(seed, conf, **(dict(shape=(1, 32), **TOY) if toy else {}))
     return r, holds(r)
@@ -360,6 +466,9 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--config", nargs="+", choices=sorted(RUNS),
                     default=sorted(RUNS))
+    ap.add_argument("--rows", action="store_true",
+                    help="the short row buffer's grouped matmul alone, at "
+                    "solar-open2-250b's widths, and nothing else")
     ap.add_argument("--seeds", type=int, nargs="+", default=[3200000001])
     ap.add_argument("--toy", action="store_true",
                     help="toy widths and rows, any platform")
@@ -374,16 +483,18 @@ def main(argv=None) -> int:
         print(json.dumps({"ok": False, "error": "no TPU: " + dev.platform}))
         return 1
     ok = True
-    for name in args.config:
+    runs = {"solar-open2-250b": _rows_alone} if args.rows else RUNS
+    names = list(runs) if args.rows else args.config
+    for name in names:
         conf = spec.load_config(spec.load_benchmark(), name)
         for seed in args.seeds:
-            r, checks = RUNS[name](seed, conf, args.toy)
+            r, checks = runs[name](seed, conf, args.toy)
             ok = ok and all(checks.values())
             print(json.dumps(dict(r, config=name, seed=seed,
                                   platform=dev.platform,
                                   device_kind=dev.device_kind,
                                   checks=checks)), flush=True)
-    print(json.dumps({"ok": ok, "configs": args.config,
+    print(json.dumps({"ok": ok, "configs": names,
                       "seeds": len(args.seeds)}))
     return 0 if ok else 1
 

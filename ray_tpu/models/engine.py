@@ -114,8 +114,9 @@ SlotCache = Dict[str, jax.Array]
 #              rule's state, and "kda_tail" [L_kda, B, taps - 1, 3 x H x
 #              dk], the projected rows of q, k, v the short convolutions
 #              reach back to
-# and, where layers have experts, "moe_counts" [2] float32: the held
-# experts fetched and the assignments that fell on them in the LAST decode
+# and, where layers have experts, "moe_counts" [3] float32: the held
+# experts fetched, the assignments that fell on them and the layers whose
+# grouped matmuls ran the short row buffer's kernel in the LAST decode
 # chunk (the host adds them up as it fetches the chunk's tokens).
 # A model of attention layers alone holds k, v, pos and start, as ever.
 
@@ -137,7 +138,7 @@ def init_slot_cache(cfg: TransformerConfig, slots: int,
             kda_tail=jnp.zeros((n_kda, slots, cfg.kda_conv - 1, 3 * H * hd),
                                cfg.dtype))
     if cfg.moe_experts:
-        cache["moe_counts"] = jnp.zeros((2,), jnp.float32)
+        cache["moe_counts"] = jnp.zeros((3,), jnp.float32)
     cache.update(pos=jnp.zeros((slots,), jnp.int32),
                  start=jnp.zeros((slots,), jnp.int32))
     return cache
@@ -357,7 +358,8 @@ def _decode_one(params: Params, cache: SlotCache, tokens: jax.Array,
             if "router" in lp and "moe_counts" in carry:
                 assignments = x.shape[0] * x.shape[1] * cfg.moe_top_k
                 carry["moe_counts"] = carry["moe_counts"] + jnp.stack(
-                    [stats["fetched"], stats["held"] * assignments])
+                    [stats["fetched"], stats["held"] * assignments,
+                     stats["rows_kernel"]])
             x = (x + down).astype(cfg.dtype)
         return dict(carry, x=x), tuple(zip(*rows))
 
@@ -644,11 +646,12 @@ class InferenceEngine:
             # layer and substep: all it holds) and the (token, expert)
             # assignments routed; of the chunks delivered, counted on the
             # device and fetched with their tokens: the held experts that
-            # got a row (whose weights a substep fetched) and the
-            # assignments that fell on held experts
+            # got a row (whose weights a substep fetched), the
+            # assignments that fell on held experts and the layers (a layer
+            # and substep) whose grouped matmuls were `ops.grouped_matmul`'s
             "kda_state_updates": 0, "moe_expert_calls": 0,
             "moe_assignments": 0, "moe_expert_fetches": 0,
-            "moe_held_assignments": 0,
+            "moe_held_assignments": 0, "moe_rows_kernel_layers": 0,
             "slow_s": 0.0, "slow_count": 0,
             # the ended streams' ledgers (`_fold_stream`): stream_open_s =
             # stream_wait_s + stream_held_s; pickup lag over stream_tokens
@@ -1173,9 +1176,10 @@ class InferenceEngine:
             big = parts[0] if len(parts) == 1 else np.concatenate(
                 parts, axis=1)
             self.stats["fetches"] += 1
-            for fetched, held in counts:
+            for fetched, held, kernel in counts:
                 self.stats["moe_expert_fetches"] += int(fetched)
                 self.stats["moe_held_assignments"] += int(round(held))
+                self.stats["moe_rows_kernel_layers"] += int(kernel)
         return big
 
     def _deliver_locked(self, big: np.ndarray, pending) -> None:

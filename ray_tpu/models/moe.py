@@ -14,7 +14,14 @@ One path, four stages, each under a `jax.named_scope` a profile groups by:
                 stable sort), group sizes by a count per expert, the
                 tokens' rows gathered into that order
   moe.experts   SwiGLU per expert as three grouped matmuls over the ragged
-                groups (`jax.lax.ragged_dot`)
+                groups: `jax.lax.ragged_dot`, whose tiles are the MXU's
+                (hundreds of rows a group: training, prefill), except on a
+                row buffer of at most one ROW_TILE on the chip (a decode
+                substep's front, a short prefill's: a dozen rows a group
+                at most, the time is the weights' fetch), which
+                `ops.grouped_matmul` streams each touched expert's
+                weights through once (`_rows_kernel`: static shapes
+                alone decide)
   moe.combine   rows gathered back into token order and summed over the k
                 slots, weighted by the routing weights, in float32
   moe.shared    where `cfg.moe_shared_d_ff`: a dense SwiGLU every token
@@ -62,6 +69,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
+from ray_tpu.ops import grouped_matmul
 from ray_tpu.parallel.sharding import with_logical_constraint as _wlc
 
 Params = Dict[str, Any]
@@ -215,6 +223,22 @@ def front_rows(assignments: int, held: int, experts: int) -> int:
     return min(rows, assignments)
 
 
+def _on_chip() -> bool:
+    return jax.default_backend() != "cpu"
+
+
+def _rows_kernel(rows: int, d: int, f: int, dtype) -> bool:
+    """Whether the three grouped matmuls of a buffer of ``rows`` rows,
+    [rows, d] x [held, d, f] twice and the way back, run
+    `grouped_matmul_rows` and not `jax.lax.ragged_dot`: the buffer is one
+    row tile at most, so whatever the held count a group has few rows and
+    the weights' fetch is the time, and the kernel takes the shapes. Off
+    the chip `ragged_dot` throughout (the kernel's tests run it in the
+    interpreter)."""
+    return (rows <= ROW_TILE and _on_chip()
+            and grouped_matmul.takes(rows, d, f, dtype))
+
+
 def _expert_rows(k, rows, masked, x, order, inv, group_sizes, top_p,
                  w_gate, w_up, w_down):
     """x [N, d] -> y [N, d]: the held experts' SwiGLU of every token,
@@ -228,11 +252,12 @@ def _expert_rows(k, rows, masked, x, order, inv, group_sizes, top_p,
             in_group = (jnp.arange(rows) < group_sizes.sum())[:, None]
             xs = jnp.where(in_group, xs, 0)
     with jax.named_scope("moe.experts"):
-        gate = jax.lax.ragged_dot(xs, w_gate.astype(dtype), group_sizes)
-        up = jax.lax.ragged_dot(xs, w_up.astype(dtype), group_sizes)
+        dot = grouped_matmul.grouped_matmul_rows if _rows_kernel(
+            rows, x.shape[1], w_gate.shape[2], dtype) else jax.lax.ragged_dot
+        gate = dot(xs, w_gate.astype(dtype), group_sizes)
+        up = dot(xs, w_up.astype(dtype), group_sizes)
         act = jax.nn.silu(gate) * up                      # [rows, f]
-        out = jax.lax.ragged_dot(act, w_down.astype(dtype),
-                                 group_sizes)             # [rows, d]
+        out = dot(act, w_down.astype(dtype), group_sizes)  # [rows, d]
     with jax.named_scope("moe.combine"):
         if masked:
             out = jnp.where(in_group, out, 0)
@@ -334,7 +359,9 @@ def moe_layer(h: jax.Array, lp: Params, cfg, mesh: Optional[Mesh] = None
     where the stage ran on the sorted buffer's front (or there is no
     front to miss), 0.0 where the rows overflowed it, stats["fetched"] the
     held experts that got at least one row (the weights the grouped matmuls
-    had to fetch: what a decode step of a few rows a chip costs).
+    had to fetch: what a decode step of a few rows a chip costs),
+    stats["rows_kernel"] 1.0 where the grouped matmuls that ran were
+    `ops.grouped_matmul`'s (`_rows_kernel`).
     """
     B, T, d = h.shape
     E, k = cfg.moe_experts, cfg.moe_top_k
@@ -389,6 +416,11 @@ def moe_layer(h: jax.Array, lp: Params, cfg, mesh: Optional[Mesh] = None
     aux = E * jnp.sum(per_expert / N
                       * probs.mean(axis=0)[first:first + held])
     load = per_expert.max() * held / jnp.maximum(on_held, 1.0)
+    # a front is whole row tiles, so the buffer it overflows into is longer
+    # than one: where a layer takes the kernel, it takes it on `rows`
+    kernel = compact if _rows_kernel(rows, d, cfg.d_ff, cfg.dtype) \
+        else jnp.zeros((), jnp.float32)
     return y, {"aux": aux, "load": load, "compact": compact,
+               "rows_kernel": kernel,
                "held": jnp.asarray(on_held / (N * k), jnp.float32),
                "fetched": jnp.sum(group_sizes > 0).astype(jnp.float32)}

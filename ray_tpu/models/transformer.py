@@ -563,7 +563,7 @@ def refuse_unserved(cfg: TransformerConfig):
 def _no_moe_stats():
     zero = jnp.zeros((), jnp.float32)
     return {"aux": zero, "load": zero, "held": zero, "compact": zero,
-            "fetched": zero}
+            "fetched": zero, "rows_kernel": zero}
 
 
 def ffn_block(h, lp, cfg: TransformerConfig, mesh: Optional[Mesh] = None):
@@ -573,7 +573,8 @@ def ffn_block(h, lp, cfg: TransformerConfig, mesh: Optional[Mesh] = None):
     "load": largest expert group over the mean group, "held": share of
     the assignments that fall on held experts, "compact": 1.0 where the
     layer's rows fit the sorted buffer's front, "fetched": held experts
-    with at least one row}, zeros for a dense layer."""
+    with at least one row, "rows_kernel": 1.0 where the grouped matmuls
+    were `ops.grouped_matmul`'s}, zeros for a dense layer."""
     if "router" in lp:
         return moe_layer(h, lp, cfg, mesh)
     return swiglu(h.astype(cfg.dtype), lp["w_gate"], lp["w_up"],

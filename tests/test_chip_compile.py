@@ -61,14 +61,16 @@ def mosaic(monkeypatch):
     """Lower the Mosaic kernels, not the interpreter the CPU backend picks,
     and let decode pick its kernel by the cache's shape as it does on a
     chip (under the CPU backend it takes the masked contraction)."""
-    from ray_tpu.models import engine
+    from ray_tpu.models import engine, moe
 
     fa = importlib.import_module("ray_tpu.ops.flash_attention")
     da = importlib.import_module("ray_tpu.ops.decode_attention")
     kda = importlib.import_module("ray_tpu.ops.kda")
-    for module in (fa, da, kda):
+    gm = importlib.import_module("ray_tpu.ops.grouped_matmul")
+    for module in (fa, da, kda, gm):
         monkeypatch.setattr(module, "_use_interpret", lambda: False)
-    monkeypatch.setattr(engine, "_on_chip", lambda: True)
+    for module in (engine, moe):
+        monkeypatch.setattr(module, "_on_chip", lambda: True)
     engine.decode_slots.clear_cache()
     yield
     engine.decode_slots.clear_cache()
@@ -439,8 +441,26 @@ def _front_holds_no_whole_buffer(text, assignments, front, d, f, at_least):
         assert scope in text, scope
 
 
+def _keeps_ragged_dot(monkeypatch, lower, text):
+    """A program whose row buffers are all longer than one row tile:
+    its compiled ``text`` holds no `ragged-dot-rows` kernel, and what
+    ``lower()`` (a fresh trace each call) hands the compiler is, to the
+    letter, what it hands it with that kernel ruled out: the parent's
+    program, so the parent's compiled text and operation histogram."""
+    from ray_tpu.models import moe
+
+    assert "ragged-dot-rows" not in text
+    lowered = []
+    for ruled_out in (False, True):     # one call site: a kernel's
+        with monkeypatch.context() as m:    # payload carries the stack
+            if ruled_out:
+                m.setattr(moe, "_rows_kernel", lambda *a: False)
+            lowered.append(lower().as_text())
+    assert "ragged_dot" in lowered[0] and lowered[0] == lowered[1]
+
+
 def test_olmoe_train_step_fits_one_chip_without_a_capacity_tensor(
-        one_chip, mosaic):
+        one_chip, mosaic, monkeypatch):
     """The cell `olmoe-1b-7b.train-4k` as the benchmark runs it (its
     config file's depth, 4 x 4096, bf16 weights and moments): the step
     compiles for one chip (the compiler refuses a program over the chip's
@@ -466,6 +486,9 @@ def test_olmoe_train_step_fits_one_chip_without_a_capacity_tensor(
                                             sharding=one_chip)}
     text = make_train_step(cfg, tx).lower(state, batch).compile().as_text()
     assert "ragged-dot" in text and "tpu_custom_call" in text
+    _keeps_ragged_dot(monkeypatch,
+                      lambda: make_train_step(cfg, tx).lower(state, batch),
+                      text)
     # every expert is held: no front, no conditional around the experts
     assert not _grouped_matmul_conditionals(text)
     E, k = cfg.moe_experts, cfg.moe_top_k
@@ -481,7 +504,7 @@ def test_olmoe_train_step_fits_one_chip_without_a_capacity_tensor(
 
 
 def test_glm_train_step_fits_one_chip_at_the_depth_its_file_states(
-        one_chip, mosaic):
+        one_chip, mosaic, monkeypatch):
     """The cell `glm-4.7-flash.train-4k-8rows` as the benchmark runs it
     (its config file's depth, 8 x 4096, bf16 weights and moments): the
     step compiles for one chip (a compile that returns fits), holds the
@@ -515,6 +538,8 @@ def test_glm_train_step_fits_one_chip_at_the_depth_its_file_states(
     text = make_train_step(cfg, tx).lower(
         _on(shapes, one_chip), batch).compile().as_text()
     assert "ragged-dot" in text and "tpu_custom_call" in text
+    _keeps_ragged_dot(monkeypatch, lambda: make_train_step(cfg, tx).lower(
+        _on(shapes, one_chip), batch), text)
     for scope in ("mla.q", "mla.kv", "mla.rope", "mla.out", "moe.shared",
                   "mtp.merge", "mtp.block", "mtp.head"):
         assert scope in text, scope
@@ -603,7 +628,7 @@ def test_kda_scan_compiles_forward_and_backward_at_the_cells_shape(one_chip,
 
 
 def test_kimi_linear_train_step_fits_one_chip_at_the_depth_its_file_states(
-        one_chip, mosaic):
+        one_chip, mosaic, monkeypatch):
     """The cell `kimi-linear-48b-a3b.train-16k-2rows` as the benchmark
     runs it (its config file's depth, 2 x 16,384, bf16 weights and
     moments): the step compiles for one chip (a compile that returns
@@ -645,6 +670,8 @@ def test_kimi_linear_train_step_fits_one_chip_at_the_depth_its_file_states(
     text = make_train_step(cfg, tx).lower(
         _on(shapes, one_chip), batch).compile().as_text()
     assert "ragged-dot" in text and "tpu_custom_call" in text
+    _keeps_ragged_dot(monkeypatch, lambda: make_train_step(cfg, tx).lower(
+        _on(shapes, one_chip), batch), text)
     for scope in ("kda.proj", "kda.conv", "kda.gate", "kda.scan", "kda.out",
                   "mla.q", "mla.kv", "mla.out", "moe.shared"):
         assert scope in text, scope
@@ -684,14 +711,17 @@ def _sized_ops(text: str, shape, ops: str) -> list:
 
 
 def test_solar_open2_serve_programs_compile_and_move_no_state(one_chip,
-                                                              mosaic):
+                                                              mosaic,
+                                                              monkeypatch):
     """The cell `solar-open2-250b.batch-closed-128` as the benchmark runs
     it (its config file's depth and share, 128 slots x 1,280 positions,
     decode chunks of 4, a prefill group of 4 x 1,024): both served
     programs compile for one chip (a compile that returns fits) with the
     weights as the engine holds them. Decode runs the two named kernels
-    and the grouped matmuls, updates the 1.6 GB of KDA states through
-    `kda_decode_step` where they lie (aliased through the kernel and the
+    and the grouped matmuls (on the 512-row front the short row buffer's
+    kernel, three a layer, fed the weights where they lie; `ragged_dot` on
+    the whole buffer the front overflows into), updates the 1.6 GB of KDA
+    states through `kda_decode_step` where they lie (aliased through the kernel and the
     two loops: nothing copies, converts, selects over or scatters into a
     whole-state-sized result, and nothing materialises one layer's [slots,
     64, 128, 128] slab on the way in or out), reads keys and values
@@ -741,9 +771,24 @@ def test_solar_open2_serve_programs_compile_and_move_no_state(one_chip,
     text = decode.as_text()
     assert re.search(r"%kda_decode_step\S* = [^\n]*custom-call\(", text)
     assert re.search(r"%decode_attention\S* = [^\n]*custom-call\(", text)
-    assert "ragged-dot" in text
     for scope in ("kda.step", "kda.conv", "attn.gate", "moe.experts"):
         assert scope in text, scope
+    # a layer's expert stage: the front's branch holds the three rows
+    # kernels and nothing beside them that moves an expert stack's weights
+    # or a block of them; the whole buffer's keeps `ragged_dot`
+    conds = _grouped_matmul_conditionals(text)
+    assert len(conds) == cfg.n_layers
+    held, d, f = lay[1]["w_gate"].shape[1:]
+    weights = "copy|convert|slice|dynamic-slice|fusion|transpose|select"
+    for whole, front in conds:
+        calls = [x for x in front if "tpu_custom_call" in x]
+        assert len(calls) == 3 and all(
+            re.search(r"%ragged-dot-rows\S* = ", x) for x in calls), calls
+        assert not any("ragged-dot-rows" in x for x in whole)
+        assert sum("tpu_custom_call" in x and "ragged-dot" in x
+                   for x in whole) >= 3
+        for shape in ((held, d, f), (d, f), (d, f // 2), (f, d // 2)):
+            assert _sized_ops("\n".join(front), shape, weights) == []
     moved = "copy|convert|select|scatter|dynamic-slice|fusion|transpose"
     assert _sized_ops(text, state.shape, moved) == []
     assert _sized_ops(text, state.shape[1:], moved) == []
@@ -774,6 +819,12 @@ def test_solar_open2_serve_programs_compile_and_move_no_state(one_chip,
     assert any(key.startswith("tpu_custom_call:kda_scan_fwd")
                and "kda.prefill" in scope for key, scope in kernels)
     assert "ragged-dot" in text
+
+    def lower_prefill():
+        prefill_slots.clear_cache()
+        return prefill_slots.lower(params, cache, i32(K, P), i32(K), i32(K),
+                                   rng, cfg)
+    _keeps_ragged_dot(monkeypatch, lower_prefill, text)
     assert _sized_ops(text, state.shape, "copy|convert|select|scatter") == []
     assert prefill.memory_analysis().temp_size_in_bytes < state_bytes
     assert weights_converted(text, rows=K * P) == []

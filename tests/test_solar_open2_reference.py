@@ -231,7 +231,8 @@ def test_an_inactive_slots_state_is_untouched_by_a_chunk():
         np.testing.assert_array_equal(after[:, 1:], before[name][:, 1:])
         assert np.abs(after[:, 0] - before[name][:, 0]).max() > 0
     assert list(np.asarray(cache["pos"])) == [68, 64, 0]
-    fetched, held = np.asarray(cache["moe_counts"])
+    fetched, held, kernel = np.asarray(cache["moe_counts"])
+    assert kernel == 0      # off the chip `ragged_dot` throughout
     # 4 substeps x 4 layers x 4 held experts offered; 3 rows x 4 a token
     assert 0 < fetched <= 4 * 4 * 4 and 0 < held <= 4 * 4 * 3 * 4
     assert fetched == int(fetched) and held == int(held)
