@@ -29,6 +29,16 @@ error beside the limit, `ok` as the cell's `correct` would have it, and
   published one (`TransformerConfig.init_depth`); the reference draws by
   the same rule, so this reads what the field is worth to the comparison.
 
+and for a cell of differential attention behind windows
+(``--workload phi-4-mini-flash-reasoning.reason-closed-64``), beside
+``program`` and ``fp8_weights``:
+
+- ``no_window`` (expect differ): a window layer attends to every earlier
+  position of its row in prefill, and a decode step reads the ring's oldest
+  place too.
+- ``lam_fixed`` (expect differ): lam = lam0(layer), the four learned vectors
+  left out.
+
 A recorded control's reading IS the result: `ok` true there says that this
 comparison cannot tell that precision from the stated one (PERF.md
 section 6, PR 42, has the readings and what follows from them). The exit
@@ -54,7 +64,18 @@ TOY_DEPLOYMENT = dict(slots=4, max_prompt_len=64, max_new_tokens=8)
 TOY_LENGTHS = [20, 51, 7, 64]
 EXPECT = {"program": "agree", "fp8_weights": "differ",
           "write_strength_halved": "differ", "state_bf16": None,
-          "init_depth_none": None}
+          "init_depth_none": None, "no_window": "differ",
+          "lam_fixed": "differ"}
+# the controls of an architecture, and its toy: all of the pattern's layers
+# at toy widths, a window the toy prompts outgrow
+CONTROLS = {
+    "solar_open2": ["program", "fp8_weights", "write_strength_halved",
+                    "state_bf16", "init_depth_none"],
+    "phi4flash": ["program", "fp8_weights", "no_window", "lam_fixed"]}
+TOYS = {"solar_open2": TOY,
+        "phi4flash": dict(vocab_size=96, d_model=32, n_heads=4, n_kv_heads=2,
+                          head_dim=8, d_ff=48, mamba_d_state=4,
+                          mamba_dt_rank=3, sliding_window=8)}
 
 
 @contextlib.contextmanager
@@ -97,6 +118,7 @@ def _fp8_in_place(eng):
     def through(x):
         x32 = x.astype(jnp.float32)
         scale = jnp.max(jnp.abs(x32)) / 240.0
+        scale = jnp.where(scale > 0, scale, 1.0)    # a leaf of zeros
         return (_round(x32 / scale, 4, 3) * scale).astype(x.dtype)
     leaves, tree = jax.tree.flatten(eng.params)
     eng.params = None
@@ -131,13 +153,50 @@ def state_bf16():
                     (engine, "kda_decode_step", kda_decode_step))
 
 
+def no_window():
+    """A window layer without its window: prefill's mask widened to every
+    valid earlier position (a position sees itself under either mask, so
+    the diagonal says which keys are no padding), decode's ring read
+    whole."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models import engine, generate
+
+    attend = generate._diff_attention
+
+    def _diff_attention(q, k, v, mask):
+        T = mask.shape[1]
+        valid = jnp.diagonal(mask, axis1=1, axis2=2)
+        return attend(q, k, v, jnp.tril(jnp.ones((T, T), bool))[None]
+                      & valid[:, None, :])
+
+    def _ring_mask(pos, start, window):
+        j = jnp.arange(window)[None, :]
+        held = pos[:, None] - 1 - (pos[:, None] - 1 - j) % window
+        return (held >= start[:, None]) & (held >= 0)
+    return _patched((generate, "_diff_attention", _diff_attention),
+                    (engine, "_ring_mask", _ring_mask))
+
+
+def lam_fixed():
+    import jax.numpy as jnp
+
+    from ray_tpu.models import engine, generate, transformer
+
+    def diff_out(o, lp, cfg, layer):
+        return transformer.diff_out(o, dict(
+            lp, diff_lambda=jnp.zeros_like(lp["diff_lambda"])), cfg, layer)
+    return _patched((generate, "diff_out", diff_out),
+                    (engine, "diff_out", diff_out))
+
+
 def run_control(replica, name: str, seed: int, lengths) -> dict:
     """`bench_check` of ``replica`` under control ``name``; the replica is
     left as it came but for ``fp8_weights`` (run it last)."""
     eng = replica.engine
     ctx, cfg = contextlib.nullcontext(), eng.cfg
-    if name == "state_bf16":
-        ctx = state_bf16()
+    if name in ("state_bf16", "no_window", "lam_fixed"):
+        ctx = globals()[name]()
     elif name == "write_strength_halved":
         eng.cfg = dataclasses.replace(cfg, kda_allow_neg_eigval=False)
     if name == "fp8_weights":
@@ -167,7 +226,8 @@ def main(argv=None) -> int:
                     default="solar-open2-250b.batch-closed-128")
     ap.add_argument("--seeds", type=int, nargs="+", default=[4200000501])
     ap.add_argument("--controls", nargs="+", choices=sorted(EXPECT),
-                    default=list(EXPECT))
+                    help="left out: every control of the cell's "
+                    "architecture")
     ap.add_argument("--lengths", type=int, nargs="+",
                     help="prompt lengths of the check's one group (left "
                     "out: the traffic file's `check.prompt_lens`)")
@@ -193,6 +253,9 @@ def main(argv=None) -> int:
         **(TOY_DEPLOYMENT if args.toy else {}))
     lengths = args.lengths or (
         TOY_LENGTHS if args.toy else traffic["check"]["prompt_lens"])
+    arch = spec.architecture_name(conf)
+    toy = TOYS[arch] if args.toy else {}
+    args.controls = args.controls or CONTROLS[arch]
     # the weights stay good until `fp8_weights`; `init_depth_none` draws its own
     order = sorted(args.controls, key=lambda n: (
         n == "init_depth_none", n == "fp8_weights"))
@@ -205,9 +268,8 @@ def main(argv=None) -> int:
                 if replica is not None:
                     replica.engine.shutdown()
                     del replica
-                over = dict(TOY if args.toy else {},
-                            **({"init_depth": None} if want == "none"
-                               else {}))
+                over = dict(toy, **({"init_depth": None} if want == "none"
+                                    else {}))
                 replica = BenchReplica(conf, platform=dev.platform,
                                        field_overrides=over,
                                        seed=spec.seed32(seed), **dep)

@@ -119,6 +119,47 @@ class TransformerConfig:
     # q, k, v, the decay's and the output gate's projections through a
     # width of `kda_gate_rank`.
     mixer_period: tuple = ("attention",)
+    # The same statement for a pattern that is no single period: segments
+    # ((kinds of one period, repeats), ...) from the first layer on, e.g.
+    # ((("mamba", "window"), 8), (("mamba", "attention"), 1), (("gmu",
+    # "cross"), 7)). None: ONE segment, `mixer_period` over the stack
+    # (what every configuration with one period states); given, it is the
+    # one statement and `mixer_period` is derived from it (a single
+    # segment's period, else every layer's kind in order). The further
+    # kinds a segment may name, beside "attention" and "kda":
+    #   "mamba"   a Mamba-1 selective scan (transformer.mamba_mixer; ops/
+    #             mamba.py): `mamba_d_state` states a channel, `mamba_expand`
+    #             x d_model channels, a causal depthwise convolution of
+    #             `mamba_d_conv` taps, dt through a rank of `mamba_dt_rank`
+    #             (None: ceil(d_model / 16))
+    #   "gmu"     a gated memory unit: W_out (m . silu(W_in n)), m the SAME
+    #             token's scan output (before the gate) of the nearest
+    #             mamba layer before it; no state
+    #   "window"  attention over the `sliding_window` positions that end at
+    #             the token itself
+    #   "cross"   queries of its own onto the keys and values of the
+    #             nearest "attention" layer before it; no K/V of its own
+    layer_pattern: Optional[tuple] = None
+    sliding_window: int = 0
+    mamba_d_state: int = 0
+    mamba_d_conv: int = 4
+    mamba_expand: int = 2
+    mamba_dt_rank: Optional[int] = None
+    # Differential attention (arXiv:2410.05258, as the SambaY decoders of
+    # arXiv:2507.06607 use it) on every attention, window and cross layer:
+    # query, key and value heads are taken in adjacent pairs; o1 = softmax(
+    # q1 k1^T) [v1 | v2], o2 = softmax(q2 k2^T) [v1 | v2]; the layer's
+    # output is RMSNorm(o1 - lam o2) (1 - lam0), lam = exp(lq1 . lk1) -
+    # exp(lq2 . lk2) + lam0, lam0 = 0.8 - 0.6 exp(-0.3 layer), the
+    # difference and the norm in float32. A query pair reads key/value
+    # pair (pair // (query pairs / key pairs)); the serving cache holds a
+    # token's keys and values as those pairs, [kv_heads / 2, 2 head_dim].
+    diff_attn: bool = False
+    # A bias on the attention projections (q, k, v and the output).
+    attn_bias: bool = False
+    # "rms": RMSNorm with a gain; "layer": LayerNorm with a gain and a bias
+    # (`rms_eps` is its eps), for the two norms of a layer and the final one.
+    norm: str = "rms"
     kda_heads: int = 0
     kda_head_dim: int = 0
     kda_conv: int = 4
@@ -159,6 +200,22 @@ class TransformerConfig:
             if not ok:
                 raise ValueError(f"TransformerConfig: {why}")
 
+        if self.layer_pattern is not None:
+            pattern = tuple((tuple(kinds), int(reps))
+                            for kinds, reps in self.layer_pattern)
+            derived = pattern[0][0] if len(pattern) == 1 else tuple(
+                kind for kinds, reps in pattern for kind in kinds * reps)
+            # (`dataclasses.replace` hands the derived period back in)
+            need(pattern and all(kinds and reps >= 1
+                                 for kinds, reps in pattern)
+                 and sum(len(kinds) * reps for kinds, reps in pattern)
+                 == self.n_layers and not self.moe_dense_layers
+                 and tuple(self.mixer_period) in (("attention",), derived),
+                 "layer_pattern is segments (kinds, repeats >= 1) that add "
+                 "up to n_layers, in place of mixer_period and of leading "
+                 "dense layers")
+            object.__setattr__(self, "layer_pattern", pattern)
+            object.__setattr__(self, "mixer_period", derived)
         object.__setattr__(self, "mixer_period", tuple(self.mixer_period))
         for name in ("dtype", "param_dtype"):    # a data file names them
             if isinstance(getattr(self, name), str):
@@ -185,8 +242,40 @@ class TransformerConfig:
             need(self.v_head_dim == self.head_dim,
                  "v_head_dim differs from head_dim without latent attention")
         period = self.mixer_period
-        need(period and set(period) <= {"attention", "kda"},
+        need(period and set(period) <= {"attention", "kda", "mamba", "gmu",
+                                        "window", "cross"},
              f"mixer_period {period!r}")
+        if {"window", "cross"} & set(period):
+            need(self.diff_attn and not self.moe_experts,
+                 f"mixer_period {period!r}: window and cross layers are "
+                 "built for differential attention and a dense feed-forward")
+            need("window" not in period or self.sliding_window >= 1,
+                 "a window layer needs sliding_window >= 1")
+        need(self.norm in ("rms", "layer"), f"norm {self.norm!r}")
+        kinds = [self.mixer_kind(i) for i in range(self.n_layers)]
+        if "mamba" in period:
+            need(self.mamba_d_state and self.mamba_d_conv >= 1
+                 and self.mamba_expand >= 1 and self.causal,
+                 "a mamba layer needs mamba_d_state, mamba_d_conv >= 1, "
+                 "mamba_expand >= 1 and a causal model")
+            if self.mamba_dt_rank is None:
+                object.__setattr__(self, "mamba_dt_rank",
+                                   -(-self.d_model // 16))
+        for kind, source in (("gmu", "mamba"), ("cross", "attention")):
+            need(all(source in kinds[:i] for i, k in enumerate(kinds)
+                     if k == kind),
+                 f"a {kind} layer reads the nearest {source} layer BEFORE it")
+        need(self.diff_attn or not self.attn_bias,
+             "attn_bias is built for differential attention")
+        if self.diff_attn:
+            need(self.n_heads % 2 == 0 and self.kv_heads % 2 == 0
+                 and (self.n_heads // 2) % (self.kv_heads // 2) == 0
+                 and self.causal and not self.use_rope
+                 and not (self.kv_lora_rank or self.qk_norm
+                          or self.attn_output_gate or self.attn_float32),
+                 "differential attention pairs adjacent heads (even n_heads "
+                 "and kv_heads) of a causal model without rotary positions, "
+                 "latents, q/k norms, an output gate or attn_float32")
         if "kda" in period:
             need(self.kda_heads and self.kda_head_dim and self.kda_gate_rank
                  and self.kda_conv >= 1 and self.causal,
@@ -220,6 +309,26 @@ class TransformerConfig:
     def mixer_kind(self, layer: int) -> str:
         return self.mixer_period[layer % len(self.mixer_period)]
 
+    def segments(self, first: int = 0, count: Optional[int] = None) -> tuple:
+        """Layers ``first`` .. ``first + count`` (default: all) as ((the
+        kinds of one repetition, repetitions), ...), the stacks the
+        parameters are built in and the programs scan: `layer_pattern`
+        where the configuration states one, else whole periods of
+        `mixer_period` where the count is whole periods, else every layer
+        once."""
+        count = self.n_layers - first if count is None else count
+        if self.layer_pattern is not None:
+            assert (first, count) == (0, self.n_layers)
+            return self.layer_pattern
+        kinds = tuple(self.mixer_kind(first + j) for j in range(count))
+        period = len(self.mixer_period)
+        return ((kinds[:period], count // period),) \
+            if count % period == 0 else ((kinds, 1),)
+
+    @property
+    def mamba_channels(self) -> int:
+        return self.mamba_expand * self.d_model
+
     def layers_of_kind(self, kind: str) -> int:
         """How many of the `n_layers` layers have mixer ``kind``."""
         return sum(self.mixer_kind(i) == kind for i in range(self.n_layers))
@@ -227,7 +336,20 @@ class TransformerConfig:
     def _layer_params(self, moe: bool, kind: str = "attention") -> int:
         d, hd, H, KV = self.d_model, self.head_dim, self.n_heads, \
             self.kv_heads
-        if kind == "kda":
+        bias = self.attn_bias
+        # differential attention: four lambda vectors, the pairs' norm
+        diff = 4 * hd + 2 * hd if self.diff_attn else 0
+        if kind == "mamba":
+            C, N, R = self.mamba_channels, self.mamba_d_state, \
+                self.mamba_dt_rank
+            attn = (d * 2 * C + (self.mamba_d_conv + 1) * C   # in, conv
+                    + C * (R + 2 * N) + R * C + C             # x, dt
+                    + N * C + C + C * d)                      # A_log, D, out
+        elif kind == "gmu":
+            attn = 2 * d * self.mamba_channels
+        elif kind == "cross":
+            attn = 2 * d * H * hd + bias * (H * hd + d) + diff
+        elif kind == "kda":
             width, r = self.kda_heads * self.kda_head_dim, self.kda_gate_rank
             attn = (4 * d * width                    # q, k, v, out
                     + 3 * self.kda_conv * width      # their convolutions
@@ -244,6 +366,7 @@ class TransformerConfig:
                     + H * self.v_head_dim * d)
         else:
             attn = (d * H * hd + 2 * d * KV * hd + H * hd * d
+                    + bias * ((H + 2 * KV) * hd + d) + diff
                     + (d * H * hd if self.attn_output_gate else 0)
                     + (H * hd + KV * hd if self.qk_norm else 0))
         if moe:
@@ -254,7 +377,8 @@ class TransformerConfig:
         else:
             ffn = 3 * d * (self.moe_dense_d_ff if self.moe_experts
                            else self.d_ff)
-        return attn + ffn + 2 * d                              # + norms
+        # + norms (a LayerNorm's bias beside its gain)
+        return attn + ffn + (4 if self.norm == "layer" else 2) * d
 
     @property
     def num_params(self) -> int:
@@ -266,7 +390,8 @@ class TransformerConfig:
         mtp = self.mtp_layers * (2 * d + 2 * d * d
                                  + self._layer_params(bool(self.moe_experts)))
         head = 0 if self.tie_embeddings else d * v
-        return v * d + layers + mtp + d + head
+        return v * d + layers + mtp + (2 if self.norm == "layer" else 1) * d \
+            + head
 
 
 # ---- presets ---------------------------------------------------------------

@@ -16,12 +16,16 @@ the reference delegates to external vLLM workers for, built TPU-first:
     transposes the whole cache on the way into every chunk and back.
   - The cache is a TREE of such leaves, each owned by one kind of token
     mixer and stacked over the layers of that kind (`SlotCache` below):
-    keys and values for the attention layers, a float32 state and the
-    short convolutions' tail for gated delta-rule (KDA) layers. The engine
-    hands the leaves through untouched; admission writes a slot's share of
-    every leaf inside the prefill program, which is also its reset. Both
-    programs walk the layers a period of mixer kinds at a time, as
-    training does, each kind with its own prefill and one-token step.
+    keys and values for the attention layers (a ring of the window's
+    places for a window layer; none for a cross layer, which reads another
+    layer's), a float32 state and the short convolutions' tail for gated
+    delta-rule (KDA) and state-space (mamba) layers: leaves of different
+    lengths by kind. The engine hands the leaves through untouched;
+    admission writes a slot's share of every leaf inside the prefill
+    program, which is also its reset. Both programs walk the layers a
+    segment of the layer pattern at a time (`cfg.segments`; one for a model
+    of one period), each a scan over a period of mixer kinds, as training
+    does, each kind with its own prefill and one-token step.
   - A decode substep reads the cache rows its requests own and writes
     only the rows that change: on a chip the layer scan carries the layer
     index and hands the whole stacked cache to one Pallas kernel
@@ -86,17 +90,19 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.models.config import TransformerConfig
-from ray_tpu.models.generate import (_final_logits, _gqa_decode_attention,
-                                     _prefill_hidden, join_period,
-                                     layer_stacks)
-from ray_tpu.models.transformer import (Params, attention_out, ffn_block,
-                                        kda_mixer, mixer_precision,
+from ray_tpu.models.generate import (_diff_decode_attention, _final_logits,
+                                     _gqa_decode_attention, _prefill_hidden,
+                                     join_period, join_segments)
+from ray_tpu.models.transformer import (Params, attention_out, block_norm,
+                                        diff_out, diff_qkv, ffn_block,
+                                        gmu_mixer, kda_mixer, layer_segments,
+                                        mamba_mixer, mixer_precision,
                                         param_logical_axes, qkv_proj,
-                                        refuse_unserved, rms_norm,
-                                        serving_params)
+                                        refuse_unserved, serving_params)
 from ray_tpu.ops.decode_attention import decode_attention, pick_block, \
     rows_read
 from ray_tpu.ops.kda import kda_decode_step
+from ray_tpu.ops.mamba import mamba_decode_step
 from ray_tpu.parallel.ring import shard_map
 from ray_tpu.parallel.sharding import logical_to_spec
 
@@ -110,10 +116,28 @@ SlotCache = Dict[str, jax.Array]
 # untouched but for the slot-wise write of a prefill (`_put_slots`):
 #   attention  "k"/"v" [L_attn, B, KV, S, hd] — KV-major (heads outside
 #              positions), the layout decode attention contracts over
+#              (under differential attention a token's keys and values are
+#              PAIRS of adjacent heads, [.., KV / 2, S, 2 hd]: how the layer
+#              reads them, and whole lanes for a 64-wide head). A "cross"
+#              layer holds nothing: it reads the leaf of the nearest
+#              attention layer before it, so ONE full-length layer can
+#              serve many
+#   window     "win_k"/"win_v" [L_window, B, KV, W, hd], W = `sliding_window`
+#              places a slot: a ring, position p at place p % W, so a
+#              window layer costs W positions whatever `max_len` is. A
+#              prefill leaves a prompt's last W positions where decode
+#              finds them; a decode step masks by the position each place
+#              holds (never a row's left padding, never a place the slot
+#              has not reached) and overwrites the oldest
 #   kda        "kda_state" [L_kda, B, H, dk, dv] float32, the gated delta
 #              rule's state, and "kda_tail" [L_kda, B, taps - 1, 3 x H x
 #              dk], the projected rows of q, k, v the short convolutions
 #              reach back to
+#   mamba      "mamba_state" [L_mamba, B, N, C] float32, the selective
+#              scan's N states a channel (state-major: channels fill the
+#              lanes; [.., C, 16] would be stored padded to 128 lanes, eight
+#              times the bytes), and "mamba_tail" [L_mamba, B, taps - 1, C],
+#              the rows its convolution reaches back to
 # and, where layers have experts, "moe_counts" [3] float32: the held
 # experts fetched, the assignments that fell on them and the layers whose
 # grouped matmuls ran the short row buffer's kernel in the LAST decode
@@ -125,18 +149,32 @@ def init_slot_cache(cfg: TransformerConfig, slots: int,
                     max_len: int) -> SlotCache:
     refuse_unserved(cfg)
     cache = {}
-    n_attn, n_kda = (cfg.layers_of_kind(kind)
-                     for kind in ("attention", "kda"))
+    n_attn, n_kda, n_window, n_mamba = (
+        cfg.layers_of_kind(kind)
+        for kind in ("attention", "kda", "window", "mamba"))
+    # differential attention reads, and so holds, pairs of heads
+    pair = 2 if cfg.diff_attn else 1
+    heads = (cfg.kv_heads // pair, pair * cfg.head_dim)
     if n_attn:
-        shape = (n_attn, slots, cfg.kv_heads, max_len, cfg.head_dim)
+        shape = (n_attn, slots, heads[0], max_len, heads[1])
         cache.update(k=jnp.zeros(shape, cfg.dtype),
                      v=jnp.zeros(shape, cfg.dtype))
+    if n_window:
+        shape = (n_window, slots, heads[0], cfg.sliding_window, heads[1])
+        cache.update(win_k=jnp.zeros(shape, cfg.dtype),
+                     win_v=jnp.zeros(shape, cfg.dtype))
     if n_kda:
         H, hd = cfg.kda_heads, cfg.kda_head_dim
         cache.update(
             kda_state=jnp.zeros((n_kda, slots, H, hd, hd), jnp.float32),
             kda_tail=jnp.zeros((n_kda, slots, cfg.kda_conv - 1, 3 * H * hd),
                                cfg.dtype))
+    if n_mamba:
+        N, C = cfg.mamba_d_state, cfg.mamba_channels
+        cache.update(
+            mamba_state=jnp.zeros((n_mamba, slots, N, C), jnp.float32),
+            mamba_tail=jnp.zeros((n_mamba, slots, cfg.mamba_d_conv - 1, C),
+                                 cfg.dtype))
     if cfg.moe_experts:
         cache["moe_counts"] = jnp.zeros((3,), jnp.float32)
     cache.update(pos=jnp.zeros((slots,), jnp.int32),
@@ -149,9 +187,13 @@ def cache_logical_axes(cache=None) -> Dict[str, tuple]:
     — serving shards the model, not the batch): of ``cache``'s, or of the
     four an attention-only model holds."""
     kv = ("layers", None, "kv_heads", None, None)
-    axes = {"k": kv, "v": kv, "pos": (None,), "start": (None,),
+    axes = {"k": kv, "v": kv, "win_k": kv, "win_v": kv, "pos": (None,),
+            "start": (None,),
             "kda_state": ("layers", None, "heads", None, None),
-            "kda_tail": ("layers", None, None, None), "moe_counts": (None,)}
+            "kda_tail": ("layers", None, None, None),
+            "mamba_state": ("layers", None, None, "mlp"),
+            "mamba_tail": ("layers", None, None, "mlp"),
+            "moe_counts": (None,)}
     return {name: axes[name] for name in
             (("k", "v", "pos", "start") if cache is None else cache)}
 
@@ -164,12 +206,13 @@ def _sample(logits, rng, greedy: bool, temperature):
 
 
 def _put_rows(cache: SlotCache, new_k: jax.Array, new_v: jax.Array,
-              slots: jax.Array, at: jax.Array):
+              slots: jax.Array, at: jax.Array, names=("k", "v")):
     """Write row i of ``new_k``/``new_v`` [L, n, KV, T, hd] into slot
-    ``slots[i]`` at positions ``at[i]`` .. ``at[i]+T``; -> (k, v). One
-    dynamic_update_slice per row (n is static: unrolled), which on a
-    donated cache moves the rows and nothing else."""
-    k, v = cache["k"], cache["v"]
+    ``slots[i]`` at positions ``at[i]`` .. ``at[i]+T`` of the cache's two
+    leaves ``names``; -> (k, v). One dynamic_update_slice per row (n is
+    static: unrolled), which on a donated cache moves the rows and nothing
+    else."""
+    k, v = (cache[name] for name in names)
     zero = jnp.zeros((), jnp.int32)
     for i in range(new_k.shape[1]):
         start = (zero, slots[i], zero, at[i], zero)
@@ -199,9 +242,10 @@ def prefill_slots(params: Params, cache: SlotCache, tokens: jax.Array,
                   temperature: float = 1.0):
     """Batched prefill: ``tokens`` [K, P] (left-padded to one shared
     bucket, first real token of row i at ``starts[i]``) lands in cache
-    rows ``slots`` [K]; -> (cache, first sampled tokens [K]). A KDA
-    layer's state and tail of those slots are REPLACED by the prompt's
-    (computed from zero): admission is the reset.
+    rows ``slots`` [K]; -> (cache, first sampled tokens [K]). A KDA or
+    mamba layer's state and tail and a window layer's ring of those slots
+    are REPLACED by the prompt's (computed from zero): admission is the
+    reset.
 
     One compiled program per (K, P) pair; K is kept to a few power-of-two
     group sizes by the scheduler. Batching prefills is a dispatch-count
@@ -222,9 +266,13 @@ def prefill_slots(params: Params, cache: SlotCache, tokens: jax.Array,
         new["k"], new["v"] = _put_rows(
             cache, cK["k"].transpose(0, 1, 3, 2, 4),
             cK["v"].transpose(0, 1, 3, 2, 4), slots, jnp.zeros_like(slots))
-    for name in ("kda_state", "kda_tail"):
+    for name in ("kda_state", "kda_tail", "mamba_state", "mamba_tail"):
         if name in cK:
             new[name] = _put_slots(cache[name], cK[name], slots)
+    for name in ("win_k", "win_v"):     # [L, K, W, KV, hd] -> KV-major
+        if name in cK:
+            new[name] = _put_slots(
+                cache[name], cK[name].transpose(0, 1, 3, 2, 4), slots)
     return new, toks
 
 
@@ -232,15 +280,18 @@ def _on_chip() -> bool:
     return jax.default_backend() != "cpu"
 
 
-def _kv_block(k_cache) -> Optional[int]:
-    """The position block the decode kernel walks this cache by, or None
-    where the masked contraction runs instead: on the CPU (where it is
-    also the tests' reference), and for a shape the kernel does not take
-    (`pick_block`). Decided, as `transformer._select_attention` decides,
+def _kv_block(cache: SlotCache, cfg: TransformerConfig) -> Optional[int]:
+    """The position block the decode kernel walks the cache's keys and
+    values by, or None where the masked contraction runs instead: on the
+    CPU (where it is also the tests' reference), for a shape the kernel
+    does not take (`pick_block`), under differential attention (whose
+    pairs of heads the kernel does not score) and for a model without
+    attention layers. Decided, as `transformer._select_attention` decides,
     by what the code can observe."""
-    if not _on_chip():
+    if "k" not in cache or cfg.diff_attn or not _on_chip():
         return None
-    return pick_block(k_cache.shape[3], k_cache.shape[4], k_cache.dtype)
+    k = cache["k"]
+    return pick_block(k.shape[3], k.shape[4], k.dtype)
 
 
 def _kernel_attention(q, cache: SlotCache, k_new, v_new, active, layer, mesh):
@@ -262,20 +313,34 @@ def _kernel_attention(q, cache: SlotCache, k_new, v_new, active, layer, mesh):
                      out_specs=heads)(*args)
 
 
+def _ring_mask(pos, start, window: int):
+    """[B, window] bool: the places of a window layer's ring a decode step
+    at ``pos`` reads. Place j holds the last position p < pos with p %
+    window == j; the token sees it where p is within the ``window``
+    positions that end at the token itself (so never the place it is about
+    to overwrite) and no left padding (nor a place never written: p < 0)."""
+    j = jnp.arange(window)[None, :]
+    last = pos[:, None] - 1
+    held = last - (last - j) % window
+    return (held >= start[:, None]) & (held > pos[:, None] - window) \
+        & (held >= 0)
+
+
 def _decode_one(params: Params, cache: SlotCache, tokens: jax.Array,
                 cfg: TransformerConfig, active: Optional[jax.Array] = None,
                 mesh=None):
     """One decode step for every slot: tokens [B] (each slot's pending
     token) -> (cache with pos advanced, logits [B, V]). ``active`` [B]
     bool left out reads every slot as active; a slot that is not active
-    attends to nothing it has cached and keeps its KDA state and tail as
-    they are (its logits are junk either way).
+    attends to nothing it has cached and keeps its KDA and mamba states
+    and tails as they are (its logits are junk either way).
 
     pos/RoPE/attention bounds are all per-row, so slots admitted at
     different times decode together in one program. The layers are walked
-    a period of mixer kinds at a time, as `transformer._trunk` walks them
-    (one stack a position of the period; one scan step a period), each
-    kind taking its own one-token step:
+    a segment of the pattern at a time (`cfg.segments`: one for a model of
+    one period), each a scan over its repeats whose step is one period of
+    mixer kinds, as `transformer._trunk` walks them (one stack a position
+    of the period), each kind taking its own one-token step:
       attention  only READS the cache and hands back the layer's new K/V
                  row ([L,B,KV,hd], a megabyte); the rows land afterwards,
                  one in-place dynamic_update_slice per slot at its own
@@ -283,96 +348,195 @@ def _decode_one(params: Params, cache: SlotCache, tokens: jax.Array,
                  is moved). A ``pos`` past the end clamps to the slot's
                  own last position, which no request's plan reads
                  (`InferenceEngine._max_len`).
-      kda        updates the slots' states where they lie: the scan
+      window     the same against its ring under `_ring_mask`; the row
+                 lands at ``pos % sliding_window``.
+      cross      reads the leaf of the nearest attention layer before it
+                 and that layer's row of THIS token, which rides the carry
+                 (it is not in the cache before the scans end).
+      kda, mamba update the slots' states where they lie: the scan
                  carries the stacked states and the layer's index, and
-                 `kda_decode_step` reads and writes the layer's blocks of
-                 the one buffer; the tails are shifted by the token.
+                 `kda_decode_step` / `mamba_decode_step` reads and writes
+                 the layer's blocks of the one buffer; the tails are
+                 shifted by the token. A mamba layer's scan output rides
+                 the carry as the memory of the gated memory units after
+                 it (gmu: no state).
     Layers with experts add what they fetched to ``moe_counts``.
     """
     pos, start = cache["pos"], cache["start"]
-    x = params["embed"].astype(cfg.dtype)[tokens[:, None]]  # [B, 1, d]
+    # (rows first: a tied table is held float32 and is not converted whole)
+    x = params["embed"][tokens[:, None]].astype(cfg.dtype)  # [B, 1, d]
     positions = pos[:, None]  # [B, 1] per-row RoPE
-    stacks = layer_stacks(params)
-    kinds = ["kda" if "kda_wq" in lp else "attention" for lp in stacks]
-    n_attn, n_kda = kinds.count("attention"), kinds.count("kda")
-    periods = jax.tree.leaves(stacks[0])[0].shape[0]
-    kernel = n_attn and _kv_block(cache["k"]) is not None
-    if active is None and (kernel or n_kda):
+    B = tokens.shape[0]
+    has = collections.Counter(cfg.mixer_kind(i) for i in range(cfg.n_layers))
+    kernel = _kv_block(cache, cfg) is not None
+    if active is None and (kernel or has["kda"] or has["mamba"]):
         active = jnp.ones_like(pos, bool)
-    # a kernel indexes [L, ...] itself: the scan carries the period's index
-    scanned = [stacks, jnp.arange(periods)]
-    if n_attn and not kernel:
-        S, dtype = cache["k"].shape[3], cache["k"].dtype
-        kpos = jnp.arange(S)[None, :]
-        mask = (kpos >= start[:, None]) & (kpos < pos[:, None])  # [B, S]
-        scanned += [c.reshape((periods, n_attn) + c.shape[1:])
-                    for c in (cache["k"], cache["v"])]
-    elif n_attn:
+    if has["attention"]:
         dtype = cache["k"].dtype
+        if not kernel:
+            kpos = jnp.arange(cache["k"].shape[3])[None, :]
+            mask = (kpos >= start[:, None]) & (kpos < pos[:, None])  # [B, S]
+    if has["window"]:
+        dtype = cache["win_k"].dtype
+        ring = _ring_mask(pos, start, cfg.sliding_window)
 
-    def layer_of(period, n, i):
-        return period if n == 1 else period * n + i
+    def state_step(name, step_fn, carry, layer):
+        def step(*token):
+            carry[name], o = step_fn(carry[name], layer, *token, active)
+            return o
+        return step
 
-    def block(carry, scanned):
-        lps, period, *kv = scanned
-        x, rows, i_attn, i_kda = carry["x"], [], 0, 0
-        for lp in lps:
-            if "kda_wq" in lp:
-                h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-                layer = layer_of(period, n_kda, i_kda)
-                i_kda += 1
+    def shift_tail(name, carry, layer, mixer):
+        """``mixer(old tail) -> (..., new tail)`` on layer ``layer``'s
+        slice of the stacked tails; an inactive slot keeps its own."""
+        old = jax.lax.dynamic_index_in_dim(carry[name], layer, 0,
+                                           keepdims=False)
+        *out, tail = mixer(old)
+        carry[name] = jax.lax.dynamic_update_index_in_dim(
+            carry[name], jnp.where(active[:, None, None], tail, old),
+            layer, 0)
+        return out
 
-                def step(*token, layer=layer):
-                    carry["kda_state"], o = kda_decode_step(
-                        carry["kda_state"], layer, *token, active)
-                    return o
-                old = jax.lax.dynamic_index_in_dim(
-                    carry["kda_tail"], layer, 0, keepdims=False)
-                o, tail = kda_mixer(h, lp, cfg, tail=old, step=step)
-                carry["kda_tail"] = jax.lax.dynamic_update_index_in_dim(
-                    carry["kda_tail"],
-                    jnp.where(active[:, None, None], tail, old), layer, 0)
+    def block(carry, scanned, kinds, first, seen, slab_names):
+        """One period of a segment: ``first`` the model's layers before
+        the segment, ``seen`` those of each kind; the cache leaves
+        ``slab_names`` scanned beside the weights, [n of the kind, ...]."""
+        lps, period, *slabs = scanned
+        carry, slabs = dict(carry), dict(zip(slab_names, slabs))
+        x, rows, at = carry["x"], {}, collections.Counter()
+        count = collections.Counter(kinds)
+        for j, (kind, lp) in enumerate(zip(kinds, lps)):
+            i, n = at[kind], count[kind]
+            at[kind] += 1
+            # this layer among its kind's, the cache leaves' leading axis
+            layer = period if n == 1 else period * n + i
+            if seen[kind]:
+                layer = layer + seen[kind]
+            if kind == "kda":
+                h = block_norm(x, lp, "attn_norm", cfg)
+                step = state_step("kda_state", kda_decode_step, carry, layer)
+                o, = shift_tail(
+                    "kda_tail", carry, layer, lambda old: kda_mixer(
+                        h, lp, cfg, tail=old, step=step))
+            elif kind == "mamba":
+                h = block_norm(x, lp, "attn_norm", cfg)
+                step = state_step("mamba_state", mamba_decode_step, carry,
+                                  layer)
+                o, y = shift_tail(
+                    "mamba_tail", carry, layer, lambda old: mamba_mixer(
+                        h, lp, cfg, tail=old, step=step))
+                if "memory" in carry:
+                    carry["memory"] = y
+            elif kind == "gmu":
+                h = block_norm(x, lp, "attn_norm", cfg)
+                o = gmu_mixer(h, carry["memory"], lp, cfg)
+            elif cfg.diff_attn:
+                h = block_norm(x, lp, "attn_norm", cfg)
+                q, k, v = diff_qkv(h, lp, cfg)
+                if kind == "cross":     # the nearest attention layer's
+                    k, v = carry["shared_k"], carry["shared_v"]
+                    kc, vc = (cache[name][seen["attention"] - 1]
+                              for name in ("k", "v"))
+                else:
+                    k, v = k[:, 0].astype(dtype), v[:, 0].astype(dtype)
+                    name = "win_" if kind == "window" else ""
+                    kc, vc = slabs[name + "k"][i], slabs[name + "v"][i]
+                    rows.setdefault(kind, []).append((k, v))
+                    if kind == "attention" and "shared_k" in carry:
+                        carry["shared_k"], carry["shared_v"] = k, v
+                o = diff_out(_diff_decode_attention(
+                    q, kc, vc, k, v, ring if kind == "window" else mask),
+                    lp, cfg, first + period * len(kinds) + j)
             else:
                 # a float32 mixer (`mixer_precision`) around the attention
                 # itself: the kernel keeps its own arithmetic, reads the
                 # cache's rows and hands back o in q's dtype
                 with mixer_precision(cfg, lp) as wide:
                     x = x.astype(wide)
-                    h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
+                    h = block_norm(x, lp, "attn_norm", cfg)
                     q, k, v = qkv_proj(h, lp, cfg, positions)
                 k, v = k[:, 0].astype(dtype), v[:, 0].astype(dtype)
                 if kernel:          # [B, KV, hd]
-                    o = _kernel_attention(
-                        q, cache, k, v, active,
-                        layer_of(period, n_attn, i_attn), mesh)
+                    o = _kernel_attention(q, cache, k, v, active, layer, mesh)
                 else:
                     o = _gqa_decode_attention(
-                        q, kv[0][i_attn], kv[1][i_attn], k, v, mask)
-                i_attn += 1
-                rows.append((k, v))
+                        q, slabs["k"][i], slabs["v"][i], k, v, mask)
+                rows.setdefault(kind, []).append((k, v))
                 with mixer_precision(cfg, lp):
                     o = attention_out(o, h, lp, cfg)
             x = x + o
             down, stats = ffn_block(
-                rms_norm(x, lp["mlp_norm"], cfg.rms_eps), lp, cfg)
+                block_norm(x, lp, "mlp_norm", cfg), lp, cfg)
             if "router" in lp and "moe_counts" in carry:
                 assignments = x.shape[0] * x.shape[1] * cfg.moe_top_k
                 carry["moe_counts"] = carry["moe_counts"] + jnp.stack(
                     [stats["fetched"], stats["held"] * assignments,
                      stats["rows_kernel"]])
             x = (x + down).astype(cfg.dtype)
-        return dict(carry, x=x), tuple(zip(*rows))
+        return dict(carry, x=x), {kind: tuple(zip(*pairs))
+                                  for kind, pairs in rows.items()}
 
     carried = {name: cache[name] for name in
-               ("kda_state", "kda_tail", "moe_counts") if name in cache}
-    carried, rows = jax.lax.scan(block, dict(carried, x=x), tuple(scanned))
-    logits = _final_logits(params, carried.pop("x"), cfg)[:, 0]  # [B, V]
-    new = dict(cache, pos=pos + 1, **carried)
-    if n_attn:
-        k_rows, v_rows = (join_period(x) for x in rows)
-        new["k"], new["v"] = _put_rows(
-            cache, k_rows[:, :, :, None], v_rows[:, :, :, None],
-            jnp.arange(tokens.shape[0], dtype=jnp.int32), pos)
+               ("kda_state", "kda_tail", "mamba_state", "mamba_tail",
+                "moe_counts") if name in cache}
+    carried["x"] = x
+    if has["gmu"]:
+        carried["memory"] = jnp.zeros((B, 1, cfg.mamba_channels),
+                                      jnp.float32)
+    if has["cross"]:
+        carried["shared_k"] = carried["shared_v"] = jnp.zeros(
+            (B, cache["k"].shape[2], cache["k"].shape[4]), cache["k"].dtype)
+    first, seen, new_rows = 0, collections.Counter(), {}
+    for (kinds, reps), stacks in zip(cfg.segments(),
+                                     layer_segments(params["layers"])):
+        count = collections.Counter(kinds)
+        # a kernel indexes [L, ...] itself: the scan carries the period's
+        # index; the masked contractions take their layers' slabs
+        scanned, slab_names = [stacks, jnp.arange(reps)], []
+        for names, kind in ((("k", "v"), "attention"),
+                            (("win_k", "win_v"), "window")):
+            n = count[kind]
+            if n and not (kind == "attention" and kernel):
+                for name in names:
+                    c = cache[name]
+                    if n * reps != c.shape[0]:
+                        c = c[seen[kind]:seen[kind] + n * reps]
+                    scanned.append(c.reshape((reps, n) + c.shape[1:]))
+                    slab_names.append(name)
+        carried, rows = jax.lax.scan(
+            partial(block, kinds=kinds, first=first,
+                    seen=collections.Counter(seen), slab_names=slab_names),
+            carried, tuple(scanned))
+        for kind, (k_rows, v_rows) in rows.items():
+            new_rows.setdefault(kind, []).append(
+                (join_period(k_rows), join_period(v_rows)))
+        first += len(kinds) * reps
+        for kind, n in count.items():
+            seen[kind] += n * reps
+    x = carried.pop("x")
+    if has["cross"]:
+        # the rows of the leaf the cross layers read are written once its
+        # last reader is done: without the tie the write may be scheduled
+        # before the last segment's loop, and the compiler copies the
+        # whole leaf twice a substep to allow for it
+        x, new_rows = jax.lax.optimization_barrier((x, new_rows))
+    logits = _final_logits(params, x, cfg)[:, 0]  # [B, V]
+    for name in ("memory", "shared_k", "shared_v"):
+        carried.pop(name, None)
+    # a slot that is not active stays where it is: what it writes lands on
+    # the one place at its frozen position (of a ring: the place that has
+    # just left the window), every substep of a chunk
+    new = dict(cache, pos=pos + 1 if active is None
+               else pos + active.astype(pos.dtype), **carried)
+    every = jnp.arange(B, dtype=jnp.int32)
+    for kind, names in (("attention", ("k", "v")),
+                        ("window", ("win_k", "win_v"))):
+        if kind in new_rows:
+            k_rows, v_rows = (join_segments(parts)
+                              for parts in zip(*new_rows[kind]))
+            new[names[0]], new[names[1]] = _put_rows(
+                cache, k_rows[:, :, :, None], v_rows[:, :, :, None], every,
+                pos % cfg.sliding_window if kind == "window" else pos, names)
     return new, logits
 
 
@@ -546,10 +710,10 @@ class InferenceEngine:
         if mesh is not None:
             from ray_tpu.parallel.sharding import shard_array, tree_shardings
 
-            if "kda" in cfg.mixer_period and mesh.size > 1:
+            if {"kda", "mamba"} & set(cfg.mixer_period) and mesh.size > 1:
                 raise NotImplementedError(
-                    "a KDA layer's decode kernel is not run per shard yet: "
-                    "serve such a model on one chip")
+                    "a KDA or mamba layer's decode kernel is not run per "
+                    "shard yet: serve such a model on one chip")
 
             shardings = tree_shardings(mesh, param_logical_axes(cfg))
             axes = cache_logical_axes(self.cache)
@@ -581,11 +745,14 @@ class InferenceEngine:
         self._slot_start = np.zeros(self.slots, np.int64)
         self._slot_pos = np.zeros(self.slots, np.int64)
         # None: the XLA contraction (or no attention layer at all)
-        self._kv_block = _kv_block(self.cache["k"]) \
-            if "k" in self.cache else None
+        self._kv_block = _kv_block(self.cache, cfg)
         # what a decode substep costs by the model's shape, for the
-        # counters: KDA layers, layers with experts and what those offer
+        # counters: KDA and mamba layers, a window layer's ring, layers
+        # with experts and what those offer
         self._kda_layers = cfg.layers_of_kind("kda")
+        self._mamba_layers = cfg.layers_of_kind("mamba")
+        self._ring_rows = self.slots * cfg.sliding_window \
+            if "win_k" in self.cache else 0
         moe_layers = cfg.n_layers if cfg.moe_experts else 0
         self._moe_calls = moe_layers * cfg.held_experts
         self._moe_assignments = moe_layers * self.slots * cfg.moe_top_k
@@ -649,6 +816,11 @@ class InferenceEngine:
             # got a row (whose weights a substep fetched), the
             # assignments that fell on held experts and the layers (a layer
             # and substep) whose grouped matmuls were `ops.grouped_matmul`'s
+            # a mamba layer's likewise (one unit: one active slot's states
+            # in EVERY mamba layer); the places of a window layer's ring
+            # fetched (a place of a slot, every window layer and head:
+            # the masked contraction reads all of them)
+            "mamba_state_updates": 0, "window_kv_rows_read": 0,
             "kda_state_updates": 0, "moe_expert_calls": 0,
             "moe_assignments": 0, "moe_expert_fetches": 0,
             "moe_held_assignments": 0, "moe_rows_kernel_layers": 0,
@@ -989,7 +1161,7 @@ class InferenceEngine:
         self._decode(np.ones(self.slots, bool))  # and the last-column slice
         jax.block_until_ready(self._next_tok_dev)
         # reset bookkeeping: positions to zero, junk K/V is unreachable
-        # (a KDA layer's state and tail are replaced at admission)
+        # (a state, a tail and a window's ring are replaced at admission)
         cache = self.cache
         self.cache = dict(cache, pos=jnp.zeros_like(cache["pos"]),
                           start=jnp.zeros_like(cache["start"]))
@@ -1155,6 +1327,10 @@ class InferenceEngine:
             self.stats["chunks_dispatched"] += 1
             if self._kda_layers:
                 self.stats["kda_state_updates"] += width * len(active_slots)
+            if self._mamba_layers:
+                self.stats["mamba_state_updates"] += \
+                    width * len(active_slots)
+            self.stats["window_kv_rows_read"] += width * self._ring_rows
             self.stats["moe_expert_calls"] += width * self._moe_calls
             self.stats["moe_assignments"] += width * self._moe_assignments
         self._inflight.append((toks, snapshot, counts))

@@ -11,7 +11,8 @@ TPU-first design:
     compiled program.
   - The layer dimension rides the same stacked-params ``lax.scan`` as
     training (`transformer.forward`: a period of mixer kinds a step, one
-    stack a position of the period), so depth costs one trace and the
+    stack a position of the period; a scan a segment of the layer pattern
+    where it has several), so depth costs one trace and the
     prompt's K/V comes back as one [L, B, S, KV, hd] array per k/v —
     contiguous HBM, no per-layer Python lists. What a KDA layer leaves a
     slot comes back the same way: its final state and the last projected
@@ -28,13 +29,18 @@ TPU-first design:
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.config import TransformerConfig
-from ray_tpu.models.transformer import (Params, attention_out, ffn_block,
-                                        kda_mixer, lm_head, mixer_precision,
-                                        qkv_proj, refuse_unserved, rms_norm)
+from ray_tpu.models.transformer import (Params, attention_out, block_norm,
+                                        diff_out, diff_qkv, ffn_block,
+                                        gmu_mixer, kda_mixer, layer_segments,
+                                        lm_head, mamba_mixer,
+                                        mixer_precision, qkv_proj,
+                                        refuse_unserved)
 
 # Large-finite instead of -inf for masked scores: a fully-masked query row
 # (a pad position in a left-padded batch) then softmaxes to uniform junk
@@ -86,16 +92,90 @@ def _gqa_decode_attention(q, k_cache, v_cache, k_new, v_new, mask):
     return o.reshape(B, 1, H, hd).astype(q.dtype)
 
 
+def _weighted_values(p, v, eq: str):
+    """einsum(eq, p, v) for float32 weights ``p`` and cached values ``v``
+    with the weights' float32 kept: against float32 values at the highest
+    precision; against bf16 values as ONE bf16 contraction of the weights'
+    leading 8 bits and the next 8, side by side as query rows (the axis
+    ``r`` of ``eq``) of one operand so that the values are read once (a
+    float32 matmul at the default precision rounds p to its leading 8, the
+    highest spends six passes on values that have only 8, and two
+    contractions read a cache leaf twice)."""
+    f32 = jnp.float32
+    if v.dtype == f32:
+        return jnp.einsum(eq, p, v, precision=jax.lax.Precision.HIGHEST)
+    hi = p.astype(v.dtype)
+    lo = (p - hi.astype(f32)).astype(v.dtype)
+    rows, out = eq.split(",")[0].index("r"), eq.split("->")[1].index("r")
+    both = jnp.einsum(eq, jnp.concatenate([hi, lo], axis=rows), v,
+                      preferred_element_type=f32)
+    hi, lo = jnp.split(both, 2, axis=out)
+    return hi + lo
+
+
+def _diff_attention(q, k, v, mask):
+    """Differential attention's two softmaxes over a row, in pairs of heads
+    (`transformer.diff_qkv`): q [B, T, P, 2, c] (a pair's [q1 | 0] and [0 |
+    q2], c = 2 head_dim), k, v [B, S, G, c] ([k1 | k2], [v1 | v2]; query
+    pair j reads key pair j // (P / G)), mask [B, T, S] -> o [B, T, P, 2,
+    c] float32: softmax(q1 k1^T) [v1 | v2] and softmax(q2 k2^T) [v1 | v2],
+    scores scaled by head_dim ** -0.5, float32 from the scores on."""
+    B, T, P, _, c = q.shape
+    G = k.shape[2]
+    qg = q.reshape(B, T, G, P // G, 2, c)
+    s = jnp.einsum("btgrec,bsgc->btgres", qg, k,
+                   preferred_element_type=jnp.float32) * (c // 2) ** -0.5
+    s = jnp.where(mask[:, :, None, None, None, :], s, _MASKED)
+    o = _weighted_values(jax.nn.softmax(s, axis=-1), v,
+                         "btgres,bsgc->btgrec")
+    return o.reshape(B, T, P, 2, c)
+
+
+def _diff_decode_attention(q, k_cache, v_cache, k_new, v_new, mask):
+    """`_diff_attention` for one query a row against a cache it only READS
+    (a full-length leaf or a window's ring): q [B, 1, P, 2, c], keys and
+    values [B, G, S, c] under ``mask`` [B, S], plus the token's own
+    ``k_new``/``v_new`` [B, G, c] as one more key column, as
+    `_gqa_decode_attention` takes them -> o [B, 1, P, 2, c] float32."""
+    B, _, P, _, c = q.shape
+    G = k_cache.shape[1]
+    f32 = jnp.float32
+    qg = q.reshape(B, G, P // G, 2, c)
+    scale = (c // 2) ** -0.5
+    s_old = jnp.einsum("bgrec,bgsc->bgres", qg, k_cache,
+                       preferred_element_type=f32) * scale
+    s_old = jnp.where(mask[:, None, None, None, :], s_old, _MASKED)
+    s_new = jnp.einsum("bgrec,bgc->bgre", qg, k_new,
+                       preferred_element_type=f32) * scale
+    top = jnp.maximum(s_old.max(axis=-1), s_new)
+    e_old = jnp.exp(s_old - top[..., None])
+    e_new = jnp.exp(s_new - top)
+    o = _weighted_values(e_old, v_cache, "bgres,bgsc->bgrec") \
+        + e_new[..., None] * v_new.astype(f32)[:, :, None, None, :]
+    o = o / (e_old.sum(axis=-1) + e_new)[..., None]
+    return o.reshape(B, 1, P, 2, c)
+
+
+def window_ring(rows, window: int):
+    """The last ``window`` positions of rows [B, P, ...] as a decode step
+    finds them in a ring of ``window`` places: position p at place p %
+    window (places no position has reached yet are zero)."""
+    P = rows.shape[1]
+    if P <= window:
+        return jnp.pad(rows, [(0, 0), (0, window - P)]
+                       + [(0, 0)] * (rows.ndim - 2))
+    return jnp.roll(rows[:, P - window:], (P - window) % window, axis=1)
+
+
 def _final_logits(params, x, cfg):
     # shared final norm + head with the training path
     return lm_head(params, x, cfg, None)
 
 
-def layer_stacks(params: Params) -> tuple:
-    """``params["layers"]`` as a tuple of stacks, one a position of the
-    period of mixer kinds (one stack for a model of one kind)."""
-    layers = params["layers"]
-    return (layers,) if isinstance(layers, dict) else tuple(layers)
+def join_segments(parts):
+    """One kind's leaves [layers of a segment, ...], a segment each, in
+    layer order: [layers, ...]."""
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
 
 
 def join_period(parts):
@@ -117,8 +197,16 @@ def _prefill_hidden(params: Params, tokens: jax.Array,
     what each mixer kind leaves a slot, stacked over the layers of that
     kind: ``k``/``v`` [L_attn, B, max_len, KV, hd]; ``kda_state`` [L_kda,
     B, H, dk, dv] float32 and ``kda_tail`` [L_kda, B, taps - 1, 3 x H x
-    dk]. Rows are padded on the left: a padded row is masked out of
-    attention, and writes nothing into a KDA state (`kda_mixer`)."""
+    dk]; ``mamba_state`` [L_mamba, B, N, C] float32 and ``mamba_tail``
+    [L_mamba, B, taps - 1, C]; ``win_k``/``win_v`` [L_window, B, window,
+    KV, hd], the prompt's last positions where a decode step finds them
+    (`window_ring`). Under differential attention keys and values are
+    pairs of heads, [.., KV / 2, 2 hd]. Rows are padded on the left: a
+    padded row is masked out of attention, and writes nothing into a KDA
+    or mamba state (`kda_mixer`, `mamba_mixer`). The layers are walked a
+    segment of the pattern at a time (`cfg.segments`), each a scan over
+    its repeats; a gated memory unit's memory and a cross layer's keys
+    and values ride the carry from the segment that makes them."""
     B, P = tokens.shape
     refuse_unserved(cfg)
     if max_len < P:
@@ -128,41 +216,83 @@ def _prefill_hidden(params: Params, tokens: jax.Array,
         # silently contradict the forward() the params were trained with
         raise ValueError("generation requires a causal (decoder) config; "
                          "this config has causal=False")
-    x = params["embed"].astype(cfg.dtype)[tokens]
+    x = params["embed"][tokens].astype(cfg.dtype)
     positions = jnp.arange(P)
 
     causal = jnp.arange(P)[:, None] >= jnp.arange(P)[None, :]
     valid = jnp.arange(P)[None, :] >= start[:, None]  # [B, S]
     prompt_mask = causal[None, :, None, None, :] & \
         valid[:, None, None, None, :]
+    seen = causal[None] & valid[:, None, :]           # [B, T, S]
+    near = seen & (jnp.arange(P)[:, None] - jnp.arange(P)[None, :]
+                   < cfg.sliding_window)[None]
+    pad = [(0, 0), (0, max_len - P), (0, 0), (0, 0)]
 
-    def period(x, lps):
-        left = {}
-        for lp in lps:
+    def period(carry, scanned, kinds, first):
+        lps, rep = scanned
+        carry, left = dict(carry), {}
+        x = carry["x"]
+        for j, (kind, lp) in enumerate(zip(kinds, lps)):
+            layer = first + rep * len(kinds) + j     # of the whole model
             with mixer_precision(cfg, lp) as dtype:
                 x = x.astype(dtype)
-                h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-                if "kda_wq" in lp:
+                h = block_norm(x, lp, "attn_norm", cfg)
+                if kind == "kda":
                     o, state, tail = kda_mixer(h, lp, cfg, valid=valid)
                     new = {"kda_state": state, "kda_tail": tail}
+                elif kind == "mamba":
+                    o, y, state, tail = mamba_mixer(h, lp, cfg, valid=valid)
+                    new = {"mamba_state": state, "mamba_tail": tail}
+                    if "memory" in carry:
+                        carry["memory"] = y
+                elif kind == "gmu":
+                    o, new = gmu_mixer(h, carry["memory"], lp, cfg), {}
+                elif cfg.diff_attn:
+                    q, k, v = diff_qkv(h, lp, cfg)
+                    new = {}
+                    if kind == "cross":
+                        k, v = carry["shared_k"], carry["shared_v"]
+                    elif kind == "window":
+                        new = {"win_k": window_ring(k, cfg.sliding_window),
+                               "win_v": window_ring(v, cfg.sliding_window)}
+                    else:
+                        new = {"k": jnp.pad(k, pad), "v": jnp.pad(v, pad)}
+                        if "shared_k" in carry:
+                            carry["shared_k"], carry["shared_v"] = k, v
+                    o = diff_out(_diff_attention(
+                        q, k, v, near if kind == "window" else seen),
+                        lp, cfg, layer)
                 else:
                     q, k, v = qkv_proj(h, lp, cfg, positions)
                     o = attention_out(
                         _gqa_attention(q, k, v, prompt_mask), h, lp, cfg)
                     # pad this layer's k/v out to max_len for the cache
-                    pad = [(0, 0), (0, max_len - P), (0, 0), (0, 0)]
                     new = {"k": jnp.pad(k.astype(cfg.dtype), pad),
                            "v": jnp.pad(v.astype(cfg.dtype), pad)}
             x = x + o
             # inference drops the MoE aux loss
-            down, _ = ffn_block(rms_norm(x, lp["mlp_norm"], cfg.rms_eps),
-                                lp, cfg)
+            down, _ = ffn_block(block_norm(x, lp, "mlp_norm", cfg), lp, cfg)
             x = (x + down).astype(cfg.dtype)
             for name, leaf in new.items():
                 left.setdefault(name, []).append(leaf)
-        return x, {name: tuple(leaves) for name, leaves in left.items()}
+        return dict(carry, x=x), {name: tuple(leaves)
+                                  for name, leaves in left.items()}
 
-    x, left = jax.lax.scan(period, x, layer_stacks(params))
-    cache = {name: join_period(leaves) for name, leaves in left.items()}
+    carry, made, first = {"x": x}, {}, 0
+    kinds_all = set(cfg.mixer_period)
+    if "gmu" in kinds_all:
+        carry["memory"] = jnp.zeros((B, P, cfg.mamba_channels), jnp.float32)
+    if "cross" in kinds_all:
+        carry["shared_k"] = carry["shared_v"] = jnp.zeros(
+            (B, P, cfg.kv_heads // 2, 2 * cfg.head_dim), cfg.dtype)
+    for (kinds, reps), stacks in zip(cfg.segments(),
+                                     layer_segments(params["layers"])):
+        carry, left = jax.lax.scan(
+            functools.partial(period, kinds=kinds, first=first), carry,
+            (stacks, jnp.arange(reps)))
+        for name, leaves in left.items():
+            made.setdefault(name, []).append(join_period(leaves))
+        first += len(kinds) * reps
+    cache = {name: join_segments(parts) for name, parts in made.items()}
     cache["pos"] = jnp.asarray(P, jnp.int32)
-    return x, cache
+    return carry["x"], cache

@@ -37,25 +37,27 @@ Params = Dict[str, Any]
 
 # ---- parameter structure ---------------------------------------------------
 
-def _stack_kinds(cfg: TransformerConfig, first: int, count: int):
-    """The mixer kinds of the ``count`` layers from layer ``first`` on, as
-    (the kinds of one repetition, repetitions): whole periods of
-    `cfg.mixer_period` where the count is whole periods (the stack is then
-    scanned a period at a time), else every layer once."""
-    kinds = tuple(cfg.mixer_kind(first + j) for j in range(count))
-    period = len(cfg.mixer_period)
-    return (kinds[:period], count // period) if count % period == 0 \
-        else (kinds, 1)
-
-
 def _per_kind(cfg: TransformerConfig, first: int, count: int, make):
-    """A stack of layers ``first`` .. ``first + count``: ``make(j, kind,
-    repetitions)`` for each kind of one repetition. One kind: what it
-    makes, as a stack has always been; several: a tuple, one entry a
-    position in the period."""
-    kinds, reps = _stack_kinds(cfg, first, count)
-    made = tuple(make(j, kind, reps) for j, kind in enumerate(kinds))
-    return made[0] if len(made) == 1 else made
+    """A stack of layers ``first`` .. ``first + count``: ``make(s, j, kind,
+    repetitions)`` for position j of segment s (`cfg.segments`). One
+    segment of one kind: what it makes, as a stack has always been; one
+    segment of several kinds: a tuple, one entry a position in the period;
+    several segments: a tuple of such tuples, one a segment."""
+    made = tuple(tuple(make(s, j, kind, reps)
+                       for j, kind in enumerate(kinds))
+                 for s, (kinds, reps) in enumerate(cfg.segments(first,
+                                                                count)))
+    if len(made) > 1:
+        return made
+    return made[0][0] if len(made[0]) == 1 else made[0]
+
+
+def layer_segments(layers) -> tuple:
+    """A stack as `_per_kind` builds it -> a tuple of segments, each a
+    tuple of stacks, one a position of the segment's period."""
+    if isinstance(layers, dict):
+        return ((layers,),)
+    return (tuple(layers),) if isinstance(layers[0], dict) else tuple(layers)
 
 
 def _stack_axes(cfg: TransformerConfig, moe: bool,
@@ -65,7 +67,27 @@ def _stack_axes(cfg: TransformerConfig, moe: bool,
         "wo": ("layers", "heads", "qkv_dim", "embed"),
         "mlp_norm": ("layers", "embed"),
     }
-    if kind == "kda":
+    if cfg.norm == "layer":
+        lay.update({"attn_norm_b": ("layers", "embed"),
+                    "mlp_norm_b": ("layers", "embed")})
+    if kind in ("mamba", "gmu"):
+        del lay["wo"]
+    if kind == "mamba":
+        lay.update({
+            "mamba_in": ("layers", None, "embed", "mlp"),
+            "mamba_conv": ("layers", None, "mlp"),
+            "mamba_conv_b": ("layers", "mlp"),
+            "mamba_x": ("layers", "mlp", None),
+            "mamba_dt": ("layers", None, "mlp"),
+            "mamba_dt_b": ("layers", "mlp"),
+            "mamba_A_log": ("layers", None, "mlp"),
+            "mamba_D": ("layers", "mlp"),
+            "mamba_out": ("layers", "mlp", "embed"),
+        })
+    elif kind == "gmu":
+        lay.update({"gmu_in": ("layers", "embed", "mlp"),
+                    "gmu_out": ("layers", "mlp", "embed")})
+    elif kind == "kda":
         proj = ("layers", "embed", "heads", "qkv_dim")
         lay.update({
             "kda_wq": proj, "kda_wk": proj, "kda_wv": proj,
@@ -94,11 +116,21 @@ def _stack_axes(cfg: TransformerConfig, moe: bool,
             "wkv_b": ("layers", None, "heads", "qkv_dim"),
         })
     else:
-        lay.update({
-            "wq": ("layers", "embed", "heads", "qkv_dim"),
-            "wk": ("layers", "embed", "kv_heads", "qkv_dim"),
-            "wv": ("layers", "embed", "kv_heads", "qkv_dim"),
-        })
+        lay["wq"] = ("layers", "embed", "heads", "qkv_dim")
+        if kind != "cross":     # a cross layer reads another layer's K/V
+            lay.update({
+                "wk": ("layers", "embed", "kv_heads", "qkv_dim"),
+                "wv": ("layers", "embed", "kv_heads", "qkv_dim"),
+            })
+        if cfg.attn_bias:
+            lay.update({"bq": ("layers", "heads", "qkv_dim"),
+                        "bo": ("layers", "embed")})
+            if kind != "cross":
+                lay.update({"bk": ("layers", "kv_heads", "qkv_dim"),
+                            "bv": ("layers", "kv_heads", "qkv_dim")})
+        if cfg.diff_attn:
+            lay.update({"diff_lambda": ("layers", None, None),
+                        "diff_norm": ("layers", None)})
         if cfg.attn_output_gate:
             lay["wg"] = ("layers", "embed", "heads", "qkv_dim")
     if cfg.qk_norm and kind != "kda":
@@ -121,13 +153,17 @@ def param_logical_axes(cfg: TransformerConfig) -> Params:
     dense = cfg.moe_dense_layers   # 0 without experts
     axes = {
         "embed": ("vocab", "embed"),
-        "layers": _per_kind(cfg, dense, cfg.n_layers - dense,
-                            lambda j, kind, n: _stack_axes(cfg, moe, kind)),
+        "layers": _per_kind(
+            cfg, dense, cfg.n_layers - dense,
+            lambda s, j, kind, n: _stack_axes(cfg, moe, kind)),
         "final_norm": ("embed",),
     }
+    if cfg.norm == "layer":
+        axes["final_norm_b"] = ("embed",)
     if dense:
         axes["dense_layers"] = _per_kind(
-            cfg, 0, dense, lambda j, kind, n: _stack_axes(cfg, False, kind))
+            cfg, 0, dense,
+            lambda s, j, kind, n: _stack_axes(cfg, False, kind))
     if cfg.mtp_layers:
         axes["mtp"] = {"h_norm": ("embed",), "e_norm": ("embed",),
                        "proj": (None, "embed"),
@@ -163,6 +199,35 @@ def _init_kda(k, cfg: TransformerConfig, L: int, normal) -> Params:
     return lay
 
 
+def _init_mamba(k, cfg: TransformerConfig, L: int, normal,
+                out_scale: float) -> Params:
+    """The leaves of ``L`` stacked Mamba-1 mixers. `A_log`, `dt_b` and `D`
+    as the published implementation draws them: A = 1 .. N a channel, the
+    step's bias so that softplus gives dt log-uniform in [0.001, 0.1), D
+    ones. The step's and the convolution's biases are small and made
+    here; `A_log` [N, C] state-major, as the states are held."""
+    d, C, N, R = cfg.d_model, cfg.mamba_channels, cfg.mamba_d_state, \
+        cfg.mamba_dt_rank
+    pd, taps = cfg.param_dtype, cfg.mamba_d_conv
+    dt = jnp.exp(jax.random.uniform(next(k), (L, C), jnp.float32,
+                                    jnp.log(0.001), jnp.log(0.1)))
+    return {
+        # a's matrix, then z's: the scan's input, then the gate
+        "mamba_in": normal(next(k), (L, 2, d, C), d ** -0.5),
+        "mamba_conv": normal(next(k), (L, taps, C), taps ** -0.5),
+        "mamba_conv_b": scaled_normal(next(k), (L, C), taps ** -0.5, pd),
+        # columns: the step's rank, then B, then C
+        "mamba_x": normal(next(k), (L, C, R + 2 * N), C ** -0.5),
+        "mamba_dt": normal(next(k), (L, R, C), R ** -0.5),
+        "mamba_dt_b": (dt + jnp.log(-jnp.expm1(-dt))).astype(pd),
+        "mamba_A_log": jnp.broadcast_to(
+            jnp.log(jnp.arange(1, N + 1, dtype=jnp.float32))[None, :, None],
+            (L, N, C)).astype(pd),
+        "mamba_D": jnp.ones((L, C), pd),
+        "mamba_out": normal(next(k), (L, C, d), out_scale * (d / C) ** 0.5),
+    }
+
+
 def _init_stack(k, cfg: TransformerConfig, L: int, moe: bool,
                 kind: str = "attention", normal=None) -> Params:
     """``L`` stacked layers of one kind, keys drawn from the iterator
@@ -176,8 +241,20 @@ def _init_stack(k, cfg: TransformerConfig, L: int, moe: bool,
     out_scale = (2 * (cfg.init_depth or cfg.n_layers)) ** -0.5 * d ** -0.5
     lay = {"attn_norm": jnp.ones((L, d), pd),
            "mlp_norm": jnp.ones((L, d), pd)}
+    if cfg.norm == "layer":
+        lay.update({"attn_norm_b": jnp.zeros((L, d), pd),
+                    "mlp_norm_b": jnp.zeros((L, d), pd)})
     out_heads = (H, cfg.v_head_dim)
-    if kind == "kda":
+    if kind == "mamba":
+        lay.update(_init_mamba(k, cfg, L, normal, out_scale))
+        out_heads = None
+    elif kind == "gmu":
+        C = cfg.mamba_channels
+        lay.update({"gmu_in": normal(next(k), (L, d, C), in_scale),
+                    "gmu_out": normal(next(k), (L, C, d),
+                                      out_scale * (d / C) ** 0.5)})
+        out_heads = None
+    elif kind == "kda":
         lay.update(_init_kda(k, cfg, L, normal))
         out_heads = (cfg.kda_heads, cfg.kda_head_dim)
     elif cfg.kv_lora_rank:
@@ -195,14 +272,29 @@ def _init_stack(k, cfg: TransformerConfig, L: int, moe: bool,
                             rkv ** -0.5),
         })
     else:
-        lay.update({
-            "wq": normal(next(k), (L, d, H, hd), in_scale),
-            "wk": normal(next(k), (L, d, KV, hd), in_scale),
-            "wv": normal(next(k), (L, d, KV, hd), in_scale),
-        })
+        lay["wq"] = normal(next(k), (L, d, H, hd), in_scale)
+        if kind != "cross":
+            lay.update({"wk": normal(next(k), (L, d, KV, hd), in_scale),
+                        "wv": normal(next(k), (L, d, KV, hd), in_scale)})
+        if cfg.attn_bias:   # small, made here, in one draw
+            heads = H + (0 if kind == "cross" else 2 * KV)
+            b = scaled_normal(next(k), (L, heads * hd + d), in_scale, pd)
+            lay.update({"bq": b[:, :H * hd].reshape(L, H, hd),
+                        "bo": b[:, heads * hd:]})
+            if kind != "cross":
+                lay.update({
+                    "bk": b[:, H * hd:(H + KV) * hd].reshape(L, KV, hd),
+                    "bv": b[:, (H + KV) * hd:heads * hd].reshape(L, KV, hd)})
+        if cfg.diff_attn:
+            # lq1, lk1, lq2, lk2: normal(0, 0.1), as published; the pairs'
+            # norm's gain over [v1 | v2]
+            lay.update({
+                "diff_lambda": scaled_normal(next(k), (L, 4, hd), 0.1, pd),
+                "diff_norm": jnp.ones((L, 2 * hd), pd)})
         if cfg.attn_output_gate:
             lay["wg"] = normal(next(k), (L, d, H, hd), in_scale)
-    lay["wo"] = normal(next(k), (L, *out_heads, d), out_scale)
+    if out_heads is not None:
+        lay["wo"] = normal(next(k), (L, *out_heads, d), out_scale)
     if cfg.qk_norm and kind != "kda":
         lay.update({"q_norm": jnp.ones((L, H * hd), pd),
                     "k_norm": jnp.ones((L, KV * hd), pd)})
@@ -238,18 +330,24 @@ def init_params(rng: jax.Array, cfg: TransformerConfig,
     def stack(first, count, moe, own, salt):
         """Layers ``first`` .. + ``count``. One kind: from ``own``, the
         keys that stack has always drawn from; a position of a period:
-        from keys of its own."""
-        one = len(_stack_kinds(cfg, first, count)[0]) == 1
-        return _per_kind(cfg, first, count, lambda j, kind, n: _init_stack(
-            own if one else iter(jax.random.split(
-                jax.random.fold_in(rng, salt + j), n_keys)),
-            cfg, n, moe, kind, normal))
+        from keys of its own (and of its segment's, past the first)."""
+        segments = cfg.segments(first, count)
+        one = len(segments) == 1 and len(segments[0][0]) == 1
+
+        def keys(s, j):
+            key = jax.random.fold_in(rng, salt + j)
+            return iter(jax.random.split(
+                jax.random.fold_in(key, s) if s else key, n_keys))
+        return _per_kind(cfg, first, count, lambda s, j, kind, n: _init_stack(
+            own if one else keys(s, j), cfg, n, moe, kind, normal))
     params: Params = {
         # an MoE model's leading dense layers are a stack of their own
         "layers": stack(dense, cfg.n_layers - dense, moe, k, 16),
         "embed": normal(next(k), (v, d), d ** -0.5),
         "final_norm": jnp.ones((d,), pd),
     }
+    if cfg.norm == "layer":
+        params["final_norm_b"] = jnp.zeros((d,), pd)
     if not cfg.tie_embeddings:
         params["lm_head"] = normal(next(k), (d, v), d ** -0.5)
     # further stacks draw from keys of their own, so the leaves above are
@@ -301,6 +399,23 @@ def rms_norm(x, gamma, eps):
     x32 = x.astype(jnp.float32)
     scale = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
     return (x32 * scale).astype(x.dtype) * gamma.astype(x.dtype)
+
+
+def layer_norm(x, gamma, beta, eps):
+    x32 = x.astype(jnp.float32)
+    x32 = x32 - jnp.mean(x32, axis=-1, keepdims=True)
+    scale = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return (x32 * scale).astype(x.dtype) * gamma.astype(x.dtype) \
+        + beta.astype(x.dtype)
+
+
+def block_norm(x, p, name: str, cfg: TransformerConfig):
+    """The norm ``name`` of a layer (or the final one) as `cfg.norm` says:
+    RMSNorm with the gain ``p[name]``, or LayerNorm with the bias
+    ``p[name + "_b"]`` beside it."""
+    if cfg.norm == "layer":
+        return layer_norm(x, p[name], p[name + "_b"], cfg.rms_eps)
+    return rms_norm(x, p[name], cfg.rms_eps)
 
 
 def _rope(x, positions, theta):
@@ -538,14 +653,158 @@ def kda_mixer(h, lp, cfg: TransformerConfig, *, valid=None, tail=None,
     return (out, state, kept) if valid is not None else out
 
 
+# ---- Mamba-1 selective scan, gated memory unit ------------------------------
+
+def mamba_mixer(h, lp, cfg: TransformerConfig, *, valid=None, tail=None,
+                step=None):
+    """A Mamba-1 mixer on normed rows h [B, T, d]: [a, z] = W_in h; a
+    through a causal depthwise convolution with a bias, then SiLU; [r, B,
+    C] = W_x a; dt = softplus(W_dt r + b_dt); the selective scan with A =
+    -exp(A_log) (ops/mamba.py: dt, A, the exponent and the state float32);
+    y = scan + D a, which is also the MEMORY a later gated memory unit
+    reads (after the skip, before the gate); out = W_out (y . silu(z)).
+    Each part under a `jax.named_scope` a profile groups by.
+
+    Prefill gives ``valid`` [B, T] bool (False on a row's left padding:
+    such a row is zero before the convolution and has dt = 0, so it writes
+    nothing and decays nothing) and gets (out, y, the final state [B, N, C]
+    float32, the last `mamba_d_conv - 1` rows of a before the convolution
+    [B, taps - 1, C]). Decode gives T = 1, the slots' ``tail`` of that shape
+    and ``step``, a function (dt, a, B, C, A) -> y [B, C] float32 that
+    advances the states (engine: `mamba_decode_step` on the layer's slice
+    of the cache), and gets (out, y, the tail shifted by this token)."""
+    from ray_tpu.ops import mamba
+
+    dt_, f32 = cfg.dtype, jnp.float32
+    T = h.shape[1]
+    N, R, taps = cfg.mamba_d_state, cfg.mamba_dt_rank, cfg.mamba_d_conv
+    with jax.named_scope("mamba.proj"):
+        a, z = (jnp.einsum("btd,dc->btc", h, lp["mamba_in"][g].astype(dt_))
+                for g in range(2))
+    if valid is not None:
+        a = jnp.where(valid[:, :, None], a, 0)
+    with jax.named_scope("mamba.conv"):
+        rows = jnp.pad(a, ((0, 0), (taps - 1, 0), (0, 0))) if tail is None \
+            else jnp.concatenate([tail.astype(a.dtype), a], axis=1)
+        kept = rows[:, rows.shape[1] - (taps - 1):]
+        w = lp["mamba_conv"].astype(f32)
+        a = jax.nn.silu(
+            sum(rows[:, i:i + T].astype(f32) * w[i] for i in range(taps))
+            + lp["mamba_conv_b"].astype(f32)).astype(dt_)
+        rbc = jnp.einsum("btc,cr->btr", a, lp["mamba_x"].astype(dt_),
+                         preferred_element_type=f32)
+        r, Bm, Cm = rbc[..., :R], rbc[..., R:R + N], rbc[..., R + N:]
+        # the step keeps float32 through its small projection
+        step_size = jax.nn.softplus(jnp.einsum(
+            "btr,rc->btc", r, lp["mamba_dt"].astype(f32),
+            precision=jax.lax.Precision.HIGHEST)
+            + lp["mamba_dt_b"].astype(f32))
+        if valid is not None:
+            step_size = jnp.where(valid[:, :, None], step_size, 0.0)
+    A = -jnp.exp(lp["mamba_A_log"].astype(f32))
+    if step is not None:
+        y = step(step_size[:, 0], a[:, 0], Bm[:, 0], Cm[:, 0], A)[:, None]
+    else:
+        y, state = mamba.mamba_scan(step_size, a, Bm, Cm, A)
+    with jax.named_scope("mamba.out"):
+        y = y + lp["mamba_D"].astype(f32) * a.astype(f32)
+        out = jnp.einsum(
+            "btc,cd->btd", (y * jax.nn.silu(z.astype(f32))).astype(dt_),
+            lp["mamba_out"].astype(dt_))
+    if step is not None or valid is None:
+        return out, y, kept
+    return out, y, state, kept
+
+
+def gmu_mixer(h, memory, lp, cfg: TransformerConfig):
+    """A gated memory unit: W_out (m . silu(W_in h)), ``memory`` [B, T, C]
+    float32 the same tokens' scan output of the nearest mamba layer."""
+    with jax.named_scope("gmu"):
+        gate = jnp.einsum("btd,dc->btc", h, lp["gmu_in"].astype(cfg.dtype),
+                          preferred_element_type=jnp.float32)
+        return jnp.einsum(
+            "btc,cd->btd", (memory * jax.nn.silu(gate)).astype(cfg.dtype),
+            lp["gmu_out"].astype(cfg.dtype))
+
+
+# ---- differential attention -------------------------------------------------
+
+def diff_qkv(h, lp, cfg: TransformerConfig):
+    """The projections of a differential attention layer on normed rows h
+    [B, T, d], with their biases, in PAIRS of adjacent heads: q [B, T, H /
+    2, 2, 2 hd], query pair j as [q1 | 0] and [0 | q2], so that each scores
+    against a key pair [k1 | k2] over whole lanes and reads only its own
+    half; k, v [B, T, KV / 2, 2 hd], None for a cross layer (which holds
+    no `wk`)."""
+    dt = cfg.dtype
+    B, T = h.shape[:2]
+    hd = cfg.head_dim
+
+    def proj(w, b):
+        x = jnp.einsum("btd,dhk->bthk", h, lp[w].astype(dt))
+        return x + lp[b].astype(dt) if b in lp else x
+    with jax.named_scope("diff_attn.qkv"):
+        q = proj("wq", "bq").reshape(B, T, cfg.n_heads // 2, 2, 1, hd)
+        q = (q * jnp.eye(2, dtype=dt)[:, :, None]).reshape(
+            B, T, cfg.n_heads // 2, 2, 2 * hd)
+        if "wk" not in lp:
+            return q, None, None
+        k, v = (proj(w, b).reshape(B, T, cfg.kv_heads // 2, 2 * hd)
+                for w, b in (("wk", "bk"), ("wv", "bv")))
+    return q, k, v
+
+
+def diff_out(o, lp, cfg: TransformerConfig, layer):
+    """o [B, T, H / 2, 2, 2 hd] float32 (a pair's two softmaxes over [v1 |
+    v2]) -> [B, T, d]: lam from the layer's four vectors and its index
+    ``layer`` (0-based, the whole model's; traced or not), the difference
+    and the pairs' RMSNorm in float32, the output projection."""
+    f32 = jnp.float32
+    B, T, pairs = o.shape[:3]
+    with jax.named_scope("diff_attn.mix"):
+        lam0 = 0.8 - 0.6 * jnp.exp(-0.3 * jnp.asarray(layer, f32))
+        lq1, lk1, lq2, lk2 = lp["diff_lambda"].astype(f32)
+        lam = jnp.exp(jnp.sum(lq1 * lk1)) - jnp.exp(jnp.sum(lq2 * lk2)) + lam0
+        o = o[:, :, :, 0] - lam * o[:, :, :, 1]
+        o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
+                              + cfg.rms_eps)
+        o = (o * lp["diff_norm"].astype(f32) * (1.0 - lam0)).astype(cfg.dtype)
+    with jax.named_scope("diff_attn.out"):
+        out = jnp.einsum("bthk,hkd->btd",
+                         o.reshape(B, T, 2 * pairs, cfg.head_dim),
+                         lp["wo"].astype(cfg.dtype))
+        return out + lp["bo"].astype(cfg.dtype) if "bo" in lp else out
+
+
+def refuse_untrained(cfg: TransformerConfig):
+    """`forward` and `loss_fn` walk one segment of attention and KDA layers:
+    raise for a configuration whose layers they would compute as another
+    model's (a stated layer pattern, its mamba, gmu, window and cross
+    layers, differential attention, LayerNorm, projection biases)."""
+    cannot = [what for has, what in (
+        (cfg.layer_pattern is not None and len(cfg.layer_pattern) > 1,
+         "a layer pattern of several segments (layer_pattern)"),
+        (set(cfg.mixer_period) - {"attention", "kda"},
+         "mamba, gmu, window or cross layers"),
+        (cfg.diff_attn, "differential attention (diff_attn)"),
+        (cfg.attn_bias, "attention projection biases (attn_bias)"),
+        (cfg.norm != "rms", "LayerNorm (norm)")) if has]
+    if cannot:
+        raise NotImplementedError(
+            "training is not implemented for a configuration with "
+            + "; ".join(cannot) + ": it serves (models/engine.py) and does "
+            "not train yet")
+
+
 def refuse_unserved(cfg: TransformerConfig):
-    """The serving paths (models/generate, models/engine) walk ONE stack of
-    layers, a period of mixer kinds at a time, hold for an attention layer
-    one k and one v row of `head_dim` a token and for a KDA layer a
+    """The serving paths (models/generate, models/engine) walk the segments
+    of ONE stack of layers, a period of mixer kinds at a time; hold for an
+    attention layer one k and one v row of `head_dim` a token (a window
+    layer: the last `sliding_window` of them), for a KDA or mamba layer a
     float32 state and the convolutions' tail a slot, and emit one token a
     step: raise for a configuration that needs a latent cache and the
-    absorbed decode form, a second stack, or a step of more than one
-    token."""
+    absorbed decode form, a second stack beside the first (leading dense
+    layers, a prediction module) or a step of more than one token."""
     cannot = [what for has, what in (
         (cfg.kv_lora_rank, "latent attention (kv_lora_rank: a latent slot "
          "cache and the absorbed decode form)"),
@@ -584,7 +843,7 @@ def ffn_block(h, lp, cfg: TransformerConfig, mesh: Optional[Mesh] = None):
 def lm_head(params: Params, x, cfg: TransformerConfig,
             mesh: Optional[Mesh] = None):
     """Final norm + (tied or separate) vocabulary projection."""
-    x = rms_norm(x, params["final_norm"], cfg.rms_eps)
+    x = block_norm(x, params, "final_norm", cfg)
     head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
     logits = jnp.einsum("btd,dv->btv", x.astype(jnp.float32),
                         head.astype(jnp.float32))
@@ -692,6 +951,7 @@ def _trunk(params: Params, tokens: jax.Array, cfg: TransformerConfig,
            mesh: Optional[Mesh] = None):
     """tokens [B, T] -> (the last layer's hidden state [B, T, d], before
     the final norm; the expert layers' stats, one entry a layer)."""
+    refuse_untrained(cfg)
     B, T = tokens.shape
     x = params["embed"].astype(cfg.dtype)[tokens]  # [B, T, d]
     x = _wlc(x, ("batch", "seq", "embed"), mesh=mesh)

@@ -67,7 +67,8 @@ def mosaic(monkeypatch):
     da = importlib.import_module("ray_tpu.ops.decode_attention")
     kda = importlib.import_module("ray_tpu.ops.kda")
     gm = importlib.import_module("ray_tpu.ops.grouped_matmul")
-    for module in (fa, da, kda, gm):
+    mamba = importlib.import_module("ray_tpu.ops.mamba")
+    for module in (fa, da, kda, gm, mamba):
         monkeypatch.setattr(module, "_use_interpret", lambda: False)
     for module in (engine, moe):
         monkeypatch.setattr(module, "_on_chip", lambda: True)
@@ -828,3 +829,77 @@ def test_solar_open2_serve_programs_compile_and_move_no_state(one_chip,
     assert _sized_ops(text, state.shape, "copy|convert|select|scatter") == []
     assert prefill.memory_analysis().temp_size_in_bytes < state_bytes
     assert weights_converted(text, rows=K * P) == []
+
+
+def test_phi4flash_serve_programs_compile_and_copy_no_leaf(one_chip, mosaic):
+    """The cell `phi-4-mini-flash-reasoning.reason-closed-64` as the
+    benchmark runs it (all 32 layers and 200,064 rows, 64 slots x 2,048
+    positions, decode chunks of 4, a prefill group of 4 x 1,024): both
+    served programs compile for one chip within 15.75 GiB with the weights
+    as the engine holds them (bf16, the tied table float32). Decode walks
+    three scans (the pattern's segments; the boundary segment's single
+    repeat inlined), runs the named kernel on the stacked states where they
+    lie (nothing copies, converts, selects over or scatters into a
+    whole-state-sized result), copies neither the ONE full-length K/V leaf
+    that eight layers read nor the rings whole, and converts no weight but
+    the tied head's table."""
+    from benchmark.harness import spec
+    from ray_tpu.models.engine import (decode_slots, init_slot_cache,
+                                       prefill_slots)
+    from ray_tpu.models.transformer import init_params, serving_params
+
+    bench = spec.load_benchmark()
+    conf = spec.load_config(bench, "phi-4-mini-flash-reasoning")
+    dep = spec.load_traffic("reason-closed-64")["deployment"]
+    cfg = spec.build_transformer_config(conf)
+    slots = dep["slots"]
+    max_len = dep["max_prompt_len"] + dep["max_new_tokens"]
+    assert (slots, max_len, cfg.n_layers, cfg.vocab_size) == (
+        64, 2048, 32, 200064)
+    params = _on(jax.eval_shape(
+        lambda k: serving_params(init_params(k, cfg), cfg),
+        jax.random.key(0)), one_chip)
+    assert params["embed"].dtype == jnp.float32
+    assert params["layers"][0][0]["mamba_in"].shape == (8, 2, 2560, 5120)
+    cache = _on(jax.eval_shape(
+        lambda: init_slot_cache(cfg, slots, max_len)), one_chip)
+    state = cache["mamba_state"]
+    assert state.shape == (9, 64, 16, 5120) and state.dtype == jnp.float32
+    assert cache["k"].shape == (1, 64, 10, 2048, 128)
+    assert cache["win_k"].shape == (8, 64, 10, 512, 128)
+    rng = _on(jax.eval_shape(lambda: jax.random.key(0)), one_chip)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    active = jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one_chip)
+    decode = decode_slots.lower(params, cache, i32(slots), active, rng, cfg,
+                                steps=4).compile()
+    assert _device_bytes(decode) < HBM_BYTES
+    text = decode.as_text()
+    assert re.search(r"%mamba_decode_step\S* = [^\n]*custom-call\(", text)
+    for scope in ("mamba.proj", "mamba.conv", "mamba.step", "mamba.out",
+                  "gmu", "diff_attn.qkv", "diff_attn.mix", "diff_attn.out"):
+        assert scope in text, scope
+    moved = "copy|convert|select|scatter|dynamic-slice|fusion|transpose"
+    assert _sized_ops(text, state.shape, moved) == []
+    assert _sized_ops(text, state.shape[1:], moved) == []
+    for name in ("k", "win_k"):
+        assert _whole_cache_ops(text, cache[name].shape) == [], name
+    # (of 2 M numbers or more: the scan's A and the convolution's taps
+    # are read as float32, 82 K numbers a layer)
+    converted = {c.split(": ")[1] for c in _weight_converts(text, params)
+                 if np.prod(eval(c.split(": ")[1])) >= 2 << 20}
+    assert converted == {"[200064,2560]"}
+    # the temporaries: the head's bf16 copy (1.02 GB) and small change
+    assert decode.memory_analysis().temp_size_in_bytes < 1.2e9
+
+    K, P = 4, dep["max_prompt_len"]
+    prefill = prefill_slots.lower(params, cache, i32(K, P), i32(K), i32(K),
+                                  rng, cfg).compile()
+    assert _device_bytes(prefill) < HBM_BYTES
+    text = prefill.as_text()
+    for scope in ("mamba.scan", "mamba.conv", "gmu", "diff_attn.mix"):
+        assert scope in text, scope
+    assert _sized_ops(text, state.shape, "copy|convert|select|scatter") == []
+    assert prefill.memory_analysis().temp_size_in_bytes < 2.0e9

@@ -1,0 +1,417 @@
+"""The Phi-4-mini-flash decoder through the engine's programs at toy widths
+(all 32 layers of the pattern, a window of 8), float32 on the CPU, held to
+the configuration's plain reference (`benchmark/architectures/
+phi4flash.py`): prefill and 40 decode steps through the slot cache at every
+step, left padding, a slot's reset at admission, an inactive slot, the
+one-token state kernel in the Pallas interpreter, the cache's leaves and
+their bytes, and what the configuration refuses."""
+
+import dataclasses
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmark.harness import spec  # noqa: E402
+from ray_tpu.models import engine as E  # noqa: E402
+from ray_tpu.models.config import TransformerConfig  # noqa: E402
+from ray_tpu.models.generate import (_final_logits, _prefill_hidden,  # noqa: E402
+                                     window_ring)
+from ray_tpu.models.transformer import (forward, init_params,  # noqa: E402
+                                        param_logical_axes)
+from ray_tpu.ops import mamba  # noqa: E402
+
+BENCH = spec.load_benchmark()
+CONF = spec.load_config(BENCH, "phi-4-mini-flash-reasoning")
+ARCH = spec.load_architecture(CONF)
+TOY = dict(vocab_size=96, d_model=32, n_heads=4, n_kv_heads=2, head_dim=8,
+           d_ff=48, mamba_d_state=4, mamba_dt_rank=3, sliding_window=8,
+           dtype="float32", param_dtype="float32")
+TOL = 2e-4      # `reference.LOGIT_REL_RMS_TOL["float32"]`
+WINDOW = 8
+
+
+def _rel_rms(got, want):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    return float(np.sqrt(np.mean((got - want) ** 2) / np.mean(want ** 2)))
+
+
+@pytest.fixture(scope="module")
+def toy():
+    """(cfg, fields, params): seeded weights with every bias moved off its
+    initial zero, so that a bias left out shows."""
+    fields = dict(spec.transformer_fields(CONF), **TOY)
+    cfg = spec.build_transformer_config(CONF, **TOY)
+    params = init_params(jax.random.key(1), cfg)
+
+    def moved(path, x):
+        name = path[-1].key if hasattr(path[-1], "key") else ""
+        if name.endswith("_b") or name == "mamba_D":
+            return x + 0.1 * jax.random.normal(
+                jax.random.key(len(name) + x.size), x.shape)
+        return x
+    return cfg, fields, jax.tree_util.tree_map_with_path(moved, params)
+
+
+def _group(prompts, P):
+    toks = np.zeros((len(prompts), P), np.int32)
+    starts = np.zeros(len(prompts), np.int32)
+    for i, p in enumerate(prompts):
+        toks[i, P - len(p):] = p
+        starts[i] = P - len(p)
+    return jnp.asarray(toks), jnp.asarray(starts)
+
+
+def _prompts(lengths, seed=0):
+    rng = np.random.default_rng(seed)
+    return [[int(t) for t in rng.integers(1, 96, n)] for n in lengths]
+
+
+def _prefill(cfg, params, cache, prompts, P, slots):
+    toks, starts = _group(prompts, P)
+    return E.prefill_slots(params, cache, toks,
+                           jnp.asarray(slots, jnp.int32), starts,
+                           jax.random.key(0), cfg)
+
+
+# ---- the engine's programs against the reference -------------------------------
+
+def test_prefill_then_forty_decode_steps_agree_at_every_step(toy):
+    """Three rows of one prefill group (one fills its bucket, two are
+    padded on the left), then 40 decode steps: five wraps of the window's
+    ring of 8, every step's logits against the reference's on the growing
+    sequence."""
+    cfg, fields, params = toy
+    P, steps = 16, 40
+    prompts = _prompts([16, 11, 5])
+    cache = E.init_slot_cache(cfg, 3, P + steps + 2)
+    cache, tok = _prefill(cfg, params, cache, prompts, P, [0, 1, 2])
+    toks, starts = _group(prompts, P)
+    pre = _final_logits(params, _prefill_hidden(
+        params, toks, cfg, P, starts)[0][:, -1:], cfg)[:, 0]
+    seqs = [list(p) for p in prompts]
+    for i in range(3):
+        want = ARCH.reference_logits(params, seqs[i], fields, CONF, last=1)
+        assert _rel_rms(pre[i], want[0]) < TOL
+        assert int(tok[i]) == int(np.argmax(want[0]))
+    decode = jax.jit(lambda p, c, t: E._decode_one(p, c, t, cfg))
+    for _ in range(steps):
+        for i in range(3):
+            seqs[i].append(int(tok[i]))
+        cache, logits = decode(params, cache, tok)
+        for i in range(3):
+            want = ARCH.reference_logits(params, seqs[i], fields, CONF,
+                                         last=1)
+            assert _rel_rms(logits[i], want[0]) < TOL, (len(seqs[i]), i)
+        tok = jnp.argmax(logits, -1).astype(jnp.int32)
+    assert len(seqs[0]) == P + steps > 5 * WINDOW
+    assert int(cache["pos"][0]) == P + steps
+
+
+def test_the_served_chunk_is_the_single_steps(toy):
+    """`decode_slots`, the program the scheduler dispatches: four substeps
+    in one program give the tokens four single steps give."""
+    cfg, _, params = toy
+    prompts = _prompts([9, 16], seed=3)
+    fresh = E.init_slot_cache(cfg, 2, 32)
+    cache, tok = _prefill(cfg, params, fresh, prompts, 16, [0, 1])
+    one, singles = jax.tree.map(jnp.copy, cache), [tok]
+    for _ in range(4):
+        one, logits = E._decode_one(params, one, singles[-1], cfg)
+        singles.append(jnp.argmax(logits, -1).astype(jnp.int32))
+    _, chunk = E.decode_slots(params, cache, tok, jnp.ones(2, bool),
+                              jax.random.key(0), cfg, steps=4)
+    np.testing.assert_array_equal(np.asarray(chunk),
+                                  np.stack(singles, axis=1))
+
+
+def test_left_padding_reaches_no_leaf(toy):
+    """Two prompts of different lengths in one prefill group leave each
+    slot what the prompt alone leaves it: state, tail, ring and K/V (the
+    padded places of K/V hold junk no mask lets through: compared from
+    the row's first real position on)."""
+    cfg, _, params = toy
+    P = 16
+    prompts = _prompts([13, 6], seed=1)
+    both, first = _prefill(cfg, params, E.init_slot_cache(cfg, 2, 24),
+                           prompts, P, [0, 1])
+    for i, prompt in enumerate(prompts):
+        alone, tok = _prefill(cfg, params, E.init_slot_cache(cfg, 2, 24),
+                              [prompt], P, [i])
+        assert int(tok[0]) == int(first[i])
+        for name in ("mamba_state", "mamba_tail", "win_k", "win_v"):
+            np.testing.assert_allclose(both[name][:, i], alone[name][:, i],
+                                       rtol=1e-5, atol=1e-6, err_msg=name)
+        for name in ("k", "v"):
+            real = slice(P - len(prompt), P)
+            np.testing.assert_allclose(
+                both[name][:, i, :, real], alone[name][:, i, :, real],
+                rtol=1e-5, atol=1e-6, err_msg=name)
+    # the shorter row's state is its own prompt's, not the padding's
+    assert not np.allclose(both["mamba_state"][:, 0],
+                           both["mamba_state"][:, 1])
+
+
+def test_a_slot_admitted_anew_reads_nothing_of_its_last_tenant(toy):
+    """Admission is the reset: a request prefilled into a slot another
+    request filled and decoded in decodes as it does from a fresh cache."""
+    cfg, _, params = toy
+    P, steps = 16, 10
+    old, new = _prompts([16], seed=5), _prompts([7], seed=6)
+    decode = jax.jit(lambda p, c, t: E._decode_one(p, c, t, cfg))
+
+    def run(cache):
+        cache, tok = _prefill(cfg, params, cache, new, P, [1])
+        tok = jnp.zeros(2, jnp.int32).at[1].set(tok[0])
+        out = []
+        for _ in range(steps):
+            cache, logits = decode(params, cache, tok)
+            out.append(logits[1])
+            tok = jnp.argmax(logits, -1).astype(jnp.int32)
+        return jnp.stack(out)
+
+    used = E.init_slot_cache(cfg, 2, 40)
+    used, tok = _prefill(cfg, params, used, old, P, [1])
+    tok = jnp.zeros(2, jnp.int32).at[1].set(tok[0])
+    for _ in range(20):     # past the window: every place of the ring written
+        used, logits = decode(params, used, tok)
+        tok = jnp.argmax(logits, -1).astype(jnp.int32)
+    np.testing.assert_allclose(run(used), run(E.init_slot_cache(cfg, 2, 40)),
+                               rtol=1e-5, atol=1e-6)
+
+
+def test_an_inactive_slot_keeps_its_leaves(toy):
+    """A slot that is not active keeps its states and tails bit for bit,
+    and of its keys and values everything but the one place at its own
+    frozen `pos` (the ring: `pos % window`), which no mask lets through and
+    the next real write overwrites."""
+    cfg, _, params = toy
+    P = 16
+    cache, tok = _prefill(cfg, params, E.init_slot_cache(cfg, 2, 24),
+                          _prompts([12, 16], seed=2), P, [0, 1])
+    before = jax.tree.map(np.asarray, cache)
+    after, _ = E.decode_slots(params, cache, tok, jnp.asarray([True, False]),
+                              jax.random.key(0), cfg, steps=3)
+    assert (int(after["pos"][0]), int(after["pos"][1])) == (P + 3, P)
+    for name in ("mamba_state", "mamba_tail"):
+        np.testing.assert_array_equal(after[name][:, 1], before[name][:, 1])
+        assert not np.array_equal(after[name][:, 0], before[name][:, 0])
+    for name, place in (("k", P), ("v", P), ("win_k", P % WINDOW),
+                        ("win_v", P % WINDOW)):
+        keep = np.arange(before[name].shape[3]) != place
+        np.testing.assert_array_equal(np.asarray(after[name])[:, 1][:, :, keep],
+                                      before[name][:, 1][:, :, keep])
+
+
+# ---- the one-token state update --------------------------------------------------
+
+def _step_inputs(slots=3, N=4, C=256, seed=0):
+    k = jax.random.split(jax.random.key(seed), 6)
+    return dict(
+        dt=0.1 * jax.nn.softplus(jax.random.normal(k[0], (slots, C))),
+        a=jax.random.normal(k[1], (slots, C)),
+        Bm=jax.random.normal(k[2], (slots, N)),
+        Cm=jax.random.normal(k[3], (slots, N)),
+        A=-jnp.exp(jax.random.normal(k[4], (N, C))),
+        state=jax.random.normal(k[5], (2, slots, N, C)))
+
+
+def test_the_kernel_in_the_interpreter_is_the_numpy_step():
+    x = _step_inputs()
+    active = jnp.asarray([True, False, True])
+    args = (x["dt"], x["a"], x["Bm"], x["Cm"], x["A"], active)
+    s_np, y_np = mamba.mamba_decode_step(x["state"], 1, *args, kernel=False)
+    s_k, y_k = mamba.mamba_decode_step(x["state"], 1, *args, kernel=True)
+    assert s_k.dtype == jnp.float32 and y_k.dtype == jnp.float32
+    np.testing.assert_allclose(s_k, s_np, rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(y_k)[[0, 2]],
+                               np.asarray(y_np)[[0, 2]], rtol=1e-5, atol=1e-5)
+    # the other layer and the inactive slot bit for bit
+    np.testing.assert_array_equal(s_k[0], x["state"][0])
+    np.testing.assert_array_equal(s_k[1, 1], x["state"][1, 1])
+    # the scan over a row is the step, a token at a time
+    T = 9
+    rows = [_step_inputs(seed=10 + t) for t in range(T)]
+    y, last = mamba.mamba_scan(*(jnp.stack([r[n] for r in rows], axis=1)
+                                 for n in ("dt", "a", "Bm", "Cm")), x["A"])
+    state, every = jnp.zeros_like(x["state"]), jnp.ones(3, bool)
+    for t, r in enumerate(rows):
+        state, y_t = mamba.mamba_decode_step(
+            state, 0, r["dt"], r["a"], r["Bm"], r["Cm"], x["A"], every,
+            kernel=True)
+        np.testing.assert_allclose(y_t, y[:, t], rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(state[0], last, rtol=1e-5, atol=1e-6)
+
+
+def test_a_state_held_in_bfloat16_does_not_agree():
+    """`assumed.state_dtype` float32 is held HERE: eight steps of the
+    kernel whose state is rounded to bfloat16 between them leave the
+    float32 tolerance behind in what the layer hands on. (At the toy
+    model's LOGITS the same rounding reads 0.5e-4 to 1.3e-4, under the
+    comparison's 2e-4: the check on the chip could not tell, which is why
+    the dtype is held by this test.)"""
+    x, every = _step_inputs(seed=3), jnp.ones(3, bool)
+    rows = [_step_inputs(seed=20 + t) for t in range(8)]
+
+    def run(held):
+        state, ys = x["state"].astype(held), []
+        for r in rows:
+            state, y = mamba.mamba_decode_step(
+                state.astype(jnp.float32), 0, r["dt"], r["a"], r["Bm"],
+                r["Cm"], x["A"], every, kernel=True)
+            assert state.dtype == jnp.float32
+            state = state.astype(held)
+            ys.append(y)
+        return jnp.stack(ys)
+    want = run(jnp.float32)
+    assert _rel_rms(run(jnp.bfloat16)[-1], want[-1]) > 10 * TOL
+
+
+# ---- the cache ---------------------------------------------------------------------
+
+def test_the_cache_has_one_full_length_layer_and_the_bytes_stated():
+    """The cell's cache by shape (nothing is allocated): ONE layer of
+    full-length keys and values, as 10 pairs of 128, that eight layers
+    read; eight rings of 512 places; nine float32 states, state-major, and
+    their tails: 2.22 GB at 64 slots x 2,048 positions, where 32 layers of
+    full-length keys and values would be 21.5 GB."""
+    cfg = spec.build_transformer_config(CONF)
+    slots, max_len = 64, 2048
+    cache = jax.eval_shape(lambda: E.init_slot_cache(cfg, slots, max_len))
+    shapes = {name: (x.shape, x.dtype.name) for name, x in cache.items()}
+    assert shapes["k"] == shapes["v"] == ((1, 64, 10, 2048, 128), "bfloat16")
+    assert shapes["win_k"] == shapes["win_v"] == ((8, 64, 10, 512, 128),
+                                                  "bfloat16")
+    assert shapes["mamba_state"] == ((9, 64, 16, 5120), "float32")
+    assert shapes["mamba_tail"] == ((9, 64, 3, 5120), "bfloat16")
+    assert set(shapes) == {"k", "v", "win_k", "win_v", "mamba_state",
+                           "mamba_tail", "pos", "start"}
+    shared = 2 * 10 * 2048 * 128 * 2            # 10.5 MB a slot
+    rings = 8 * 2 * 10 * 512 * 128 * 2          # 21.0 MB
+    states = 9 * 16 * 5120 * 4                  # 2.95 MB
+    tails = 9 * 3 * 5120 * 2                    # 0.28 MB
+    total = sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                for x in cache.values())
+    assert total == slots * (shared + rings + states + tails + 2 * 4)
+    assert 2.21e9 < total < 2.23e9
+    assert 32 * slots * shared == 21_474_836_480
+    assert set(E.cache_logical_axes(cache)) == set(cache)
+    for name, axes in E.cache_logical_axes(cache).items():
+        assert len(axes) == cache[name].ndim, name
+
+
+def test_the_ring_holds_a_prompts_last_positions_where_decode_finds_them():
+    rows = jnp.arange(1, 21, dtype=jnp.float32)[None, :, None]   # P = 20
+    ring = np.asarray(window_ring(rows, 8))[0, :, 0]
+    for p in range(12, 20):                 # the last 8 positions
+        assert ring[p % 8] == p + 1
+    short = np.asarray(window_ring(rows[:, :5], 8))[0, :, 0]
+    np.testing.assert_array_equal(short, [1, 2, 3, 4, 5, 0, 0, 0])
+    # a decode step at pos 20 with 3 padded places reads positions 13..19
+    mask = np.asarray(E._ring_mask(jnp.asarray([20, 6]), jnp.asarray([3, 3]),
+                                   8))
+    assert sorted(int(ring[j]) - 1 for j in np.flatnonzero(mask[0])) \
+        == list(range(13, 20))
+    # and at pos 6 positions 3..5: no padding, no place never written
+    assert sorted(np.flatnonzero(mask[1])) == [3, 4, 5]
+
+
+# ---- the configuration ---------------------------------------------------------------
+
+def test_the_architecture_counts_what_the_initialiser_makes(toy):
+    cfg, fields, params = toy
+    leaves = sum(x.size for x in jax.tree.leaves(params))
+    assert ARCH.num_params(fields, CONF) == cfg.num_params == leaves
+    real = spec.build_transformer_config(CONF)
+    shapes = jax.eval_shape(lambda k: init_params(k, real),
+                            jax.random.key(0))
+    assert sum(int(np.prod(x.shape)) for x in jax.tree.leaves(shapes)) \
+        == real.num_params == 3_852_562_944
+    # one stack a position of a segment, its leading axis the repeats
+    assert [[jax.tree.leaves(stack)[0].shape[0] for stack in segment]
+            for segment in shapes["layers"]] == [[8, 8], [1, 1], [7, 7]]
+    assert shapes["layers"][0][0]["mamba_A_log"].shape == (8, 16, 5120)
+    assert "wk" not in shapes["layers"][2][1]       # a cross layer
+    assert shapes["layers"][1][1]["wk"].dtype == jnp.bfloat16
+    axes = param_logical_axes(real)
+    is_axes = lambda x: isinstance(x, tuple) and all(  # noqa: E731
+        isinstance(e, (str, type(None))) for e in x)
+    for x, a in zip(jax.tree.leaves(shapes),
+                    jax.tree.leaves(axes, is_leaf=is_axes)):
+        assert x.ndim == len(a)
+
+
+def test_the_initialiser_draws_the_scan_as_published(toy):
+    cfg, _, _ = toy
+    params = init_params(jax.random.key(2), cfg)
+    lp = params["layers"][0][0]
+    np.testing.assert_allclose(
+        np.exp(np.asarray(lp["mamba_A_log"], np.float64))[0, :, 0],
+        np.arange(1, cfg.mamba_d_state + 1), rtol=1e-6)
+    dt = np.asarray(jax.nn.softplus(lp["mamba_dt_b"]))
+    assert 0.001 <= dt.min() and dt.max() <= 0.1
+    np.testing.assert_array_equal(lp["mamba_D"], 1.0)
+    lam = np.asarray(params["layers"][0][1]["diff_lambda"])
+    assert lam.shape == (8, 4, cfg.head_dim) and 0.05 < lam.std() < 0.2
+
+
+def test_training_refuses_the_configuration_by_name(toy):
+    cfg, _, params = toy
+    with pytest.raises(NotImplementedError, match="layer_pattern"):
+        forward(params, jnp.zeros((1, 4), jnp.int32), cfg)
+
+
+@pytest.mark.parametrize("change, why", [
+    (dict(layer_pattern=((("mamba", "window"), 2),)), "add up to n_layers"),
+    (dict(sliding_window=0), "sliding_window"),
+    (dict(diff_attn=False, attn_bias=False), "differential"),
+    (dict(layer_pattern=((("gmu", "window"), 16),)), "gmu layer reads"),
+    (dict(layer_pattern=((("mamba", "cross"), 16),)), "cross layer reads"),
+    (dict(mamba_d_state=0), "mamba_d_state"),
+    (dict(norm="batch"), "norm"),
+])
+def test_the_configuration_refuses_what_it_cannot_state(toy, change, why):
+    cfg, _, _ = toy
+    if "layer_pattern" in change:   # (the derived period goes with it)
+        change = dict(change, mixer_period=("attention",))
+    with pytest.raises(ValueError, match=why):
+        dataclasses.replace(cfg, **change)
+
+
+def test_one_segment_is_the_period_it_always_was():
+    """`layer_pattern` and `mixer_period` are one statement: a single
+    segment IS its period, several derive every layer's kind, and a
+    configuration that states a period gets the segment it always built."""
+    base = dict(vocab_size=64, d_model=32, n_layers=4, n_heads=4, d_ff=48,
+                kda_heads=2, kda_head_dim=8, kda_gate_rank=4)
+    period = TransformerConfig(mixer_period=("attention", "kda"), **base)
+    stated = TransformerConfig(
+        layer_pattern=((("attention", "kda"), 2),), **base)
+    assert period.segments() == stated.segments() \
+        == ((("attention", "kda"), 2),)
+    assert stated.mixer_period == ("attention", "kda")
+    assert dataclasses.replace(stated, d_ff=32).segments() \
+        == stated.segments()
+    a = init_params(jax.random.key(0), period)
+    b = init_params(jax.random.key(0), stated)
+    assert jax.tree.structure(a) == jax.tree.structure(b)
+    for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
+        np.testing.assert_array_equal(x, y)
+
+
+def test_the_controls_script_runs_the_harness_check_at_toy_size():
+    """`chip_serve_controls.py --toy` on this cell: the cell's `BenchReplica`
+    and `bench_check` as the program is and with the window left out (the
+    verdicts at the cell's sizes are the chip's)."""
+    import chip_serve_controls as controls
+
+    assert controls.main([
+        "--toy", "--workload", "phi-4-mini-flash-reasoning.reason-closed-64",
+        "--controls", "program", "no_window"]) == 0
