@@ -32,8 +32,11 @@ the reference delegates to external vLLM workers for, built TPU-first:
     (`ops/decode_attention.py`), which fetches, per slot, the position
     blocks that cross ``[start, pos)`` and nothing for a slot that is not
     active (a layer's slab sliced out for a custom call would be copied
-    first: 84 MB a layer for K and V each at 32 slots x 1280); on the CPU,
-    and for a shape the kernel does not take, the scan takes the cache as
+    first: 84 MB a layer for K and V each at 32 slots x 1280; under
+    differential attention the full-length leaf's pairs of heads are the
+    same call, for its own layer and for every cross layer that reads it,
+    and a window layer's ring keeps the contraction); on the CPU, and for
+    a shape the kernel does not take, the scan takes the cache as
     read-only input and `_gqa_decode_attention` contracts over every
     position under a mask. Either way the new token attends to its own
     K/V as one more key column, and the B new rows per layer are written
@@ -280,35 +283,37 @@ def _on_chip() -> bool:
     return jax.default_backend() != "cpu"
 
 
-def _kv_block(cache: SlotCache, cfg: TransformerConfig) -> Optional[int]:
+def _kv_block(cache: SlotCache) -> Optional[int]:
     """The position block the decode kernel walks the cache's keys and
     values by, or None where the masked contraction runs instead: on the
     CPU (where it is also the tests' reference), for a shape the kernel
-    does not take (`pick_block`), under differential attention (whose
-    pairs of heads the kernel does not score) and for a model without
-    attention layers. Decided, as `transformer._select_attention` decides,
-    by what the code can observe."""
-    if "k" not in cache or cfg.diff_attn or not _on_chip():
+    does not take (`pick_block`: under differential attention it sees the
+    PAIRS the leaf holds, whole lanes for a head of 64) and for a model
+    without attention layers. Decided, as `transformer._select_attention`
+    decides, by what the code can observe."""
+    if "k" not in cache or not _on_chip():
         return None
     k = cache["k"]
     return pick_block(k.shape[3], k.shape[4], k.dtype)
 
 
-def _kernel_attention(q, cache: SlotCache, k_new, v_new, active, layer, mesh):
-    """`decode_attention` on one layer of the whole stacked cache. GSPMD
+def _kernel_attention(q, cache: SlotCache, k_new, v_new, active, layer, mesh,
+                      **how):
+    """`decode_attention` on one layer of the whole stacked cache (``how``:
+    its scale and output dtype, where they are not a GQA layer's). GSPMD
     cannot partition a Mosaic kernel: on a mesh it runs per shard of the
     KV heads (heads are independent), as `transformer._attention` runs
     the train kernel."""
     args = (q, cache["k"], cache["v"], k_new, v_new, cache["pos"],
             cache["start"], active, layer)
     if mesh is None or mesh.size == 1:
-        return decode_attention(*args)
+        return decode_attention(*args, **how)
     kv, heads, new = (
         logical_to_spec(axes, mesh_axes=mesh.axis_names)
         for axes in (cache_logical_axes()["k"], (None, None, "heads", None),
                      (None, "kv_heads", None)))
     rep = jax.sharding.PartitionSpec()
-    return shard_map(decode_attention, mesh=mesh,
+    return shard_map(partial(decode_attention, **how), mesh=mesh,
                      in_specs=(heads, kv, kv, new, new, rep, rep, rep, rep),
                      out_specs=heads)(*args)
 
@@ -368,7 +373,7 @@ def _decode_one(params: Params, cache: SlotCache, tokens: jax.Array,
     positions = pos[:, None]  # [B, 1] per-row RoPE
     B = tokens.shape[0]
     has = collections.Counter(cfg.mixer_kind(i) for i in range(cfg.n_layers))
-    kernel = _kv_block(cache, cfg) is not None
+    kernel = _kv_block(cache) is not None
     if active is None and (kernel or has["kda"] or has["mamba"]):
         active = jnp.ones_like(pos, bool)
     if has["attention"]:
@@ -435,18 +440,32 @@ def _decode_one(params: Params, cache: SlotCache, tokens: jax.Array,
                 q, k, v = diff_qkv(h, lp, cfg)
                 if kind == "cross":     # the nearest attention layer's
                     k, v = carry["shared_k"], carry["shared_v"]
-                    kc, vc = (cache[name][seen["attention"] - 1]
-                              for name in ("k", "v"))
+                    layer = seen["attention"] - 1
                 else:
                     k, v = k[:, 0].astype(dtype), v[:, 0].astype(dtype)
-                    name = "win_" if kind == "window" else ""
-                    kc, vc = slabs[name + "k"][i], slabs[name + "v"][i]
                     rows.setdefault(kind, []).append((k, v))
                     if kind == "attention" and "shared_k" in carry:
                         carry["shared_k"], carry["shared_v"] = k, v
-                o = diff_out(_diff_decode_attention(
-                    q, kc, vc, k, v, ring if kind == "window" else mask),
-                    lp, cfg, first + period * len(kinds) + j)
+                if kernel and kind != "window":
+                    # the pairs are a grouped-query call: a key pair's
+                    # query rows [q1 | 0], [0 | q2] a query pair, scaled by
+                    # the head's width, o float32 for `diff_out` to subtract
+                    o = _kernel_attention(
+                        q.reshape(B, 1, -1, q.shape[-1]), cache, k, v,
+                        active, layer, mesh, scale=cfg.head_dim ** -0.5,
+                        out_dtype=jnp.float32).reshape(q.shape)
+                else:
+                    # the masked contraction: the CPU's path, and a window
+                    # layer's anywhere (a ring's valid places are not
+                    # [start, pos) once it wraps)
+                    if kind == "cross":
+                        kc, vc = cache["k"][layer], cache["v"][layer]
+                    else:
+                        name = "win_" if kind == "window" else ""
+                        kc, vc = slabs[name + "k"][i], slabs[name + "v"][i]
+                    o = _diff_decode_attention(
+                        q, kc, vc, k, v, ring if kind == "window" else mask)
+                o = diff_out(o, lp, cfg, first + period * len(kinds) + j)
             else:
                 # a float32 mixer (`mixer_precision`) around the attention
                 # itself: the kernel keeps its own arithmetic, reads the
@@ -745,7 +764,7 @@ class InferenceEngine:
         self._slot_start = np.zeros(self.slots, np.int64)
         self._slot_pos = np.zeros(self.slots, np.int64)
         # None: the XLA contraction (or no attention layer at all)
-        self._kv_block = _kv_block(self.cache, cfg)
+        self._kv_block = _kv_block(self.cache)
         # what a decode substep costs by the model's shape, for the
         # counters: KDA and mamba layers, a window layer's ring, layers
         # with experts and what those offer

@@ -204,18 +204,26 @@ def _kernel(layer_ref, pos_ref, start_ref, active_ref, q_ref, kn_ref, vn_ref,
 
 
 def decode_attention(q, k_cache, v_cache, k_new, v_new, pos, start, active,
-                     layer, *, block: int | None = None):
+                     layer, *, block: int | None = None,
+                     scale: float | None = None, out_dtype=None):
     """q [B, 1, H, hd] against layer ``layer`` of the stacked cache
     k_cache / v_cache [L, B, KV, S, hd], rows ``[start[b], pos[b])`` of
     each active slot, plus the token's own k_new / v_new [B, KV, hd] as one
     more key column -> [B, 1, H, hd]: what
     `generate._gqa_decode_attention` gives for an active slot. ``block``
-    left out is `pick_block`'s."""
+    left out is `pick_block`'s, ``scale`` (of the scores) ``hd ** -0.5``
+    and ``out_dtype`` q's. Differential attention's pairs of heads are this
+    call too (`generate._diff_decode_attention`: KV pairs of width 2 hd,
+    the rows [q1 | 0] and [0 | q2] of the query pairs that read a key
+    pair, the scores scaled by the HEAD's width and o float32, since the
+    caller subtracts one softmax's output from the other's)."""
     B, _, H, d = q.shape
     _, _, KV, S, _ = k_cache.shape
     reps = H // KV
     block = block or pick_block(S, d, k_cache.dtype)
     dtype = k_cache.dtype
+    scale = d ** -0.5 if scale is None else scale
+    out_dtype = jnp.dtype(out_dtype or q.dtype)
     in_vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)
     q2 = q.reshape(B, KV, reps, d).astype(dtype)
@@ -223,7 +231,7 @@ def decode_attention(q, k_cache, v_cache, k_new, v_new, pos, start, active,
     tile = KV * block * d * dtype.itemsize
     small = B * KV * max(2 * reps, 32 // dtype.itemsize) * max(d, _LANES)
     o = pl.pallas_call(
-        functools.partial(_kernel, scale=d ** -0.5, block=block, max_len=S),
+        functools.partial(_kernel, scale=scale, block=block, max_len=S),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(1,),
@@ -237,11 +245,13 @@ def decode_attention(q, k_cache, v_cache, k_new, v_new, pos, start, active,
                 pltpu.SMEM((B * (S // block),), jnp.int32),
                 state, state,
                 pltpu.VMEM((B, KV, 2 * reps, d), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((B, KV, reps, d), q.dtype),
-        # the K/V buffers; q, the own column, the output and the float32
-        # state, each padded to whole tiles; room for spills
+        out_shape=jax.ShapeDtypeStruct((B, KV, reps, d), out_dtype),
+        # the K/V buffers; q and the own column (6 bytes a number of
+        # `small`), the float32 state (12) and the output, each padded to
+        # whole tiles, and 4 to spare; room for spills
         compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=2 * _DEPTH * tile + 24 * small + (16 << 20)),
+            vmem_limit_bytes=2 * _DEPTH * tile
+            + (22 + out_dtype.itemsize) * small + (16 << 20)),
         name="decode_attention",
         interpret=_use_interpret(),
     )(jnp.reshape(layer, (1,)).astype(jnp.int32), pos.astype(jnp.int32),

@@ -252,9 +252,17 @@ def test_serve_programs_compile(one_chip, mosaic, make_cfg, slots, max_len,
     # (the contraction's) or as a slice cut out for the custom call (84 MB
     # a layer, K and V each, at the cells' size)
     assert ("tpu_custom_call" in text) == kernel
-    if kernel:
-        assert re.search(r"%decode_attention\S* = [^\n]*custom-call\(", text)
+    if kernel:      # a GQA layer's call hands o back in q's dtype
+        assert _decode_attention_results(text) == [
+            f"bf16[{slots},{cfg.kv_heads},{cfg.n_heads // cfg.kv_heads},"
+            f"{cfg.head_dim}]"]
         assert _layer_slab_ops(text, cache["k"].shape[1:]) == []
+
+
+def _decode_attention_results(hlo: str) -> list:
+    """["dtype[dims]"] of the program's `decode_attention` custom calls."""
+    return re.findall(r"%decode_attention\S* = (\w+\[[\d,]+\])\S* "
+                      r"custom-call\(", hlo)
 
 
 def _layer_slab_ops(hlo: str, slab_shape) -> list:
@@ -335,10 +343,9 @@ def test_tensor2_decode_runs_the_kernel_per_shard(topo, mosaic):
     active = jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=whole)
     text = decode_slots.lower(params, cache, tokens, active, rng, cfg,
                               steps=4, mesh=mesh).compile().as_text()
-    out = re.findall(r"%decode_attention\S* = \w+\[([\d,]+)\]\S* "
-                     r"custom-call\(", text)
-    assert out == [f"{slots},{cfg.kv_heads // 2},"
-                   f"{cfg.n_heads // cfg.kv_heads},{cfg.head_dim}"]
+    assert _decode_attention_results(text) == [
+        f"bf16[{slots},{cfg.kv_heads // 2},"
+        f"{cfg.n_heads // cfg.kv_heads},{cfg.head_dim}]"]
     assert "all-reduce" in text  # the tensor-parallel output projection
     assert _whole_cache_ops(text, (cfg.n_layers, slots, cfg.kv_heads // 2,
                                    1280, cfg.head_dim)) == []
@@ -771,7 +778,10 @@ def test_solar_open2_serve_programs_compile_and_move_no_state(one_chip,
     assert _device_bytes(decode) < HBM_BYTES
     text = decode.as_text()
     assert re.search(r"%kda_decode_step\S* = [^\n]*custom-call\(", text)
-    assert re.search(r"%decode_attention\S* = [^\n]*custom-call\(", text)
+    # (o in q's dtype, which the float32 mixer around it makes float32)
+    assert _decode_attention_results(text) == [
+        f"f32[{slots},{cfg.kv_heads},{cfg.n_heads // cfg.kv_heads},"
+        f"{cfg.head_dim}]"]
     for scope in ("kda.step", "kda.conv", "attn.gate", "moe.experts"):
         assert scope in text, scope
     # a layer's expert stage: the front's branch holds the three rows
@@ -840,9 +850,11 @@ def test_phi4flash_serve_programs_compile_and_copy_no_leaf(one_chip, mosaic):
     three scans (the pattern's segments; the boundary segment's single
     repeat inlined), runs the named kernel on the stacked states where they
     lie (nothing copies, converts, selects over or scatters into a
-    whole-state-sized result), copies neither the ONE full-length K/V leaf
-    that eight layers read nor the rings whole, and converts no weight but
-    the tied head's table."""
+    whole-state-sized result), reads the ONE full-length K/V leaf through
+    `decode_attention` alone (the full layer's call and the scanned cross
+    layers', four query rows a key pair, o float32; the masked
+    contraction's scores stay for the rings), copies neither that leaf nor
+    the rings whole, and converts no weight but the tied head's table."""
     from benchmark.harness import spec
     from ray_tpu.models.engine import (decode_slots, init_slot_cache,
                                        prefill_slots)
@@ -886,6 +898,21 @@ def test_phi4flash_serve_programs_compile_and_copy_no_leaf(one_chip, mosaic):
     assert _sized_ops(text, state.shape[1:], moved) == []
     for name in ("k", "win_k"):
         assert _whole_cache_ops(text, cache[name].shape) == [], name
+    # the leaf's eight readers are the kernel's calls (layer 17's and one
+    # in each loop that holds cross layers): 4 rows a key pair, float32 for
+    # `diff_out` to subtract; nothing materialises the leaf's one layer on
+    # the way in, and the contraction's [slots, pairs, 2, 2, positions]
+    # scores are there at a ring's 512 places and not at the leaf's 2,048
+    G, S, c = cache["k"].shape[2:]
+    rows = 2 * cfg.n_heads // cfg.kv_heads
+    calls = _decode_attention_results(text)
+    assert len(calls) >= 2 and set(calls) == {
+        f"f32[{slots},{G},{rows},{c}]"}, calls
+    assert _layer_slab_ops(text, cache["k"].shape[1:]) == []
+    W = cfg.sliding_window
+    assert f"f32[{slots},{G},2,2,{W}]" in text
+    for scores in ((slots, G, 2, 2, S), (slots, G, rows, S)):
+        assert "[" + ",".join(map(str, scores)) + "]" not in text, scores
     # (of 2 M numbers or more: the scan's A and the convolution's taps
     # are read as float32, 82 K numbers a layer)
     converted = {c.split(": ")[1] for c in _weight_converts(text, params)

@@ -1,5 +1,7 @@
 """The decode-attention kernel (interpret mode) against the masked
-contraction it replaces on a chip, `generate._gqa_decode_attention`.
+contraction it replaces on a chip: `generate._gqa_decode_attention`, and
+`generate._diff_decode_attention` for differential attention's pairs of
+heads (the same call with the head's scale and a float32 output).
 
 The kernel gets a cache in which every row a slot does not own, and every
 other layer, is NaN: what it does not read cannot reach its output. The
@@ -10,7 +12,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models.generate import _gqa_decode_attention
+from ray_tpu.models.generate import (_diff_decode_attention,
+                                     _gqa_decode_attention)
 from ray_tpu.ops.decode_attention import (block_bounds, decode_attention,
                                           pick_block, rows_read)
 
@@ -29,48 +32,109 @@ _CASES = {
     "inactive_between_active": [
         (2, 13, True), (0, 30, False), (7, 25, True), (0, 0, False),
         (24, 31, True)],
+    "full_beside_empty_and_parked": [
+        (0, _S, True), (0, 0, True), (0, _S, False), (1, _S - 1, True)],
 }
+# (KV heads, query rows a KV head, head width). The last is differential
+# attention as the Phi cell holds it: 10 key pairs of 128 lanes ([k1 | k2],
+# heads of 64), two query pairs a key pair, each [q1 | 0] and [0 | q2]: four
+# query rows a key pair, the scores scaled by the HEAD's width, o float32
+_LAYOUTS = {"group_of_1": (_KV, 1, _HD), "group_of_2": (_KV, 2, _HD),
+            "pairs_of_heads": (10, 4, 128)}
+
+
+def _slots(case):
+    slots = _CASES[case]
+    return (len(slots),) + tuple(np.array(x) for x in zip(*slots))
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-@pytest.mark.parametrize("reps", [1, 2], ids=lambda r: f"group_of_{r}")
+@pytest.mark.parametrize("layout", list(_LAYOUTS))
 @pytest.mark.parametrize("case", list(_CASES))
-def test_kernel_equals_the_masked_contraction(case, reps, dtype):
-    slots = _CASES[case]
-    B = len(slots)
-    start, pos, active = (np.array(x) for x in zip(*slots))
+def test_kernel_equals_the_masked_contraction(case, layout, dtype):
+    B, start, pos, active = _slots(case)
+    KV, reps, hd = _LAYOUTS[layout]
+    pairs = layout == "pairs_of_heads"
     rng = np.random.RandomState(len(case) + reps)
     dt = jnp.dtype(dtype)
 
     def normal(*shape):
         return jnp.asarray(rng.randn(*shape), dt)
 
-    k = normal(_LAYERS, B, _KV, _S, _HD)
-    v = normal(_LAYERS, B, _KV, _S, _HD)
-    q = normal(B, 1, _KV * reps, _HD)
-    k_new, v_new = normal(B, _KV, _HD), normal(B, _KV, _HD)
+    k = normal(_LAYERS, B, KV, _S, hd)
+    v = normal(_LAYERS, B, KV, _S, hd)
+    q = normal(B, 1, KV * reps, hd)
+    k_new, v_new = normal(B, KV, hd), normal(B, KV, hd)
     kpos = np.arange(_S)[None, :]
-    owned = (kpos >= start[:, None]) & (kpos < pos[:, None])  # [B, S]
-    want = _gqa_decode_attention(q, k[_LAYER], v[_LAYER], k_new, v_new,
-                                 jnp.asarray(owned))
+    owned = jnp.asarray((kpos >= start[:, None]) & (kpos < pos[:, None]))
+    if pairs:   # rows [q1 | 0], [0 | q2], as `transformer.diff_qkv` has them
+        first = (np.arange(hd) < hd // 2)[None, :]
+        second = (np.arange(KV * reps) % 2 == 1)[:, None]
+        q = q * jnp.asarray(first != second, dt)
+        how = dict(scale=(hd // 2) ** -0.5, out_dtype=jnp.float32)
+        want = _diff_decode_attention(
+            q.reshape(B, 1, -1, 2, hd), k[_LAYER], v[_LAYER], k_new, v_new,
+            owned).reshape(q.shape)
+    else:
+        how = {}
+        want = _gqa_decode_attention(q, k[_LAYER], v[_LAYER], k_new, v_new,
+                                     owned)
 
+    # every row a slot does not own, and every other layer, is NaN
     poison = np.ones((_LAYERS, B, 1, _S, 1), bool)
-    poison[_LAYER] = ~owned[:, None, :, None]
+    poison[_LAYER] = ~np.asarray(owned)[:, None, :, None]
     got = decode_attention(
         q, jnp.where(poison, jnp.nan, k), jnp.where(poison, jnp.nan, v),
         k_new, v_new, jnp.asarray(pos), jnp.asarray(start),
-        jnp.asarray(active), jnp.asarray(_LAYER), block=_BLOCK)
-    assert got.shape == q.shape and got.dtype == q.dtype
+        jnp.asarray(active), jnp.asarray(_LAYER), block=_BLOCK, **how)
+    assert got.shape == q.shape and got.dtype == want.dtype == (
+        jnp.float32 if pairs else q.dtype)
     got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
     assert np.isfinite(got).all()  # an inactive slot's row too
-    tol = {"float32": 2e-6, "bfloat16": 8e-3}[dtype]  # bf16: an output ulp
+    # bf16 out: an output ulp; float32 out against a bf16 cache: both sides
+    # keep the probabilities' 16 bits (`generate._weighted_values`)
+    tol = 2e-6 if dtype == "float32" else 2e-4 if pairs else 8e-3
     np.testing.assert_allclose(got[active], want[active], atol=tol, rtol=tol)
+    if pairs:   # what `diff_out` goes on with: o1 - lam o2 of a query pair
+        np.testing.assert_allclose(
+            (got[:, :, 0::2] - 0.7 * got[:, :, 1::2])[active],
+            (want[:, :, 0::2] - 0.7 * want[:, :, 1::2])[active],
+            atol=tol, rtol=tol)
     # a slot with nothing cached, or not active, sees its own token alone
     alone = ~active | (pos <= start)
     own = np.repeat(np.asarray(v_new, np.float32), reps, axis=1)[:, None]
     np.testing.assert_allclose(got[alone], own[alone], atol=tol, rtol=tol)
     _, count = block_bounds(start, pos, active, _BLOCK, _S)
     assert (count[alone] == 0).all() and (count[~alone] > 0).all()
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_the_defaults_are_the_grouped_query_call(dtype):
+    """Scale and output dtype left out are `hd ** -0.5` and q's: the same
+    bits as stating them, and a float32 output is that result before its
+    one rounding."""
+    B, start, pos, active = _slots("left_padding_inside_and_on_an_edge")
+    rng = np.random.RandomState(7)
+    dt = jnp.dtype(dtype)
+
+    def normal(*shape):
+        return jnp.asarray(rng.randn(*shape), dt)
+
+    k, v = normal(_LAYERS, B, _KV, _S, _HD), normal(_LAYERS, B, _KV, _S, _HD)
+    q = normal(B, 1, _KV * 2, _HD)
+    args = (q, k, v, normal(B, _KV, _HD), normal(B, _KV, _HD),
+            jnp.asarray(pos), jnp.asarray(start), jnp.asarray(active),
+            jnp.asarray(_LAYER))
+    plain = decode_attention(*args, block=_BLOCK)
+    stated = decode_attention(*args, block=_BLOCK, scale=_HD ** -0.5,
+                              out_dtype=dt)
+    assert plain.dtype == stated.dtype == dt
+    np.testing.assert_array_equal(np.asarray(plain, np.float32),
+                                  np.asarray(stated, np.float32))
+    wide = decode_attention(*args, block=_BLOCK, out_dtype=jnp.float32)
+    assert wide.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(wide.astype(dt), np.float32),
+                                  np.asarray(plain, np.float32))
 
 
 def test_the_bounds_count_the_rows_of_the_blocks_a_slot_crosses():

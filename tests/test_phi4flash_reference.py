@@ -26,7 +26,7 @@ from ray_tpu.models.generate import (_final_logits, _prefill_hidden,  # noqa: E4
                                      window_ring)
 from ray_tpu.models.transformer import (forward, init_params,  # noqa: E402
                                         param_logical_axes)
-from ray_tpu.ops import mamba  # noqa: E402
+from ray_tpu.ops import decode_attention, mamba  # noqa: E402
 
 BENCH = spec.load_benchmark()
 CONF = spec.load_config(BENCH, "phi-4-mini-flash-reasoning")
@@ -113,6 +113,46 @@ def test_prefill_then_forty_decode_steps_agree_at_every_step(toy):
         tok = jnp.argmax(logits, -1).astype(jnp.int32)
     assert len(seqs[0]) == P + steps > 5 * WINDOW
     assert int(cache["pos"][0]) == P + steps
+
+
+def test_the_shared_leaf_through_the_decode_kernel_is_the_contraction(
+        toy, monkeypatch):
+    """On a chip the full-length leaf's eight readers (its own layer and
+    the seven cross layers) go through `ops/decode_attention.py`, here the
+    interpreter walking blocks of 8 positions, and the rings keep the
+    masked contraction: 12 steps from three left-padded rows, one of them
+    parked, give the logits of the contraction throughout (the program the
+    test above holds to the reference)."""
+    cfg, _, params = toy
+    P, steps = 16, 12
+    cache, tok = _prefill(cfg, params, E.init_slot_cache(cfg, 4, 32),
+                          _prompts([16, 11, 5]), P, [0, 1, 3])
+    active = jnp.asarray([True, True, False, True])
+    tok = jnp.zeros(4, jnp.int32).at[jnp.asarray([0, 1, 3])].set(tok)
+
+    def run(cache, tok):
+        step = jax.jit(lambda p, c, t: E._decode_one(p, c, t, cfg, active))
+        out = []
+        for _ in range(steps):
+            cache, logits = step(params, cache, tok)
+            out.append(logits)
+            tok = jnp.argmax(logits, -1).astype(jnp.int32)
+        return cache, out
+    assert E._kv_block(cache) is None
+    want_cache, want = run(cache, tok)
+    monkeypatch.setattr(decode_attention, "_BLOCK", 8)
+    monkeypatch.setattr(E, "_on_chip", lambda: True)
+    assert E._kv_block(cache) == 8
+    got_cache, got = run(cache, tok)
+    live = np.asarray(active)
+    for a, b in zip(got, want):
+        assert _rel_rms(a[live], b[live]) < 1e-5
+        np.testing.assert_array_equal(np.argmax(a[live], -1),
+                                      np.argmax(b[live], -1))
+    assert int(got_cache["pos"][0]) == P + steps and \
+        int(got_cache["pos"][2]) == 0
+    np.testing.assert_allclose(np.asarray(got_cache["k"]),
+                               np.asarray(want_cache["k"]), atol=1e-5)
 
 
 def test_the_served_chunk_is_the_single_steps(toy):
