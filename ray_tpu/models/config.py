@@ -13,6 +13,12 @@ from typing import Optional
 
 import jax.numpy as jnp
 
+# The mixer kinds that hold nothing a slot keeps and read no other position
+# of their own stream: a gated memory unit reads the SAME token's memory, a
+# cross layer its own row's query against another layer's keys and values
+# (`TransformerConfig.layer_pattern`, `tail_segment`).
+LAST_ROW_KINDS = frozenset({"gmu", "cross"})
+
 
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
@@ -324,6 +330,21 @@ class TransformerConfig:
         period = len(self.mixer_period)
         return ((kinds[:period], count // period),) \
             if count % period == 0 else ((kinds, 1),)
+
+    def tail_segment(self) -> int:
+        """The index in `segments()` of the first of the pattern's TRAILING
+        segments made of `LAST_ROW_KINDS` alone (`len(segments())` where
+        the pattern ends in any other kind, as every pattern of one
+        segment does). From that segment to the last layer a token's
+        stream depends on nothing later layers computed at OTHER
+        positions and no slot keeps anything, so a prompt pass whose
+        reader takes the last position's logits carries that one
+        position through them (`generate._prefill_hidden`)."""
+        segments = self.segments()
+        at = len(segments)
+        while at and set(segments[at - 1][0]) <= LAST_ROW_KINDS:
+            at -= 1
+        return at
 
     @property
     def mamba_channels(self) -> int:

@@ -248,7 +248,10 @@ def prefill_slots(params: Params, cache: SlotCache, tokens: jax.Array,
     rows ``slots`` [K]; -> (cache, first sampled tokens [K]). A KDA or
     mamba layer's state and tail and a window layer's ring of those slots
     are REPLACED by the prompt's (computed from zero): admission is the
-    reset.
+    reset. Of the hidden states only the last position's is read, every
+    row's last real token: where the pattern ends in layers that need no
+    other position (`cfg.tail_segment`), `_prefill_hidden` carries that
+    one alone through them and hands back [K, 1, d].
 
     One compiled program per (K, P) pair; K is kept to a few power-of-two
     group sizes by the scheduler. Batching prefills is a dispatch-count
@@ -697,6 +700,11 @@ class InferenceEngine:
                  max_inflight: int = 6):
         refuse_unserved(cfg)
         self.cfg = cfg
+        # the layers a prompt's LAST position alone passes in prefill
+        # (`cfg.tail_segment`; 0 for a pattern that ends in any other kind)
+        self._last_row_layers = sum(
+            len(kinds) * reps
+            for kinds, reps in cfg.segments()[cfg.tail_segment():])
         self.slots = int(slots)
         self.max_prompt_len = int(max_prompt_len)
         self.max_new_tokens = int(max_new_tokens)
@@ -821,6 +829,9 @@ class InferenceEngine:
             "queue_wait_s": 0.0, "first_token_s": 0.0, "first_tokens": 0,
             "chunks_ahead_at_admit": 0, "prefill_padded_tokens": 0,
             "prefill_prompt_tokens": 0,
+            # (row position, layer) pairs the prefill programs computed,
+            # and what every position through every layer would be
+            "prefill_layer_tokens": 0, "prefill_padded_layer_tokens": 0,
             # cache rows (a position of a slot, every layer and head) of
             # the decode substeps dispatched: all there are, those decode
             # attention fetches, and those an active slot owns
@@ -1100,6 +1111,9 @@ class InferenceEngine:
             self.stats["queue_wait_s"] += now - req.t_submit
             self.stats["prefill_prompt_tokens"] += len(req.prompt)
         self.stats["prefill_padded_tokens"] += K * P
+        L, last = self.cfg.n_layers, self._last_row_layers
+        self.stats["prefill_layer_tokens"] += K * P * (L - last) + K * last
+        self.stats["prefill_padded_layer_tokens"] += K * P * L
         self.stats["chunks_ahead_at_admit"] += ahead
         with self._timed("prefill_dispatch_wall_s",
                          "engine.prefill_dispatch", heartbeat=False,

@@ -17,6 +17,11 @@ TPU-first design:
     contiguous HBM, no per-layer Python lists. What a KDA layer leaves a
     slot comes back the same way: its final state and the last projected
     rows its convolutions reach back to, one entry a KDA layer.
+  - The prompt pass computes what a slot keeps and the last position's
+    logits, nothing else: where the layer pattern ends in layers that
+    leave a slot nothing and read no other position of their own stream
+    (a decoder-hybrid-decoder's cross-decoder: `cfg.tail_segment`), only
+    a row's last position passes them, K rows and not K x P.
   - Keys/values are cached *post-RoPE* and *pre-GQA-expansion* (KV heads,
     not Q heads): memory scales with kv_heads, and the repeat to Q heads
     happens inside the attention contraction.
@@ -190,10 +195,14 @@ def join_period(parts):
 def _prefill_hidden(params: Params, tokens: jax.Array,
                     cfg: TransformerConfig, max_len: int,
                     start: jax.Array):
-    """Prompt pass returning final HIDDEN states [B,P,d] + the filled
-    cache — the caller projects only the positions it reads to vocab
-    space (a [B,P,V] float32 logits tensor is ~2 GB for llama3-8b at
-    P=512 and is pure waste on the serving hot path). The cache holds
+    """Prompt pass returning final HIDDEN states + the filled cache: the
+    hidden of the positions that passed EVERY layer, [B,P,d], or [B,1,d],
+    the last position's alone, where the pattern ends in layers that
+    need no other (below). The caller projects only the positions it
+    reads to vocab space (a [B,P,V] float32 logits tensor is ~2 GB for
+    llama3-8b at P=512 and is pure waste on the serving hot path), and
+    every caller that serves reads ``[:, -1:]``: rows are padded on the
+    left, so that is every row's last real token. The cache holds
     what each mixer kind leaves a slot, stacked over the layers of that
     kind: ``k``/``v`` [L_attn, B, max_len, KV, hd]; ``kda_state`` [L_kda,
     B, H, dk, dv] float32 and ``kda_tail`` [L_kda, B, taps - 1, 3 x H x
@@ -206,7 +215,21 @@ def _prefill_hidden(params: Params, tokens: jax.Array,
     or mamba state (`kda_mixer`, `mamba_mixer`). The layers are walked a
     segment of the pattern at a time (`cfg.segments`), each a scan over
     its repeats; a gated memory unit's memory and a cross layer's keys
-    and values ride the carry from the segment that makes them."""
+    and values ride the carry from the segment that makes them.
+
+    From `cfg.tail_segment()` on (a decoder-hybrid-decoder's cross-
+    decoder, arXiv:2507.06607: the trailing segments of gated memory
+    units and cross layers) the walk carries the LAST position only. Such
+    a layer leaves a slot nothing, a gated memory unit reads the same
+    token's memory and a cross layer its own row's query against keys and
+    values an earlier layer made for every position, so the last
+    position's stream through them depends on no other position's: the
+    stream and the memory narrow to ``[:, -1:]``, a cross layer's mask to
+    the last query's row, the shared keys and values stay [B, P, ...].
+    Every cache leaf is made before that segment and is what the walk of
+    all positions makes; the P - 1 rows not computed are rows no caller
+    read. A pattern that ends in any other kind (every pattern of one
+    segment) has no such segment and is walked as it always was."""
     B, P = tokens.shape
     refuse_unserved(cfg)
     if max_len < P:
@@ -228,7 +251,7 @@ def _prefill_hidden(params: Params, tokens: jax.Array,
                    < cfg.sliding_window)[None]
     pad = [(0, 0), (0, max_len - P), (0, 0), (0, 0)]
 
-    def period(carry, scanned, kinds, first):
+    def period(carry, scanned, kinds, first, seen):
         lps, rep = scanned
         carry, left = dict(carry), {}
         x = carry["x"]
@@ -285,11 +308,17 @@ def _prefill_hidden(params: Params, tokens: jax.Array,
     if "cross" in kinds_all:
         carry["shared_k"] = carry["shared_v"] = jnp.zeros(
             (B, P, cfg.kv_heads // 2, 2 * cfg.head_dim), cfg.dtype)
-    for (kinds, reps), stacks in zip(cfg.segments(),
-                                     layer_segments(params["layers"])):
+    tail = cfg.tail_segment()
+    for at, ((kinds, reps), stacks) in enumerate(zip(
+            cfg.segments(), layer_segments(params["layers"]))):
+        if at == tail:      # the last position's stream, memory and mask
+            carry = dict(carry, x=carry["x"][:, -1:])
+            if "memory" in carry:
+                carry["memory"] = carry["memory"][:, -1:]
+            seen = seen[:, -1:]
         carry, left = jax.lax.scan(
-            functools.partial(period, kinds=kinds, first=first), carry,
-            (stacks, jnp.arange(reps)))
+            functools.partial(period, kinds=kinds, first=first, seen=seen),
+            carry, (stacks, jnp.arange(reps)))
         for name, leaves in left.items():
             made.setdefault(name, []).append(join_period(leaves))
         first += len(kinds) * reps

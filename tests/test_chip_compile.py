@@ -930,3 +930,17 @@ def test_phi4flash_serve_programs_compile_and_copy_no_leaf(one_chip, mosaic):
         assert scope in text, scope
     assert _sized_ops(text, state.shape, "copy|convert|select|scatter") == []
     assert prefill.memory_analysis().temp_size_in_bytes < 2.0e9
+    # the cross-decoder (layers 18-31, the scan over the stack of seven)
+    # runs on each row's LAST position: its feed-forward's activation is
+    # [K, d_ff] there and [K, P, d_ff] in the self-decoder's scan alone
+    comps = _computations(text)
+    bodies = [comps[name] for name in set(re.findall(
+        r"while\(.*?body=%?([\w.\-]+)", text))]
+    cross, own = ([b for b in bodies if any(
+        f"bf16[{repeats},{cfg.d_model},{cfg.d_ff}]" in x for x in b)]
+        for repeats in (7, 8))
+    assert len(cross) == len(own) == 1
+    assert _shaped(own[0], K, P, cfg.d_ff) and not _shaped(own[0], K, cfg.d_ff)
+    assert _shaped(cross[0], K, cfg.d_ff)
+    assert _shaped(cross[0], K, P, cfg.d_ff) == []
+    assert _shaped(cross[0], K, P, cfg.mamba_channels) == []

@@ -58,6 +58,11 @@ def test_a_group_is_the_oldest_and_the_oldest_of_its_bucket(model):
                     (16, [rid[1], rid[2], rid[4], rid[5]]),
                     (16, [rid[6]]), (64, [rid[7]])]
     assert eng.stats["prefill_padded_tokens"] == 3 * 64 + 5 * 16
+    # every layer of this pattern leaves keys and values: every position
+    # of a group passes every layer
+    assert eng.stats["prefill_layer_tokens"] \
+        == eng.stats["prefill_padded_layer_tokens"] \
+        == (3 * 64 + 5 * 16) * eng.cfg.n_layers
     assert eng.stats["prefills"] == 8 and not eng._queue.qsize()
     # every answer is what the request gets alone
     alone = _engine(model)

@@ -21,7 +21,8 @@ if ROOT not in sys.path:
 
 from benchmark.harness import spec  # noqa: E402
 from ray_tpu.models import engine as E  # noqa: E402
-from ray_tpu.models.config import TransformerConfig  # noqa: E402
+from ray_tpu.models.config import (LAST_ROW_KINDS,  # noqa: E402
+                                   TransformerConfig)
 from ray_tpu.models.generate import (_final_logits, _prefill_hidden,  # noqa: E402
                                      window_ring)
 from ray_tpu.models.transformer import (forward, init_params,  # noqa: E402
@@ -113,6 +114,136 @@ def test_prefill_then_forty_decode_steps_agree_at_every_step(toy):
         tok = jnp.argmax(logits, -1).astype(jnp.int32)
     assert len(seqs[0]) == P + steps > 5 * WINDOW
     assert int(cache["pos"][0]) == P + steps
+
+
+# ---- the prompt pass stops at the cross-decoder for all but the last token -----
+
+def _every_position(monkeypatch):
+    """`_prefill_hidden` as it walks a pattern without such a tail: every
+    position through every layer."""
+    monkeypatch.setattr(TransformerConfig, "tail_segment",
+                        lambda self: len(self.segments()))
+
+
+@pytest.mark.parametrize("P, lengths", [(16, [16, 11, 5]), (32, [9, 32, 20])])
+def test_the_last_position_alone_passes_the_cross_decoder(
+        toy, monkeypatch, P, lengths):
+    """Layers 18-31 (gated memory units and cross layers) leave a slot
+    nothing and read no other position of their own stream: the prompt pass
+    hands back the LAST position's hidden alone, [B, 1, d], whose logits
+    are the reference's and those of the walk of every position, and every
+    cache leaf is that walk's to the bit."""
+    cfg, fields, params = toy
+    assert cfg.tail_segment() == 2
+    prompts = _prompts(lengths, seed=P)
+    toks, starts = _group(prompts, P)
+    last, cache = _prefill_hidden(params, toks, cfg, P + 8, starts)
+    assert last.shape == (3, 1, cfg.d_model)
+    got = _final_logits(params, last, cfg)[:, 0]
+    for i, prompt in enumerate(prompts):
+        want = ARCH.reference_logits(params, prompt, fields, CONF, last=1)
+        assert _rel_rms(got[i], want[0]) < TOL
+    _every_position(monkeypatch)
+    every, whole = _prefill_hidden(params, toks, cfg, P + 8, starts)
+    assert every.shape == (3, P, cfg.d_model)
+    assert _rel_rms(got, _final_logits(params, every[:, -1:], cfg)[:, 0]) \
+        < 1e-6
+    assert set(cache) == set(whole) == {
+        "mamba_state", "mamba_tail", "win_k", "win_v", "k", "v", "pos"}
+    for name in whole:
+        np.testing.assert_array_equal(cache[name], whole[name], err_msg=name)
+
+
+@pytest.mark.parametrize("config, tail", [
+    ("phi-4-mini-flash-reasoning", 2), ("internlm2-1.8b", None),
+    ("mistral-7b-v0.3", None), ("olmoe-1b-7b", None),
+    ("glm-4.7-flash", None), ("kimi-linear-48b-a3b", None),
+    ("solar-open2-250b", None)])
+def test_only_this_pattern_has_a_tail_of_its_last_position(config, tail):
+    """`tail_segment` by the kinds of the pattern's trailing segments: the
+    cross-decoder's segment here, and `len(segments)` (no narrowing, the
+    program every position walks) for every other accepted configuration,
+    each of whose layers leaves keys and values or a state."""
+    cfg = spec.build_transformer_config(spec.load_config(BENCH, config))
+    segments = cfg.segments()
+    assert cfg.tail_segment() == (len(segments) if tail is None else tail)
+    if tail is None:
+        assert not set(segments[-1][0]) & LAST_ROW_KINDS
+
+
+@pytest.mark.parametrize("pattern, tail, last_layers", [
+    # a mamba and a window layer AFTER a (gmu, cross) segment: they read
+    # every position of their stream, so nothing before them narrows
+    (((("mamba", "attention"), 1), (("gmu", "cross"), 2),
+      (("mamba", "window"), 1), (("gmu", "cross"), 1)), 3, 2),
+    (((("mamba", "attention"), 1), (("gmu", "cross"), 2),
+      (("mamba", "window"), 1)), 3, 0),
+    # two trailing segments of such kinds are one tail
+    (((("mamba", "attention"), 2), (("gmu", "cross"), 1),
+      (("gmu", "gmu", "cross"), 1)), 1, 5),
+])
+def test_a_layer_that_reads_its_row_is_never_behind_the_narrowing(
+        toy, monkeypatch, pattern, tail, last_layers):
+    base, _, _ = toy
+    n_layers = sum(len(kinds) * reps for kinds, reps in pattern)
+    cfg = dataclasses.replace(base, layer_pattern=pattern, n_layers=n_layers,
+                              mixer_period=("attention",))
+    assert cfg.tail_segment() == tail
+    params = init_params(jax.random.key(4), cfg)
+    P = 16
+    toks, starts = _group(_prompts([16, 7], seed=8), P)
+    last, cache = _prefill_hidden(params, toks, cfg, P, starts)
+    assert last.shape == (2, 1 if last_layers else P, cfg.d_model)
+    _every_position(monkeypatch)
+    every, whole = _prefill_hidden(params, toks, cfg, P, starts)
+    np.testing.assert_allclose(last[:, -1], every[:, -1], rtol=1e-5,
+                               atol=1e-6)
+    assert set(cache) == set(whole)
+    for name in whole:      # the later mamba layer's state and ring among them
+        np.testing.assert_array_equal(cache[name], whole[name], err_msg=name)
+    monkeypatch.undo()
+    eng = E.InferenceEngine(params, cfg, slots=2, max_prompt_len=P,
+                            max_new_tokens=2, decode_chunk=1)
+    reqs = [eng.submit(p) for p in _prompts([16, 7], seed=8)]
+    for _ in range(50):
+        if all(r.done.is_set() for r in reqs):
+            break
+        eng.step()
+    assert all(r.done.is_set() for r in reqs)
+    # the counters that say so: K x P a layer every position passes, K a
+    # layer of the tail
+    rows, padded = eng.stats["prefills"], eng.stats["prefill_padded_tokens"]
+    assert rows == 2 and padded == 2 * P
+    assert eng.stats["prefill_padded_layer_tokens"] == padded * n_layers
+    assert eng.stats["prefill_layer_tokens"] == \
+        padded * (n_layers - last_layers) + rows * last_layers
+
+
+def test_the_benchmark_reads_the_share_of_layer_passes_from_the_counters():
+    """`prefill_layer_pass_share.batch`: data alone (the accepted
+    `engine_ratio` reader), in the three cells whose result is tokens per
+    second; 18 of 32 layers x every position + 14 x a row's last here, and
+    nothing to read from a program without the counters."""
+    name = "prefill_layer_pass_share.batch"
+    entry = next(m for m in BENCH["per_layer"] if m["name"] == name)
+    assert {"internlm2-1.8b.batch-closed", "solar-open2-250b.batch-closed-128",
+            "phi-4-mini-flash-reasoning.reason-closed-64"} \
+        <= set(entry["workloads"])
+    for cell in entry["workloads"]:
+        assert entry["moves"] in [m["name"] for m in spec.metrics_for(
+            BENCH, cell, "end_to_end")]
+    metric = spec.load_layer_metric(name)
+    for key in ("unit", "better", "source", "layer", "moves"):
+        assert metric[key] == entry[key], key
+    read = spec.load_reader(metric)
+    K, P = 4, 1024
+    eng = {"prefill_padded_tokens": K * P,
+           "prefill_layer_tokens": K * P * 18 + K * 14,
+           "prefill_padded_layer_tokens": K * P * 32}
+    assert 56.25 < read({"out": {"counters": {"engine": eng}}}, metric) \
+        < 56.3
+    del eng["prefill_layer_tokens"]
+    assert read({"out": {"counters": {"engine": eng}}}, metric) is None
 
 
 def test_the_shared_leaf_through_the_decode_kernel_is_the_contraction(
