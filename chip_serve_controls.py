@@ -131,12 +131,12 @@ def _fp8_in_place(eng):
 def state_bf16():
     import jax
 
-    from ray_tpu.models import engine
+    from ray_tpu.models import generate
     from ray_tpu.ops import kda
 
     def rounded(x):
         return _round(x, 8, 7)
-    scan, step = kda.kda_scan, engine.kda_decode_step
+    scan, step = kda.kda_scan, generate.kda_decode_step
 
     def kda_scan(*args, final_state=False, **kw):
         if not final_state:
@@ -150,7 +150,7 @@ def state_bf16():
         return jax.lax.dynamic_update_index_in_dim(
             state, rounded(mine), layer, 0), o
     return _patched((kda, "kda_scan", kda_scan),
-                    (engine, "kda_decode_step", kda_decode_step))
+                    (generate, "kda_decode_step", kda_decode_step))
 
 
 def no_window():
@@ -181,13 +181,12 @@ def no_window():
 def lam_fixed():
     import jax.numpy as jnp
 
-    from ray_tpu.models import engine, generate, transformer
+    from ray_tpu.models import generate, transformer
 
     def diff_out(o, lp, cfg, layer):
         return transformer.diff_out(o, dict(
             lp, diff_lambda=jnp.zeros_like(lp["diff_lambda"])), cfg, layer)
-    return _patched((generate, "diff_out", diff_out),
-                    (engine, "diff_out", diff_out))
+    return _patched((generate, "diff_out", diff_out))
 
 
 def run_control(replica, name: str, seed: int, lengths) -> dict:

@@ -9,16 +9,45 @@ failover tests without real hosts (SURVEY.md §4.2).
 
 from __future__ import annotations
 
+import os
+import re
 from typing import Optional
 
 from ray_tpu.core import api
 from ray_tpu.core.resources import TpuTopology
 
 
+def _kill_agent(proc, store_name: str = ""):
+    """SIGKILL an agent process (simulates host loss) and unlink its
+    /dev/shm arena, ``store_name`` or, before the head knows it, whatever
+    arena the process maps: SIGKILL gives the agent no chance to unlink its
+    own, and each orphan pins object_store_memory bytes of shared memory
+    until someone removes it (ROADMAP 5c)."""
+    names = {store_name} - {""}
+    if not names and proc.poll() is None:
+        try:
+            with open(f"/proc/{proc.pid}/maps") as fh:
+                names = set(re.findall(r"/dev/shm/(rtpu_\w+)", fh.read()))
+        except OSError:
+            pass
+    if proc.poll() is None:
+        try:
+            proc.kill()
+        except OSError:
+            pass
+    proc.wait(timeout=10)
+    for name in names:
+        try:
+            os.unlink(f"/dev/shm/{name}")
+        except OSError:
+            pass
+
+
 class Cluster:
     def __init__(self, initialize_head: bool = True,
                  head_node_args: Optional[dict] = None):
         self._info = None
+        self._remote = []   # the RemoteNodeHandles of the agents it started
         if initialize_head:
             args = dict(head_node_args or {})
             self._info = api.init(**args)
@@ -42,8 +71,12 @@ class Cluster:
             labels=labels, tpu_topology=tpu_topology)
 
     def remove_node(self, node_idx: int):
-        """Kill a logical node (workers die, objects on it are lost)."""
+        """Kill a node (workers die, objects on it are lost; a remote
+        node's agent process and arena go with it)."""
         self.head.remove_node(node_idx)
+        for handle in self._remote:
+            if handle.node_idx == node_idx:
+                handle.terminate()
 
     # ------------------------------------------------ real remote processes
 
@@ -59,7 +92,6 @@ class Cluster:
         cross-host object transfer) on one machine. Returns a
         RemoteNodeHandle with .node_idx / .terminate().
         """
-        import os
         import subprocess
         import sys
         import time
@@ -88,17 +120,23 @@ class Cluster:
             if new:
                 idx = new.pop()
                 node = self.head.nodes.get(idx)
-                return RemoteNodeHandle(
-                    proc, idx, getattr(node, "store_name", ""))
+                self._remote.append(RemoteNodeHandle(
+                    proc, idx, getattr(node, "store_name", "")))
+                return self._remote[-1]
             if proc.poll() is not None:
                 out = proc.stdout.read().decode(errors="replace")
                 raise RuntimeError(f"node agent died: {out[-2000:]}")
             time.sleep(0.05)
-        proc.kill()
+        _kill_agent(proc)
         raise TimeoutError("node agent did not register in time")
 
     def shutdown(self):
+        """Also ends the agents a test left running or killed itself (one
+        that FAILS mid-way never reaches its own `terminate()`): none
+        outlives the cluster, and none leaves its arena behind."""
         api.shutdown()
+        for handle in self._remote:
+            handle.terminate()
 
 
 class NodeKiller:
@@ -227,24 +265,10 @@ class RemoteNodeHandle:
         self.proc = proc
         self.node_idx = node_idx
         #: the agent's /dev/shm arena file name, so terminate() can
-        #: sweep it — SIGKILL gives the agent no chance to unlink its
-        #: own arena, and each orphan pins object_store_memory bytes of
-        #: shared memory until someone removes it (ROADMAP 5c)
+        #: sweep it
         self.store_name = store_name
 
     def terminate(self):
         """Kill the agent process (simulates host loss) and sweep its
         leaked /dev/shm arena."""
-        if self.proc.poll() is None:
-            try:
-                self.proc.kill()
-            except OSError:
-                pass
-        self.proc.wait(timeout=10)
-        if self.store_name:
-            import os
-
-            try:
-                os.unlink(f"/dev/shm/{self.store_name}")
-            except OSError:
-                pass
+        _kill_agent(self.proc, self.store_name)

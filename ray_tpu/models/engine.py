@@ -11,37 +11,16 @@ the reference delegates to external vLLM workers for, built TPU-first:
     [L, B, KV, S, hd] array — static shapes, one compiled decode program
     for the life of the engine. A slot is a row; admission writes a new
     prompt's K/V into a freed row, eviction is just host bookkeeping.
-    It is stored KV-heads-outside-positions because that is the order
-    the decode contraction reads it in: stored any other way, XLA
-    transposes the whole cache on the way into every chunk and back.
-  - The cache is a TREE of such leaves, each owned by one kind of token
-    mixer and stacked over the layers of that kind (`SlotCache` below):
-    keys and values for the attention layers (a ring of the window's
-    places for a window layer; none for a cross layer, which reads another
-    layer's), a float32 state and the short convolutions' tail for gated
-    delta-rule (KDA) and state-space (mamba) layers: leaves of different
-    lengths by kind. The engine hands the leaves through untouched;
-    admission writes a slot's share of every leaf inside the prefill
-    program, which is also its reset. Both programs walk the layers a
-    segment of the layer pattern at a time (`cfg.segments`; one for a model
-    of one period), each a scan over a period of mixer kinds, as training
-    does, each kind with its own prefill and one-token step.
-  - A decode substep reads the cache rows its requests own and writes
-    only the rows that change: on a chip the layer scan carries the layer
-    index and hands the whole stacked cache to one Pallas kernel
-    (`ops/decode_attention.py`), which fetches, per slot, the position
-    blocks that cross ``[start, pos)`` and nothing for a slot that is not
-    active (a layer's slab sliced out for a custom call would be copied
-    first: 84 MB a layer for K and V each at 32 slots x 1280; under
-    differential attention the full-length leaf's pairs of heads are the
-    same call, for its own layer and for every cross layer that reads it,
-    and a window layer's ring keeps the contraction); on the CPU, and for
-    a shape the kernel does not take, the scan takes the cache as
-    read-only input and `_gqa_decode_attention` contracts over every
-    position under a mask. Either way the new token attends to its own
-    K/V as one more key column, and the B new rows per layer are written
-    in place AFTER the scan (a per-layer write inside the scan makes the
-    scan re-stack, and XLA copy, the whole cache every substep).
+  - The cache is a TREE of such leaves, each owned by one KIND of token
+    mixer and stacked over the layers of that kind (`SlotCache` below).
+    What a kind keeps a slot, how it lands there, its prefill and its
+    one-token step are ONE table, `generate.MIXERS`: this module walks the
+    layers as training does, looks each kind up and names none. Admission
+    writes a slot's share of every leaf in the prefill program.
+  - A decode substep reads the cache rows its requests own (on a chip a
+    Pallas kernel, `ops/decode_attention.py`, fetches the blocks crossing
+    ``[start, pos)``; else a masked contraction runs over every position)
+    and writes only the rows that change, AFTER the scan (`_decode_one`).
   - Each decode step advances EVERY active slot by one token in a single
     batched program (per-row cache positions, per-row RoPE), then the
     host admits queued prompts into any slots that finished — finished
@@ -51,8 +30,7 @@ the reference delegates to external vLLM workers for, built TPU-first:
     in the slot rows; queued prompts admit in groups of up to 4 as ONE
     batched program, the oldest with the oldest of its own bucket (a
     group pads to its largest member), and prefills interleave with
-    decode chunks so
-    time-to-first-token stays bounded under load.
+    decode chunks so time-to-first-token stays bounded under load.
   - Dispatch and fetch are pipelined across two threads: the scheduler
     thread admits + dispatches (cheap async calls), the fetcher thread
     does the device->host token transfers, which overlap with queued
@@ -86,6 +64,7 @@ import time
 from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass, field
 from functools import partial
+from types import SimpleNamespace
 from typing import Dict, List, Optional, Sequence
 
 import jax
@@ -93,91 +72,33 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.models.config import TransformerConfig
-from ray_tpu.models.generate import (_diff_decode_attention, _final_logits,
-                                     _gqa_decode_attention, _prefill_hidden,
+from ray_tpu.models.generate import (MIXERS, _final_logits, _prefill_hidden,
                                      join_period, join_segments)
-from ray_tpu.models.transformer import (Params, attention_out, block_norm,
-                                        diff_out, diff_qkv, ffn_block,
-                                        gmu_mixer, kda_mixer, layer_segments,
-                                        mamba_mixer, mixer_precision,
-                                        param_logical_axes, qkv_proj,
+from ray_tpu.models.transformer import (Params, block_norm, ffn_block,
+                                        layer_segments, param_logical_axes,
                                         refuse_unserved, serving_params)
-from ray_tpu.ops.decode_attention import decode_attention, pick_block, \
-    rows_read
-from ray_tpu.ops.kda import kda_decode_step
-from ray_tpu.ops.mamba import mamba_decode_step
-from ray_tpu.parallel.ring import shard_map
-from ray_tpu.parallel.sharding import logical_to_spec
+from ray_tpu.ops.decode_attention import pick_block, rows_read
 
 log = logging.getLogger(__name__)
 
 SlotCache = Dict[str, jax.Array]
 # A tree of leaves with a slots axis B. The engine's own: "pos" [B], slot
-# b's next write position, and "start" [B], its first real (non-pad)
-# position. Every other leaf belongs to the layers of ONE mixer kind,
-# stacked over them (`init_slot_cache`), and the engine hands it through
-# untouched but for the slot-wise write of a prefill (`_put_slots`):
-#   attention  "k"/"v" [L_attn, B, KV, S, hd] — KV-major (heads outside
-#              positions), the layout decode attention contracts over
-#              (under differential attention a token's keys and values are
-#              PAIRS of adjacent heads, [.., KV / 2, S, 2 hd]: how the layer
-#              reads them, and whole lanes for a 64-wide head). A "cross"
-#              layer holds nothing: it reads the leaf of the nearest
-#              attention layer before it, so ONE full-length layer can
-#              serve many
-#   window     "win_k"/"win_v" [L_window, B, KV, W, hd], W = `sliding_window`
-#              places a slot: a ring, position p at place p % W, so a
-#              window layer costs W positions whatever `max_len` is. A
-#              prefill leaves a prompt's last W positions where decode
-#              finds them; a decode step masks by the position each place
-#              holds (never a row's left padding, never a place the slot
-#              has not reached) and overwrites the oldest
-#   kda        "kda_state" [L_kda, B, H, dk, dv] float32, the gated delta
-#              rule's state, and "kda_tail" [L_kda, B, taps - 1, 3 x H x
-#              dk], the projected rows of q, k, v the short convolutions
-#              reach back to
-#   mamba      "mamba_state" [L_mamba, B, N, C] float32, the selective
-#              scan's N states a channel (state-major: channels fill the
-#              lanes; [.., C, 16] would be stored padded to 128 lanes, eight
-#              times the bytes), and "mamba_tail" [L_mamba, B, taps - 1, C],
-#              the rows its convolution reaches back to
+# b's next write position, "start" [B], its first real (non-pad) position,
 # and, where layers have experts, "moe_counts" [3] float32: the held
 # experts fetched, the assignments that fell on them and the layers whose
 # grouped matmuls ran the short row buffer's kernel in the LAST decode
-# chunk (the host adds them up as it fetches the chunk's tokens).
-# A model of attention layers alone holds k, v, pos and start, as ever.
+# chunk (the host adds them up as it fetches the chunk's tokens). Every
+# other leaf belongs to the layers of ONE mixer kind (`generate.MIXERS`) and
+# is written as its `Mixer.land` says; attention alone: k, v, pos, start.
 
 
 def init_slot_cache(cfg: TransformerConfig, slots: int,
                     max_len: int) -> SlotCache:
     refuse_unserved(cfg)
-    cache = {}
-    n_attn, n_kda, n_window, n_mamba = (
-        cfg.layers_of_kind(kind)
-        for kind in ("attention", "kda", "window", "mamba"))
-    # differential attention reads, and so holds, pairs of heads
-    pair = 2 if cfg.diff_attn else 1
-    heads = (cfg.kv_heads // pair, pair * cfg.head_dim)
-    if n_attn:
-        shape = (n_attn, slots, heads[0], max_len, heads[1])
-        cache.update(k=jnp.zeros(shape, cfg.dtype),
-                     v=jnp.zeros(shape, cfg.dtype))
-    if n_window:
-        shape = (n_window, slots, heads[0], cfg.sliding_window, heads[1])
-        cache.update(win_k=jnp.zeros(shape, cfg.dtype),
-                     win_v=jnp.zeros(shape, cfg.dtype))
-    if n_kda:
-        H, hd = cfg.kda_heads, cfg.kda_head_dim
-        cache.update(
-            kda_state=jnp.zeros((n_kda, slots, H, hd, hd), jnp.float32),
-            kda_tail=jnp.zeros((n_kda, slots, cfg.kda_conv - 1, 3 * H * hd),
-                               cfg.dtype))
-    if n_mamba:
-        N, C = cfg.mamba_d_state, cfg.mamba_channels
-        cache.update(
-            mamba_state=jnp.zeros((n_mamba, slots, N, C), jnp.float32),
-            mamba_tail=jnp.zeros((n_mamba, slots, cfg.mamba_d_conv - 1, C),
-                                 cfg.dtype))
+    cache = {name: jnp.zeros(shape, dtype)
+             for kind, mixer in MIXERS.items() if cfg.layers_of_kind(kind)
+             for name, (shape, dtype) in mixer.leaves(cfg, slots,
+                                                      max_len).items()}
     if cfg.moe_experts:
         cache["moe_counts"] = jnp.zeros((3,), jnp.float32)
     cache.update(pos=jnp.zeros((slots,), jnp.int32),
@@ -186,19 +107,14 @@ def init_slot_cache(cfg: TransformerConfig, slots: int,
 
 
 def cache_logical_axes(cache=None) -> Dict[str, tuple]:
-    """Logical axes of the slot cache's leaves (slots axis stays unsharded
-    — serving shards the model, not the batch): of ``cache``'s, or of the
-    four an attention-only model holds."""
-    kv = ("layers", None, "kv_heads", None, None)
-    axes = {"k": kv, "v": kv, "win_k": kv, "win_v": kv, "pos": (None,),
-            "start": (None,),
-            "kda_state": ("layers", None, "heads", None, None),
-            "kda_tail": ("layers", None, None, None),
-            "mamba_state": ("layers", None, None, "mlp"),
-            "mamba_tail": ("layers", None, None, "mlp"),
-            "moe_counts": (None,)}
+    """Logical axes of ``cache``'s leaves, or of the four an attention-only
+    model holds (no slots axis: serving shards the model, not the batch)."""
+    axes = {"pos": (None,), "start": (None,), "moe_counts": (None,)}
+    for mixer in MIXERS.values():
+        axes.update(mixer.axes)
     return {name: axes[name] for name in
-            (("k", "v", "pos", "start") if cache is None else cache)}
+            ((*MIXERS["attention"].axes, "pos", "start")
+             if cache is None else cache)}
 
 
 def _sample(logits, rng, greedy: bool, temperature):
@@ -213,8 +129,7 @@ def _put_rows(cache: SlotCache, new_k: jax.Array, new_v: jax.Array,
     """Write row i of ``new_k``/``new_v`` [L, n, KV, T, hd] into slot
     ``slots[i]`` at positions ``at[i]`` .. ``at[i]+T`` of the cache's two
     leaves ``names``; -> (k, v). One dynamic_update_slice per row (n is
-    static: unrolled), which on a donated cache moves the rows and nothing
-    else."""
+    static: unrolled): on a donated cache it moves the rows, nothing else."""
     k, v = (cache[name] for name in names)
     zero = jnp.zeros((), jnp.int32)
     for i in range(new_k.shape[1]):
@@ -227,9 +142,8 @@ def _put_rows(cache: SlotCache, new_k: jax.Array, new_v: jax.Array,
 
 
 def _put_slots(leaf: jax.Array, new: jax.Array, slots: jax.Array):
-    """Row i of ``new`` [L, n, ...] over the whole of slot ``slots[i]`` of
-    a cache leaf [L, B, ...]: what a request admitted into the slot finds
-    there is its own prefill's, never the last tenant's."""
+    """Row i of ``new`` [L, n, ...] over the whole of slot ``slots[i]`` of a
+    leaf [L, B, ...]: a request finds there its own, never the last tenant's."""
     zero = jnp.zeros((), jnp.int32)
     for i in range(new.shape[1]):
         leaf = jax.lax.dynamic_update_slice(
@@ -245,40 +159,27 @@ def prefill_slots(params: Params, cache: SlotCache, tokens: jax.Array,
                   temperature: float = 1.0):
     """Batched prefill: ``tokens`` [K, P] (left-padded to one shared
     bucket, first real token of row i at ``starts[i]``) lands in cache
-    rows ``slots`` [K]; -> (cache, first sampled tokens [K]). A KDA or
-    mamba layer's state and tail and a window layer's ring of those slots
-    are REPLACED by the prompt's (computed from zero): admission is the
-    reset. Of the hidden states only the last position's is read, every
-    row's last real token: where the pattern ends in layers that need no
-    other position (`cfg.tail_segment`), `_prefill_hidden` carries that
-    one alone through them and hands back [K, 1, d].
-
-    One compiled program per (K, P) pair; K is kept to a few power-of-two
-    group sizes by the scheduler. Batching prefills is a dispatch-count
-    lever: each program dispatch has a fixed host cost and the B=1
-    prefill wastes most of the MXU, so admitting 4 queued prompts as one
-    [4, P] program beats 4 serial [1, P] programs (not measured on a
-    local chip).
-    """
+    rows ``slots`` [K]; -> (cache, first sampled tokens [K]). What a layer
+    keeps a slot whole (a state, a tail, a ring) is REPLACED by the
+    prompt's, computed from zero: admission is the reset. One compiled
+    program per (K, P) pair; the scheduler keeps K to a few group sizes."""
     K, P = tokens.shape
     x, cK = _prefill_hidden(params, tokens, cfg, P, starts)
     last = _final_logits(params, x[:, -1:], cfg)[:, 0]  # [K, V]
     toks = _sample(last, rng, greedy, temperature)      # [K]
     new = dict(cache, pos=cache["pos"].at[slots].set(P),
                start=cache["start"].at[slots].set(starts))
-    if "k" in cK:
-        # cK["k"]: [L, K, P, KV, hd] -> the cache's [L, K, KV, P, hd] (the
-        # prompt's K/V is small), then row i into slot row slots[i]
-        new["k"], new["v"] = _put_rows(
-            cache, cK["k"].transpose(0, 1, 3, 2, 4),
-            cK["v"].transpose(0, 1, 3, 2, 4), slots, jnp.zeros_like(slots))
-    for name in ("kda_state", "kda_tail", "mamba_state", "mamba_tail"):
-        if name in cK:
-            new[name] = _put_slots(cache[name], cK[name], slots)
-    for name in ("win_k", "win_v"):     # [L, K, W, KV, hd] -> KV-major
-        if name in cK:
-            new[name] = _put_slots(
-                cache[name], cK[name].transpose(0, 1, 3, 2, 4), slots)
+    for mixer in MIXERS.values():       # `Mixer.land`
+        names = tuple(name for name in mixer.axes if name in cK)
+        # keys, values [L, K, P, KV, hd] -> KV-major (small), each as it lands
+        made = (cK[name] if mixer.land == "slot"
+                else cK[name].transpose(0, 1, 3, 2, 4) for name in names)
+        if names and mixer.land == "rows":      # row i into slot slots[i]
+            new.update(zip(names, _put_rows(
+                cache, *made, slots, jnp.zeros_like(slots), names)))
+        else:
+            new.update((name, _put_slots(cache[name], leaf, slots))
+                       for name, leaf in zip(names, made))
     return new, toks
 
 
@@ -289,44 +190,21 @@ def _on_chip() -> bool:
 def _kv_block(cache: SlotCache) -> Optional[int]:
     """The position block the decode kernel walks the cache's keys and
     values by, or None where the masked contraction runs instead: on the
-    CPU (where it is also the tests' reference), for a shape the kernel
-    does not take (`pick_block`: under differential attention it sees the
-    PAIRS the leaf holds, whole lanes for a head of 64) and for a model
-    without attention layers. Decided, as `transformer._select_attention`
-    decides, by what the code can observe."""
+    CPU, for a shape the kernel does not take (`pick_block`: under
+    differential attention the PAIRS the leaf holds) and for a model
+    without attention layers: decided by what the code can observe."""
     if "k" not in cache or not _on_chip():
         return None
     k = cache["k"]
     return pick_block(k.shape[3], k.shape[4], k.dtype)
 
 
-def _kernel_attention(q, cache: SlotCache, k_new, v_new, active, layer, mesh,
-                      **how):
-    """`decode_attention` on one layer of the whole stacked cache (``how``:
-    its scale and output dtype, where they are not a GQA layer's). GSPMD
-    cannot partition a Mosaic kernel: on a mesh it runs per shard of the
-    KV heads (heads are independent), as `transformer._attention` runs
-    the train kernel."""
-    args = (q, cache["k"], cache["v"], k_new, v_new, cache["pos"],
-            cache["start"], active, layer)
-    if mesh is None or mesh.size == 1:
-        return decode_attention(*args, **how)
-    kv, heads, new = (
-        logical_to_spec(axes, mesh_axes=mesh.axis_names)
-        for axes in (cache_logical_axes()["k"], (None, None, "heads", None),
-                     (None, "kv_heads", None)))
-    rep = jax.sharding.PartitionSpec()
-    return shard_map(partial(decode_attention, **how), mesh=mesh,
-                     in_specs=(heads, kv, kv, new, new, rep, rep, rep, rep),
-                     out_specs=heads)(*args)
-
-
 def _ring_mask(pos, start, window: int):
-    """[B, window] bool: the places of a window layer's ring a decode step
-    at ``pos`` reads. Place j holds the last position p < pos with p %
-    window == j; the token sees it where p is within the ``window``
-    positions that end at the token itself (so never the place it is about
-    to overwrite) and no left padding (nor a place never written: p < 0)."""
+    """[B, window] bool: the places of a ring a decode step at ``pos`` reads.
+    Place j holds the last position p < pos with p % window == j; the token
+    sees it where p is within the ``window`` positions that end at the token
+    (so never the place it is about to overwrite) and no left padding (nor
+    a place never written: p < 0)."""
     j = jnp.arange(window)[None, :]
     last = pos[:, None] - 1
     held = last - (last - j) % window
@@ -338,155 +216,61 @@ def _decode_one(params: Params, cache: SlotCache, tokens: jax.Array,
                 cfg: TransformerConfig, active: Optional[jax.Array] = None,
                 mesh=None):
     """One decode step for every slot: tokens [B] (each slot's pending
-    token) -> (cache with pos advanced, logits [B, V]). ``active`` [B]
-    bool left out reads every slot as active; a slot that is not active
-    attends to nothing it has cached and keeps its KDA and mamba states
-    and tails as they are (its logits are junk either way).
+    token) -> (cache with pos advanced, logits [B, V]). ``active`` [B] bool
+    left out reads every slot as active; a slot that is not attends to
+    nothing cached and keeps its states and tails (its logits are junk).
 
-    pos/RoPE/attention bounds are all per-row, so slots admitted at
-    different times decode together in one program. The layers are walked
-    a segment of the pattern at a time (`cfg.segments`: one for a model of
-    one period), each a scan over its repeats whose step is one period of
-    mixer kinds, as `transformer._trunk` walks them (one stack a position
-    of the period), each kind taking its own one-token step:
-      attention  only READS the cache and hands back the layer's new K/V
-                 row ([L,B,KV,hd], a megabyte); the rows land afterwards,
-                 one in-place dynamic_update_slice per slot at its own
-                 ``pos`` (on the donated, loop-carried cache nothing else
-                 is moved). A ``pos`` past the end clamps to the slot's
-                 own last position, which no request's plan reads
-                 (`InferenceEngine._max_len`).
-      window     the same against its ring under `_ring_mask`; the row
-                 lands at ``pos % sliding_window``.
-      cross      reads the leaf of the nearest attention layer before it
-                 and that layer's row of THIS token, which rides the carry
-                 (it is not in the cache before the scans end).
-      kda, mamba update the slots' states where they lie: the scan
-                 carries the stacked states and the layer's index, and
-                 `kda_decode_step` / `mamba_decode_step` reads and writes
-                 the layer's blocks of the one buffer; the tails are
-                 shifted by the token. A mamba layer's scan output rides
-                 the carry as the memory of the gated memory units after
-                 it (gmu: no state).
-    Layers with experts add what they fetched to ``moe_counts``.
-    """
+    The layers are walked as `transformer._trunk` walks them (pos, RoPE
+    and attention bounds all per-row): a segment of the pattern at a time
+    (`cfg.segments`), each a scan over its repeats whose step is one period
+    of mixer kinds, each kind taking its own step (`generate.MIXERS`).
+    Leaves that land as rows or in a ring (`Mixer.land`) the scan only
+    READS (the kernel indexes the whole stack, a masked contraction takes
+    its layers' slabs); each layer's new row ([L,B,KV,hd], a megabyte)
+    lands afterwards, one in-place dynamic_update_slice per slot: a write
+    inside the scan makes it re-stack, and XLA copy, the whole cache every
+    substep. A ``pos`` past the end clamps to the slot's own last position,
+    which no request's plan reads (`InferenceEngine._max_len`). Leaves
+    that land a slot whole ride the carry and are updated where they lie."""
     pos, start = cache["pos"], cache["start"]
     # (rows first: a tied table is held float32 and is not converted whole)
     x = params["embed"][tokens[:, None]].astype(cfg.dtype)  # [B, 1, d]
     positions = pos[:, None]  # [B, 1] per-row RoPE
     B = tokens.shape[0]
-    has = collections.Counter(cfg.mixer_kind(i) for i in range(cfg.n_layers))
+    mixers = {kind: mixer for kind, mixer in MIXERS.items()
+              if cfg.layers_of_kind(kind)}
+    # how the model's leaves land, and the places of one that lands so
+    places = {mixer.land: cache[next(iter(mixer.axes))].shape[-2]
+              for mixer in mixers.values() if mixer.land}
     kernel = _kv_block(cache) is not None
-    if active is None and (kernel or has["kda"] or has["mamba"]):
+    if active is None and (kernel or "slot" in places):
         active = jnp.ones_like(pos, bool)
-    if has["attention"]:
-        dtype = cache["k"].dtype
-        if not kernel:
-            kpos = jnp.arange(cache["k"].shape[3])[None, :]
-            mask = (kpos >= start[:, None]) & (kpos < pos[:, None])  # [B, S]
-    if has["window"]:
-        dtype = cache["win_k"].dtype
-        ring = _ring_mask(pos, start, cfg.sliding_window)
-
-    def state_step(name, step_fn, carry, layer):
-        def step(*token):
-            carry[name], o = step_fn(carry[name], layer, *token, active)
-            return o
-        return step
-
-    def shift_tail(name, carry, layer, mixer):
-        """``mixer(old tail) -> (..., new tail)`` on layer ``layer``'s
-        slice of the stacked tails; an inactive slot keeps its own."""
-        old = jax.lax.dynamic_index_in_dim(carry[name], layer, 0,
-                                           keepdims=False)
-        *out, tail = mixer(old)
-        carry[name] = jax.lax.dynamic_update_index_in_dim(
-            carry[name], jnp.where(active[:, None, None], tail, old),
-            layer, 0)
-        return out
+    mask = ring = None      # the places of a leaf that a token reads
+    if "rows" in places and not kernel:
+        kpos = jnp.arange(places["rows"])[None, :]
+        mask = (kpos >= start[:, None]) & (kpos < pos[:, None])  # [B, S]
+    if "ring" in places:
+        ring = _ring_mask(pos, start, places["ring"])
+    shared = dict(cache=cache, positions=positions, active=active,
+                  mask=mask, ring=ring, kernel=kernel, mesh=mesh)
 
     def block(carry, scanned, kinds, first, seen, slab_names):
-        """One period of a segment: ``first`` the model's layers before
-        the segment, ``seen`` those of each kind; the cache leaves
-        ``slab_names`` scanned beside the weights, [n of the kind, ...]."""
+        # ``first``: the model's layers before the segment, ``seen``: of
+        # each kind; ``slab_names``: the leaves scanned beside the weights
         lps, period, *slabs = scanned
         carry, slabs = dict(carry), dict(zip(slab_names, slabs))
-        x, rows, at = carry["x"], {}, collections.Counter()
-        count = collections.Counter(kinds)
+        x, rows = carry["x"], {}
         for j, (kind, lp) in enumerate(zip(kinds, lps)):
-            i, n = at[kind], count[kind]
-            at[kind] += 1
+            i, n = kinds[:j].count(kind), kinds.count(kind)
             # this layer among its kind's, the cache leaves' leading axis
             layer = period if n == 1 else period * n + i
             if seen[kind]:
                 layer = layer + seen[kind]
-            if kind == "kda":
-                h = block_norm(x, lp, "attn_norm", cfg)
-                step = state_step("kda_state", kda_decode_step, carry, layer)
-                o, = shift_tail(
-                    "kda_tail", carry, layer, lambda old: kda_mixer(
-                        h, lp, cfg, tail=old, step=step))
-            elif kind == "mamba":
-                h = block_norm(x, lp, "attn_norm", cfg)
-                step = state_step("mamba_state", mamba_decode_step, carry,
-                                  layer)
-                o, y = shift_tail(
-                    "mamba_tail", carry, layer, lambda old: mamba_mixer(
-                        h, lp, cfg, tail=old, step=step))
-                if "memory" in carry:
-                    carry["memory"] = y
-            elif kind == "gmu":
-                h = block_norm(x, lp, "attn_norm", cfg)
-                o = gmu_mixer(h, carry["memory"], lp, cfg)
-            elif cfg.diff_attn:
-                h = block_norm(x, lp, "attn_norm", cfg)
-                q, k, v = diff_qkv(h, lp, cfg)
-                if kind == "cross":     # the nearest attention layer's
-                    k, v = carry["shared_k"], carry["shared_v"]
-                    layer = seen["attention"] - 1
-                else:
-                    k, v = k[:, 0].astype(dtype), v[:, 0].astype(dtype)
-                    rows.setdefault(kind, []).append((k, v))
-                    if kind == "attention" and "shared_k" in carry:
-                        carry["shared_k"], carry["shared_v"] = k, v
-                if kernel and kind != "window":
-                    # the pairs are a grouped-query call: a key pair's
-                    # query rows [q1 | 0], [0 | q2] a query pair, scaled by
-                    # the head's width, o float32 for `diff_out` to subtract
-                    o = _kernel_attention(
-                        q.reshape(B, 1, -1, q.shape[-1]), cache, k, v,
-                        active, layer, mesh, scale=cfg.head_dim ** -0.5,
-                        out_dtype=jnp.float32).reshape(q.shape)
-                else:
-                    # the masked contraction: the CPU's path, and a window
-                    # layer's anywhere (a ring's valid places are not
-                    # [start, pos) once it wraps)
-                    if kind == "cross":
-                        kc, vc = cache["k"][layer], cache["v"][layer]
-                    else:
-                        name = "win_" if kind == "window" else ""
-                        kc, vc = slabs[name + "k"][i], slabs[name + "v"][i]
-                    o = _diff_decode_attention(
-                        q, kc, vc, k, v, ring if kind == "window" else mask)
-                o = diff_out(o, lp, cfg, first + period * len(kinds) + j)
-            else:
-                # a float32 mixer (`mixer_precision`) around the attention
-                # itself: the kernel keeps its own arithmetic, reads the
-                # cache's rows and hands back o in q's dtype
-                with mixer_precision(cfg, lp) as wide:
-                    x = x.astype(wide)
-                    h = block_norm(x, lp, "attn_norm", cfg)
-                    q, k, v = qkv_proj(h, lp, cfg, positions)
-                k, v = k[:, 0].astype(dtype), v[:, 0].astype(dtype)
-                if kernel:          # [B, KV, hd]
-                    o = _kernel_attention(q, cache, k, v, active, layer, mesh)
-                else:
-                    o = _gqa_decode_attention(
-                        q, slabs["k"][i], slabs["v"][i], k, v, mask)
-                rows.setdefault(kind, []).append((k, v))
-                with mixer_precision(cfg, lp):
-                    o = attention_out(o, h, lp, cfg)
-            x = x + o
+            x, row = MIXERS[kind].step(x, lp, cfg, SimpleNamespace(
+                **shared, slabs=slabs, carry=carry, seen=seen, layer=layer,
+                i=i, index=lambda: first + period * len(kinds) + j))
+            if row:
+                rows.setdefault(kind, []).append(row)
             down, stats = ffn_block(
                 block_norm(x, lp, "mlp_norm", cfg), lp, cfg)
             if "router" in lp and "moe_counts" in carry:
@@ -498,16 +282,13 @@ def _decode_one(params: Params, cache: SlotCache, tokens: jax.Array,
         return dict(carry, x=x), {kind: tuple(zip(*pairs))
                                   for kind, pairs in rows.items()}
 
-    carried = {name: cache[name] for name in
-               ("kda_state", "kda_tail", "mamba_state", "mamba_tail",
-                "moe_counts") if name in cache}
+    carried = {name: cache[name] for mixer in mixers.values()
+               if mixer.land == "slot" for name in mixer.axes}
+    if "moe_counts" in cache:
+        carried["moe_counts"] = cache["moe_counts"]
     carried["x"] = x
-    if has["gmu"]:
-        carried["memory"] = jnp.zeros((B, 1, cfg.mamba_channels),
-                                      jnp.float32)
-    if has["cross"]:
-        carried["shared_k"] = carried["shared_v"] = jnp.zeros(
-            (B, cache["k"].shape[2], cache["k"].shape[4]), cache["k"].dtype)
+    for mixer in mixers.values():
+        carried.update(mixer.carry(cfg, B, None, cache))
     first, seen, new_rows = 0, collections.Counter(), {}
     for (kinds, reps), stacks in zip(cfg.segments(),
                                      layer_segments(params["layers"])):
@@ -515,11 +296,11 @@ def _decode_one(params: Params, cache: SlotCache, tokens: jax.Array,
         # a kernel indexes [L, ...] itself: the scan carries the period's
         # index; the masked contractions take their layers' slabs
         scanned, slab_names = [stacks, jnp.arange(reps)], []
-        for names, kind in ((("k", "v"), "attention"),
-                            (("win_k", "win_v"), "window")):
+        for kind, mixer in mixers.items():
             n = count[kind]
-            if n and not (kind == "attention" and kernel):
-                for name in names:
+            if n and (mixer.land == "ring"
+                      or mixer.land == "rows" and not kernel):
+                for name in mixer.axes:
                     c = cache[name]
                     if n * reps != c.shape[0]:
                         c = c[seen[kind]:seen[kind] + n * reps]
@@ -535,30 +316,29 @@ def _decode_one(params: Params, cache: SlotCache, tokens: jax.Array,
         first += len(kinds) * reps
         for kind, n in count.items():
             seen[kind] += n * reps
-    x = carried.pop("x")
-    if has["cross"]:
-        # the rows of the leaf the cross layers read are written once its
-        # last reader is done: without the tie the write may be scheduled
-        # before the last segment's loop, and the compiler copies the
-        # whole leaf twice a substep to allow for it
+    x = carried["x"]
+    if any(mixer.reads for mixer in mixers.values()):
+        # the rows of a leaf that another kind's layers read are written once
+        # its last reader is done: without the tie the write may be scheduled
+        # before the last loop, and the whole leaf copied twice a substep
         x, new_rows = jax.lax.optimization_barrier((x, new_rows))
     logits = _final_logits(params, x, cfg)[:, 0]  # [B, V]
-    for name in ("memory", "shared_k", "shared_v"):
-        carried.pop(name, None)
     # a slot that is not active stays where it is: what it writes lands on
     # the one place at its frozen position (of a ring: the place that has
     # just left the window), every substep of a chunk
     new = dict(cache, pos=pos + 1 if active is None
-               else pos + active.astype(pos.dtype), **carried)
+               else pos + active.astype(pos.dtype),
+               **{name: carried[name] for name in carried if name in cache})
     every = jnp.arange(B, dtype=jnp.int32)
-    for kind, names in (("attention", ("k", "v")),
-                        ("window", ("win_k", "win_v"))):
+    for kind, mixer in mixers.items():
         if kind in new_rows:
+            names = tuple(mixer.axes)
             k_rows, v_rows = (join_segments(parts)
                               for parts in zip(*new_rows[kind]))
-            new[names[0]], new[names[1]] = _put_rows(
+            new.update(zip(names, _put_rows(
                 cache, k_rows[:, :, :, None], v_rows[:, :, :, None], every,
-                pos % cfg.sliding_window if kind == "window" else pos, names)
+                pos % places["ring"] if mixer.land == "ring" else pos,
+                names)))
     return new, logits
 
 
@@ -580,11 +360,10 @@ def decode_slots(params: Params, cache: SlotCache, tokens: jax.Array,
     input column lets the pipelined host loop learn prefill-sampled
     first tokens from the same fetch (the token chain itself never
     leaves the device). Rows whose input is ``eos_id`` or that hit it
-    mid-chunk freeze on-device (keep emitting eos);
-    inactive slots compute junk into a position the next real write or
-    prefill overwrites, their positions don't advance, and the host
-    ignores their samples. ``mesh``: the mesh the params and the cache are
-    sharded over, which the decode kernel needs to run per shard.
+    mid-chunk freeze on-device (keep emitting eos); inactive slots compute
+    junk into a position the next real write or prefill overwrites, their
+    positions don't advance, and the host ignores their samples. ``mesh``:
+    the one the params and the cache are sharded over (the kernel needs it).
     """
     pos0 = cache["pos"]
     if "moe_counts" in cache:   # this chunk's alone
@@ -603,8 +382,7 @@ def decode_slots(params: Params, cache: SlotCache, tokens: jax.Array,
         substep, (cache, tokens, done0), jax.random.split(rng, steps))
     # only active rows advance; inactive rows' junk substep writes are
     # overwritten by the next prefill/real decode at their frozen pos
-    new_pos = jnp.where(active, cache["pos"],
-                        pos0).astype(jnp.int32)
+    new_pos = jnp.where(active, cache["pos"], pos0).astype(jnp.int32)
     cache = dict(cache, pos=new_pos)
     return cache, jnp.concatenate([tokens[:, None], toks.T], axis=1)
 

@@ -1,40 +1,36 @@
-"""The building blocks of the serving programs: prompt pass and attention.
+"""The building blocks of the serving programs: the prompt pass, attention
+and the table of served mixer kinds (`MIXERS`).
 
 `ray_tpu.models.engine` compiles its two programs from these (prefill:
-`_prefill_hidden` + `_final_logits`; decode: inside its own layer scan,
-`ops/decode_attention.py`'s kernel on a chip and `_gqa_decode_attention`
-on the CPU). There is no decode loop or cache of this module's own.
-TPU-first design:
+`_prefill_hidden` + `_final_logits`; decode: its own layer scan over the
+table's one-token steps). There is no decode loop or cache of this
+module's own. TPU-first design:
 
   - Static shapes everywhere: a prompt is left-padded to a bucket and
     its K/V padded out to ``max_len``, so each (rows, bucket) pair is one
     compiled program.
   - The layer dimension rides the same stacked-params ``lax.scan`` as
-    training (`transformer.forward`: a period of mixer kinds a step, one
-    stack a position of the period; a scan a segment of the layer pattern
-    where it has several), so depth costs one trace and the
-    prompt's K/V comes back as one [L, B, S, KV, hd] array per k/v —
-    contiguous HBM, no per-layer Python lists. What a KDA layer leaves a
-    slot comes back the same way: its final state and the last projected
-    rows its convolutions reach back to, one entry a KDA layer.
+    training (`transformer.forward`: a period of mixer kinds a step, a scan
+    a segment of the layer pattern), so depth costs one trace and what a
+    prompt leaves comes back as one [L, B, ...] array a leaf — contiguous
+    HBM, no per-layer Python lists.
   - The prompt pass computes what a slot keeps and the last position's
-    logits, nothing else: where the layer pattern ends in layers that
-    leave a slot nothing and read no other position of their own stream
-    (a decoder-hybrid-decoder's cross-decoder: `cfg.tail_segment`), only
-    a row's last position passes them, K rows and not K x P.
+    logits, nothing else: through trailing layers that leave a slot
+    nothing (`cfg.tail_segment`) only a row's last position passes.
   - Keys/values are cached *post-RoPE* and *pre-GQA-expansion* (KV heads,
     not Q heads): memory scales with kv_heads, and the repeat to Q heads
     happens inside the attention contraction.
   - Decode attention at T=1 per step is HBM-bandwidth-bound: its time is
-    the cache bytes it reads. `_gqa_decode_attention`, a dense masked
-    contraction, reads every position of every slot whatever the mask
-    says; it is the CPU path and the reference the decode kernel is held
-    to, which reads only the blocks of positions a request owns.
+    the cache bytes it reads. The masked contractions here read every
+    position of every slot: the CPU path, and the reference of the decode
+    kernel, which reads only the blocks of positions a request owns.
 """
 
 from __future__ import annotations
 
 import functools
+from types import SimpleNamespace
+from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -46,6 +42,11 @@ from ray_tpu.models.transformer import (Params, attention_out, block_norm,
                                         lm_head, mamba_mixer,
                                         mixer_precision, qkv_proj,
                                         refuse_unserved)
+from ray_tpu.ops.decode_attention import decode_attention
+from ray_tpu.ops.kda import kda_decode_step
+from ray_tpu.ops.mamba import mamba_decode_step
+from ray_tpu.parallel.ring import shard_map
+from ray_tpu.parallel.sharding import logical_to_spec
 
 # Large-finite instead of -inf for masked scores: a fully-masked query row
 # (a pad position in a left-padded batch) then softmaxes to uniform junk
@@ -192,6 +193,271 @@ def join_period(parts):
     return x.reshape((-1,) + x.shape[2:])
 
 
+# ---- the served mixer kinds -------------------------------------------------
+# What serving knows of a KIND of token mixer stands here and nowhere else,
+# one record a kind (`MIXERS`). `_prefill_hidden` below and the engine's
+# cache and programs walk the layers and look the kind up.
+
+_KV_AXES = ("layers", None, "kv_heads", None, None)
+
+
+class Mixer(NamedTuple):
+    # (cfg, slots, max_len) -> {leaf: (shape, dtype)}: what the kind keeps a
+    # slot, stacked over its layers; and the leaves' logical axes
+    leaves: Callable = lambda cfg, slots, max_len: {}
+    axes: dict = {}
+    # how a prefill's leaf reaches the slots, and a step's row the leaf:
+    # "rows"  [L, K, P, KV, hd] -> KV-major, a slot's rows from position 0;
+    #         a step hands back the token's (k, v), written at ``pos``
+    # "ring"  the same transpose, the whole slot; the row at ``pos % places``
+    # "slot"  the whole slot as made; a step updates the carried stack
+    land: Optional[str] = None
+    # (h, lp, cfg, ctx) -> (o, {leaf: the prompt's}): h the normed rows, ctx
+    # what `_prefill_hidden` shares: seen [B, T, P] (causal, no padding),
+    # near (seen, within the window), index() (the layer's, of the model)
+    prefill: Optional[Callable] = None
+    # (x, lp, cfg, ctx) -> (x after the mixer, the token's (k, v) or ()): ctx
+    # what `engine._decode_one` shares: slabs {leaf: [the kind's layers in
+    # the period, B, ...]}, layer (this one among its kind's: the leaves'
+    # leading axis), i (the same within the period), seen {kind: its layers
+    # before the segment}, mask [B, S] (the places [start, pos) of a full-
+    # length leaf), kernel (the decode kernel takes those leaves), index()
+    step: Optional[Callable] = None
+    # (cfg, B, T, cache) -> what rides the carry from a layer before the
+    # kind's (a step's: T None, the cache given); ``ctx.carry`` is writable
+    carry: Callable = lambda cfg, B, T, cache: {}
+    reads: Optional[str] = None     # the kind whose leaves its layers read
+
+
+def _kv_leaves(kind: str, names, places):
+    """Keys and values [L, B, KV, places, hd]: KV-major, the order decode
+    attention contracts in (else XLA transposes the whole cache into every
+    chunk). Under differential attention PAIRS of adjacent heads, [.., KV /
+    2, places, 2 hd]: as the layer reads them, whole lanes for a 64-wide
+    head. A window layer's are a ring, position p at place p % W."""
+    def leaves(cfg, slots, max_len):
+        pair = 2 if cfg.diff_attn else 1
+        shape = (cfg.layers_of_kind(kind), slots, cfg.kv_heads // pair,
+                 places(cfg, max_len), pair * cfg.head_dim)
+        return {name: (shape, cfg.dtype) for name in names}
+    return leaves, dict.fromkeys(names, _KV_AXES)
+
+
+def _kda_leaves(cfg, slots, max_len):    # the delta rule's state, and the
+    # projected rows of q, k, v the short convolutions reach back to
+    n, H, hd = cfg.layers_of_kind("kda"), cfg.kda_heads, cfg.kda_head_dim
+    return {"kda_state": ((n, slots, H, hd, hd), jnp.float32),
+            "kda_tail": ((n, slots, cfg.kda_conv - 1, 3 * H * hd), cfg.dtype)}
+
+
+def _mamba_leaves(cfg, slots, max_len):
+    """The scan's N states a channel (state-major: [.., C, 16] would be
+    stored padded to 128 lanes) and the rows its convolution reaches to."""
+    n, C = cfg.layers_of_kind("mamba"), cfg.mamba_channels
+    return {"mamba_state": ((n, slots, cfg.mamba_d_state, C), jnp.float32),
+            "mamba_tail": ((n, slots, cfg.mamba_d_conv - 1, C), cfg.dtype)}
+
+
+def _gmu_carry(cfg, B, T, cache):   # the nearest mamba layer's scan output
+    return {"memory": jnp.zeros((B, T or 1, cfg.mamba_channels),
+                                jnp.float32)}
+
+
+def _cross_carry(cfg, B, T, cache):
+    # the nearest attention layer's keys and values of the same token(s)
+    rows = jnp.zeros(
+        ((B,) if T is None else (B, T))
+        + (cfg.kv_heads // 2, 2 * cfg.head_dim),
+        cfg.dtype if cache is None else cache["k"].dtype)
+    return {"shared_k": rows, "shared_v": rows}
+
+
+def _attention_prefill(h, lp, cfg, ctx):
+    if cfg.diff_attn:
+        q, k, v = diff_qkv(h, lp, cfg)
+        new = {"k": jnp.pad(k, ctx.pad), "v": jnp.pad(v, ctx.pad)}
+        if "shared_k" in ctx.carry:
+            ctx.carry["shared_k"], ctx.carry["shared_v"] = k, v
+        return diff_out(_diff_attention(q, k, v, ctx.seen), lp, cfg,
+                        ctx.index()), new
+    q, k, v = qkv_proj(h, lp, cfg, ctx.positions)
+    o = attention_out(_gqa_attention(q, k, v, ctx.prompt_mask), h, lp, cfg)
+    # pad this layer's k/v out to max_len for the cache
+    return o, {"k": jnp.pad(k.astype(cfg.dtype), ctx.pad),
+               "v": jnp.pad(v.astype(cfg.dtype), ctx.pad)}
+
+
+def _window_prefill(h, lp, cfg, ctx):
+    q, k, v = diff_qkv(h, lp, cfg)
+    new = {"win_k": window_ring(k, cfg.sliding_window),
+           "win_v": window_ring(v, cfg.sliding_window)}
+    return diff_out(_diff_attention(q, k, v, ctx.near), lp, cfg,
+                    ctx.index()), new
+
+
+def _cross_prefill(h, lp, cfg, ctx):
+    q, _, _ = diff_qkv(h, lp, cfg)
+    o = _diff_attention(q, ctx.carry["shared_k"], ctx.carry["shared_v"],
+                        ctx.seen)
+    return diff_out(o, lp, cfg, ctx.index()), {}
+
+
+def _kda_prefill(h, lp, cfg, ctx):
+    o, state, tail = kda_mixer(h, lp, cfg, valid=ctx.valid)
+    return o, {"kda_state": state, "kda_tail": tail}
+
+
+def _mamba_prefill(h, lp, cfg, ctx):
+    o, y, state, tail = mamba_mixer(h, lp, cfg, valid=ctx.valid)
+    if "memory" in ctx.carry:
+        ctx.carry["memory"] = y
+    return o, {"mamba_state": state, "mamba_tail": tail}
+
+
+def _gmu_prefill(h, lp, cfg, ctx):
+    return gmu_mixer(h, ctx.carry["memory"], lp, cfg), {}
+
+
+def _kernel_attention(q, cache, k_new, v_new, active, layer, mesh, **how):
+    """`decode_attention` on one layer of the whole stacked cache (``how``:
+    its scale and output dtype, where they are not a GQA layer's). GSPMD
+    cannot partition a Mosaic kernel: on a mesh it runs per shard of the
+    KV heads, as `transformer._attention` runs the train kernel."""
+    args = (q, cache["k"], cache["v"], k_new, v_new, cache["pos"],
+            cache["start"], active, layer)
+    if mesh is None or mesh.size == 1:
+        return decode_attention(*args, **how)
+    kv, heads, new = (
+        logical_to_spec(axes, mesh_axes=mesh.axis_names)
+        for axes in (_KV_AXES, (None, None, "heads", None),
+                     (None, "kv_heads", None)))
+    rep = jax.sharding.PartitionSpec()
+    return shard_map(functools.partial(decode_attention, **how), mesh=mesh,
+                     in_specs=(heads, kv, kv, new, new, rep, rep, rep, rep),
+                     out_specs=heads)(*args)
+
+
+def _diff_attend(q, k, v, cfg, ctx, layer, slab):
+    """Differential attention's token against ``layer`` of the full-length
+    leaf. To the kernel the pairs are a grouped-query call: a key pair's
+    query rows [q1 | 0], [0 | q2] a query pair, scaled by the head's width, o
+    float32 for `diff_out` to subtract. Else the contraction over ``slab()``."""
+    if ctx.kernel:
+        return _kernel_attention(
+            q.reshape(q.shape[0], 1, -1, q.shape[-1]), ctx.cache, k, v,
+            ctx.active, layer, ctx.mesh, scale=cfg.head_dim ** -0.5,
+            out_dtype=jnp.float32).reshape(q.shape)
+    return _diff_decode_attention(q, *slab(), k, v, ctx.mask)
+
+
+def _attention_step(x, lp, cfg, ctx):
+    """Only READS the cache and hands back the layer's new K/V row, rounded
+    as it is read back later: the token's one more key column."""
+    slab = lambda: (ctx.slabs["k"][ctx.i], ctx.slabs["v"][ctx.i])  # noqa: E731
+    if cfg.diff_attn:
+        q, k, v = diff_qkv(block_norm(x, lp, "attn_norm", cfg), lp, cfg)
+        k, v = (r[:, 0].astype(ctx.cache["k"].dtype) for r in (k, v))
+        if "shared_k" in ctx.carry:
+            ctx.carry["shared_k"], ctx.carry["shared_v"] = k, v
+        o = _diff_attend(q, k, v, cfg, ctx, ctx.layer, slab)
+        return x + diff_out(o, lp, cfg, ctx.index()), (k, v)
+    # a float32 mixer (`mixer_precision`) around the attention itself: the
+    # kernel keeps its own arithmetic and hands back o in q's dtype
+    with mixer_precision(cfg, lp) as wide:
+        x = x.astype(wide)
+        h = block_norm(x, lp, "attn_norm", cfg)
+        q, k, v = qkv_proj(h, lp, cfg, ctx.positions)
+    k, v = (r[:, 0].astype(ctx.cache["k"].dtype) for r in (k, v))
+    if ctx.kernel:          # [B, KV, hd]
+        o = _kernel_attention(q, ctx.cache, k, v, ctx.active, ctx.layer,
+                              ctx.mesh)
+    else:
+        o = _gqa_decode_attention(q, *slab(), k, v, ctx.mask)
+    with mixer_precision(cfg, lp):
+        o = attention_out(o, h, lp, cfg)
+    return x + o, (k, v)
+
+
+def _window_step(x, lp, cfg, ctx):
+    """`_attention_step` against the layer's ring, by the masked contraction
+    anywhere: a ring's valid places are not [start, pos) once it wraps."""
+    q, k, v = diff_qkv(block_norm(x, lp, "attn_norm", cfg), lp, cfg)
+    k, v = (r[:, 0].astype(ctx.cache["win_k"].dtype) for r in (k, v))
+    o = _diff_decode_attention(q, ctx.slabs["win_k"][ctx.i],
+                               ctx.slabs["win_v"][ctx.i], k, v, ctx.ring)
+    return x + diff_out(o, lp, cfg, ctx.index()), (k, v)
+
+
+def _cross_step(x, lp, cfg, ctx):
+    """Reads the leaf of the nearest attention layer before it and that
+    layer's row of THIS token (not in the cache before the scans end)."""
+    q, _, _ = diff_qkv(block_norm(x, lp, "attn_norm", cfg), lp, cfg)
+    layer = ctx.seen["attention"] - 1
+    o = _diff_attend(
+        q, ctx.carry["shared_k"], ctx.carry["shared_v"], cfg, ctx, layer,
+        lambda: (ctx.cache["k"][layer], ctx.cache["v"][layer]))
+    return x + diff_out(o, lp, cfg, ctx.index()), ()
+
+
+def _state_step(x, lp, cfg, ctx, mixer, step_fn, state: str, tail: str):
+    """``step_fn`` updates the layer's blocks of the carried stack of states
+    where they lie, the layer's slice of the stacked tails shifts by the
+    token (not active: both kept). -> ``mixer``'s, the tail aside."""
+    carry, layer, active = ctx.carry, ctx.layer, ctx.active
+    h = block_norm(x, lp, "attn_norm", cfg)
+
+    def step(*token):
+        carry[state], o = step_fn(carry[state], layer, *token, active)
+        return o
+    old = jax.lax.dynamic_index_in_dim(carry[tail], layer, 0, keepdims=False)
+    *out, new = mixer(h, lp, cfg, tail=old, step=step)
+    carry[tail] = jax.lax.dynamic_update_index_in_dim(
+        carry[tail], jnp.where(active[:, None, None], new, old), layer, 0)
+    return out
+
+
+def _kda_step(x, lp, cfg, ctx):
+    o, = _state_step(x, lp, cfg, ctx, kda_mixer, kda_decode_step,
+                     "kda_state", "kda_tail")
+    return x + o, ()
+
+
+def _mamba_step(x, lp, cfg, ctx):
+    o, y = _state_step(x, lp, cfg, ctx, mamba_mixer, mamba_decode_step,
+                       "mamba_state", "mamba_tail")
+    if "memory" in ctx.carry:   # of the gated memory units after it
+        ctx.carry["memory"] = y
+    return x + o, ()
+
+
+def _gmu_step(x, lp, cfg, ctx):
+    h = block_norm(x, lp, "attn_norm", cfg)
+    return x + gmu_mixer(h, ctx.carry["memory"], lp, cfg), ()
+
+
+# (leaves, masks, carried entries and landings are traced in this order)
+MIXERS = {
+    "attention": Mixer(
+        *_kv_leaves("attention", ("k", "v"), lambda cfg, max_len: max_len),
+        "rows", _attention_prefill, _attention_step),
+    "kda": Mixer(
+        _kda_leaves, {"kda_state": ("layers", None, "heads", None, None),
+                      "kda_tail": ("layers", None, None, None)},
+        "slot", _kda_prefill, _kda_step),
+    "mamba": Mixer(
+        _mamba_leaves, {"mamba_state": ("layers", None, None, "mlp"),
+                        "mamba_tail": ("layers", None, None, "mlp")},
+        "slot", _mamba_prefill, _mamba_step),
+    "window": Mixer(
+        *_kv_leaves("window", ("win_k", "win_v"),
+                    lambda cfg, max_len: cfg.sliding_window),
+        "ring", _window_prefill, _window_step),
+    "gmu": Mixer(prefill=_gmu_prefill, step=_gmu_step, carry=_gmu_carry),
+    "cross": Mixer(prefill=_cross_prefill, step=_cross_step,
+                   carry=_cross_carry, reads="attention"),
+}
+
+
 def _prefill_hidden(params: Params, tokens: jax.Array,
                     cfg: TransformerConfig, max_len: int,
                     start: jax.Array):
@@ -200,36 +466,20 @@ def _prefill_hidden(params: Params, tokens: jax.Array,
     the last position's alone, where the pattern ends in layers that
     need no other (below). The caller projects only the positions it
     reads to vocab space (a [B,P,V] float32 logits tensor is ~2 GB for
-    llama3-8b at P=512 and is pure waste on the serving hot path), and
-    every caller that serves reads ``[:, -1:]``: rows are padded on the
-    left, so that is every row's last real token. The cache holds
-    what each mixer kind leaves a slot, stacked over the layers of that
-    kind: ``k``/``v`` [L_attn, B, max_len, KV, hd]; ``kda_state`` [L_kda,
-    B, H, dk, dv] float32 and ``kda_tail`` [L_kda, B, taps - 1, 3 x H x
-    dk]; ``mamba_state`` [L_mamba, B, N, C] float32 and ``mamba_tail``
-    [L_mamba, B, taps - 1, C]; ``win_k``/``win_v`` [L_window, B, window,
-    KV, hd], the prompt's last positions where a decode step finds them
-    (`window_ring`). Under differential attention keys and values are
-    pairs of heads, [.., KV / 2, 2 hd]. Rows are padded on the left: a
-    padded row is masked out of attention, and writes nothing into a KDA
-    or mamba state (`kda_mixer`, `mamba_mixer`). The layers are walked a
-    segment of the pattern at a time (`cfg.segments`), each a scan over
-    its repeats; a gated memory unit's memory and a cross layer's keys
-    and values ride the carry from the segment that makes them.
+    llama3-8b at P=512), and every caller that serves reads ``[:, -1:]``:
+    rows are padded on the left, so that is every row's last real token.
+    The cache holds what each mixer kind leaves a slot (`MIXERS`), stacked
+    over the layers of that kind; keys and values positions-major, [L, B,
+    max_len, KV, hd]. A padded row is masked out of attention and writes
+    nothing into a state.
 
-    From `cfg.tail_segment()` on (a decoder-hybrid-decoder's cross-
-    decoder, arXiv:2507.06607: the trailing segments of gated memory
-    units and cross layers) the walk carries the LAST position only. Such
-    a layer leaves a slot nothing, a gated memory unit reads the same
-    token's memory and a cross layer its own row's query against keys and
-    values an earlier layer made for every position, so the last
-    position's stream through them depends on no other position's: the
-    stream and the memory narrow to ``[:, -1:]``, a cross layer's mask to
-    the last query's row, the shared keys and values stay [B, P, ...].
-    Every cache leaf is made before that segment and is what the walk of
-    all positions makes; the P - 1 rows not computed are rows no caller
-    read. A pattern that ends in any other kind (every pattern of one
-    segment) has no such segment and is walked as it always was."""
+    From `cfg.tail_segment()` on (a decoder-hybrid-decoder's cross-decoder,
+    arXiv:2507.06607: trailing gated memory units and cross layers) the
+    walk carries the LAST position only. Such a layer leaves a slot nothing
+    and reads its own token's memory, or its own row's query against keys
+    and values made earlier for every position: the stream and the memory
+    narrow to ``[:, -1:]``, a cross layer's mask to the last query's row.
+    The P - 1 rows not computed are rows no caller read."""
     B, P = tokens.shape
     refuse_unserved(cfg)
     if max_len < P:
@@ -241,7 +491,6 @@ def _prefill_hidden(params: Params, tokens: jax.Array,
                          "this config has causal=False")
     x = params["embed"][tokens].astype(cfg.dtype)
     positions = jnp.arange(P)
-
     causal = jnp.arange(P)[:, None] >= jnp.arange(P)[None, :]
     valid = jnp.arange(P)[None, :] >= start[:, None]  # [B, S]
     prompt_mask = causal[None, :, None, None, :] & \
@@ -255,43 +504,16 @@ def _prefill_hidden(params: Params, tokens: jax.Array,
         lps, rep = scanned
         carry, left = dict(carry), {}
         x = carry["x"]
+        ctx = SimpleNamespace(
+            positions=positions, valid=valid, prompt_mask=prompt_mask,
+            seen=seen, near=near, pad=pad, carry=carry)
         for j, (kind, lp) in enumerate(zip(kinds, lps)):
             layer = first + rep * len(kinds) + j     # of the whole model
+            ctx.index = lambda: layer
             with mixer_precision(cfg, lp) as dtype:
                 x = x.astype(dtype)
                 h = block_norm(x, lp, "attn_norm", cfg)
-                if kind == "kda":
-                    o, state, tail = kda_mixer(h, lp, cfg, valid=valid)
-                    new = {"kda_state": state, "kda_tail": tail}
-                elif kind == "mamba":
-                    o, y, state, tail = mamba_mixer(h, lp, cfg, valid=valid)
-                    new = {"mamba_state": state, "mamba_tail": tail}
-                    if "memory" in carry:
-                        carry["memory"] = y
-                elif kind == "gmu":
-                    o, new = gmu_mixer(h, carry["memory"], lp, cfg), {}
-                elif cfg.diff_attn:
-                    q, k, v = diff_qkv(h, lp, cfg)
-                    new = {}
-                    if kind == "cross":
-                        k, v = carry["shared_k"], carry["shared_v"]
-                    elif kind == "window":
-                        new = {"win_k": window_ring(k, cfg.sliding_window),
-                               "win_v": window_ring(v, cfg.sliding_window)}
-                    else:
-                        new = {"k": jnp.pad(k, pad), "v": jnp.pad(v, pad)}
-                        if "shared_k" in carry:
-                            carry["shared_k"], carry["shared_v"] = k, v
-                    o = diff_out(_diff_attention(
-                        q, k, v, near if kind == "window" else seen),
-                        lp, cfg, layer)
-                else:
-                    q, k, v = qkv_proj(h, lp, cfg, positions)
-                    o = attention_out(
-                        _gqa_attention(q, k, v, prompt_mask), h, lp, cfg)
-                    # pad this layer's k/v out to max_len for the cache
-                    new = {"k": jnp.pad(k.astype(cfg.dtype), pad),
-                           "v": jnp.pad(v.astype(cfg.dtype), pad)}
+                o, new = MIXERS[kind].prefill(h, lp, cfg, ctx)
             x = x + o
             # inference drops the MoE aux loss
             down, _ = ffn_block(block_norm(x, lp, "mlp_norm", cfg), lp, cfg)
@@ -302,12 +524,9 @@ def _prefill_hidden(params: Params, tokens: jax.Array,
                                   for name, leaves in left.items()}
 
     carry, made, first = {"x": x}, {}, 0
-    kinds_all = set(cfg.mixer_period)
-    if "gmu" in kinds_all:
-        carry["memory"] = jnp.zeros((B, P, cfg.mamba_channels), jnp.float32)
-    if "cross" in kinds_all:
-        carry["shared_k"] = carry["shared_v"] = jnp.zeros(
-            (B, P, cfg.kv_heads // 2, 2 * cfg.head_dim), cfg.dtype)
+    for kind, mixer in MIXERS.items():
+        if kind in cfg.mixer_period:
+            carry.update(mixer.carry(cfg, B, P, None))
     tail = cfg.tail_segment()
     for at, ((kinds, reps), stacks) in enumerate(zip(
             cfg.segments(), layer_segments(params["layers"]))):

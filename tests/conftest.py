@@ -6,6 +6,7 @@ semantics in one process (SURVEY.md §4). The env vars must be set before any
 JAX backend initializes.
 """
 
+import contextlib
 import os
 
 # Tests never touch an accelerator: force the CPU backend whatever the
@@ -53,35 +54,71 @@ def pytest_collection_modifyitems(config, items):
             item.add_marker(pytest.mark.slow)
 
 
-@pytest.fixture(scope="session", autouse=True)
-def _no_orphan_arenas():
-    """Arena-hygiene invariant (memory observatory): the suite FAILS if
-    it leaves orphaned ``/dev/shm/rtpu_*`` arenas behind — files no live
-    process maps, each pinning its full arena size in shared memory
-    until someone unlinks them (an r18 session leaked ~126 GB this
-    way). r19 added unlink-on-exit; this fixture turns it from a doctor
-    hint into an enforced CI invariant. Pre-existing orphans (other
-    sessions on a shared host) are snapshotted and excluded — only
-    arenas THIS suite leaked fail it."""
+def _orphan_arenas(mine, what: str) -> str:
+    """Arena-hygiene invariant (memory observatory): the orphaned
+    ``/dev/shm/rtpu_*`` arenas that ``mine(path)`` admits, as a message, ""
+    where there are none: files no live process maps, each pinning its full
+    arena size in shared memory until someone unlinks them (an r18 session
+    leaked ~126 GB this way). Agent/worker teardown is asynchronous: late
+    atexit unlinkers get one grace window before a leak is declared."""
+    import time
+
     from ray_tpu.dashboard import orphan_arena_files
 
-    before = {p for p, _ in orphan_arena_files()}
-    yield
-    leaked = [x for x in orphan_arena_files() if x[0] not in before]
-    if leaked:
-        # agent/worker teardown is asynchronous: give late atexit
-        # unlinkers one grace window before declaring the leak
-        import time as _t
+    for grace in (2.0, None):
+        leaked = [x for x in orphan_arena_files() if mine(x[0])]
+        if not leaked:
+            return ""
+        if grace:
+            time.sleep(grace)
+    total_mb = sum(sz for _, sz in leaked) / (1024 * 1024)
+    names = ", ".join(p for p, _ in leaked[:8])
+    return (f"{what} leaked {len(leaked)} orphaned shm arena(s) pinning "
+            f"{total_mb:.0f} MB: {names} — a store was created without "
+            "being destroyed/unlinked on teardown")
 
-        _t.sleep(2.0)
-        leaked = [x for x in orphan_arena_files() if x[0] not in before]
-    if leaked:
-        total_mb = sum(sz for _, sz in leaked) / (1024 * 1024)
-        names = ", ".join(p for p, _ in leaked[:8])
-        raise RuntimeError(
-            f"test session leaked {len(leaked)} orphaned shm arena(s) "
-            f"pinning {total_mb:.0f} MB: {names} — a store was created "
-            "without being destroyed/unlinked on teardown")
+
+@contextlib.contextmanager
+def _own_arenas_gone():
+    """Around a runtime fixture's shutdown: an arena the test's own head
+    registered and the shutdown left orphaned fails THAT test, once. It is
+    unlinked here, so nothing else is charged for it."""
+    from ray_tpu.core import api
+
+    mine = {f"/dev/shm/{node.store_name}"
+            for node in (api._head.nodes.values() if api._head else ())}
+    yield
+    mine = {path for path in mine if os.path.exists(path)}
+    message = mine and _orphan_arenas(mine.__contains__,
+                                      "this test's runtime")
+    if message:
+        for path in mine:
+            with contextlib.suppress(OSError):
+                os.unlink(path)
+        raise RuntimeError(message)
+
+
+# A leak no runtime fixture can see (a test that starts its own processes) is
+# charged ONCE a run, by the process that owns the run (the xdist controller,
+# or the only process there is): a per-worker check over all of /dev/shm
+# charged one leaked arena to whatever test each of the six workers ran last.
+def pytest_sessionstart(session):
+    from ray_tpu.dashboard import orphan_arena_files
+
+    if not hasattr(session.config, "workerinput"):
+        # orphans of other sessions on a shared host are not this run's
+        session.config._arenas_before = {p for p, _ in orphan_arena_files()}
+
+
+def pytest_sessionfinish(session):
+    before = getattr(session.config, "_arenas_before", None)
+    message = before is not None and _orphan_arenas(
+        lambda path: path not in before, "test session")
+    if message:
+        session.config.get_terminal_writer().line("\nERROR: " + message,
+                                                  red=True)
+        session.exitstatus = max(int(session.exitstatus),
+                                 int(pytest.ExitCode.TESTS_FAILED))
 
 
 @pytest.fixture
@@ -91,7 +128,8 @@ def ray_start():
 
     info = ray_tpu.init(num_cpus=4, num_tpus=0)
     yield info
-    ray_tpu.shutdown()
+    with _own_arenas_gone():
+        ray_tpu.shutdown()
 
 
 @pytest.fixture
@@ -102,7 +140,8 @@ def ray_start_cluster():
     cluster = Cluster(initialize_head=True,
                       head_node_args={"num_cpus": 2, "num_tpus": 0})
     yield cluster
-    cluster.shutdown()
+    with _own_arenas_gone():
+        cluster.shutdown()
 
 
 @pytest.fixture(scope="module")
@@ -112,4 +151,5 @@ def shared_ray():
 
     info = ray_tpu.init(num_cpus=4, num_tpus=0, ignore_reinit_error=True)
     yield info
-    ray_tpu.shutdown()
+    with _own_arenas_gone():
+        ray_tpu.shutdown()

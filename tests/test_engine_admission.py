@@ -3,11 +3,15 @@ group is the oldest queued request and the oldest after it of its own
 bucket, so a group pads to its members' bucket and not to a stranger's."""
 
 import jax
+import jax.numpy as jnp
 import pytest
+from test_served_program_goldens import TOYS, spec
 
 from ray_tpu.models.config import tiny_config
-from ray_tpu.models.engine import InferenceEngine
-from ray_tpu.models.transformer import init_params
+from ray_tpu.models.engine import (InferenceEngine, cache_logical_axes,
+                                   init_slot_cache, prefill_slots)
+from ray_tpu.models.generate import MIXERS
+from ray_tpu.models.transformer import init_params, serving_params
 
 SHORT, LONG = [3] * 5, [5] * 40           # buckets 16 and 64
 
@@ -96,3 +100,46 @@ def test_the_look_ahead_ends_at_as_many_entries_as_there_are_slots(model):
     # the tenth entry is past the eight the first look sees
     assert seen[0] == (64, [reqs[0].rid])
     _drive(eng, reqs)
+
+
+@pytest.mark.parametrize("name", sorted(TOYS))
+def test_the_slot_cache_is_what_the_table_of_mixer_kinds_says(name):
+    """A served configuration's cache (toy widths): beside the engine's own
+    leaves, the names, shapes and dtypes `init_slot_cache` makes and the
+    axes `cache_logical_axes` gives are `generate.MIXERS`' ``leaves`` and
+    ``axes`` of the kinds the model has, and a prefill hands every leaf back
+    as it took it."""
+    cfg = spec.build_transformer_config(
+        spec.load_config(spec.load_benchmark(), name), **TOYS[name])
+    slots, max_len, (K, P) = 4, 48, (2, 32)
+    cache = jax.eval_shape(lambda: init_slot_cache(cfg, slots, max_len))
+    kinds = [kind for kind in MIXERS if cfg.layers_of_kind(kind)]
+    assert set(kinds) == set(cfg.mixer_period)
+    leaves = {leaf: (shape, jnp.dtype(dtype)) for kind in kinds for
+              leaf, (shape, dtype) in MIXERS[kind].leaves(
+                  cfg, slots, max_len).items()}
+    axes = {leaf: ax for kind in kinds
+            for leaf, ax in MIXERS[kind].axes.items()}
+    own = {"pos": ((slots,), jnp.int32), "start": ((slots,), jnp.int32)}
+    if cfg.moe_experts:
+        own["moe_counts"] = ((3,), jnp.float32)
+    assert {leaf: (x.shape, x.dtype) for leaf, x in cache.items()} \
+        == {**leaves, **own}
+    assert list(axes) == list(leaves)
+    assert cache_logical_axes(cache) \
+        == {**axes, **{leaf: (None,) for leaf in own}}
+    for leaf, ax in cache_logical_axes(cache).items():
+        assert len(ax) == cache[leaf].ndim, leaf
+    assert list(cache_logical_axes()) == ["k", "v", "pos", "start"]
+    for kind in ("gmu", "cross"):       # they keep a slot nothing
+        assert MIXERS[kind].leaves(cfg, slots, max_len) == {} \
+            and not MIXERS[kind].axes and MIXERS[kind].land is None
+    params = jax.eval_shape(
+        lambda k: serving_params(init_params(k, cfg), cfg), jax.random.key(0))
+    new, toks = jax.eval_shape(
+        lambda p, c, t, s, r: prefill_slots(p, c, t, s, s, r, cfg), params,
+        cache, jax.ShapeDtypeStruct((K, P), jnp.int32),
+        jax.ShapeDtypeStruct((K,), jnp.int32), jax.random.key(0))
+    assert toks.shape == (K,)
+    assert {leaf: (x.shape, x.dtype) for leaf, x in new.items()} \
+        == {leaf: (x.shape, x.dtype) for leaf, x in cache.items()}

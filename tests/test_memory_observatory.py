@@ -506,3 +506,30 @@ def test_api_summary_memory_endpoint(ray_start):
     finally:
         dash.stop()
     assert ref
+
+
+def test_a_killed_nodes_arena_is_gone_after_cluster_shutdown():
+    """A host test that FAILS mid-way never reaches its own
+    `handle.terminate()`: the agent it killed (SIGKILL: no exit path runs,
+    so the agent cannot unlink its arena) and an agent it left running are
+    both ended by `Cluster.shutdown`, and neither leaves its /dev/shm
+    arena behind to pin its size until someone sweeps it."""
+    import os
+
+    from ray_tpu.cluster_utils import Cluster
+
+    cluster = Cluster(initialize_head=True,
+                      head_node_args={"num_cpus": 1, "num_tpus": 0})
+    try:
+        killed = cluster.add_remote_node(num_cpus=1)
+        left = cluster.add_remote_node(num_cpus=1)
+        paths = [f"/dev/shm/{h.store_name}" for h in (killed, left)]
+        assert all(h.store_name for h in (killed, left))
+        assert all(os.path.exists(p) for p in paths)
+        killed.proc.kill()
+        killed.proc.wait(timeout=10)
+        assert os.path.exists(paths[0])     # nobody has unlinked it yet
+    finally:
+        cluster.shutdown()
+    assert not any(os.path.exists(p) for p in paths)
+    assert left.proc.poll() is not None
