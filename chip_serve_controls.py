@@ -39,6 +39,20 @@ and for a cell of differential attention behind windows
 - ``lam_fixed`` (expect differ): lam = lam0(layer), the four learned vectors
   left out.
 
+and for a cell of Mamba-2 layers beside NoPE GQA layers under four fixed
+multipliers (``--workload granite-4.0-h-micro.reason-closed-64``), beside
+``program`` and ``fp8_weights``, five rules, each RECORDED (four attention
+layers of forty behind a 0.22 may hide a scale: a wrong rule that agrees
+is a finding about the check):
+
+- ``attn_scale_sqrt``: the softmax scaled by 64 ** -0.5, not by the
+  published `attention_multiplier` 1/64.
+- ``residual_one``: `residual_multiplier` 1.0.
+- ``no_decay``: A = 0, a state that forgets nothing.
+- ``norm_before_gate``: RMSNorm(y) . silu(z) in place of RMSNorm(y . silu(z)).
+- ``mamba2_state_bf16``: a slot's Mamba-2 state rounded to bfloat16 after
+  prefill and after every decode step.
+
 A recorded control's reading IS the result: `ok` true there says that this
 comparison cannot tell that precision from the stated one (PERF.md
 section 6, PR 42, has the readings and what follows from them). The exit
@@ -65,17 +79,30 @@ TOY_LENGTHS = [20, 51, 7, 64]
 EXPECT = {"program": "agree", "fp8_weights": "differ",
           "write_strength_halved": "differ", "state_bf16": None,
           "init_depth_none": None, "no_window": "differ",
-          "lam_fixed": "differ"}
+          "lam_fixed": "differ", "attn_scale_sqrt": None,
+          "residual_one": None, "no_decay": None, "norm_before_gate": None,
+          "mamba2_state_bf16": None}
 # the controls of an architecture, and its toy: all of the pattern's layers
 # at toy widths, a window the toy prompts outgrow
 CONTROLS = {
     "solar_open2": ["program", "fp8_weights", "write_strength_halved",
                     "state_bf16", "init_depth_none"],
-    "phi4flash": ["program", "fp8_weights", "no_window", "lam_fixed"]}
+    "phi4flash": ["program", "fp8_weights", "no_window", "lam_fixed"],
+    "granitemoehybrid": ["program", "fp8_weights", "attn_scale_sqrt",
+                         "residual_one", "no_decay", "norm_before_gate",
+                         "mamba2_state_bf16"]}
 TOYS = {"solar_open2": TOY,
         "phi4flash": dict(vocab_size=96, d_model=32, n_heads=4, n_kv_heads=2,
                           head_dim=8, d_ff=48, mamba_d_state=4,
-                          mamba_dt_rank=3, sliding_window=8)}
+                          mamba_dt_rank=3, sliding_window=8),
+        "granitemoehybrid": dict(vocab_size=96, d_model=32, n_heads=4,
+                                 n_kv_heads=2, head_dim=8, d_ff=48,
+                                 mamba_heads=8, mamba_head_dim=8,
+                                 mamba_d_state=16, mamba_chunk=8)}
+# a control that is the configuration with ONE field changed
+FIELD_CONTROLS = {"write_strength_halved": {"kda_allow_neg_eigval": False},
+                  "attn_scale_sqrt": {"attn_scale": None},
+                  "residual_one": {"residual_scale": 1.0}}
 
 
 @contextlib.contextmanager
@@ -153,6 +180,50 @@ def state_bf16():
                     (generate, "kda_decode_step", kda_decode_step))
 
 
+def _mamba2_patched(scan_args=lambda args: args, state=lambda s: s):
+    """The Mamba-2 recurrence's two forms with their arguments (dt, x, B, C,
+    A) and the state they leave passed through ``scan_args`` / ``state``."""
+    import jax
+
+    from ray_tpu.models import generate
+    from ray_tpu.ops import mamba2
+
+    scan, step = mamba2.mamba2_scan, generate.mamba2_decode_step
+
+    def mamba2_scan(*args, **kw):
+        y, s = scan(*scan_args(args), **kw)
+        return y, state(s)
+
+    def mamba2_decode_step(stack, layer, *token, **kw):
+        *token, active = token
+        stack, y = step(stack, layer, *scan_args(tuple(token)), active, **kw)
+        mine = jax.lax.dynamic_index_in_dim(stack, layer, 0, keepdims=False)
+        return jax.lax.dynamic_update_index_in_dim(
+            stack, state(mine), layer, 0), y
+    return _patched((mamba2, "mamba2_scan", mamba2_scan),
+                    (generate, "mamba2_decode_step", mamba2_decode_step))
+
+
+def no_decay():
+    return _mamba2_patched(scan_args=lambda a: a[:4] + (a[4] * 0.0,))
+
+
+def mamba2_state_bf16():
+    return _mamba2_patched(state=lambda s: _round(s, 8, 7))
+
+
+def norm_before_gate():
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import transformer
+
+    def mamba2_gated_norm(y, z, gain, eps):
+        return transformer.rms_norm(y, gain, eps) \
+            * jax.nn.silu(z.astype(jnp.float32))
+    return _patched((transformer, "mamba2_gated_norm", mamba2_gated_norm))
+
+
 def no_window():
     """A window layer without its window: prefill's mask widened to every
     valid earlier position (a position sees itself under either mask, so
@@ -194,10 +265,11 @@ def run_control(replica, name: str, seed: int, lengths) -> dict:
     left as it came but for ``fp8_weights`` (run it last)."""
     eng = replica.engine
     ctx, cfg = contextlib.nullcontext(), eng.cfg
-    if name in ("state_bf16", "no_window", "lam_fixed"):
+    if name in ("state_bf16", "no_window", "lam_fixed", "no_decay",
+                "norm_before_gate", "mamba2_state_bf16"):
         ctx = globals()[name]()
-    elif name == "write_strength_halved":
-        eng.cfg = dataclasses.replace(cfg, kda_allow_neg_eigval=False)
+    elif name in FIELD_CONTROLS:
+        eng.cfg = dataclasses.replace(cfg, **FIELD_CONTROLS[name])
     if name == "fp8_weights":
         _fp8_in_place(eng)
     try:

@@ -138,6 +138,14 @@ class TransformerConfig:
     #             x d_model channels, a causal depthwise convolution of
     #             `mamba_d_conv` taps, dt through a rank of `mamba_dt_rank`
     #             (None: ceil(d_model / 16))
+    #   "mamba2"  a Mamba-2 state-space layer with a SCALAR decay a head
+    #             (arXiv:2405.21060; transformer.mamba2_mixer; ops/mamba2.py):
+    #             `mamba_heads` heads of `mamba_head_dim` (= `mamba_expand` x
+    #             d_model together), `mamba_d_state` states a channel, ONE B
+    #             and C a token for all heads (`mamba_groups` 1), the same
+    #             convolution over [x | B | C], a gated RMSNorm before the
+    #             output projection; a prompt's scan in chunks of
+    #             `mamba_chunk` positions
     #   "gmu"     a gated memory unit: W_out (m . silu(W_in n)), m the SAME
     #             token's scan output (before the gate) of the nearest
     #             mamba layer before it; no state
@@ -151,6 +159,10 @@ class TransformerConfig:
     mamba_d_conv: int = 4
     mamba_expand: int = 2
     mamba_dt_rank: Optional[int] = None
+    mamba_heads: int = 0
+    mamba_head_dim: int = 0
+    mamba_groups: int = 1
+    mamba_chunk: int = 256
     # Differential attention (arXiv:2410.05258, as the SambaY decoders of
     # arXiv:2507.06607 use it) on every attention, window and cross layer:
     # query, key and value heads are taken in adjacent pairs; o1 = softmax(
@@ -161,6 +173,13 @@ class TransformerConfig:
     # pair (pair // (query pairs / key pairs)); the serving cache holds a
     # token's keys and values as those pairs, [kv_heads / 2, 2 head_dim].
     diff_attn: bool = False
+    # An ordinary attention layer's keys and values held in the serving cache
+    # as PAIRS of adjacent heads, [kv_heads / 2, 2 head_dim] a token (as
+    # differential attention's are): a head of 64 then fills the 128 lanes,
+    # nothing is stored padded, and the decode kernel takes the cache, a
+    # query head reading its pair as the row [q | 0] or [0 | q]. The
+    # mathematics is the unpaired layer's.
+    kv_head_pairs: bool = False
     # A bias on the attention projections (q, k, v and the output).
     attn_bias: bool = False
     # "rms": RMSNorm with a gain; "layer": LayerNorm with a gain and a bias
@@ -200,6 +219,18 @@ class TransformerConfig:
     # `mtp_weight` x its cross entropy joins the total loss.
     mtp_layers: int = 0
     mtp_weight: float = 0.0
+    # Four fixed multipliers on the stream (the muP scalars a published
+    # config states: `embedding_multiplier`, `residual_multiplier`,
+    # `attention_multiplier`, `logits_scaling`), each None = absent, and
+    # absent means NO operation in any program: the embedding rows times
+    # `embed_scale`; a mixer's and a feed-forward's output times
+    # `residual_scale` before it joins the stream; an ordinary attention
+    # layer's scores times `attn_scale` in place of head_dim ** -0.5; the
+    # logits over `logit_divisor`. Serving only (`refuse_untrained`).
+    embed_scale: Optional[float] = None
+    residual_scale: Optional[float] = None
+    attn_scale: Optional[float] = None
+    logit_divisor: Optional[float] = None
 
     def __post_init__(self):
         def need(ok, why):
@@ -248,8 +279,8 @@ class TransformerConfig:
             need(self.v_head_dim == self.head_dim,
                  "v_head_dim differs from head_dim without latent attention")
         period = self.mixer_period
-        need(period and set(period) <= {"attention", "kda", "mamba", "gmu",
-                                        "window", "cross"},
+        need(period and set(period) <= {"attention", "kda", "mamba", "mamba2",
+                                        "gmu", "window", "cross"},
              f"mixer_period {period!r}")
         if {"window", "cross"} & set(period):
             need(self.diff_attn and not self.moe_experts,
@@ -258,6 +289,13 @@ class TransformerConfig:
             need("window" not in period or self.sliding_window >= 1,
                  "a window layer needs sliding_window >= 1")
         need(self.norm in ("rms", "layer"), f"norm {self.norm!r}")
+        need(not self.kv_head_pairs or (
+            self.kv_heads % 2 == 0 and not self.diff_attn
+            and not self.kv_lora_rank),
+            "kv_head_pairs pairs an even number of ordinary key/value heads")
+        need(self.attn_scale is None or not self.diff_attn,
+             "attn_scale is the scale of an ordinary attention layer's "
+             "scores")
         kinds = [self.mixer_kind(i) for i in range(self.n_layers)]
         if "mamba" in period:
             need(self.mamba_d_state and self.mamba_d_conv >= 1
@@ -267,6 +305,15 @@ class TransformerConfig:
             if self.mamba_dt_rank is None:
                 object.__setattr__(self, "mamba_dt_rank",
                                    -(-self.d_model // 16))
+        if "mamba2" in period:
+            need(self.mamba_d_state and self.mamba_d_conv >= 1 and self.causal
+                 and self.mamba_heads * self.mamba_head_dim
+                 == self.mamba_channels > 0 and self.mamba_groups == 1
+                 and self.mamba_chunk >= 1,
+                 "a mamba2 layer needs mamba_d_state, mamba_d_conv >= 1, "
+                 "mamba_heads x mamba_head_dim = mamba_expand x d_model, ONE "
+                 "group of B and C (mamba_groups 1), mamba_chunk >= 1 and a "
+                 "causal model")
         for kind, source in (("gmu", "mamba"), ("cross", "attention")):
             need(all(source in kinds[:i] for i, k in enumerate(kinds)
                      if k == kind),
@@ -350,6 +397,13 @@ class TransformerConfig:
     def mamba_channels(self) -> int:
         return self.mamba_expand * self.d_model
 
+    @property
+    def mamba2_conv_width(self) -> int:
+        """[x | B | C] side by side: what a mamba2 layer's convolution runs
+        over and its slot's tail keeps."""
+        return self.mamba_channels \
+            + 2 * self.mamba_groups * self.mamba_d_state
+
     def layers_of_kind(self, kind: str) -> int:
         """How many of the `n_layers` layers have mixer ``kind``."""
         return sum(self.mixer_kind(i) == kind for i in range(self.n_layers))
@@ -366,6 +420,11 @@ class TransformerConfig:
             attn = (d * 2 * C + (self.mamba_d_conv + 1) * C   # in, conv
                     + C * (R + 2 * N) + R * C + C             # x, dt
                     + N * C + C + C * d)                      # A_log, D, out
+        elif kind == "mamba2":
+            C, H, wide = self.mamba_channels, self.mamba_heads, \
+                self.mamba2_conv_width
+            attn = (d * (C + wide + H) + (self.mamba_d_conv + 1) * wide
+                    + 3 * H + C + C * d)    # dt_b, A_log, D; the norm; out
         elif kind == "gmu":
             attn = 2 * d * self.mamba_channels
         elif kind == "cross":

@@ -73,7 +73,8 @@ import numpy as np
 
 from ray_tpu.models.config import TransformerConfig
 from ray_tpu.models.generate import (MIXERS, _final_logits, _prefill_hidden,
-                                     join_period, join_segments)
+                                     embed_tokens, join_period,
+                                     join_segments, residual)
 from ray_tpu.models.transformer import (Params, block_norm, ffn_block,
                                         layer_segments, param_logical_axes,
                                         refuse_unserved, serving_params)
@@ -233,8 +234,7 @@ def _decode_one(params: Params, cache: SlotCache, tokens: jax.Array,
     which no request's plan reads (`InferenceEngine._max_len`). Leaves
     that land a slot whole ride the carry and are updated where they lie."""
     pos, start = cache["pos"], cache["start"]
-    # (rows first: a tied table is held float32 and is not converted whole)
-    x = params["embed"][tokens[:, None]].astype(cfg.dtype)  # [B, 1, d]
+    x = embed_tokens(params, tokens[:, None], cfg)  # [B, 1, d]
     positions = pos[:, None]  # [B, 1] per-row RoPE
     B = tokens.shape[0]
     mixers = {kind: mixer for kind, mixer in MIXERS.items()
@@ -243,6 +243,9 @@ def _decode_one(params: Params, cache: SlotCache, tokens: jax.Array,
     places = {mixer.land: cache[next(iter(mixer.axes))].shape[-2]
               for mixer in mixers.values() if mixer.land}
     kernel = _kv_block(cache) is not None
+    # the served chunk names the active slots and donates its cache; a
+    # caller that names none may keep the cache it gave (`Mixer.hands_back`)
+    in_place = active is not None
     if active is None and (kernel or "slot" in places):
         active = jnp.ones_like(pos, bool)
     mask = ring = None      # the places of a leaf that a token reads
@@ -252,7 +255,8 @@ def _decode_one(params: Params, cache: SlotCache, tokens: jax.Array,
     if "ring" in places:
         ring = _ring_mask(pos, start, places["ring"])
     shared = dict(cache=cache, positions=positions, active=active,
-                  mask=mask, ring=ring, kernel=kernel, mesh=mesh)
+                  mask=mask, ring=ring, kernel=kernel, mesh=mesh,
+                  in_place=in_place)
 
     def block(carry, scanned, kinds, first, seen, slab_names):
         # ``first``: the model's layers before the segment, ``seen``: of
@@ -278,12 +282,13 @@ def _decode_one(params: Params, cache: SlotCache, tokens: jax.Array,
                 carry["moe_counts"] = carry["moe_counts"] + jnp.stack(
                     [stats["fetched"], stats["held"] * assignments,
                      stats["rows_kernel"]])
-            x = (x + down).astype(cfg.dtype)
+            x = residual(x, down, cfg).astype(cfg.dtype)
         return dict(carry, x=x), {kind: tuple(zip(*pairs))
                                   for kind, pairs in rows.items()}
 
     carried = {name: cache[name] for mixer in mixers.values()
-               if mixer.land == "slot" for name in mixer.axes}
+               if mixer.land == "slot"
+               and (in_place or not mixer.hands_back) for name in mixer.axes}
     if "moe_counts" in cache:
         carried["moe_counts"] = cache["moe_counts"]
     carried["x"] = x
@@ -335,6 +340,9 @@ def _decode_one(params: Params, cache: SlotCache, tokens: jax.Array,
             names = tuple(mixer.axes)
             k_rows, v_rows = (join_segments(parts)
                               for parts in zip(*new_rows[kind]))
+            if mixer.land == "slot":    # handed back whole, a layer each
+                new.update(zip(names, (k_rows, v_rows)))
+                continue
             new.update(zip(names, _put_rows(
                 cache, k_rows[:, :, :, None], v_rows[:, :, :, None], every,
                 pos % places["ring"] if mixer.land == "ring" else pos,
@@ -515,10 +523,11 @@ class InferenceEngine:
         if mesh is not None:
             from ray_tpu.parallel.sharding import shard_array, tree_shardings
 
-            if {"kda", "mamba"} & set(cfg.mixer_period) and mesh.size > 1:
+            if {"kda", "mamba", "mamba2"} & set(cfg.mixer_period) \
+                    and mesh.size > 1:
                 raise NotImplementedError(
-                    "a KDA or mamba layer's decode kernel is not run per "
-                    "shard yet: serve such a model on one chip")
+                    "a KDA, mamba or mamba2 layer's decode kernel is not run "
+                    "per shard yet: serve such a model on one chip")
 
             shardings = tree_shardings(mesh, param_logical_axes(cfg))
             axes = cache_logical_axes(self.cache)
@@ -556,6 +565,7 @@ class InferenceEngine:
         # with experts and what those offer
         self._kda_layers = cfg.layers_of_kind("kda")
         self._mamba_layers = cfg.layers_of_kind("mamba")
+        self._mamba2_layers = cfg.layers_of_kind("mamba2")
         self._ring_rows = self.slots * cfg.sliding_window \
             if "win_k" in self.cache else 0
         moe_layers = cfg.n_layers if cfg.moe_experts else 0
@@ -624,11 +634,13 @@ class InferenceEngine:
             # got a row (whose weights a substep fetched), the
             # assignments that fell on held experts and the layers (a layer
             # and substep) whose grouped matmuls were `ops.grouped_matmul`'s
-            # a mamba layer's likewise (one unit: one active slot's states
-            # in EVERY mamba layer); the places of a window layer's ring
+            # a mamba layer's and a mamba2 layer's likewise (one unit: one
+            # active slot's states in EVERY layer of the kind); the places
+            # of a window layer's ring
             # fetched (a place of a slot, every window layer and head:
             # the masked contraction reads all of them)
-            "mamba_state_updates": 0, "window_kv_rows_read": 0,
+            "mamba_state_updates": 0, "mamba2_state_updates": 0,
+            "window_kv_rows_read": 0,
             "kda_state_updates": 0, "moe_expert_calls": 0,
             "moe_assignments": 0, "moe_expert_fetches": 0,
             "moe_held_assignments": 0, "moe_rows_kernel_layers": 0,
@@ -1140,6 +1152,9 @@ class InferenceEngine:
                 self.stats["kda_state_updates"] += width * len(active_slots)
             if self._mamba_layers:
                 self.stats["mamba_state_updates"] += \
+                    width * len(active_slots)
+            if self._mamba2_layers:
+                self.stats["mamba2_state_updates"] += \
                     width * len(active_slots)
             self.stats["window_kv_rows_read"] += width * self._ring_rows
             self.stats["moe_expert_calls"] += width * self._moe_calls

@@ -39,12 +39,13 @@ from ray_tpu.models.config import TransformerConfig
 from ray_tpu.models.transformer import (Params, attention_out, block_norm,
                                         diff_out, diff_qkv, ffn_block,
                                         gmu_mixer, kda_mixer, layer_segments,
-                                        lm_head, mamba_mixer,
+                                        lm_head, mamba2_mixer, mamba_mixer,
                                         mixer_precision, qkv_proj,
                                         refuse_unserved)
 from ray_tpu.ops.decode_attention import decode_attention
 from ray_tpu.ops.kda import kda_decode_step
 from ray_tpu.ops.mamba import mamba_decode_step
+from ray_tpu.ops.mamba2 import mamba2_decode_step
 from ray_tpu.parallel.ring import shard_map
 from ray_tpu.parallel.sharding import logical_to_spec
 
@@ -56,38 +57,43 @@ from ray_tpu.parallel.sharding import logical_to_spec
 _MASKED = jnp.float32(jnp.finfo(jnp.float32).min / 2)
 
 
-def _gqa_attention(q, k, v, mask):
+def _gqa_attention(q, k, v, mask, scale=None):
     """q [B,T,H,hd] vs keys/values [B,S,KV,hd] under a broadcastable
     mask [B,T,1,1,S]. GQA expansion happens by reshaping q into
-    [KV, reps] groups — no materialized repeat of k/v."""
+    [KV, reps] groups — no materialized repeat of k/v. ``scale`` of the
+    scores: left out, hd ** -0.5."""
     B, T, H, hd = q.shape
     KV = k.shape[2]
     reps = H // KV
+    scale = hd ** -0.5 if scale is None else scale
     qg = q.reshape(B, T, KV, reps, hd)
     scores = jnp.einsum("btkrh,bskh->btkrs", qg.astype(jnp.float32),
-                        k.astype(jnp.float32)) * (hd ** -0.5)
+                        k.astype(jnp.float32)) * scale
     scores = jnp.where(mask, scores, _MASKED)
     probs = jax.nn.softmax(scores, axis=-1)
     o = jnp.einsum("btkrs,bskh->btkrh", probs, v.astype(jnp.float32))
     return o.reshape(B, T, H, hd).astype(q.dtype)
 
 
-def _gqa_decode_attention(q, k_cache, v_cache, k_new, v_new, mask):
+def _gqa_decode_attention(q, k_cache, v_cache, k_new, v_new, mask,
+                          scale=None):
     """One query per row, q [B,1,H,hd], against a cache it only READS:
     keys/values [B,KV,S,hd] (KV-major, the order this contraction walks)
     under ``mask`` [B,S], plus the token's own ``k_new``/``v_new``
     [B,KV,hd] as one more key column — one softmax over both, so the
     result is what `_gqa_attention` gives once the row is written. The
     caller rounds k_new/v_new to the cache's dtype first (attention then
-    sees the values it would have read back)."""
+    sees the values it would have read back). ``scale`` of the scores: left
+    out, hd ** -0.5."""
     B, _, H, hd = q.shape
     KV = k_cache.shape[1]
+    scale = hd ** -0.5 if scale is None else scale
     qg = q.reshape(B, KV, H // KV, hd).astype(jnp.float32)
     s_old = jnp.einsum("bkrh,bksh->bkrs", qg,
-                       k_cache.astype(jnp.float32)) * (hd ** -0.5)
+                       k_cache.astype(jnp.float32)) * scale
     s_old = jnp.where(mask[:, None, None, :], s_old, _MASKED)
     s_new = jnp.einsum("bkrh,bkh->bkr", qg,
-                       k_new.astype(jnp.float32)) * (hd ** -0.5)
+                       k_new.astype(jnp.float32)) * scale
     # softmax over [s_old | s_new] without concatenating to S+1 columns
     top = jnp.maximum(s_old.max(axis=-1), s_new)
     e_old = jnp.exp(s_old - top[..., None])
@@ -178,6 +184,25 @@ def _final_logits(params, x, cfg):
     return lm_head(params, x, cfg, None)
 
 
+def embed_tokens(params, tokens, cfg):
+    """The tokens' rows of the table in the compute dtype, times
+    `cfg.embed_scale` where the model states one (rows first: a tied table
+    is held float32 and is not converted whole)."""
+    x = params["embed"][tokens]
+    if cfg.embed_scale is not None:
+        x = x * cfg.embed_scale
+    return x.astype(cfg.dtype)
+
+
+def residual(x, o, cfg):
+    """The stream after a mixer's or a feed-forward's output ``o`` joins it:
+    x + o, o times `cfg.residual_scale` where the model states one. The ONE
+    place the prompt walk, every kind's one-token step and the engine's
+    feed-forward add through."""
+    return x + o if cfg.residual_scale is None \
+        else x + o * cfg.residual_scale
+
+
 def join_segments(parts):
     """One kind's leaves [layers of a segment, ...], a segment each, in
     layer order: [layers, ...]."""
@@ -210,7 +235,8 @@ class Mixer(NamedTuple):
     # "rows"  [L, K, P, KV, hd] -> KV-major, a slot's rows from position 0;
     #         a step hands back the token's (k, v), written at ``pos``
     # "ring"  the same transpose, the whole slot; the row at ``pos % places``
-    # "slot"  the whole slot as made; a step updates the carried stack
+    # "slot"  the whole slot as made; a step updates the carried stack (or,
+    #         `hands_back`, leaves it alone and hands back the layer's leaves)
     land: Optional[str] = None
     # (h, lp, cfg, ctx) -> (o, {leaf: the prompt's}): h the normed rows, ctx
     # what `_prefill_hidden` shares: seen [B, T, P] (causal, no padding),
@@ -227,16 +253,23 @@ class Mixer(NamedTuple):
     # kind's (a step's: T None, the cache given); ``ctx.carry`` is writable
     carry: Callable = lambda cfg, B, T, cache: {}
     reads: Optional[str] = None     # the kind whose leaves its layers read
+    # True: where the walk may not write into the cache it was given
+    # (``ctx.in_place`` False: `engine._decode_one` without ``active``, a
+    # step whose caller may keep that cache) the kind's stacks do not ride
+    # the carry; a step READS its layer of ``ctx.cache`` and hands back the
+    # layer's new leaves as its row, which replace the stacks after the scans
+    hands_back: bool = False
 
 
 def _kv_leaves(kind: str, names, places):
     """Keys and values [L, B, KV, places, hd]: KV-major, the order decode
     attention contracts in (else XLA transposes the whole cache into every
-    chunk). Under differential attention PAIRS of adjacent heads, [.., KV /
-    2, places, 2 hd]: as the layer reads them, whole lanes for a 64-wide
-    head. A window layer's are a ring, position p at place p % W."""
+    chunk). Under differential attention (and `cfg.kv_head_pairs`) PAIRS of
+    adjacent heads, [.., KV / 2, places, 2 hd]: as the layer reads them,
+    whole lanes for a 64-wide head. A window layer's are a ring, position p
+    at place p % W."""
     def leaves(cfg, slots, max_len):
-        pair = 2 if cfg.diff_attn else 1
+        pair = 2 if cfg.diff_attn or cfg.kv_head_pairs else 1
         shape = (cfg.layers_of_kind(kind), slots, cfg.kv_heads // pair,
                  places(cfg, max_len), pair * cfg.head_dim)
         return {name: (shape, cfg.dtype) for name in names}
@@ -256,6 +289,19 @@ def _mamba_leaves(cfg, slots, max_len):
     n, C = cfg.layers_of_kind("mamba"), cfg.mamba_channels
     return {"mamba_state": ((n, slots, cfg.mamba_d_state, C), jnp.float32),
             "mamba_tail": ((n, slots, cfg.mamba_d_conv - 1, C), cfg.dtype)}
+
+
+def _mamba2_leaves(cfg, slots, max_len):
+    """The recurrence's states, state-major as Mamba-1's are (N states x
+    the H x P channels: `ops/mamba2.py` says why), and the rows of [x | B |
+    C] its convolution reaches to, side by side as ONE row a slot: [.., 3,
+    4352] is stored padded to 4 rows, and XLA then re-tiles the whole stack
+    between two layers' updates to save the padding."""
+    n = cfg.layers_of_kind("mamba2")
+    return {"mamba2_state": ((n, slots, cfg.mamba_d_state,
+                              cfg.mamba_channels), jnp.float32),
+            "mamba2_tail": ((n, slots, (cfg.mamba_d_conv - 1)
+                             * cfg.mamba2_conv_width), cfg.dtype)}
 
 
 def _gmu_carry(cfg, B, T, cache):   # the nearest mamba layer's scan output
@@ -281,7 +327,11 @@ def _attention_prefill(h, lp, cfg, ctx):
         return diff_out(_diff_attention(q, k, v, ctx.seen), lp, cfg,
                         ctx.index()), new
     q, k, v = qkv_proj(h, lp, cfg, ctx.positions)
-    o = attention_out(_gqa_attention(q, k, v, ctx.prompt_mask), h, lp, cfg)
+    o = attention_out(_gqa_attention(q, k, v, ctx.prompt_mask,
+                                     cfg.attn_scale), h, lp, cfg)
+    if cfg.kv_head_pairs:   # as the cache holds them: [.., KV / 2, 2 hd]
+        k, v = (r.reshape(r.shape[:2] + (cfg.kv_heads // 2, -1))
+                for r in (k, v))
     # pad this layer's k/v out to max_len for the cache
     return o, {"k": jnp.pad(k.astype(cfg.dtype), ctx.pad),
                "v": jnp.pad(v.astype(cfg.dtype), ctx.pad)}
@@ -312,6 +362,11 @@ def _mamba_prefill(h, lp, cfg, ctx):
     if "memory" in ctx.carry:
         ctx.carry["memory"] = y
     return o, {"mamba_state": state, "mamba_tail": tail}
+
+
+def _mamba2_prefill(h, lp, cfg, ctx):
+    o, state, tail = mamba2_mixer(h, lp, cfg, valid=ctx.valid)
+    return o, {"mamba2_state": state, "mamba2_tail": tail}
 
 
 def _gmu_prefill(h, lp, cfg, ctx):
@@ -350,6 +405,30 @@ def _diff_attend(q, k, v, cfg, ctx, layer, slab):
     return _diff_decode_attention(q, *slab(), k, v, ctx.mask)
 
 
+def _head_pairs(q, k, v, cfg):
+    """A token's q [B, 1, H, hd], k, v [B, KV, hd] as a cache of PAIRS reads
+    them (`cfg.kv_head_pairs`): k, v [B, KV / 2, 2 hd], adjacent heads side
+    by side; a query head as [q | 0] where its key/value head is a pair's
+    first and [0 | q] where it is the second, so that the pair's row scores
+    its own half alone: a grouped-query layer of KV / 2 heads, 128 wide, to
+    the kernel and to the contraction alike."""
+    B, _, H, hd = q.shape
+    KV = k.shape[1]
+    q = q.reshape(B, 1, KV // 2, 2, H // KV, 1, hd) \
+        * jnp.eye(2, dtype=q.dtype)[:, None, :, None]
+    return (q.reshape(B, 1, H, 2 * hd),
+            *(r.reshape(B, KV // 2, 2 * hd) for r in (k, v)))
+
+
+def _own_halves(o, cfg):
+    """What `_head_pairs`'s query rows read, o [B, 1, H, 2 hd] over a pair's
+    [v1 | v2], -> [B, 1, H, hd]: each head its own value head's half."""
+    B, _, H, wide = o.shape
+    o = o.reshape(B, 1, cfg.kv_heads // 2, 2, H // cfg.kv_heads, 2, wide // 2)
+    return jnp.concatenate([o[:, :, :, :1, :, 0], o[:, :, :, 1:, :, 1]],
+                           axis=3).reshape(B, 1, H, wide // 2)
+
+
 def _attention_step(x, lp, cfg, ctx):
     """Only READS the cache and hands back the layer's new K/V row, rounded
     as it is read back later: the token's one more key column."""
@@ -360,7 +439,7 @@ def _attention_step(x, lp, cfg, ctx):
         if "shared_k" in ctx.carry:
             ctx.carry["shared_k"], ctx.carry["shared_v"] = k, v
         o = _diff_attend(q, k, v, cfg, ctx, ctx.layer, slab)
-        return x + diff_out(o, lp, cfg, ctx.index()), (k, v)
+        return residual(x, diff_out(o, lp, cfg, ctx.index()), cfg), (k, v)
     # a float32 mixer (`mixer_precision`) around the attention itself: the
     # kernel keeps its own arithmetic and hands back o in q's dtype
     with mixer_precision(cfg, lp) as wide:
@@ -368,14 +447,20 @@ def _attention_step(x, lp, cfg, ctx):
         h = block_norm(x, lp, "attn_norm", cfg)
         q, k, v = qkv_proj(h, lp, cfg, ctx.positions)
     k, v = (r[:, 0].astype(ctx.cache["k"].dtype) for r in (k, v))
+    scale = cfg.attn_scale
+    if cfg.kv_head_pairs:   # (the scale is the HEAD's, not the pair's)
+        q, k, v = _head_pairs(q, k, v, cfg)
+        scale = cfg.head_dim ** -0.5 if scale is None else scale
     if ctx.kernel:          # [B, KV, hd]
         o = _kernel_attention(q, ctx.cache, k, v, ctx.active, ctx.layer,
-                              ctx.mesh)
+                              ctx.mesh, scale=scale)
     else:
-        o = _gqa_decode_attention(q, *slab(), k, v, ctx.mask)
+        o = _gqa_decode_attention(q, *slab(), k, v, ctx.mask, scale)
+    if cfg.kv_head_pairs:
+        o = _own_halves(o, cfg)
     with mixer_precision(cfg, lp):
         o = attention_out(o, h, lp, cfg)
-    return x + o, (k, v)
+    return residual(x, o, cfg), (k, v)
 
 
 def _window_step(x, lp, cfg, ctx):
@@ -385,7 +470,7 @@ def _window_step(x, lp, cfg, ctx):
     k, v = (r[:, 0].astype(ctx.cache["win_k"].dtype) for r in (k, v))
     o = _diff_decode_attention(q, ctx.slabs["win_k"][ctx.i],
                                ctx.slabs["win_v"][ctx.i], k, v, ctx.ring)
-    return x + diff_out(o, lp, cfg, ctx.index()), (k, v)
+    return residual(x, diff_out(o, lp, cfg, ctx.index()), cfg), (k, v)
 
 
 def _cross_step(x, lp, cfg, ctx):
@@ -396,30 +481,45 @@ def _cross_step(x, lp, cfg, ctx):
     o = _diff_attend(
         q, ctx.carry["shared_k"], ctx.carry["shared_v"], cfg, ctx, layer,
         lambda: (ctx.cache["k"][layer], ctx.cache["v"][layer]))
-    return x + diff_out(o, lp, cfg, ctx.index()), ()
+    return residual(x, diff_out(o, lp, cfg, ctx.index()), cfg), ()
 
 
-def _state_step(x, lp, cfg, ctx, mixer, step_fn, state: str, tail: str):
+def _state_step(x, lp, cfg, ctx, mixer, step_fn, state: str, tail: str,
+                in_place: bool = True):
     """``step_fn`` updates the layer's blocks of the carried stack of states
     where they lie, the layer's slice of the stacked tails shifts by the
-    token (not active: both kept). -> ``mixer``'s, the tail aside."""
-    carry, layer, active = ctx.carry, ctx.layer, ctx.active
+    token (not active: both kept). -> ``mixer``'s, the tail aside.
+    ``in_place`` False (`Mixer.hands_back`): the stacks are ``ctx.cache``'s
+    and only read; -> ``mixer``'s with the layer's (state, tail) in the
+    tail's place."""
+    carry, layer, active = ctx.carry if in_place else ctx.cache, ctx.layer, \
+        ctx.active
     h = block_norm(x, lp, "attn_norm", cfg)
+    made = []
 
     def step(*token):
-        carry[state], o = step_fn(carry[state], layer, *token, active)
+        if in_place:
+            carry[state], o = step_fn(carry[state], layer, *token, active)
+        else:
+            mine, o = step_fn(carry[state], layer, *token, active,
+                              in_place=False)
+            made.append(mine)
         return o
     old = jax.lax.dynamic_index_in_dim(carry[tail], layer, 0, keepdims=False)
     *out, new = mixer(h, lp, cfg, tail=old, step=step)
-    carry[tail] = jax.lax.dynamic_update_index_in_dim(
-        carry[tail], jnp.where(active[:, None, None], new, old), layer, 0)
+    new = jnp.where(jnp.expand_dims(active, tuple(range(1, new.ndim))), new,
+                    old)
+    if not in_place:
+        return *out, (made[0], new)
+    carry[tail] = jax.lax.dynamic_update_index_in_dim(carry[tail], new,
+                                                      layer, 0)
     return out
 
 
 def _kda_step(x, lp, cfg, ctx):
     o, = _state_step(x, lp, cfg, ctx, kda_mixer, kda_decode_step,
                      "kda_state", "kda_tail")
-    return x + o, ()
+    return residual(x, o, cfg), ()
 
 
 def _mamba_step(x, lp, cfg, ctx):
@@ -427,12 +527,18 @@ def _mamba_step(x, lp, cfg, ctx):
                        "mamba_state", "mamba_tail")
     if "memory" in ctx.carry:   # of the gated memory units after it
         ctx.carry["memory"] = y
-    return x + o, ()
+    return residual(x, o, cfg), ()
+
+
+def _mamba2_step(x, lp, cfg, ctx):
+    o, *row = _state_step(x, lp, cfg, ctx, mamba2_mixer, mamba2_decode_step,
+                          "mamba2_state", "mamba2_tail", ctx.in_place)
+    return residual(x, o, cfg), (row[0] if row else ())
 
 
 def _gmu_step(x, lp, cfg, ctx):
     h = block_norm(x, lp, "attn_norm", cfg)
-    return x + gmu_mixer(h, ctx.carry["memory"], lp, cfg), ()
+    return residual(x, gmu_mixer(h, ctx.carry["memory"], lp, cfg), cfg), ()
 
 
 # (leaves, masks, carried entries and landings are traced in this order)
@@ -448,6 +554,10 @@ MIXERS = {
         _mamba_leaves, {"mamba_state": ("layers", None, None, "mlp"),
                         "mamba_tail": ("layers", None, None, "mlp")},
         "slot", _mamba_prefill, _mamba_step),
+    "mamba2": Mixer(
+        _mamba2_leaves, {"mamba2_state": ("layers", None, None, "mlp"),
+                         "mamba2_tail": ("layers", None, "mlp")},
+        "slot", _mamba2_prefill, _mamba2_step, hands_back=True),
     "window": Mixer(
         *_kv_leaves("window", ("win_k", "win_v"),
                     lambda cfg, max_len: cfg.sliding_window),
@@ -489,7 +599,7 @@ def _prefill_hidden(params: Params, tokens: jax.Array,
         # silently contradict the forward() the params were trained with
         raise ValueError("generation requires a causal (decoder) config; "
                          "this config has causal=False")
-    x = params["embed"][tokens].astype(cfg.dtype)
+    x = embed_tokens(params, tokens, cfg)
     positions = jnp.arange(P)
     causal = jnp.arange(P)[:, None] >= jnp.arange(P)[None, :]
     valid = jnp.arange(P)[None, :] >= start[:, None]  # [B, S]
@@ -514,10 +624,10 @@ def _prefill_hidden(params: Params, tokens: jax.Array,
                 x = x.astype(dtype)
                 h = block_norm(x, lp, "attn_norm", cfg)
                 o, new = MIXERS[kind].prefill(h, lp, cfg, ctx)
-            x = x + o
+            x = residual(x, o, cfg)
             # inference drops the MoE aux loss
             down, _ = ffn_block(block_norm(x, lp, "mlp_norm", cfg), lp, cfg)
-            x = (x + down).astype(cfg.dtype)
+            x = residual(x, down, cfg).astype(cfg.dtype)
             for name, leaf in new.items():
                 left.setdefault(name, []).append(leaf)
         return dict(carry, x=x), {name: tuple(leaves)

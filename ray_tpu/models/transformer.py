@@ -70,9 +70,21 @@ def _stack_axes(cfg: TransformerConfig, moe: bool,
     if cfg.norm == "layer":
         lay.update({"attn_norm_b": ("layers", "embed"),
                     "mlp_norm_b": ("layers", "embed")})
-    if kind in ("mamba", "gmu"):
+    if kind in ("mamba", "mamba2", "gmu"):
         del lay["wo"]
-    if kind == "mamba":
+    if kind == "mamba2":
+        lay.update({
+            "mamba2_in": ("layers", "embed", "mlp"),
+            "mamba2_dt": ("layers", "embed", "heads"),
+            "mamba2_conv": ("layers", None, "mlp"),
+            "mamba2_conv_b": ("layers", "mlp"),
+            "mamba2_dt_b": ("layers", "heads"),
+            "mamba2_A_log": ("layers", "heads"),
+            "mamba2_D": ("layers", "heads"),
+            "mamba2_norm": ("layers", "mlp"),
+            "mamba2_out": ("layers", "mlp", "embed"),
+        })
+    elif kind == "mamba":
         lay.update({
             "mamba_in": ("layers", None, "embed", "mlp"),
             "mamba_conv": ("layers", None, "mlp"),
@@ -228,6 +240,34 @@ def _init_mamba(k, cfg: TransformerConfig, L: int, normal,
     }
 
 
+def _init_mamba2(k, cfg: TransformerConfig, L: int, normal,
+                 out_scale: float) -> Params:
+    """The leaves of ``L`` stacked Mamba-2 mixers. `A_log`, `dt_b`, `D` and
+    the norm's gain as the published implementation draws them: A = 1 .. H
+    a head, the step's bias so that softplus gives dt log-uniform in
+    [0.001, 0.1), D and the gain ones."""
+    d, C, H = cfg.d_model, cfg.mamba_channels, cfg.mamba_heads
+    wide, pd, taps = cfg.mamba2_conv_width, cfg.param_dtype, cfg.mamba_d_conv
+    dt = jnp.exp(jax.random.uniform(next(k), (L, H), jnp.float32,
+                                    jnp.log(0.001), jnp.log(0.1)))
+    return {
+        # columns: the gate z, then [x | B | C]; the step a head is a leaf
+        # of its own: one published matrix [z | xBC | dt] in two, so that
+        # both are whole lanes wide and neither is sliced by column
+        "mamba2_in": normal(next(k), (L, d, C + wide), d ** -0.5),
+        "mamba2_dt": normal(next(k), (L, d, H), d ** -0.5),
+        "mamba2_conv": normal(next(k), (L, taps, wide), taps ** -0.5),
+        "mamba2_conv_b": scaled_normal(next(k), (L, wide), taps ** -0.5, pd),
+        "mamba2_dt_b": (dt + jnp.log(-jnp.expm1(-dt))).astype(pd),
+        "mamba2_A_log": jnp.broadcast_to(
+            jnp.log(jnp.arange(1, H + 1, dtype=jnp.float32)),
+            (L, H)).astype(pd),
+        "mamba2_D": jnp.ones((L, H), pd),
+        "mamba2_norm": jnp.ones((L, C), pd),
+        "mamba2_out": normal(next(k), (L, C, d), out_scale * (d / C) ** 0.5),
+    }
+
+
 def _init_stack(k, cfg: TransformerConfig, L: int, moe: bool,
                 kind: str = "attention", normal=None) -> Params:
     """``L`` stacked layers of one kind, keys drawn from the iterator
@@ -247,6 +287,9 @@ def _init_stack(k, cfg: TransformerConfig, L: int, moe: bool,
     out_heads = (H, cfg.v_head_dim)
     if kind == "mamba":
         lay.update(_init_mamba(k, cfg, L, normal, out_scale))
+        out_heads = None
+    elif kind == "mamba2":
+        lay.update(_init_mamba2(k, cfg, L, normal, out_scale))
         out_heads = None
     elif kind == "gmu":
         C = cfg.mamba_channels
@@ -716,6 +759,82 @@ def mamba_mixer(h, lp, cfg: TransformerConfig, *, valid=None, tail=None,
     return out, y, state, kept
 
 
+def mamba2_gated_norm(y, z, gain, eps):
+    """RMSNorm(y . silu(z)) over all channels with a gain, float32: the gate
+    FIRST, then the norm (`chip_serve_controls.py` runs the other order)."""
+    return rms_norm(y * jax.nn.silu(z.astype(jnp.float32)), gain, eps)
+
+
+def mamba2_mixer(h, lp, cfg: TransformerConfig, *, valid=None, tail=None,
+                 step=None):
+    """A Mamba-2 mixer on normed rows h [B, T, d]: [z | xBC] = W_in h
+    (widths C | C + 2 N), dt = W_dt h (H wide, float32 from the product on:
+    the published [z | xBC | dt] matrix as two leaves); xBC
+    through a causal depthwise convolution with a bias, then SiLU, and
+    split [x | B | C]: x [H, P] a head, ONE B and one C [N] a token for all
+    heads; dt = softplus(dt + b_dt) a head; the recurrence with the SCALAR
+    A = -exp(A_log) a head (ops/mamba2.py: dt, A, the exponent and the state
+    float32); y = scan + D x; the gate FIRST, then the norm: RMSNorm(y .
+    silu(z)) over all C channels with a gain; out = W_out y. Each part
+    under a `jax.named_scope` a profile groups by.
+
+    Prefill gives ``valid`` [B, T] bool (False on a row's left padding:
+    such a row is zero before the convolution and has dt = 0, so it writes
+    nothing and decays nothing) and gets (out, the final state [B, N, C]
+    float32, the last `mamba_d_conv - 1` rows of xBC before the convolution
+    as ONE row [B, (taps - 1) x (C + 2 N)]: whole lanes under any tiling).
+    Decode gives T = 1, the slots' ``tail`` of that shape and ``step``, a
+    function (dt [B, H], x [B, H, P], B, C [B, N], A [H]) -> y [B, H, P]
+    float32 that advances the states (engine: `mamba2_decode_step` on the
+    layer's slice of the cache), and gets (out, the tail shifted by this
+    token)."""
+    from ray_tpu.ops import mamba2
+
+    dt_, f32 = cfg.dtype, jnp.float32
+    B, T = h.shape[:2]
+    C, H, P, N = cfg.mamba_channels, cfg.mamba_heads, cfg.mamba_head_dim, \
+        cfg.mamba_d_state
+    wide, taps = cfg.mamba2_conv_width, cfg.mamba_d_conv
+    with jax.named_scope("mamba2.proj"):
+        zx = jnp.einsum("btd,dc->btc", h, lp["mamba2_in"].astype(dt_))
+        z, xbc = zx[..., :C], zx[..., C:]
+        step_size = jnp.einsum("btd,dh->bth", h, lp["mamba2_dt"].astype(dt_),
+                               preferred_element_type=f32)
+    if valid is not None:
+        xbc = jnp.where(valid[:, :, None], xbc, 0)
+    with jax.named_scope("mamba2.conv"):
+        rows = jnp.pad(xbc, ((0, 0), (taps - 1, 0), (0, 0))) if tail is None \
+            else jnp.concatenate(
+                [tail.astype(xbc.dtype).reshape(B, taps - 1, wide), xbc],
+                axis=1)
+        kept = rows[:, rows.shape[1] - (taps - 1):].reshape(B, -1)
+        wc = lp["mamba2_conv"].astype(f32)
+        xbc = jax.nn.silu(
+            sum(rows[:, i:i + T].astype(f32) * wc[i] for i in range(taps))
+            + lp["mamba2_conv_b"].astype(f32))
+        x = xbc[..., :C].astype(dt_).reshape(B, T, H, P)
+        Bm, Cm = xbc[..., C:C + N], xbc[..., C + N:]
+        step_size = jax.nn.softplus(step_size
+                                    + lp["mamba2_dt_b"].astype(f32))
+        if valid is not None:
+            step_size = jnp.where(valid[:, :, None], step_size, 0.0)
+    A = -jnp.exp(lp["mamba2_A_log"].astype(f32))
+    if step is not None:
+        y = step(step_size[:, 0], x[:, 0], Bm[:, 0], Cm[:, 0], A)[:, None]
+    else:
+        y, state = mamba2.mamba2_scan(step_size, x, Bm, Cm, A,
+                                      chunk=cfg.mamba_chunk)
+    with jax.named_scope("mamba2.out"):
+        y = y + lp["mamba2_D"].astype(f32)[:, None] * x.astype(f32)
+        y = mamba2_gated_norm(y.reshape(B, T, C), z, lp["mamba2_norm"],
+                              cfg.rms_eps)
+        out = jnp.einsum("btc,cd->btd", y.astype(dt_),
+                         lp["mamba2_out"].astype(dt_))
+    if step is not None or valid is None:
+        return out, kept
+    return out, state, kept
+
+
 def gmu_mixer(h, memory, lp, cfg: TransformerConfig):
     """A gated memory unit: W_out (m . silu(W_in h)), ``memory`` [B, T, C]
     float32 the same tokens' scan output of the nearest mamba layer."""
@@ -779,13 +898,19 @@ def diff_out(o, lp, cfg: TransformerConfig, layer):
 def refuse_untrained(cfg: TransformerConfig):
     """`forward` and `loss_fn` walk one segment of attention and KDA layers:
     raise for a configuration whose layers they would compute as another
-    model's (a stated layer pattern, its mamba, gmu, window and cross
-    layers, differential attention, LayerNorm, projection biases)."""
+    model's (a stated layer pattern, its mamba, mamba2, gmu, window and
+    cross layers, differential attention, LayerNorm, projection biases, the
+    four fixed multipliers on the stream)."""
     cannot = [what for has, what in (
         (cfg.layer_pattern is not None and len(cfg.layer_pattern) > 1,
          "a layer pattern of several segments (layer_pattern)"),
         (set(cfg.mixer_period) - {"attention", "kda"},
-         "mamba, gmu, window or cross layers"),
+         "mamba, mamba2, gmu, window or cross layers"),
+        (any(scale is not None for scale in (
+            cfg.embed_scale, cfg.residual_scale, cfg.attn_scale,
+            cfg.logit_divisor)),
+         "fixed multipliers on the stream (embed_scale, residual_scale, "
+         "attn_scale, logit_divisor)"),
         (cfg.diff_attn, "differential attention (diff_attn)"),
         (cfg.attn_bias, "attention projection biases (attn_bias)"),
         (cfg.norm != "rms", "LayerNorm (norm)")) if has]
@@ -801,7 +926,8 @@ def refuse_unserved(cfg: TransformerConfig):
     of ONE stack of layers, a period of mixer kinds at a time; hold for an
     attention layer one k and one v row of `head_dim` a token (a window
     layer: the last `sliding_window` of them), for a KDA or mamba layer a
-    float32 state and the convolutions' tail a slot, and emit one token a
+    float32 state and the convolutions' tail a slot (a mamba2 layer's
+    likewise), and emit one token a
     step: raise for a configuration that needs a latent cache and the
     absorbed decode form, a second stack beside the first (leading dense
     layers, a prediction module) or a step of more than one token."""
@@ -842,11 +968,14 @@ def ffn_block(h, lp, cfg: TransformerConfig, mesh: Optional[Mesh] = None):
 
 def lm_head(params: Params, x, cfg: TransformerConfig,
             mesh: Optional[Mesh] = None):
-    """Final norm + (tied or separate) vocabulary projection."""
+    """Final norm + (tied or separate) vocabulary projection, over
+    `cfg.logit_divisor` where the model states one."""
     x = block_norm(x, params, "final_norm", cfg)
     head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
     logits = jnp.einsum("btd,dv->btv", x.astype(jnp.float32),
                         head.astype(jnp.float32))
+    if cfg.logit_divisor is not None:
+        logits = logits / cfg.logit_divisor
     return _wlc(logits, ("batch", "seq", "vocab"), mesh=mesh)
 
 

@@ -944,3 +944,111 @@ def test_phi4flash_serve_programs_compile_and_copy_no_leaf(one_chip, mosaic):
     assert _shaped(cross[0], K, cfg.d_ff)
     assert _shaped(cross[0], K, P, cfg.d_ff) == []
     assert _shaped(cross[0], K, P, cfg.mamba_channels) == []
+
+
+def test_granite_hybrid_serve_programs_compile_and_move_no_state(one_chip,
+                                                                 mosaic,
+                                                                 monkeypatch):
+    """The cell `granite-4.0-h-micro.reason-closed-64` as the benchmark runs
+    it (all 40 layers and 100,352 rows, 64 slots x 2,048 positions, decode
+    chunks of 4, a prefill group of 4 x 1,024): both served programs compile
+    for one chip within 15.75 GiB with the weights as the engine holds them
+    (bf16, the tied table float32). Decode runs the named kernel on the
+    stacked states where they lie (nothing copies, converts, selects over or
+    scatters into a whole-state-sized result), reads the keys and values,
+    held as pairs of heads, through `decode_attention` alone, re-tiles
+    neither the states nor the tails, and materialises no column slice of a layer's input
+    projection; the prefill of 4 x 1,024 walks the prompt's recurrence in
+    chunks (no loop of 1,024 trips) and fits."""
+    from benchmark.harness import spec
+    from ray_tpu.models.engine import (decode_slots, init_slot_cache,
+                                       prefill_slots)
+    from ray_tpu.models.transformer import init_params, serving_params
+
+    monkeypatch.setattr(importlib.import_module("ray_tpu.ops.mamba2"),
+                        "_use_interpret", lambda: False)
+    bench = spec.load_benchmark()
+    conf = spec.load_config(bench, "granite-4.0-h-micro")
+    dep = spec.load_traffic("reason-closed-64")["deployment"]
+    cfg = spec.build_transformer_config(conf)
+    slots = dep["slots"]
+    max_len = dep["max_prompt_len"] + dep["max_new_tokens"]
+    assert (slots, max_len, cfg.n_layers, cfg.vocab_size) == (
+        64, 2048, 40, 100352)
+    params = _on(jax.eval_shape(
+        lambda k: serving_params(init_params(k, cfg), cfg),
+        jax.random.key(0)), one_chip)
+    assert params["embed"].dtype == jnp.float32
+    assert params["layers"][0]["mamba2_in"].shape == (4, 2048, 8448)
+    assert params["layers"][0]["mamba2_dt"].shape == (4, 2048, 64)
+    assert "mamba2_in" not in params["layers"][5]       # the attention layer
+    cache = _on(jax.eval_shape(
+        lambda: init_slot_cache(cfg, slots, max_len)), one_chip)
+    state, tail = cache["mamba2_state"], cache["mamba2_tail"]
+    assert state.shape == (36, 64, 128, 4096) and state.dtype == jnp.float32
+    assert tail.shape == (36, 64, 3 * 4352) and tail.dtype == jnp.bfloat16
+    # the 8 key/value heads of 64 as 4 pairs of 128: whole lanes
+    assert cache["k"].shape == (4, 64, 4, 2048, 128)
+    rng = _on(jax.eval_shape(lambda: jax.random.key(0)), one_chip)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    active = jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one_chip)
+    decode = decode_slots.lower(params, cache, i32(slots), active, rng, cfg,
+                                steps=4).compile()
+    assert _device_bytes(decode) < HBM_BYTES
+    text = decode.as_text()
+    assert re.search(r"%mamba2_decode_step\S* = [^\n]*custom-call\(", text)
+    assert not re.search(r"%mamba_decode_step\S* = ", text)
+    for scope in ("mamba2.proj", "mamba2.conv", "mamba2.step", "mamba2.out"):
+        assert scope in text, scope
+    moved = "copy|convert|select|scatter|dynamic-slice|fusion|transpose"
+    assert _sized_ops(text, state.shape, moved) == []
+    assert _sized_ops(text, state.shape[1:], moved) == []
+    # the tails' stack is updated in place: no copy re-tiles it between two
+    # layers' updates (as [.., 3, 4352], padded to 4 rows, XLA did, twice a
+    # layer), and the input projection is read whole, never as a column
+    # slice written out first
+    assert _sized_ops(text, tail.shape, "copy|convert|transpose") == []
+    assert _sized_ops(text, (2048, 4352), "copy|fusion|dynamic-slice") == []
+    # the four attention layers read their pairs through the decode kernel,
+    # 8 query rows a pair ([q | 0] four times, [0 | q] four times); nothing
+    # slices a layer's slab out of the stack first
+    calls = _decode_attention_results(text)
+    assert calls and set(calls) == {f"bf16[{slots},4,8,128]"}, calls
+    assert _layer_slab_ops(text, cache["k"].shape[1:]) == []
+    assert _whole_cache_ops(text, cache["k"].shape) == []
+    # the temporaries: no second copy of the tied table, no slab of states
+    assert decode.memory_analysis().temp_size_in_bytes < 0.3e9
+
+    # a decode step whose cache is NOT donated (the benchmark's check reads
+    # a step's logits so) may not write into the 4.8 GB of states it was
+    # given, and a copy of them does not fit beside them: `_decode_one`
+    # without ``active`` hands each layer's new states back and copies none
+    from ray_tpu.models.engine import _decode_one
+
+    kept = jax.jit(lambda p, c, t: _decode_one(p, c, t, cfg)[1]).lower(
+        params, cache, i32(slots)).compile()
+    assert _device_bytes(kept) < HBM_BYTES
+    assert kept.memory_analysis().temp_size_in_bytes < 0.5e9
+    assert _sized_ops(kept.as_text(), state.shape, "copy") == []
+
+    K, P = 4, dep["max_prompt_len"]
+    prefill = prefill_slots.lower(params, cache, i32(K, P), i32(K), i32(K),
+                                  rng, cfg).compile()
+    assert _device_bytes(prefill) < HBM_BYTES
+    text = prefill.as_text()
+    for scope in ("mamba2.scan", "mamba2.conv", "mamba2.proj", "mamba2.out"):
+        assert scope in text, scope
+    assert _sized_ops(text, state.shape, "copy|convert|select|scatter") == []
+    assert prefill.memory_analysis().temp_size_in_bytes < 2.0e9
+    # the loops: the pattern's 4 periods and the bucket's 4 chunks of 256;
+    # nothing steps through the 1,024 positions
+    comps = _computations(text)
+    conditions = set(re.findall(r"while\(.*?condition=%?([\w.\-]+)", text))
+    assert len(conditions) == 10        # the periods, a mamba2 layer's chunks
+    for name in conditions:
+        bounds = [int(n) for line in comps[name]
+                  for n in re.findall(r"s32\[\]\S* constant\((\d+)\)", line)]
+        assert bounds and max(bounds) <= 4, (name, bounds)

@@ -2,7 +2,8 @@
 widths on the CPU path (the masked contractions), the lowered text of
 `prefill_slots` (2, 32) and of `decode_slots` at ``steps=4`` for InternLM2,
 Solar-Open2 and Phi-4-mini-flash hashes to what commit 2582cbb (PR 46,
-before the engine's mixer kinds became `generate.MIXERS`) gave. A change
+before the engine's mixer kinds became `generate.MIXERS`) gave (Granite
+4.0-H: to what the PR that brought it, PR 49, gave). A change
 that moves where Python keeps a branch emits the same operations in the
 same order and passes; one that reorders, adds or drops an operation
 changes what is compiled, loaded and measured, and fails here before any
@@ -43,6 +44,10 @@ TOYS = {
         vocab_size=96, d_model=32, n_heads=4, n_kv_heads=2, head_dim=8,
         d_ff=48, mamba_d_state=4, mamba_dt_rank=3, sliding_window=8,
         dtype="float32", param_dtype="float32"),
+    "granite-4.0-h-micro": dict(
+        vocab_size=96, d_model=32, n_heads=4, n_kv_heads=2, head_dim=8,
+        d_ff=48, mamba_heads=8, mamba_head_dim=8, mamba_d_state=16,
+        mamba_chunk=8, dtype="float32", param_dtype="float32"),
 }
 SLOTS, MAX_LEN, GROUP, STEPS = 4, 48, (2, 32), 4
 PARENT = {
@@ -53,6 +58,10 @@ PARENT = {
     "phi-4-mini-flash-reasoning": {"prefill": "2450e959c4784041",
                                    "decode": "0326e1264dd9f5db"},
 }
+# a configuration that came later: its programs' text on the tree of the PR
+# that brought it (PR 49), held from then on as the three above are
+PARENT["granite-4.0-h-micro"] = {"prefill": "2d64df4c13a69164",
+                                 "decode": "1571abf10c7b8621"}
 
 
 def _lower(cfg, program, slots, max_len, group, on=lambda x: x):
@@ -111,7 +120,8 @@ def described_v5e() -> dict:
     chip = SingleDeviceSharding(topologies.get_topology_desc(
         platform="tpu", topology_name="v5e:2x2").devices[0])
     for module in ("flash_attention", "decode_attention", "kda",
-                   "grouped_matmul", "mamba"):     # as `test_chip_compile`
+                   "grouped_matmul", "mamba", "mamba2"):
+        # as `test_chip_compile`
         importlib.import_module(
             "ray_tpu.ops." + module)._use_interpret = lambda: False
     engine._on_chip = moe._on_chip = lambda: True
@@ -130,7 +140,8 @@ def described_v5e() -> dict:
     out = {}
     for name, traffic in (("internlm2-1.8b", "batch-closed"),
                           ("solar-open2-250b", "batch-closed-128"),
-                          ("phi-4-mini-flash-reasoning", "reason-closed-64")):
+                          ("phi-4-mini-flash-reasoning", "reason-closed-64"),
+                          ("granite-4.0-h-micro", "reason-closed-64")):
         cfg = spec.build_transformer_config(
             spec.load_config(spec.load_benchmark(), name))
         dep = spec.load_traffic(traffic)["deployment"]
