@@ -11,12 +11,22 @@ traffic file and `--seed`, paces them, and times every token at the client.
 A traced run reads the program's own `engine.*` spans (they are in the
 profiler's trace whenever one runs) and differences `engine.stats` over
 the traced seconds; it wraps nothing and names no method of the engine.
-The one tie to the engine's private names that is left is `bench_check`
-(the served programs return no logits).
+The replica starts and stops the profiler and never opens the profile: the
+driver side reduces it in a child process of its own, after the replica's
+last call (`reduce_trace_outside`). The one tie to the engine's private
+names that is left is `bench_check` (the served programs return no logits).
+
+The replica's weights are drawn from the traffic file's
+`deployment.weights_seed` (`spec.weights_seed`); `--seed` draws the traffic
+and the check's prompts.
 """
 
 from __future__ import annotations
 
+import json
+import os
+import subprocess
+import sys
 import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
@@ -46,6 +56,9 @@ class BenchReplica(_ContinuousLLMReplica):
         cfg = spec.build_transformer_config(conf, root,
                                             **(field_overrides or {}))
         self._bench_compiles = probes.CompileCounter()
+        # the number the weights are drawn from: `bench_check` draws its
+        # reference from the same one
+        self._bench_weights_seed = replica_kwargs.get("seed", 0)
         super().__init__(cfg, **replica_kwargs)
         self._bench_init_unix = t0
         self._bench_up_unix = time.time()
@@ -111,9 +124,11 @@ class BenchReplica(_ContinuousLLMReplica):
         must be the argmax of the reference's logits (where its top two
         are further apart than the logits' own error). The reference
         computes with weights of its own: the float32 tree the program's
-        initialiser makes from the same seed, as the replica got it before
-        it stored it its own way (`serving_params`), never the engine's
-        tree, so a fault in how the replica holds its weights shows. That
+        initialiser makes from the number this replica's weights were
+        drawn from, as the replica got it before it stored it its own way
+        (`serving_params`), never the engine's tree, so a fault in how the
+        replica holds its weights shows. ``seed`` (the run's `--seed`)
+        draws the check's prompts and nothing of the model. That
         tree is on the chip where it fits beside what the replica holds
         and on the host where it does not (`reference.tree_fits_on_device`,
         from this replica's own numbers; `reference_weights` in the result
@@ -179,7 +194,8 @@ class BenchReplica(_ContinuousLLMReplica):
             + self._bench_program_temp_bytes)
         t0 = time.perf_counter()
         ref_params = reference.draw_params(
-            lambda k: init_params(k, cfg), seed, on_chip=fits)
+            lambda k: init_params(k, cfg), self._bench_weights_seed,
+            on_chip=fits)
         jax.block_until_ready(ref_params)
         t1 = time.perf_counter()
         rows, ok = [], True
@@ -203,7 +219,10 @@ class BenchReplica(_ContinuousLLMReplica):
     def bench_mark(self) -> dict:
         self._bench_compiles.mark()
         self._bench_stats0 = dict(self.engine.stats)
-        return {"unix": time.time()}
+        # this process's own clock beside the read, here and in
+        # `bench_counters`: the seconds between the two reads hold no
+        # round trip of a handle
+        return {"unix": time.time(), "clock_s": time.perf_counter()}
 
     def bench_counters(self) -> dict:
         """Engine counters since the mark, compilations since the mark,
@@ -211,7 +230,8 @@ class BenchReplica(_ContinuousLLMReplica):
         from benchmark.harness import probes
 
         now, was = dict(self.engine.stats), self._bench_stats0 or {}
-        return {"engine": {k: now[k] - was.get(k, 0) for k in now},
+        return {"clock_s": time.perf_counter(),
+                "engine": {k: now[k] - was.get(k, 0) for k in now},
                 "engine_total": now, "slots": self.engine.slots,
                 "compilations": self._bench_compiles.since_mark(),
                 "memory_peak_bytes": probes.memory_peak_bytes()}
@@ -242,23 +262,11 @@ class BenchReplica(_ContinuousLLMReplica):
         tr["wall_s"] = time.perf_counter() - tr["t0"]
         tr["stats"] = {k: now[k] - tr["stats0"].get(k, 0) for k in now}
         jax.profiler.stop_trace()
-        return {"wall_s": tr["wall_s"],
+        # what only this process knows; the profile stays where the
+        # profiler wrote it and this process never opens it
+        # (`reduce_trace_outside`)
+        return {"wall_s": tr["wall_s"], "stats": tr["stats"],
                 "stop_s": time.perf_counter() - tr["t0"] - tr["wall_s"]}
-
-    def bench_trace_reduce(self) -> dict:
-        """After the window: reduce the trace here, where the file is."""
-        from benchmark.harness import xplane
-
-        tr = self._bench_trace
-        red = xplane.reduce_trace(tr["dir"])
-        red.pop("op_count", None)
-        red["trace_wall_s"] = tr["wall_s"]
-        # the engine's own counts, differenced over the traced seconds
-        red["padded_prefill_tokens"] = tr["stats"].get(
-            "prefill_padded_tokens", 0)
-        red["prefill_dispatches"] = tr["stats"].get("prefill_dispatches", 0)
-        red["engine_in_trace"] = tr["stats"]
-        return red
 
 
 def _is_argmax(token, want_logits, agreement: dict) -> bool:
@@ -279,11 +287,12 @@ def _is_argmax(token, want_logits, agreement: dict) -> bool:
 
 # ---------------------------------------------------------------- driver
 
-def deploy(conf: dict, traffic: dict, seed: int, *, platform: str,
+def deploy(conf: dict, traffic: dict, *, platform: str,
            root: str = spec.ROOT, field_overrides=None,
            timeout_s: float = 900.0):
-    """`serve.run` of one BenchReplica; -> (handle, seconds until it
-    answered, its `bench_info`)."""
+    """`serve.run` of one BenchReplica; -> (handle, its `bench_info` with
+    the seconds until it answered). The replica's weights are drawn from
+    the traffic file's `deployment.weights_seed`, never from `--seed`."""
     from ray_tpu import serve
     from ray_tpu.serve.deployment import deployment
     from ray_tpu.serve.llm import _tpu_lease
@@ -295,8 +304,8 @@ def deploy(conf: dict, traffic: dict, seed: int, *, platform: str,
         num_replicas=1, max_concurrent_queries=dep["max_concurrency"],
         ray_actor_options=_tpu_lease(1)).bind(
             conf, platform=platform, root=root,
-            field_overrides=field_overrides, seed=spec.seed32(seed),
-            **engine_kwargs)
+            field_overrides=field_overrides,
+            seed=spec.seed32(spec.weights_seed(traffic)), **engine_kwargs)
     t0 = time.time()
     handle = serve.run(app, name="bench", route_prefix="/bench",
                        timeout_s=timeout_s)
@@ -548,6 +557,11 @@ def run_closed_loop(handle, traffic: dict, vocab: int, args, trace_dir):
     n_clients = sched["clients"]
     client = _Client(handle, vocab, n_clients)
     nxt = {"i": 0}
+
+    def made():   # the engine's own count of the tokens it has made
+        return call(handle, "bench_counters")["engine_total"]["tokens_out"]
+
+    made0 = made()   # before the ramp's first request
     t_open = time.perf_counter() + sched["ramp_s"]
     window_s = args.seconds
     window = _Window(handle, traffic, args, trace_dir, t_open, window_s)
@@ -565,6 +579,9 @@ def run_closed_loop(handle, traffic: dict, vocab: int, args, trace_dir):
         f.result(timeout=window_s + sched["ramp_s"] + 600)
     extra = window.join()
     client.pool.shutdown(wait=True)
+    extra["whole_run"] = {   # the last client has its answer
+        "engine_tokens_out": made() - made0,
+        "client_tokens": sum(r["n"] for r in client.records)}
     sched["window_s"] = window_s
     return sched, client.records, extra
 
@@ -628,8 +645,8 @@ def run(cell: dict, conf: dict, traffic: dict, args, *, root: str,
 
 def _run(conf, traffic, args, root, platform, field_overrides, trace_dir):
     out: dict = {}
-    handle, info = deploy(conf, traffic, args.seed, platform=platform,
-                          root=root, field_overrides=field_overrides)
+    handle, info = deploy(conf, traffic, platform=platform, root=root,
+                          field_overrides=field_overrides)
     out["info"] = info
     out["chip_worker_ready_s"] = info["chip_worker_ready_s"]
     out["warm"] = call(handle, "bench_warm")
@@ -648,25 +665,73 @@ def _run(conf, traffic, args, root, platform, field_overrides, trace_dir):
         else reduce_closed_loop
     out["client"] = reducer(sched, records)
     out["n_requests_sent"] = len(records)
-    if args.trace:
-        if "trace_error" in out:
-            raise RuntimeError("the traced window failed: "
-                               + out["trace_error"])
-        out["trace"] = call(handle, "bench_trace_reduce")
+    if args.trace and "trace_error" in out:
+        raise RuntimeError("the traced window failed: " + out["trace_error"])
     # the engine's heartbeat, every run: what stalled, on which thread,
     # for how long and when (`at_s`: seconds from the window's opening;
-    # past the window it is the tail or a traced run's trace reduction)
+    # past the window it is the tail)
     out["slow_events"] = [
         dict(ev, at_s=ev["t_wall"] - out["window_open_unix"])
         for ev in call(handle, "engine_slow_events")]
+    if args.trace:   # after the replica's last call
+        t0 = time.perf_counter()
+        out["trace"] = reduce_trace_outside(trace_dir, out["trace_stop"],
+                                            root)
+        out["trace_reduce_s"] = time.perf_counter() - t0
     return out
+
+
+def reduce_trace_outside(trace_dir: str, stopped: dict,
+                         root: str = spec.ROOT) -> dict:
+    """The traced run's profile -> what the readers read, reduced in a
+    child of THIS (driver) process and never in the replica: reading a
+    profile and walking its events hold an interpreter's lock for tens of
+    seconds, and a replica that does not answer `metrics()` for 10 s is
+    taken for dead (`serve/controller.py`). A child, because the reader is
+    JAX's and the driver process imports no JAX; held to the CPU, because
+    the replica still has the chip. ``stopped`` is what `bench_trace_stop`
+    returned: the traced seconds and the engine's own counters differenced
+    over them. A profile that is missing or cannot be read fails the run:
+    no retry, and no empty trace in its place."""
+    reader = os.path.join(root, "benchmark", "harness", "xplane.py")
+    child = subprocess.run(
+        # -P keeps the file's directory off the child's path: a module of
+        # the harness (`stats`, `traffic`) must not shadow one JAX imports
+        [sys.executable, "-P", reader, trace_dir],
+        text=True, env=dict(os.environ, JAX_PLATFORMS="cpu"),
+        stdout=subprocess.PIPE, stderr=subprocess.PIPE, timeout=240)
+    if child.returncode != 0:
+        raise RuntimeError(
+            f"the profile under {trace_dir} could not be reduced (the "
+            f"child exited with code {child.returncode}): "
+            + child.stderr.strip()[-600:])
+    red = json.loads(child.stdout.splitlines()[-1])
+    if "xplane_bytes" not in red:   # `reduce_trace` found no file
+        raise RuntimeError(f"no profile (*.xplane.pb) under {trace_dir}: "
+                           "the replica's profiler wrote none")
+    red.pop("op_count", None)
+    red["trace_wall_s"] = stopped["wall_s"]
+    # the engine's own counts, differenced over the traced seconds
+    red["padded_prefill_tokens"] = stopped["stats"].get(
+        "prefill_padded_tokens", 0)
+    red["prefill_dispatches"] = stopped["stats"].get("prefill_dispatches", 0)
+    red["engine_in_trace"] = stopped["stats"]
+    return red
 
 
 def judge(out: dict) -> dict:
     c = out["client"]
-    return {
+    checks = {
         "reference_agrees": out["check"]["ok"],
         "repeat_identical": out["repeat"]["ok"],
         "no_request_failed": c["failed"] == 0 and c["attempted"] > 0,
         "no_compilation_in_window": out["counters"]["compilations"] == 0,
     }
+    if "whole_run" in out:
+        # a closed loop's rate rests on the engine's `tokens_out`: every
+        # request of a closed loop returns, so over the whole run the
+        # count EQUALS the tokens the clients received, to the token
+        run = out["whole_run"]
+        checks["tokens_made_are_tokens_received"] = \
+            run["engine_tokens_out"] == run["client_tokens"]
+    return checks

@@ -78,7 +78,28 @@ def load_traffic(name: str, root: str = ROOT) -> dict:
         raise SpecError(f"traffic {name!r}: kind must be one of "
                         f"{TRAFFIC_KINDS}, got {t.get('kind')!r}")
     t["name"] = name
+    weights_seed(t)   # refused here where the mix states none
     return t
+
+
+def weights_seed(traffic: dict) -> int:
+    """The whole number a cell's weights are drawn from: a train mix
+    states it at the top of its file, a serve mix under `deployment`. A
+    deployment holds ONE checkpoint and what a run varies is its data, so
+    `--seed` draws the traffic (batches, lengths, arrivals, prompts, the
+    check's rows) and nothing of the model. No default: a mix that left
+    the key out would come to follow `--seed` again."""
+    held = traffic if traffic.get("kind") == "train" \
+        else traffic.get("deployment") or {}
+    seed = held.get("weights_seed")
+    if not isinstance(seed, int) or isinstance(seed, bool) or seed < 0:
+        where = "weights_seed" if held is traffic \
+            else "deployment.weights_seed"
+        raise SpecError(
+            f"traffic {traffic.get('name')!r}: a mix states the "
+            f"seed of its weights as a whole number under `{where}`, got "
+            f"{seed!r}")
+    return seed
 
 
 def load_peaks(root: str = ROOT) -> dict:

@@ -2,10 +2,13 @@
 the worker that leases the chip(s), as a user's loop would be.
 
 `train_loop` is the worker side (it alone touches JAX); `run` is the
-driver side. The loop: weights from `--seed` by the program's initialiser,
-the logits/loss check against the plain reference, one compiled step (its
-`memory_analysis()` kept), one warm-up step, then the measured window of
-steps on fresh seeded batches, each prepared while the step before runs.
+driver side. The loop: weights by the program's initialiser from the
+traffic file's `weights_seed` (`spec.weights_seed`: the checkpoint a job
+starts from does not change from run to run), the logits/loss check against
+the plain reference, one compiled step (its `memory_analysis()` kept), one
+warm-up step, then the measured window of steps on fresh batches drawn from
+`--seed` (as the check's rows are), each prepared while the step before
+runs.
 """
 
 from __future__ import annotations
@@ -123,7 +126,9 @@ def train_loop(config):
         n = math.prod(traffic["mesh"].values())
         mesh = MeshSpec(**traffic["mesh"]).build(jax.devices()[:n])
     compiles = probes.CompileCounter()
-    key = jax.random.key(spec.seed32(seed))
+    # the weights of the check and of the train state: the cell's, not
+    # the run's (`--seed` draws the batches and the check's rows)
+    key = jax.random.key(spec.seed32(spec.weights_seed(traffic)))
 
     # -- correctness, outside the window, before the train state exists:
     # the same weights (same key, same initialiser) and nothing else

@@ -6,10 +6,13 @@ covers, and the longest idle gaps named by the host span they fall in
 engine's own `engine.*` spans).
 
 Pure functions over (name, start_ns, duration_ns) tuples, plus one loader
-that needs nothing but JAX (`jax.profiler.ProfileData`). Only the process
-that held the chip has the trace; it runs `reduce_trace` and ships the
-small result. Checked against `benchmark/fixtures/*.xplane.pb`, recorded on
-the chip.
+that needs nothing but JAX (`jax.profiler.ProfileData`). A train cell's
+worker runs `reduce_trace` on its own trace and ships the small result; a
+serve cell's profile is reduced by this file run as a program, a child of
+the driver process (`python benchmark/harness/xplane.py <trace_dir>` prints
+`reduce_trace`'s result as one line of JSON;
+`serve_cell.reduce_trace_outside`), never by the replica. Checked against
+`benchmark/fixtures/*.xplane.pb`, recorded on the chip.
 """
 
 from __future__ import annotations
@@ -327,3 +330,10 @@ def reduce_trace(trace_dir: str, **kw) -> dict:
     red = reduce_planes(load_planes(path), **kw)
     red["xplane_bytes"] = os.path.getsize(path)
     return red
+
+
+if __name__ == "__main__":
+    import json
+    import sys
+
+    print(json.dumps(reduce_trace(sys.argv[1])))

@@ -5,9 +5,11 @@
 A new process per run: starts a local ray_tpu cluster, runs the cell in the
 worker (train) or replica (serve) that leases the chip(s), and prints ONE
 last line of JSON: `correct`, `attempted`, `failed`, `metrics`, `device`
-(and `breakdown` in a traced run), then `compared`: each number the
-reference check compared beside its limit, which the last line on
-standard error repeats. Earlier lines are information. With
+(and `breakdown` in a traced run; a closed loop adds `tokens_made`, what
+its rate counts, and `tokens_whole_requests`, the count the rate was until
+PR 56, which decides nothing), then `compared`: each number the run
+compared beside its limit, which the last line on standard error repeats.
+Earlier lines are information. With
 `--trace 0` the metrics are the cell's end-to-end metrics, timed with the
 profiler off; with `--trace 1` they are its per-layer metrics.
 
@@ -55,9 +57,27 @@ def end_to_end_values(kind: str, out: dict, setup_s: float) -> dict:
     elif kind == "open_loop":
         vals["tpot_p50_ms"] = stats.percentile(out["client"]["tpot_ms"], 50)
     elif kind == "closed_loop":
-        c = out["client"]
-        vals["serve_tokens_per_s"] = stats.rate(c["tokens"], c["window_s"])
+        vals["serve_tokens_per_s"] = closed_loop_counts(out)[
+            "tokens_made"]["per_s"]
     return vals
+
+
+def closed_loop_counts(out: dict) -> dict:
+    """A closed loop's two counts of its window, each with its seconds and
+    its rate. `tokens_made` is `serve_tokens_per_s`: the engine's
+    `tokens_out` at the read that closes the window less at the read that
+    opens it, over the seconds between the two reads on the replica's own
+    clock. `tokens_whole_requests` is what the metric was until PR 56, the
+    tokens of the whole requests that ENDED in the window by the client's
+    clock: it decides nothing, and stands beside the rate in every run's
+    line."""
+    c, client = out["counters"], out["client"]
+    counts = {"tokens_made": (c["engine"]["tokens_out"],
+                              c["clock_s"] - out["mark"]["clock_s"]),
+              "tokens_whole_requests": (client["tokens"],
+                                        client["window_s"])}
+    return {name: {"tokens": n, "window_s": s, "per_s": stats.rate(n, s)}
+            for name, (n, s) in counts.items()}
 
 
 def compared_numbers(check: dict) -> dict:
@@ -85,6 +105,18 @@ def compared_numbers(check: dict) -> dict:
     out["served_tokens_not_the_references"] = [
         sum(not r["served_tokens_ok"] for r in check["rows"]), 0]
     return out
+
+
+def compared_of_run(out: dict) -> dict:
+    """`compared_numbers` of the reference check and, for a closed loop,
+    the whole run's identity: the tokens the engine counted less the
+    tokens the clients received, which is 0."""
+    compared = compared_numbers(out["check"])
+    if "whole_run" in out:
+        run = out["whole_run"]
+        compared["tokens_made_minus_tokens_received"] = [
+            abs(run["engine_tokens_out"] - run["client_tokens"]), 0]
+    return compared
 
 
 def run_cell(bench: dict, cell: dict, args, *, root: str = spec.ROOT,
@@ -136,8 +168,9 @@ def run_cell(bench: dict, cell: dict, args, *, root: str = spec.ROOT,
           **{k: out[k] for k in (
               "compile_s", "check_s", "program_argument_bytes",
               "program_temp_bytes", "state_bytes", "steps", "window_s",
-              "step_metrics", "warm", "info", "repeat", "n_requests_sent", "slow_events",
-              "sleeper") if k in out},
+              "step_metrics", "warm", "info", "repeat", "n_requests_sent",
+              "slow_events", "sleeper", "whole_run", "trace_stop",
+              "trace_reduce_s") if k in out},
           first_token_ms={
               "mean": stats.mean(out["client"]["ttft_ms"]),
               **{f"p{q}": stats.percentile(out["client"]["ttft_ms"], q)
@@ -175,7 +208,9 @@ def run_cell(bench: dict, cell: dict, args, *, root: str = spec.ROOT,
                               if k in declared}
         if not trace.get("busy_s"):
             line["correct"] = False  # a traced run must see the device
-    line["compared"] = compared_numbers(out["check"])
+    if kind == "closed_loop":
+        line.update(closed_loop_counts(out))
+    line["compared"] = compared_of_run(out)
     return line
 
 

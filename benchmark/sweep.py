@@ -49,8 +49,7 @@ def main(argv=None) -> int:
     vocab = spec.transformer_fields(conf)["vocab_size"]
     ray_tpu.init()
     try:
-        handle, info = serve_cell.deploy(conf, traffic, args.seed,
-                                         platform="tpu")
+        handle, info = serve_cell.deploy(conf, traffic, platform="tpu")
         print(json.dumps({"device": info["device"],
                           "warm": serve_cell.call(handle, "bench_warm")}),
               flush=True)

@@ -163,7 +163,8 @@ def copy_with_additions(tmp_path_factory):
     with open(os.path.join(b, "traffic", "train-toy.json"), "w") as f:
         json.dump({"kind": "train", "seq_len": 32, "rows": 2,
                    "param_dtype": "float32", "mu_dtype": "float32",
-                   "learning_rate": 1e-3, "attention_impl": "auto",
+                   "learning_rate": 1e-3, "weights_seed": 0,
+                   "attention_impl": "auto",
                    "mesh": None, "check": {"rows": 1}, "trace_steps": 2,
                    "why": "a toy"}, f)
     with open(os.path.join(b, "layer_metrics", "toy_steps_per_s.json"),

@@ -19,7 +19,7 @@ TINY = dict(vocab_size=512, d_model=64, n_layers=2, n_heads=4, n_kv_heads=2,
 TOY_DEPLOYMENT = {"slots": 4, "max_concurrency": 8, "max_prompt_len": 64,
                   "max_new_tokens": 16, "eos_id": -1, "greedy": True}
 TOY_SERVE = {
-    "deployment": TOY_DEPLOYMENT,
+    "deployment": dict(TOY_DEPLOYMENT, weights_seed=0),
     "prompt_len": {"median": 20, "sigma": 0.9, "min": 4, "max": 64},
     "output_len": {"median": 8, "sigma": 0.7, "min": 2, "max": 16},
     "rate_per_s": 6.0, "ramp_s": 1.5, "tail_s": 3.0, "clients": 8,
@@ -70,8 +70,19 @@ def test_cell_runs_end_to_end_at_toy_size(cpu_cluster, cell):
     assert sorted(line["compared"]) == (
         ["logits_rel_rms", "loss_abs_diff"] if "train" in cell else
         ["decode_logits_rel_rms", "prefill_logits_rel_rms",
-         "served_tokens_not_the_references"])
+         "served_tokens_not_the_references"]
+        + ["tokens_made_minus_tokens_received"] * ("closed" in cell))
     assert all(v <= limit for v, limit in line["compared"].values())
+    if "closed" in cell:
+        # the rate is the engine's count over the replica's clock; the
+        # old count of whole requests stands beside it and decides nothing
+        old = line["tokens_whole_requests"]
+        assert old["per_s"] == old["tokens"] / old["window_s"] > 0
+        run = info["whole_run"]
+        assert run["engine_tokens_out"] == run["client_tokens"] > 0
+        assert info["checks"]["tokens_made_are_tokens_received"] is True
+    else:
+        assert "tokens_whole_requests" not in line
     if "chat" in cell:   # a fixed request count: rate x window
         assert line["attempted"] == round(TOY_SERVE["rate_per_s"] * 3.0)
     if "train" not in cell:
@@ -148,7 +159,11 @@ def test_the_serve_check_draws_its_own_reference_weights():
         assert all(0.04 < r["prefill"]["rel_rms_error"] < 0.06
                    for r in bad["rows"])
         rep.engine.params = held
-        # another seed than the replica's is another model
-        assert not rep.bench_check(8, [40])["ok"]
+        # `--seed` draws the check's prompts and nothing of the model ...
+        assert rep.bench_check(8, [40])["ok"]
+        # ... and a reference drawn from another number than the
+        # replica's weights is another model
+        rep._bench_weights_seed = 8
+        assert not rep.bench_check(7, [40])["ok"]
     finally:
         rep.engine.shutdown()

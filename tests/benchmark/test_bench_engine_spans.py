@@ -169,8 +169,8 @@ def renamed(tmp_path_factory):
         with ThreadPoolExecutor(len(prompts)) as pool:   # idle before, and
             answers = list(pool.map(                     # idle again after
                 lambda p: rep(p, max_new_tokens=4)["token_ids"], prompts))
-        rep.bench_trace_stop()
-        red = rep.bench_trace_reduce()
+        red = serve_cell.reduce_trace_outside(trace_dir,
+                                              rep.bench_trace_stop())
         requests = rep.engine_requests()
     finally:
         rep.engine.shutdown()

@@ -123,10 +123,16 @@ def test_the_cell_and_the_metrics_it_reports_are_found_by_name():
         NAME, "train-4k-8rows", 1)
     t, was = spec.load_traffic("train-4k-8rows"), \
         spec.load_traffic("train-4k")
-    # train-4k with 8 rows and nothing else changed
-    differs = ("rows", "why", "name")
+    # train-4k with 8 rows; since PR 56 the Kimi cell's learning rate, at
+    # which a seeded router stays at its balance, and the weights seed
+    # whose held share stands at the balance the cell's `why` states
+    differs = ("rows", "why", "name", "learning_rate", "weights_seed",
+               "weights_seed_why")
     assert (t["rows"], was["rows"]) == (8, 4)
-    assert t["learning_rate"] == was["learning_rate"] == 3e-4
+    assert (t["learning_rate"], was["learning_rate"]) == (1e-5, 3e-4)
+    assert t["learning_rate"] == spec.load_traffic(
+        "train-16k-2rows")["learning_rate"]
+    assert "1e-5" in t["why"] and "0.125" in t["weights_seed_why"]
     assert {k: v for k, v in t.items() if k not in differs} \
         == {k: v for k, v in was.items() if k not in differs}
     e2e = {m["name"] for m in spec.metrics_for(BENCH, CELL, "end_to_end")}

@@ -91,7 +91,7 @@ ADDED = {
 PARENT_BENCHMARK = "814970941da5c80b50b7cbb48e417f07a2c26c6c746d3684a6afbe2bf48efc05"
 PARENT_COUNTS = {"configs": 4, "workloads": 6, "end_to_end": 4,
                  "per_layer": 48}
-PARENT_FILES = json.loads(r"""{"benchmark/README.md": "d94aabbe2de002a0", "benchmark/__init__.py":
+PARENT_FILES = json.loads(r"""{"benchmark/README.md": "b908979af41bb3ea", "benchmark/__init__.py":
 "e3b0c44298fc1c14", "benchmark/architectures/dense_gqa.py":
 "53e288c623944cfb", "benchmark/architectures/glm4_moe_lite.py":
 "7ad392cd851e61eb", "benchmark/architectures/olmoe.py": "c5e33fe0d813ca97",
@@ -105,12 +105,12 @@ PARENT_FILES = json.loads(r"""{"benchmark/README.md": "d94aabbe2de002a0", "bench
 "benchmark/harness/flops.py": "0d524519114c7bb6",
 "benchmark/harness/probes.py": "0f68edd762504afa",
 "benchmark/harness/reference.py": "e26b7316061a8daa",
-"benchmark/harness/serve_cell.py": "cc7ffd4cf2e6c3f4",
-"benchmark/harness/spec.py": "5a4e7b89186364ce",
+"benchmark/harness/serve_cell.py": "f417f73f7aca48ea",
+"benchmark/harness/spec.py": "0e1b1e8965222e2f",
 "benchmark/harness/stats.py": "f4c456201ca11bde",
 "benchmark/harness/traffic.py": "b05f83fa3ccda644",
-"benchmark/harness/train_cell.py": "cb5f17c6bb596982",
-"benchmark/harness/xplane.py": "27632fb62517c990",
+"benchmark/harness/train_cell.py": "bc6156348bbaaeea",
+"benchmark/harness/xplane.py": "826b4017797bc54b",
 "benchmark/layer_metrics/batch.decode_attention_roofline.json":
 "4b9a4e86af5cb9c3",
 "benchmark/layer_metrics/chat.decode_attention_roofline.json":
@@ -157,7 +157,7 @@ PARENT_FILES = json.loads(r"""{"benchmark/README.md": "d94aabbe2de002a0", "bench
 "benchmark/layer_metrics/moe_grouped_matmul_step_share.olmoe.json":
 "19a74b3e84918a4b",
 "benchmark/layer_metrics/moe_held_assignment_share.json":
-"78a4c3a3782dd9d6",
+"18c70a1d8d8e6ee4",
 "benchmark/layer_metrics/moe_held_grouped_matmul_roofline.json":
 "368a7a1a01611f59", "benchmark/layer_metrics/moe_load_max_over_mean.json":
 "76e283274796efa7", "benchmark/layer_metrics/mtp_step_share.json":
@@ -200,19 +200,19 @@ PARENT_FILES = json.loads(r"""{"benchmark/README.md": "d94aabbe2de002a0", "bench
 "benchmark/readers/train_mfu.py": "39ba8cc79133b82a",
 "benchmark/readers/train_step_device.py": "c9d6c43386ac6fcc",
 "benchmark/record_fixture.py": "f448bf67c2c4e0ae", "benchmark/rehearse.py":
-"68c5f445df3cce82", "benchmark/run.py": "4b4a14b9593ce2f0",
-"benchmark/sweep.py": "ff616bc7f275a05a", "benchmark/term_limits.py":
+"68c5f445df3cce82", "benchmark/run.py": "9833d1b085faa66f",
+"benchmark/sweep.py": "57ad9ad60c5e657f", "benchmark/term_limits.py":
 "b61a70a1272fdd94", "benchmark/traffic/batch-closed.json":
-"71619b93007d224c", "benchmark/traffic/chat-steady.json":
-"1590fb75f7afd8e3", "benchmark/traffic/train-4k-8rows.json":
-"a2551a8dda87af2b", "benchmark/traffic/train-4k.json": "abead43f75afffe5",
-"benchmark/traffic/train-fsdp2tp2.json": "05e4f7e6ce8e638d",
+"35a0010f7a4287fd", "benchmark/traffic/chat-steady.json":
+"f5f809afba5aa2f1", "benchmark/traffic/train-4k-8rows.json":
+"ec4bade0d377e0b6", "benchmark/traffic/train-4k.json": "f7415070e5f66142",
+"benchmark/traffic/train-fsdp2tp2.json": "aa8aae94dcaebe87",
 "tests/benchmark/bench_paths.py": "7ae2ca7969fcfdd9",
-"tests/benchmark/test_bench_additions.py": "59a5097787f1b945",
-"tests/benchmark/test_bench_cells_cpu.py": "b27688586ef2e6a1",
-"tests/benchmark/test_bench_engine_spans.py": "4e2473341409c3b5",
+"tests/benchmark/test_bench_additions.py": "1054b319070ef7c9",
+"tests/benchmark/test_bench_cells_cpu.py": "82196c88f3af6e01",
+"tests/benchmark/test_bench_engine_spans.py": "c2a0ec095d21f6a8",
 "tests/benchmark/test_bench_flops.py": "ee8075a6223acfb3",
-"tests/benchmark/test_bench_glm4_moe_lite.py": "e277e686697bf04d",
+"tests/benchmark/test_bench_glm4_moe_lite.py": "273badb51990d066",
 "tests/benchmark/test_bench_olmoe.py": "d0ee1b82f33fdab3",
 "tests/benchmark/test_bench_reference.py": "45e19703e189262e",
 "tests/benchmark/test_bench_run_cpu.py": "9b418b66a5c385c8",
@@ -357,7 +357,9 @@ def test_the_cell_and_the_metrics_it_reports_are_found_by_name():
     assert t["rows"] * t["seq_len"] == was["rows"] * was["seq_len"]
     assert t["learning_rate"] == 1e-5 and "1e-5" in t["why"]
     assert t["check"] == {"rows": 1} and t["mesh"] is None
-    differs = ("rows", "seq_len", "learning_rate", "why", "name")
+    differs = ("rows", "seq_len", "why", "name", "weights_seed",
+               "weights_seed_why")   # one learning rate since PR 56
+    assert "0.03125" in t["weights_seed_why"]
     assert {k: v for k, v in t.items() if k not in differs} \
         == {k: v for k, v in was.items() if k not in differs}
     e2e = {m["name"] for m in spec.metrics_for(BENCH, CELL, "end_to_end")}

@@ -54,7 +54,8 @@ TOY_FIELDS = dict(vocab_size=96, d_model=32, n_heads=4, n_kv_heads=2,
                   sliding_window=8, dtype="float32", param_dtype="float32")
 TOY_TRAFFIC = {
     "deployment": {"slots": 4, "max_concurrency": 8, "max_prompt_len": 64,
-                   "max_new_tokens": 16, "eos_id": -1, "greedy": True},
+                   "max_new_tokens": 16, "eos_id": -1, "greedy": True,
+                   "weights_seed": 0},
     "prompt_len": {"median": 20, "sigma": 0.9, "min": 4, "max": 64},
     "output_len": {"median": 8, "sigma": 0.7, "min": 2, "max": 16},
     "ramp_s": 1.5, "clients": 8, "client_threads": 8, "pool": 24,
@@ -74,7 +75,7 @@ PARENT_BENCHMARK = \
     "d2fa914f01bd992b43c7db266b32e359865f2157b9f85d26bd5f8285eff398db"
 PARENT_COUNTS = {"configs": 6, "workloads": 8, "end_to_end": 4,
                  "per_layer": 67}
-PARENT_FILES = json.loads(r"""{"benchmark/README.md": "d94aabbe2de002a0", "benchmark/__init__.py":
+PARENT_FILES = json.loads(r"""{"benchmark/README.md": "b908979af41bb3ea", "benchmark/__init__.py":
 "e3b0c44298fc1c14", "benchmark/architectures/dense_gqa.py":
 "53e288c623944cfb", "benchmark/architectures/glm4_moe_lite.py":
 "7ad392cd851e61eb", "benchmark/architectures/kimi_linear.py":
@@ -92,12 +93,12 @@ PARENT_FILES = json.loads(r"""{"benchmark/README.md": "d94aabbe2de002a0", "bench
 "benchmark/harness/flops.py": "0d524519114c7bb6",
 "benchmark/harness/probes.py": "0f68edd762504afa",
 "benchmark/harness/reference.py": "e26b7316061a8daa",
-"benchmark/harness/serve_cell.py": "cc7ffd4cf2e6c3f4",
-"benchmark/harness/spec.py": "5a4e7b89186364ce",
+"benchmark/harness/serve_cell.py": "f417f73f7aca48ea",
+"benchmark/harness/spec.py": "0e1b1e8965222e2f",
 "benchmark/harness/stats.py": "f4c456201ca11bde",
 "benchmark/harness/traffic.py": "b05f83fa3ccda644",
-"benchmark/harness/train_cell.py": "cb5f17c6bb596982",
-"benchmark/harness/xplane.py": "27632fb62517c990",
+"benchmark/harness/train_cell.py": "bc6156348bbaaeea",
+"benchmark/harness/xplane.py": "826b4017797bc54b",
 "benchmark/layer_metrics/batch.decode_attention_roofline.json":
 "4b9a4e86af5cb9c3",
 "benchmark/layer_metrics/chat.decode_attention_roofline.json":
@@ -158,11 +159,11 @@ PARENT_FILES = json.loads(r"""{"benchmark/README.md": "d94aabbe2de002a0", "bench
 "benchmark/layer_metrics/moe_grouped_matmul_step_share.olmoe.json":
 "19a74b3e84918a4b",
 "benchmark/layer_metrics/moe_held_assignment_share.1of32.json":
-"2f790c26c2c2ca5a",
+"a590ca46d4c35e70",
 "benchmark/layer_metrics/moe_held_assignment_share.json":
-"78a4c3a3782dd9d6",
+"18c70a1d8d8e6ee4",
 "benchmark/layer_metrics/moe_held_assignment_share.serve.json":
-"c1c4e37a4160f58b",
+"c1a2ab1c70c9161d",
 "benchmark/layer_metrics/moe_held_grouped_matmul_roofline.json":
 "368a7a1a01611f59", "benchmark/layer_metrics/moe_load_max_over_mean.json":
 "76e283274796efa7", "benchmark/layer_metrics/mtp_step_share.json":
@@ -219,27 +220,27 @@ PARENT_FILES = json.loads(r"""{"benchmark/README.md": "d94aabbe2de002a0", "bench
 "benchmark/readers/train_mfu.py": "39ba8cc79133b82a",
 "benchmark/readers/train_step_device.py": "c9d6c43386ac6fcc",
 "benchmark/record_fixture.py": "f448bf67c2c4e0ae", "benchmark/rehearse.py":
-"68c5f445df3cce82", "benchmark/run.py": "4b4a14b9593ce2f0",
-"benchmark/sweep.py": "ff616bc7f275a05a", "benchmark/term_limits.py":
+"68c5f445df3cce82", "benchmark/run.py": "9833d1b085faa66f",
+"benchmark/sweep.py": "57ad9ad60c5e657f", "benchmark/term_limits.py":
 "b61a70a1272fdd94", "benchmark/traffic/batch-closed-128.json":
-"512486e5d97eb632", "benchmark/traffic/batch-closed.json":
-"71619b93007d224c", "benchmark/traffic/chat-steady.json":
-"1590fb75f7afd8e3", "benchmark/traffic/train-16k-2rows.json":
-"124e84f9f1b693cf", "benchmark/traffic/train-4k-8rows.json":
-"a2551a8dda87af2b", "benchmark/traffic/train-4k.json": "abead43f75afffe5",
-"benchmark/traffic/train-fsdp2tp2.json": "05e4f7e6ce8e638d",
+"e853d6ea577e2d71", "benchmark/traffic/batch-closed.json":
+"35a0010f7a4287fd", "benchmark/traffic/chat-steady.json":
+"f5f809afba5aa2f1", "benchmark/traffic/train-16k-2rows.json":
+"8db2f31473646315", "benchmark/traffic/train-4k-8rows.json":
+"ec4bade0d377e0b6", "benchmark/traffic/train-4k.json": "f7415070e5f66142",
+"benchmark/traffic/train-fsdp2tp2.json": "aa8aae94dcaebe87",
 "tests/benchmark/bench_paths.py": "7ae2ca7969fcfdd9",
-"tests/benchmark/test_bench_additions.py": "59a5097787f1b945",
-"tests/benchmark/test_bench_cells_cpu.py": "b27688586ef2e6a1",
-"tests/benchmark/test_bench_engine_spans.py": "4e2473341409c3b5",
+"tests/benchmark/test_bench_additions.py": "1054b319070ef7c9",
+"tests/benchmark/test_bench_cells_cpu.py": "82196c88f3af6e01",
+"tests/benchmark/test_bench_engine_spans.py": "c2a0ec095d21f6a8",
 "tests/benchmark/test_bench_flops.py": "ee8075a6223acfb3",
-"tests/benchmark/test_bench_glm4_moe_lite.py": "e277e686697bf04d",
-"tests/benchmark/test_bench_kimi_linear.py": "8aaa57a7cedd554a",
+"tests/benchmark/test_bench_glm4_moe_lite.py": "273badb51990d066",
+"tests/benchmark/test_bench_kimi_linear.py": "6bf2d87b03e88238",
 "tests/benchmark/test_bench_olmoe.py": "d0ee1b82f33fdab3",
 "tests/benchmark/test_bench_reference.py": "45e19703e189262e",
 "tests/benchmark/test_bench_run_cpu.py": "9b418b66a5c385c8",
 "tests/benchmark/test_bench_serve_seam.py": "11230a634ae70bf9",
-"tests/benchmark/test_bench_solar_open2.py": "b75073b695a67009",
+"tests/benchmark/test_bench_solar_open2.py": "a9592e8c669b61e2",
 "tests/benchmark/test_bench_spec.py": "a7e9fc3b793a7562",
 "tests/benchmark/test_bench_stats.py": "684b2859b6ee7f13",
 "tests/benchmark/test_bench_stream_ledger.py": "a00072d265bda511",
@@ -353,9 +354,12 @@ def test_the_cell_and_its_traffic():
     assert len(cell["why"]) <= 200
     traffic = spec.load_traffic("reason-closed-64")
     assert traffic["kind"] == "closed_loop"
-    assert traffic["deployment"] == {
+    dep = dict(traffic["deployment"])
+    assert "ONE checkpoint" in dep.pop("weights_seed_why")
+    assert dep == {
         "slots": 64, "max_concurrency": 128, "max_prompt_len": 1024,
-        "max_new_tokens": 1024, "eos_id": -1, "greedy": True}
+        "max_new_tokens": 1024, "eos_id": -1, "greedy": True,
+        "weights_seed": 0}
     assert (traffic["clients"], traffic["client_threads"],
             traffic["pool"], traffic["ramp_s"], traffic["trace_at_s"],
             traffic["trace_s"]) == (128, 128, 214, 20, 10, 5)

@@ -50,7 +50,8 @@ TOY_FIELDS = dict(vocab_size=256, d_model=32, n_heads=4, n_kv_heads=2,
                   moe_top_k=4, moe_shared_d_ff=24, dtype="float32")
 TOY_TRAFFIC = {
     "deployment": {"slots": 4, "max_concurrency": 8, "max_prompt_len": 64,
-                   "max_new_tokens": 16, "eos_id": -1, "greedy": True},
+                   "max_new_tokens": 16, "eos_id": -1, "greedy": True,
+                   "weights_seed": 0},
     "prompt_len": {"median": 20, "sigma": 0.9, "min": 4, "max": 64},
     "output_len": {"median": 8, "sigma": 0.7, "min": 2, "max": 16},
     "ramp_s": 1.5, "clients": 8, "client_threads": 8, "pool": 24,
@@ -124,8 +125,16 @@ def test_the_cell_and_its_traffic():
     traffic = spec.load_traffic("batch-closed-128")
     base = spec.load_traffic("batch-closed")
     assert traffic["kind"] == "closed_loop"
-    assert traffic["deployment"] == dict(base["deployment"], slots=128,
-                                         max_concurrency=256)
+    dep, was = dict(traffic["deployment"]), dict(base["deployment"])
+    # the one serve cell whose speed follows the draw of its weights: its
+    # number is the first of 0..7 at which the held share reads within 8%
+    # of the 0.125 a balanced router gives (the file lists the readings);
+    # a dense model's is 0
+    assert dep.pop("weights_seed") in range(8)
+    assert was.pop("weights_seed") == 0
+    assert "0.125" in dep.pop("weights_seed_why") and was.pop(
+        "weights_seed_why")
+    assert dep == dict(was, slots=128, max_concurrency=256)
     assert (traffic["clients"], traffic["client_threads"]) == (256, 256)
     for key in ("prompt_len", "output_len", "pool", "ramp_s", "trace_at_s",
                 "trace_s", "check"):
