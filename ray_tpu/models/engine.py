@@ -77,7 +77,8 @@ from ray_tpu.models.generate import (MIXERS, _final_logits, _prefill_hidden,
                                      join_segments, residual)
 from ray_tpu.models.transformer import (Params, block_norm, ffn_block,
                                         layer_segments, param_logical_axes,
-                                        refuse_unserved, serving_params)
+                                        refuse_unserved, serving_params,
+                                        without_head_copy)
 from ray_tpu.ops.decode_attention import pick_block, rows_read
 
 log = logging.getLogger(__name__)
@@ -165,6 +166,11 @@ def prefill_slots(params: Params, cache: SlotCache, tokens: jax.Array,
     prompt's, computed from zero: admission is the reset. One compiled
     program per (K, P) pair; the scheduler keeps K to a few group sizes."""
     K, P = tokens.shape
+    # the prompt pass reads the float32 head, not its held copy: XLA keeps
+    # a group of ONE row's float32 product off the MXU, and there the copy
+    # is another result (first-token logits apart by up to 0.009 on the
+    # chip; groups of 2 and 4 equal to the bit: chip_head_copy.py)
+    params = without_head_copy(params)
     x, cK = _prefill_hidden(params, tokens, cfg, P, starts)
     last = _final_logits(params, x[:, -1:], cfg)[:, 0]  # [K, V]
     toks = _sample(last, rng, greedy, temperature)      # [K]
@@ -473,8 +479,8 @@ class InferenceEngine:
     ``serve_forever`` runs steps on a background thread; ``submit`` /
     ``submit_stream`` are thread-safe entry points. The weights are held
     in the dtype the programs read them in (`serving_params`: ``cfg.dtype``,
-    the head and an MoE router float32; converted once here), not in the
-    dtype they were given in.
+    the head and an MoE router float32, the head's bf16 copy beside it;
+    converted once here), not in the dtype they were given in.
     """
 
     def __init__(self, params: Params, cfg: TransformerConfig, *,

@@ -36,10 +36,11 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.config import TransformerConfig
-from ray_tpu.models.transformer import (Params, attention_out, block_norm,
-                                        diff_out, diff_qkv, ffn_block,
-                                        gmu_mixer, kda_mixer, layer_segments,
-                                        lm_head, mamba2_mixer, mamba_mixer,
+from ray_tpu.models.transformer import (HEAD_COPY, Params, attention_out,
+                                        block_norm, diff_out, diff_qkv,
+                                        ffn_block, gmu_mixer, kda_mixer,
+                                        layer_segments, lm_head,
+                                        mamba2_mixer, mamba_mixer,
                                         mixer_precision, qkv_proj,
                                         refuse_unserved)
 from ray_tpu.ops.decode_attention import decode_attention
@@ -186,8 +187,15 @@ def _final_logits(params, x, cfg):
 
 def embed_tokens(params, tokens, cfg):
     """The tokens' rows of the table in the compute dtype, times
-    `cfg.embed_scale` where the model states one (rows first: a tied table
-    is held float32 and is not converted whole)."""
+    `cfg.embed_scale` where the model states one (rows first, then the
+    rounding). A replica's tied, unscaled table is read from its rounded
+    copy (`transformer.with_head_copy`), whose rows ARE the rows rounded:
+    XLA gathers the float32 leaf's by rounding the WHOLE table first (in a
+    decode chunk the convert it shared with the head's, 2 GB read and 1 GB
+    written for 64 rows of Phi-4-mini-flash's)."""
+    if cfg.embed_scale is None and cfg.tie_embeddings \
+            and HEAD_COPY in params:
+        return params[HEAD_COPY][tokens]
     x = params["embed"][tokens]
     if cfg.embed_scale is not None:
         x = x * cfg.embed_scale

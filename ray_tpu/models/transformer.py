@@ -410,6 +410,10 @@ def init_params(rng: jax.Array, cfg: TransformerConfig,
     return params
 
 
+# the leaf a served tree carries beside its float32 vocabulary head
+HEAD_COPY = "head_bf16"
+
+
 def serving_params(params: Params, cfg: TransformerConfig,
                    shardings: Optional[Params] = None) -> Params:
     """``params`` as a serving replica holds them: every leaf in the dtype
@@ -417,8 +421,10 @@ def serving_params(params: Params, cfg: TransformerConfig,
     ``cfg.dtype`` (the ``.astype(cfg.dtype)`` at each use is the same
     rounding, made once here). The leaves read through
     ``.astype(float32)`` (`read_in_float32`: the vocabulary head, the MoE
-    router) stay float32. A leaf already in its dtype is returned as it
-    is: with ``cfg.dtype`` float32, a float32 tree comes back untouched.
+    router) stay float32; a bf16 tree carries the head's bf16 copy beside
+    its float32 leaf (`with_head_copy`). A leaf already in its dtype is
+    returned as it is: with ``cfg.dtype`` float32, a float32 tree comes
+    back untouched.
     Converted leaf by leaf (after its ``device_put`` where ``shardings``
     gives one, which the conversion keeps), so a second whole tree stands
     beside the first only as long as the caller keeps the first.
@@ -432,8 +438,38 @@ def serving_params(params: Params, cfg: TransformerConfig,
             else jax.device_put(x, sharding)
         return x if x.dtype == want else x.astype(want)
 
-    return jax.tree_util.tree_map_with_path(
-        held, params, *(() if shardings is None else (shardings,)))
+    if shardings is not None and HEAD_COPY in params:   # held already
+        shardings = dict(shardings, **{HEAD_COPY: shardings[in_float32[0]]})
+    return with_head_copy(jax.tree_util.tree_map_with_path(
+        held, params, *(() if shardings is None else (shardings,))), cfg)
+
+
+def with_head_copy(held: Params, cfg: TransformerConfig) -> Params:
+    """``held``, a tree as a replica holds it, with the vocabulary head
+    rounded ONCE to bf16 beside its float32 leaf (`read_in_float32`'s
+    first: `lm_head`, or `embed` where tied), in the leaf's own layout and
+    so, by the conversion, its sharding. It is the operand the MXU has
+    always been given: a float32 matmul at the default precision is one
+    bf16 pass with float32 accumulation, for which XLA rounded the leaf in
+    every program (hoisted out of a decode chunk's substeps and no
+    further: as long as the head's own matmul, once a chunk for ever).
+    `lm_head` reads the copy where the tree holds it, and
+    `generate.embed_tokens` a tied table's rows; the prompt pass is given
+    the tree without it (`engine.prefill_slots` says why). Only a tree
+    that computes in bf16 gets one: float32 compute multiplies in float32
+    and its tree comes back as it is, as does one that holds the copy
+    already. The ONE place that decides the copy's name, dtype, layout and
+    sharding, for `serving_params` and `serve.llm.drawn_serving_params`."""
+    if jnp.dtype(cfg.dtype) != jnp.bfloat16 or HEAD_COPY in held:
+        return held
+    leaf = held[read_in_float32(cfg)[0]]
+    return dict(held, **{HEAD_COPY: leaf.astype(jnp.bfloat16)})
+
+
+def without_head_copy(held: Params) -> Params:
+    """``held`` less the head's copy (the leaves shared): the tree a
+    program that must read the float32 leaf is given."""
+    return {k: v for k, v in held.items() if k != HEAD_COPY}
 
 
 # ---- building blocks -------------------------------------------------------
@@ -971,9 +1007,17 @@ def lm_head(params: Params, x, cfg: TransformerConfig,
     """Final norm + (tied or separate) vocabulary projection, over
     `cfg.logit_divisor` where the model states one."""
     x = block_norm(x, params, "final_norm", cfg)
-    head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
-    logits = jnp.einsum("btd,dv->btv", x.astype(jnp.float32),
-                        head.astype(jnp.float32))
+    if HEAD_COPY in params:     # a replica's tree: `with_head_copy`
+        head = params[HEAD_COPY]
+        logits = jnp.einsum(
+            "btd,vd->btv" if cfg.tie_embeddings else "btd,dv->btv",
+            x.astype(head.dtype), head,
+            preferred_element_type=jnp.float32)
+    else:
+        head = (params["embed"].T if cfg.tie_embeddings
+                else params["lm_head"])
+        logits = jnp.einsum("btd,dv->btv", x.astype(jnp.float32),
+                            head.astype(jnp.float32))
     if cfg.logit_divisor is not None:
         logits = logits / cfg.logit_divisor
     return _wlc(logits, ("batch", "seq", "vocab"), mesh=mesh)

@@ -51,11 +51,13 @@ def drawn_serving_params(cfg, seed: int):
     float32 tree never stands whole on the device: the largest float32
     buffer alive is one leaf's. A replica of a model whose float32 copy (4
     bytes a parameter) is larger than the chip starts by this rule, and so
-    does every other."""
+    does every other. The head's bf16 copy is made of the drawn leaf as
+    `serving_params` makes it (`transformer.with_head_copy`)."""
     import jax
     import jax.numpy as jnp
 
-    from ray_tpu.models.transformer import init_params, read_in_float32
+    from ray_tpu.models.transformer import (init_params, read_in_float32,
+                                            with_head_copy)
 
     in_float32 = read_in_float32(cfg)
     plan = init_params(jax.random.key(seed), cfg,
@@ -68,7 +70,7 @@ def drawn_serving_params(cfg, seed: int):
             return leaf(jnp.dtype(cfg.param_dtype), want)
         return leaf if leaf.dtype == want else leaf.astype(want)
 
-    return jax.tree_util.tree_map_with_path(held, plan)
+    return with_head_copy(jax.tree_util.tree_map_with_path(held, plan), cfg)
 
 
 @functools.lru_cache(maxsize=None)
@@ -124,7 +126,8 @@ class _ContinuousLLMReplica:
     by train) is given.
     The engine holds the weights as its programs read them: ``cfg.dtype``
     (a float32 checkpoint is rounded once at deploy, not in every
-    program) but for the float32 vocabulary head and MoE router.
+    program) but for the float32 vocabulary head (with its bf16 copy
+    beside it, which a decode step multiplies by) and MoE router.
 
     ``tensor_parallel`` > 1 shards the model over that many local devices
     (a `num_tpus=N`-class replica): params/cache carry tensor-axis

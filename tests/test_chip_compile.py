@@ -194,10 +194,11 @@ def test_serve_programs_compile(one_chip, mosaic, make_cfg, slots, max_len,
                                 group, kernel):
     """The serve replica's programs, decode chunks of 4, and the weights
     as the engine holds them (`serving_params`: the preset's compute
-    dtype, the vocabulary head float32)."""
+    dtype, the vocabulary head float32 with its bf16 copy beside it)."""
     from ray_tpu.models.engine import (decode_slots, init_slot_cache,
                                        prefill_slots)
-    from ray_tpu.models.transformer import init_params, serving_params
+    from ray_tpu.models.transformer import (HEAD_COPY, init_params,
+                                            serving_params)
 
     cfg = make_cfg()
     K, P = group
@@ -219,25 +220,33 @@ def test_serve_programs_compile(one_chip, mosaic, make_cfg, slots, max_len,
                                 steps=4).compile()
     assert _device_bytes(decode) < HBM_BYTES
     # The programs read the weights as they are held: no instruction
-    # converts a weight but the float32 vocabulary head, which `lm_head`
-    # reads through `.astype(float32)` and the MXU rounds once a program
-    # (a tree held in another dtype than the forward reads brings back
-    # one convert of every matrix per PROGRAM: a third of a decode chunk
-    # on the chip, 13.8 ms of every prefill), and no temporary is
-    # weight-sized but the head's bf16 copy (525 MB; the smallest stacked
-    # matrix is 33 MB, the float32 tree's bf16 copy 2.5 GB): decode's are
-    # small change, the prefill's its own activations, the float32 scores
-    # and probabilities of [K x P] rows first (268 MB each at 8 x 512).
+    # converts a weight (a tree held in another dtype than the forward
+    # reads brings back one convert of every matrix per PROGRAM: a third
+    # of a decode chunk on the chip, 13.8 ms of every prefill), the
+    # float32 vocabulary head among them: `lm_head` multiplies by the bf16
+    # copy the tree holds (`with_head_copy`), the MXU's operand, which XLA
+    # made of the float32 leaf once a program before (525 MB of
+    # temporaries and 1.8 ms of every decode chunk of InternLM2's), and no
+    # temporary is weight-sized (the smallest stacked matrix is 33 MB):
+    # decode's are small change, the prefill's its own activations, the
+    # float32 scores and probabilities of [K x P] rows first (268 MB each
+    # at 8 x 512).
     head = params["embed" if cfg.tie_embeddings else "lm_head"]
     assert head.dtype == jnp.float32
-    head_copy = head.size * 2
+    assert params[HEAD_COPY].dtype == jnp.bfloat16
+    assert params[HEAD_COPY].shape == head.shape
     cache_bytes = 2 * cache["k"].size * cache["k"].dtype.itemsize
     scores = K * cfg.n_heads * P * P * 4
-    for program, room in ((decode, 16 << 20), (prefill, 3 * scores)):
-        assert _weight_converts(program.as_text(), params,
-                                but=head.shape) == []
+    # The prompt pass is given the tree without the copy and rounds the
+    # leaf itself, once a program (`prefill_slots` says why).
+    head_copy = head.size * 2
+    for program, room, but in ((decode, 16 << 20, ()),
+                               (prefill, head_copy + 3 * scores,
+                                head.shape)):
+        assert _weight_converts(program.as_text(), params, but=but) == []
         assert program.memory_analysis().temp_size_in_bytes \
-            < 0.1 * cache_bytes + head_copy + room
+            < 0.1 * cache_bytes + room
+    assert _head_sized_ops(decode.as_text(), head) == []
     # A decode substep writes only the rows that change, in place: no
     # instruction copies, selects over or scatters into a whole-cache-sized
     # result (a per-layer write inside the layer scan, or a cache stored in
@@ -303,6 +312,21 @@ def _weight_converts(hlo: str, params, but=()) -> list:
     return found
 
 
+def _head_sized_ops(hlo: str, head) -> list:
+    """`name = type[...] convert|copy|transpose(...)` lines of compiled
+    text whose result has as many elements as the vocabulary head: the
+    head made again in another dtype or layout, once a program (inside a
+    decode chunk: hoisted out of its substeps and no further)."""
+    found = []
+    for m in re.finditer(
+            r"^\s*(?:ROOT )?(\S+) = (\w+)\[([\d,]+)\]\S* "
+            r"(convert|copy|transpose)\(", hlo, re.M):
+        if np.prod([int(d) for d in m.group(3).split(",")]) == head.size:
+            found.append(f"{m.group(1)}: {m.group(2)}[{m.group(3)}] "
+                         f"{m.group(4)}")
+    return found
+
+
 def _whole_cache_ops(hlo: str, cache_shape) -> list:
     """`name = bf16[...] copy|select|scatter(...)` lines of compiled text
     whose result has the cache's dimensions, in any order."""
@@ -322,7 +346,8 @@ def test_tensor2_decode_runs_the_kernel_per_shard(topo, mosaic):
     runs it per shard of the KV heads; each chip's call sees 4 of the 8."""
     from ray_tpu.models.engine import (cache_logical_axes, decode_slots,
                                        init_slot_cache)
-    from ray_tpu.models.transformer import (init_params, param_logical_axes,
+    from ray_tpu.models.transformer import (HEAD_COPY, init_params,
+                                            param_logical_axes,
                                             serving_params)
     from ray_tpu.parallel import MeshSpec
     from ray_tpu.parallel.sharding import logical_sharding, tree_shardings
@@ -330,9 +355,11 @@ def test_tensor2_decode_runs_the_kernel_per_shard(topo, mosaic):
     cfg = _internlm2()
     slots = 32
     mesh = MeshSpec(data=1, fsdp=1, tensor=2).build(topo.devices[:2])
+    shardings = tree_shardings(mesh, param_logical_axes(cfg))
+    shardings[HEAD_COPY] = shardings["lm_head"]     # as `serving_params`
     params = _on(jax.eval_shape(
         lambda k: serving_params(init_params(k, cfg), cfg),
-        jax.random.key(0)), tree_shardings(mesh, param_logical_axes(cfg)))
+        jax.random.key(0)), shardings)
     axes = cache_logical_axes()
     cache = _on(jax.eval_shape(lambda: init_slot_cache(cfg, slots, 1280)),
                 {k: logical_sharding(mesh, axes[k]) for k in axes})
@@ -347,6 +374,11 @@ def test_tensor2_decode_runs_the_kernel_per_shard(topo, mosaic):
         f"bf16[{slots},{cfg.kv_heads // 2},"
         f"{cfg.n_heads // cfg.kv_heads},{cfg.head_dim}]"]
     assert "all-reduce" in text  # the tensor-parallel output projection
+    # each chip multiplies by its half of the head's copy as it lies
+    assert _head_sized_ops(text, params["lm_head"]) == []
+    half = jax.ShapeDtypeStruct((cfg.d_model, cfg.vocab_size // 2),
+                                jnp.bfloat16)
+    assert _head_sized_ops(text, half) == []
     assert _whole_cache_ops(text, (cfg.n_layers, slots, cfg.kv_heads // 2,
                                    1280, cfg.head_dim)) == []
 
@@ -816,6 +848,8 @@ def test_solar_open2_serve_programs_compile_and_move_no_state(one_chip,
         return sorted(s for s in shapes
                       if np.prod(s) >= 2 << 20 and s[0] != rows)
     assert weights_converted(text) == []
+    # nor the head: the chunk multiplies by the bf16 copy the tree holds
+    assert _head_sized_ops(text, params["lm_head"]) == []
     # the states are the program's largest buffer and it holds them once:
     # all its temporaries together are smaller than they are
     state_bytes = 4 * int(np.prod(state.shape))
@@ -854,7 +888,8 @@ def test_phi4flash_serve_programs_compile_and_copy_no_leaf(one_chip, mosaic):
     `decode_attention` alone (the full layer's call and the scanned cross
     layers', four query rows a key pair, o float32; the masked
     contraction's scores stay for the rings), copies neither that leaf nor
-    the rings whole, and converts no weight but the tied head's table."""
+    the rings whole, and converts no weight, the tied head's table
+    included: it reads the bf16 copy the tree holds beside it."""
     from benchmark.harness import spec
     from ray_tpu.models.engine import (decode_slots, init_slot_cache,
                                        prefill_slots)
@@ -917,9 +952,16 @@ def test_phi4flash_serve_programs_compile_and_copy_no_leaf(one_chip, mosaic):
     # are read as float32, 82 K numbers a layer)
     converted = {c.split(": ")[1] for c in _weight_converts(text, params)
                  if np.prod(eval(c.split(": ")[1])) >= 2 << 20}
-    assert converted == {"[200064,2560]"}
-    # the temporaries: the head's bf16 copy (1.02 GB) and small change
-    assert decode.memory_analysis().temp_size_in_bytes < 1.2e9
+    assert converted == set()
+    # the tied table's bf16 copy is a leaf of the tree (`with_head_copy`):
+    # the head multiplies by it and the tokens' rows are gathered from it,
+    # so the chunk makes no copy of the table (XLA rounded the WHOLE
+    # float32 table once a chunk for both, 1.02 GB of temporaries and 4.6
+    # ms of every four substeps) and does not read the float32 leaf at all
+    assert _head_sized_ops(text, params["embed"]) == []
+    assert params["head_bf16"].shape == params["embed"].shape
+    assert "params__head_bf16" in text and "params__embed" not in text
+    assert decode.memory_analysis().temp_size_in_bytes < 0.1e9
 
     K, P = 4, dep["max_prompt_len"]
     prefill = prefill_slots.lower(params, cache, i32(K, P), i32(K), i32(K),
@@ -1021,6 +1063,8 @@ def test_granite_hybrid_serve_programs_compile_and_move_no_state(one_chip,
     assert _whole_cache_ops(text, cache["k"].shape) == []
     # the temporaries: no second copy of the tied table, no slab of states
     assert decode.memory_analysis().temp_size_in_bytes < 0.3e9
+    assert _head_sized_ops(text, params["embed"]) == []
+    assert "params__head_bf16" in text
 
     # a decode step whose cache is NOT donated (the benchmark's check reads
     # a step's logits so) may not write into the 4.8 GB of states it was

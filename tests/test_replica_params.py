@@ -9,8 +9,8 @@ import numpy as np
 import pytest
 
 from ray_tpu.models.config import TransformerConfig, tiny_config
-from ray_tpu.models.transformer import (init_params, read_in_float32,
-                                        serving_params)
+from ray_tpu.models.transformer import (HEAD_COPY, init_params,
+                                        read_in_float32, serving_params)
 from ray_tpu.serve import llm
 
 HYBRID = dict(vocab_size=128, d_model=64, n_layers=4, n_heads=4,
@@ -45,7 +45,36 @@ def test_leaf_by_leaf_equals_the_whole_tree_converted(name):
         np.testing.assert_array_equal(
             np.asarray(a.astype(jnp.float32)),
             np.asarray(b.astype(jnp.float32)), err_msg=where)
-    assert sum(x.size for x in jax.tree.leaves(got)) == cfg.num_params
+    # the parameters, and the head's bf16 copy beside them where there is one
+    copy = got.get(HEAD_COPY)
+    assert (copy is not None) == (jnp.dtype(cfg.dtype) == jnp.bfloat16)
+    assert sum(x.size for x in jax.tree.leaves(got)) == cfg.num_params \
+        + (0 if copy is None else copy.size)
+
+
+@pytest.mark.parametrize("name", list(CONFIGS))
+def test_the_drawn_tree_carries_the_copy_the_whole_tree_would(name):
+    """One function decides the copy for both makers of a replica's tree
+    (`transformer.with_head_copy`): the drawn tree's is the drawn head
+    leaf rounded once, the same leaf to the bit as
+    `serving_params(init_params(...))`'s, and a float32-compute tree
+    carries none from either."""
+    cfg = CONFIGS[name]()
+    got = llm.drawn_serving_params(cfg, 5)
+    want = serving_params(init_params(jax.random.key(5), cfg), cfg)
+    if jnp.dtype(cfg.dtype) != jnp.bfloat16:
+        assert HEAD_COPY not in got and HEAD_COPY not in want
+        return
+    head = got[read_in_float32(cfg)[0]]
+    copy = got[HEAD_COPY]
+    assert head.dtype == jnp.float32 and copy.dtype == jnp.bfloat16
+    assert copy.shape == head.shape == want[HEAD_COPY].shape
+    np.testing.assert_array_equal(
+        np.asarray(copy.astype(jnp.float32)),
+        np.asarray(head.astype(jnp.bfloat16).astype(jnp.float32)))
+    np.testing.assert_array_equal(
+        np.asarray(copy.astype(jnp.float32)),
+        np.asarray(want[HEAD_COPY].astype(jnp.float32)))
 
 
 def test_the_float32_tree_never_stands_whole(monkeypatch):

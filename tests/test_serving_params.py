@@ -13,9 +13,11 @@ from ray_tpu.models import engine as engine_mod
 from ray_tpu.models.config import tiny_config
 from ray_tpu.models.engine import (InferenceEngine, _decode_one,
                                    init_slot_cache, prefill_slots)
-from ray_tpu.models.generate import _prefill_hidden
-from ray_tpu.models.transformer import (forward, init_params,
-                                        read_in_float32, serving_params)
+from ray_tpu.models.generate import _final_logits, _prefill_hidden
+from ray_tpu.models.transformer import (HEAD_COPY, forward, init_params,
+                                        lm_head, read_in_float32,
+                                        serving_params, with_head_copy,
+                                        without_head_copy)
 
 _MODELS = {
     "untied": dict(),
@@ -46,6 +48,7 @@ def test_float32_compute_returns_the_tree_untouched(model):
     assert jax.tree.structure(held) == jax.tree.structure(params)
     for a, b in zip(jax.tree.leaves(held), jax.tree.leaves(params)):
         assert a is b
+    assert HEAD_COPY not in held and with_head_copy(params, cfg) is params
 
 
 @pytest.mark.parametrize("model", list(_MODELS))
@@ -54,6 +57,8 @@ def test_bfloat16_compute_holds_each_leaf_as_the_forward_reads_it(model):
     params = init_params(jax.random.key(0), cfg)
     held = _named(serving_params(params, cfg))
     given = _named(params)
+    copy = held.pop(f"['{HEAD_COPY}']")  # its own test, below
+    assert copy.dtype == jnp.bfloat16
     assert set(held) == set(given)
     assert ("['lm_head']" in held) == (not cfg.tie_embeddings)
     assert set(read_in_float32(cfg)) == _FLOAT32_LEAVES[model]
@@ -101,13 +106,24 @@ def test_a_tensor_parallel_engine_holds_sharded_leaves_in_the_compute_dtype():
     eng = InferenceEngine(params, cfg, slots=2, max_prompt_len=8,
                           max_new_tokens=4, mesh=mesh)
     want = serving_params(params, cfg)
-    for leaf, s, w in zip(*(jax.tree.leaves(x)
-                            for x in (eng.params, shardings, want))):
+    for leaf, s, w in zip(*(jax.tree.leaves(x) for x in (
+            without_head_copy(eng.params), shardings,
+            without_head_copy(want)))):
         assert leaf.sharding == s and leaf.dtype == w.dtype
         assert (leaf == w).all()
     assert any(len(leaf.sharding.device_set) == 2 and
                not leaf.sharding.is_fully_replicated
                for leaf in jax.tree.leaves(eng.params))
+    # the head's copy lies as the head does: split over its vocabulary
+    copy, head = eng.params[HEAD_COPY], eng.params["lm_head"]
+    assert copy.sharding == head.sharding == shardings["lm_head"]
+    assert not copy.sharding.is_fully_replicated
+    assert copy.dtype == jnp.bfloat16 and (copy == want[HEAD_COPY]).all()
+    # given the held, placed tree again, an engine places it the same
+    again = InferenceEngine(eng.params, cfg, slots=2, max_prompt_len=8,
+                            max_new_tokens=4, mesh=mesh)
+    assert again.params[HEAD_COPY].sharding == head.sharding
+    assert (again.params[HEAD_COPY] == copy).all()
 
 
 def _prompts(cfg, K, P):
@@ -125,8 +141,13 @@ def test_programs_on_the_held_tree_equal_those_on_the_float32_tree(
         model, monkeypatch):
     """Hidden states of `_prefill_hidden` and `_decode_one` and the K/V
     they write are bit-equal: rounding a leaf once is what the program's
-    own cast did at every use. So are the logits: the head is held in
-    float32, as `lm_head` reads it."""
+    own cast did at every use. The logits on the held tree are the head's
+    with BOTH operands rounded to bf16 and float32 accumulation: what the
+    chip's one bf16 pass has always computed for the float32 product
+    `lm_head` writes (on the CPU a float32 matmul is a float32 matmul, so
+    the unrounded product differs by the rounding and is no yardstick
+    here; `chip_head_copy.py` holds the two equal to the bit on the
+    chip). On the float32 leaf alone they are the float32 tree's."""
     cfg = tiny_config(dtype=jnp.bfloat16, **_MODELS[model])
     params = init_params(jax.random.key(0), cfg)
     held = serving_params(params, cfg)
@@ -153,13 +174,35 @@ def test_programs_on_the_held_tree_equal_those_on_the_float32_tree(
 
     f32, c32, h32 = decode(params, hidden=True)
     f16, c16, h16 = decode(held, hidden=True)
+    # the prompt pass reads the float32 leaf, copy or none: its logits,
+    # and so its first tokens, are the float32 tree's
+    assert (_final_logits(without_head_copy(held), x16[:, -1:], cfg)
+            == _final_logits(params, x32[:, -1:], cfg)).all()
+    assert (f16 == f32).all()
+    # (a copy of NaNs changes nothing: `prefill_slots` does not read it)
+    poisoned = dict(held, **{HEAD_COPY: jnp.full_like(held[HEAD_COPY],
+                                                      jnp.nan)})
+    assert (prefill_slots(poisoned, init_slot_cache(cfg, K, S), toks,
+                          jnp.arange(K, dtype=jnp.int32), starts, rng,
+                          cfg)[1] == f32).all()
     assert h16.shape == (K, cfg.d_model) and (h16 == h32).all()
     assert (c16["k"] == c32["k"]).all() and (c16["v"] == c32["v"]).all()
 
     _, _, logits32 = decode(params, hidden=False)
+    _, _, logits_leaf = decode(without_head_copy(held), hidden=False)
+    assert logits_leaf.dtype == jnp.float32
+    assert (logits_leaf == logits32).all()
     _, _, logits_held = decode(held, hidden=False)
     assert logits_held.dtype == jnp.float32
-    assert (logits_held == logits32).all()
+    rounded = jax.tree_util.tree_map_with_path(
+        lambda path, x: x.astype(jnp.bfloat16).astype(jnp.float32)
+        if path[-1].key in read_in_float32(cfg)[:1] else x, params)
+    with jax.default_matmul_precision("float32"):
+        want = lm_head(rounded, h32[:, None], cfg)[:, 0]
+    # products of two bf16 numbers are exact in float32: only the order
+    # of the sum is the backend's
+    np.testing.assert_allclose(logits_held, want, rtol=0, atol=2e-6)
+    assert (logits_held != logits32).any()     # the rounding is there
 
 
 @pytest.mark.parametrize("model", list(_MODELS))
@@ -210,6 +253,7 @@ def test_engine_holds_the_tree_and_serves_the_forwards_greedy_tokens(model):
         want = jnp.float32 if name.split("'")[-2] in \
             _FLOAT32_LEAVES[model] else jnp.bfloat16
         assert leaf.dtype == want, name
+    assert HEAD_COPY in eng.params
     prompts = [[int(t) for t in np.random.RandomState(i).randint(
         1, cfg.vocab_size, n)] for i, n in enumerate((8, 3, 5))]
     reqs = [eng.submit(p, 6) for p in prompts]
@@ -238,3 +282,73 @@ def test_engine_holds_the_tree_and_serves_the_forwards_greedy_tokens(model):
                 checked += 1
             seq.append(tok)  # the engine's own token: each step on its own
     assert checked >= 9  # half of the 18 owed
+
+
+@pytest.mark.parametrize("model", list(_MODELS))
+def test_a_bfloat16_tree_carries_the_heads_copy_beside_the_float32_leaf(
+        model):
+    """`with_head_copy`: the head leaf (`lm_head`; the table where tied)
+    rounded ONCE to bf16, in the leaf's own layout, under one name; the
+    float32 leaf stays, the same array. A tree that holds the copy
+    already comes back as it is."""
+    cfg = tiny_config(dtype=jnp.bfloat16, **_MODELS[model])
+    params = init_params(jax.random.key(0), cfg)
+    held = serving_params(params, cfg)
+    name = "embed" if cfg.tie_embeddings else "lm_head"
+    assert read_in_float32(cfg)[0] == name
+    assert set(held) == set(params) | {HEAD_COPY}
+    leaf, copy = held[name], held[HEAD_COPY]
+    assert leaf is params[name] and leaf.dtype == jnp.float32
+    assert copy.dtype == jnp.bfloat16 and copy.shape == leaf.shape
+    assert (copy == leaf.astype(jnp.bfloat16)).all()
+    assert (copy.astype(jnp.float32) != leaf).any()
+    assert with_head_copy(held, cfg) is held
+    assert serving_params(held, cfg)[HEAD_COPY] is copy
+    # a train tree holds none and `lm_head` on it is the float32 product
+    assert HEAD_COPY not in params
+
+
+@pytest.mark.parametrize("model", list(_MODELS))
+def test_the_step_reads_the_copy_if_the_tree_holds_it(model):
+    """By structure, as `ffn_block` asks ``"router" in lp``: the head on a
+    tree with the copy does not read the float32 leaf at all (a leaf of
+    NaNs changes nothing), and without the copy it reads nothing else.
+    A tied table's rows reach the stream from the copy too: the rows
+    rounded are the copy's rows."""
+    from ray_tpu.models.generate import embed_tokens
+
+    cfg = tiny_config(dtype=jnp.bfloat16, **_MODELS[model])
+    held = serving_params(init_params(jax.random.key(0), cfg), cfg)
+    name = read_in_float32(cfg)[0]
+    x = jax.random.normal(jax.random.key(1), (3, 1, cfg.d_model),
+                          jnp.bfloat16)
+    want = lm_head(held, x, cfg)
+    assert want.dtype == jnp.float32 and np.isfinite(want).all()
+    poisoned = dict(held, **{name: jnp.full_like(held[name], jnp.nan)})
+    assert (lm_head(poisoned, x, cfg) == want).all()
+    assert np.isnan(lm_head(without_head_copy(poisoned), x, cfg)).all()
+    tokens = jnp.asarray([[1], [5], [7]], jnp.int32)
+    rows = embed_tokens(held, tokens, cfg)
+    assert rows.dtype == jnp.bfloat16
+    assert (rows == embed_tokens(without_head_copy(held), tokens,
+                                 cfg)).all()
+    if cfg.tie_embeddings:
+        assert (embed_tokens(poisoned, tokens, cfg) == rows).all()
+
+
+def test_a_scaled_tied_table_is_read_from_the_leaf():
+    """`embed_scale` multiplies the float32 row before it is rounded: the
+    copy's rows would be rounded first, another number."""
+    from ray_tpu.models.generate import embed_tokens
+
+    cfg = tiny_config(dtype=jnp.bfloat16, tie_embeddings=True,
+                      embed_scale=12.0)
+    params = init_params(jax.random.key(0), cfg)
+    held = serving_params(params, cfg)
+    tokens = jnp.asarray([[1, 5, 7]], jnp.int32)
+    assert (embed_tokens(held, tokens, cfg)
+            == embed_tokens(params, tokens, cfg)).all()
+    poisoned = dict(held, **{HEAD_COPY: jnp.full_like(held[HEAD_COPY],
+                                                      jnp.nan)})
+    assert (embed_tokens(poisoned, tokens, cfg)
+            == embed_tokens(params, tokens, cfg)).all()
