@@ -80,6 +80,7 @@ from ray_tpu.models.transformer import (Params, block_norm, ffn_block,
                                         refuse_unserved, serving_params,
                                         without_head_copy)
 from ray_tpu.ops.decode_attention import pick_block, rows_read
+from ray_tpu.utils.compile_cache import follow_compile_ledger
 
 log = logging.getLogger(__name__)
 
@@ -491,113 +492,6 @@ class InferenceEngine:
                  min_bucket: int = 16, decode_chunk: int = 4,
                  max_inflight: int = 6):
         refuse_unserved(cfg)
-        self.cfg = cfg
-        # the layers a prompt's LAST position alone passes in prefill
-        # (`cfg.tail_segment`; 0 for a pattern that ends in any other kind)
-        self._last_row_layers = sum(
-            len(kinds) * reps
-            for kinds, reps in cfg.segments()[cfg.tail_segment():])
-        self.slots = int(slots)
-        self.max_prompt_len = int(max_prompt_len)
-        self.max_new_tokens = int(max_new_tokens)
-        self.greedy = bool(greedy)
-        self.temperature = float(temperature)
-        self.eos_id = int(eos_id)
-        self.pad_id = int(pad_id)
-        self.mesh = mesh
-        # multi-step scheduling: decode_chunk substeps per dispatch (one
-        # host round-trip per chunk); admission happens between chunks
-        self.decode_chunk = max(1, int(decode_chunk))
-        # pipelined mode: how many dispatched-but-unfetched decode chunks
-        # may exist before the dispatch loop waits for the fetcher.
-        # A device->host fetch OVERLAPS with queued execution, so the
-        # win is dispatching ahead while a previous fetch is in flight;
-        # the cap bounds result-delivery latency (~cap * chunk_time + one
-        # fetch). What a fetch costs on a local chip: chip_smoke.py's
-        # serve line (fetch_s_per_fetch).
-        self.max_inflight = max(1, int(max_inflight))
-        self._max_len = self.max_prompt_len + self.max_new_tokens
-        self._buckets = []
-        b = max(8, int(min_bucket))
-        while b < self.max_prompt_len:
-            self._buckets.append(b)
-            b *= 2
-        self._buckets.append(self.max_prompt_len)
-
-        shardings = None
-        self.cache = init_slot_cache(cfg, self.slots, self._max_len)
-        if mesh is not None:
-            from ray_tpu.parallel.sharding import shard_array, tree_shardings
-
-            if {"kda", "mamba", "mamba2"} & set(cfg.mixer_period) \
-                    and mesh.size > 1:
-                raise NotImplementedError(
-                    "a KDA, mamba or mamba2 layer's decode kernel is not run "
-                    "per shard yet: serve such a model on one chip")
-
-            shardings = tree_shardings(mesh, param_logical_axes(cfg))
-            axes = cache_logical_axes(self.cache)
-            self.cache = {k: shard_array(mesh, v, axes[k])
-                          for k, v in self.cache.items()}
-        self.params = serving_params(params, cfg, shardings)
-
-        self._rng = jax.random.key(seed)
-        self._step_i = itertools.count()
-        self._rid = itertools.count()
-        self._queue: "queue.Queue[_Request]" = queue.Queue()
-        self._slot_req: List[Optional[_Request]] = [None] * self.slots
-        # planned-occupancy scheduling: _slot_left[s] is how many tokens
-        # the resident request is still OWED BY DISPATCH (not by fetch).
-        # Residency is length-bounded and known at submit time, so
-        # admission decisions never wait for a device->host fetch — the
-        # fetch is pure result delivery. eos can only shorten a plan; it
-        # is reclaimed when a fetch reveals it.
-        self._slot_left: List[int] = [0] * self.slots
-        # slots admitted but not yet decoded once: their next chunk's
-        # echo column carries the prefill-sampled token (emit from col 0)
-        self._slot_new: List[bool] = [False] * self.slots
-        # the token chain lives ON DEVICE: chunk N+1's inputs are chunk
-        # N's last samples (or a prefill's first sample, merged in with
-        # .at[slot].set) — the host never syncs to keep the chain going
-        self._next_tok_dev = jnp.zeros(self.slots, jnp.int32)
-        # what the device's cache["start"] / cache["pos"] hold for a
-        # resident slot, kept on the host for the decode_kv_rows_* counters
-        self._slot_start = np.zeros(self.slots, np.int64)
-        self._slot_pos = np.zeros(self.slots, np.int64)
-        # None: the XLA contraction (or no attention layer at all)
-        self._kv_block = _kv_block(self.cache)
-        # what a decode substep costs by the model's shape, for the
-        # counters: KDA and mamba layers, a window layer's ring, layers
-        # with experts and what those offer
-        self._kda_layers = cfg.layers_of_kind("kda")
-        self._mamba_layers = cfg.layers_of_kind("mamba")
-        self._mamba2_layers = cfg.layers_of_kind("mamba2")
-        self._ring_rows = self.slots * cfg.sliding_window \
-            if "win_k" in self.cache else 0
-        moe_layers = cfg.n_layers if cfg.moe_experts else 0
-        self._moe_calls = moe_layers * cfg.held_experts
-        self._moe_assignments = moe_layers * self.slots * cfg.moe_top_k
-        # dispatched-but-unfetched chunks: [(toks_dev [B, K+1],
-        # [(slot, request, emit_from_col, take)], the chunk's moe_counts
-        # or None)] — inline step() fetches
-        # them in the step that dispatched them, the fetcher thread as the
-        # device finishes them (one transfer for all that are ready)
-        self._inflight: List[tuple] = []
-        self._work = threading.Event()  # set when there may be work
-        self._lock = threading.Lock()   # guards step() vs concurrent step()
-        self._stop = threading.Event()
-        self._thread: Optional[threading.Thread] = None
-        # pipelined fetcher (serve_forever only): consumes _inflight so
-        # the dispatch loop never blocks on a device->host transfer
-        self._fetcher: Optional[threading.Thread] = None
-        self._fetch_evt = threading.Event()   # work for the fetcher
-        # set when the step loop died on an unrecoverable error (device /
-        # XLA failure); submit() raises from then on instead of queueing
-        # work that nothing will ever drain. _death_lock orders submit's
-        # check+enqueue against _die's drain (NOT _lock — that is held for
-        # the whole of a step(), and submissions must not block on it)
-        self._fatal: Optional[BaseException] = None
-        self._death_lock = threading.Lock()
         # running counters, always on. Plain numbers under dot-free keys,
         # every key here from the start (readers difference all of them);
         # each is written by one thread, slow_* by whichever was slow, the
@@ -658,7 +552,19 @@ class InferenceEngine:
             "stream_pickup_lag_s": 0.0, "first_pickup_s": 0.0,
             "first_pickups": 0, "streams_closed": 0, "streams_abandoned": 0,
             # caller's stamp to `_make_request`, of the requests that carry one
-            "entry_leg_s": 0.0, "entries": 0}
+            "entry_leg_s": 0.0, "entries": 0,
+            # the deploy by phase: what drawing or loading the weights took
+            # (`serve.replica_weights`: the replica that then built this
+            # engine writes it; 0 where no replica did), this constructor
+            # (`engine.init`), `warmup()` (`engine.warmup`) and the
+            # programs it ran (`engine.warmup_program` each)
+            "weights_s": 0.0, "engine_init_s": 0.0, "warmup_s": 0.0,
+            "warmup_programs": 0}
+        # and the PROCESS's compile ledger, which its listeners keep
+        # current in here: compile_requests = programs_compiled +
+        # programs_loaded, compile_wait_s (of it cache_load_s),
+        # trace_lower_s (`utils.compile_cache`)
+        follow_compile_ledger(self, self.stats)
         # up to `max_concurrency` request threads end streams and make
         # requests at once and `stats[k] += x` is not atomic. NOT `_lock`:
         # the fetcher races the scheduler for that one (ROADMAP D5)
@@ -667,6 +573,115 @@ class InferenceEngine:
         self.request_log: collections.deque = collections.deque(maxlen=1024)
         self._episodes: Dict[str, dict] = {}  # thread -> its open episode
         self._park = "idle"  # why _dispatch_locked last dispatched nothing
+        with self._timed("engine_init_s", "engine.init", heartbeat=False):
+            self.cfg = cfg
+            # the layers a prompt's LAST position alone passes in prefill
+            # (`cfg.tail_segment`; 0 for a pattern that ends in any other kind)
+            self._last_row_layers = sum(
+                len(kinds) * reps
+                for kinds, reps in cfg.segments()[cfg.tail_segment():])
+            self.slots = int(slots)
+            self.max_prompt_len = int(max_prompt_len)
+            self.max_new_tokens = int(max_new_tokens)
+            self.greedy = bool(greedy)
+            self.temperature = float(temperature)
+            self.eos_id = int(eos_id)
+            self.pad_id = int(pad_id)
+            self.mesh = mesh
+            # multi-step scheduling: decode_chunk substeps per dispatch (one
+            # host round-trip per chunk); admission happens between chunks
+            self.decode_chunk = max(1, int(decode_chunk))
+            # pipelined mode: how many dispatched-but-unfetched decode chunks
+            # may exist before the dispatch loop waits for the fetcher.
+            # A device->host fetch OVERLAPS with queued execution, so the
+            # win is dispatching ahead while a previous fetch is in flight;
+            # the cap bounds result-delivery latency (~cap * chunk_time + one
+            # fetch). What a fetch costs on a local chip: chip_smoke.py's
+            # serve line (fetch_s_per_fetch).
+            self.max_inflight = max(1, int(max_inflight))
+            self._max_len = self.max_prompt_len + self.max_new_tokens
+            self._buckets = []
+            b = max(8, int(min_bucket))
+            while b < self.max_prompt_len:
+                self._buckets.append(b)
+                b *= 2
+            self._buckets.append(self.max_prompt_len)
+
+            shardings = None
+            self.cache = init_slot_cache(cfg, self.slots, self._max_len)
+            if mesh is not None:
+                from ray_tpu.parallel.sharding import (shard_array,
+                                                       tree_shardings)
+
+                if {"kda", "mamba", "mamba2"} & set(cfg.mixer_period) \
+                        and mesh.size > 1:
+                    raise NotImplementedError(
+                        "a KDA, mamba or mamba2 layer's decode kernel is not "
+                        "run per shard yet: serve such a model on one chip")
+
+                shardings = tree_shardings(mesh, param_logical_axes(cfg))
+                axes = cache_logical_axes(self.cache)
+                self.cache = {k: shard_array(mesh, v, axes[k])
+                              for k, v in self.cache.items()}
+            self.params = serving_params(params, cfg, shardings)
+
+            self._rng = jax.random.key(seed)
+            self._step_i = itertools.count()
+            self._rid = itertools.count()
+            self._queue: "queue.Queue[_Request]" = queue.Queue()
+            self._slot_req: List[Optional[_Request]] = [None] * self.slots
+            # planned-occupancy scheduling: _slot_left[s] is how many tokens
+            # the resident request is still OWED BY DISPATCH (not by fetch).
+            # Residency is length-bounded and known at submit time, so
+            # admission decisions never wait for a device->host fetch — the
+            # fetch is pure result delivery. eos can only shorten a plan; it
+            # is reclaimed when a fetch reveals it.
+            self._slot_left: List[int] = [0] * self.slots
+            # slots admitted but not yet decoded once: their next chunk's
+            # echo column carries the prefill-sampled token (emit from col 0)
+            self._slot_new: List[bool] = [False] * self.slots
+            # the token chain lives ON DEVICE: chunk N+1's inputs are chunk
+            # N's last samples (or a prefill's first sample, merged in with
+            # .at[slot].set) — the host never syncs to keep the chain going
+            self._next_tok_dev = jnp.zeros(self.slots, jnp.int32)
+            # what the device's cache["start"] / cache["pos"] hold for a
+            # resident slot, kept on the host for the decode_kv_rows_* counters
+            self._slot_start = np.zeros(self.slots, np.int64)
+            self._slot_pos = np.zeros(self.slots, np.int64)
+            # None: the XLA contraction (or no attention layer at all)
+            self._kv_block = _kv_block(self.cache)
+            # what a decode substep costs by the model's shape, for the
+            # counters: KDA and mamba layers, a window layer's ring, layers
+            # with experts and what those offer
+            self._kda_layers = cfg.layers_of_kind("kda")
+            self._mamba_layers = cfg.layers_of_kind("mamba")
+            self._mamba2_layers = cfg.layers_of_kind("mamba2")
+            self._ring_rows = self.slots * cfg.sliding_window \
+                if "win_k" in self.cache else 0
+            moe_layers = cfg.n_layers if cfg.moe_experts else 0
+            self._moe_calls = moe_layers * cfg.held_experts
+            self._moe_assignments = moe_layers * self.slots * cfg.moe_top_k
+            # dispatched-but-unfetched chunks: [(toks_dev [B, K+1],
+            # [(slot, request, emit_from_col, take)], the chunk's moe_counts
+            # or None)] — inline step() fetches
+            # them in the step that dispatched them, the fetcher thread as the
+            # device finishes them (one transfer for all that are ready)
+            self._inflight: List[tuple] = []
+            self._work = threading.Event()  # set when there may be work
+            self._lock = threading.Lock()  # guards step() vs concurrent step()
+            self._stop = threading.Event()
+            self._thread: Optional[threading.Thread] = None
+            # pipelined fetcher (serve_forever only): consumes _inflight so
+            # the dispatch loop never blocks on a device->host transfer
+            self._fetcher: Optional[threading.Thread] = None
+            self._fetch_evt = threading.Event()   # work for the fetcher
+            # set when the step loop died on an unrecoverable error (device /
+            # XLA failure); submit() raises from then on instead of queueing
+            # work that nothing will ever drain. _death_lock orders submit's
+            # check+enqueue against _die's drain (NOT _lock — that is held for
+            # the whole of a step(), and submissions must not block on it)
+            self._fatal: Optional[BaseException] = None
+            self._death_lock = threading.Lock()
 
     # -------------------------------------------------------- submission
 
@@ -805,10 +820,11 @@ class InferenceEngine:
         ``stats[key]``. A spanned region is a thread state, and one
         occurrence over ``_SLOW_S`` while work waits is a slow event
         (``heartbeat=False``: a child whose seconds its parent's key
-        already holds). ``episode_if`` marks a wait that wakes by its own
+        already holds, or a phase of the deploy, which is no stall).
+        ``episode_if`` marks a wait that wakes by its own
         timeout: back-to-back occurrences are ONE episode, counted for as
         long as the predicate says work is waiting for this thread."""
-        t0 = time.perf_counter()
+        t0, compiles0 = time.perf_counter(), self.stats["compile_requests"]
         try:
             with jax.profiler.TraceAnnotation(span, **meta) if span \
                     else nullcontext():
@@ -819,19 +835,24 @@ class InferenceEngine:
             if span and heartbeat and (episode_if or dt > _SLOW_S):
                 reason = meta.get("reason")
                 self._beat(span[len("engine."):]
-                           + (f"_{reason}" if reason else ""),
-                           t0, dt, episode_if)
+                           + (f"_{reason}" if reason else ""), t0, dt,
+                           self.stats["compile_requests"] - compiles0,
+                           episode_if)
 
-    def _beat(self, state: str, t0: float, dt: float, episode_if):
+    def _beat(self, state: str, t0: float, dt: float, compiles: int,
+              episode_if):
         """The heartbeat behind `_timed`: ``slow_events`` gets one entry
         (and the log one warning) per occurrence or episode that passed
         ``_SLOW_S`` while work waited; ``slow_s`` its thread-seconds (a
-        stall that blocks both threads counts on each)."""
+        stall that blocks both threads counts on each); ``compiles`` the
+        programs this process asked the backend for meanwhile, so a stall
+        that was a compile says so (`compile_log()` names the program)."""
         thread = threading.current_thread().name
         if episode_if is None:
             if not self._work_waits():
                 return  # e.g. the lock held by a warm-up: idle, not stalled
-            ev = {"state": state, "t_perf": t0, "seconds": dt}
+            ev = {"state": state, "t_perf": t0, "seconds": dt,
+                  "compiles": compiles}
         elif not episode_if():
             self._episodes.pop(thread, None)  # idle, not stalled
             return
@@ -839,8 +860,10 @@ class InferenceEngine:
             ev = self._episodes.get(thread)
             if ev is None or ev["state"] != state:
                 ev = self._episodes[thread] = {
-                    "state": state, "t_perf": t0, "seconds": 0.0}
+                    "state": state, "t_perf": t0, "seconds": 0.0,
+                    "compiles": 0}
             ev["seconds"] += dt
+            ev["compiles"] += compiles
             if ev["seconds"] <= _SLOW_S:
                 return
         if "thread" in ev:  # an episode already reported keeps growing
@@ -855,9 +878,11 @@ class InferenceEngine:
         self.stats["slow_count"] += 1
         self.stats["slow_s"] += ev["seconds"]
         log.warning("engine thread %s slow: %.2f s in state %s "
-                    "(%d queued, %d planned slots, %d undelivered chunks)",
+                    "(%d queued, %d planned slots, %d undelivered chunks, "
+                    "%d compiles)",
                     thread, ev["seconds"], state, ev["queued"],
-                    ev["planned_slots"], ev["undelivered_chunks"])
+                    ev["planned_slots"], ev["undelivered_chunks"],
+                    ev["compiles"])
 
     def _undelivered(self) -> int:
         """Decode chunks dispatched whose tokens have not reached their
@@ -971,31 +996,42 @@ class InferenceEngine:
     def warmup(self):
         """Compile every program the serving loop can hit (per-bucket x
         per-group-size prefills, the decode chunk) so no compile lands
-        mid-traffic. Resets slot state afterwards; call before serving."""
+        mid-traffic. Resets slot state afterwards; call before serving.
+        One `engine.warmup` span (``stats["warmup_s"]``) over an
+        `engine.warmup_program` a program: the seconds until its dispatch
+        returned, which is its compile or its load from the cache."""
         sizes = [s for s in self._GROUP_SIZES if s <= self.slots]
-        for bucket in self._buckets:
-            for K in sizes:
-                toks = np.full((K, bucket), self.pad_id, np.int32)
-                toks[:, -1] = 1
-                self.cache, first = prefill_slots(
-                    self.params, self.cache, jnp.asarray(toks),
-                    jnp.arange(K, dtype=jnp.int32),
-                    jnp.full((K,), bucket - 1, jnp.int32),
-                    self._next_rng(), self.cfg, self.greedy,
-                    self.temperature)
-                # warm the chain-merge too (_admit_group runs it per
-                # group size; a mid-traffic compile stalls the loop)
-                self._next_tok_dev = self._next_tok_dev.at[
-                    jnp.arange(K, dtype=jnp.int32)].set(first)
-        self._decode(np.ones(self.slots, bool))  # and the last-column slice
-        jax.block_until_ready(self._next_tok_dev)
-        # reset bookkeeping: positions to zero, junk K/V is unreachable
-        # (a state, a tail and a window's ring are replaced at admission)
-        cache = self.cache
-        self.cache = dict(cache, pos=jnp.zeros_like(cache["pos"]),
-                          start=jnp.zeros_like(cache["start"]))
-        self._next_tok_dev = jnp.zeros(self.slots, jnp.int32)
+        with self._timed("warmup_s", "engine.warmup", heartbeat=False):
+            for bucket in self._buckets:
+                for K in sizes:
+                    with self._warmup_program(K=K, P=bucket):
+                        toks = np.full((K, bucket), self.pad_id, np.int32)
+                        toks[:, -1] = 1
+                        self.cache, first = prefill_slots(
+                            self.params, self.cache, jnp.asarray(toks),
+                            jnp.arange(K, dtype=jnp.int32),
+                            jnp.full((K,), bucket - 1, jnp.int32),
+                            self._next_rng(), self.cfg, self.greedy,
+                            self.temperature)
+                        # warm the chain-merge too (_admit_group runs it per
+                        # group size; a mid-traffic compile stalls the loop)
+                        self._next_tok_dev = self._next_tok_dev.at[
+                            jnp.arange(K, dtype=jnp.int32)].set(first)
+            with self._warmup_program(decode=self.decode_chunk):
+                # and the last-column slice
+                self._decode(np.ones(self.slots, bool))
+            jax.block_until_ready(self._next_tok_dev)
+            # reset bookkeeping: positions to zero, junk K/V is unreachable
+            # (a state, a tail and a window's ring are replaced at admission)
+            cache = self.cache
+            self.cache = dict(cache, pos=jnp.zeros_like(cache["pos"]),
+                              start=jnp.zeros_like(cache["start"]))
+            self._next_tok_dev = jnp.zeros(self.slots, jnp.int32)
         return self
+
+    def _warmup_program(self, **meta):
+        self.stats["warmup_programs"] += 1
+        return jax.profiler.TraceAnnotation("engine.warmup_program", **meta)
 
     def _emit_to(self, req: _Request, slot: int, tok: int,
                  t_delivered: float):

@@ -12,6 +12,7 @@ blocked in ``__call__`` or ``stream`` is a request in that engine's queue.
 from __future__ import annotations
 
 import functools
+import time
 from typing import List, Optional, Sequence
 
 import numpy as np
@@ -147,7 +148,15 @@ class _ContinuousLLMReplica:
 
         cfg = (model if isinstance(model, TransformerConfig)
                else get_config(model))
-        params = _replica_params(cfg, checkpoint_dir, seed)
+        # the deploy's first phase, timed as the engine's `_timed` times
+        # the two after it (`engine.init`, `engine.warmup`); the engine is
+        # not there yet, so it is handed the seconds below
+        t0 = time.perf_counter()
+        with jax.profiler.TraceAnnotation(
+                "serve.replica_weights",
+                source="drawn" if checkpoint_dir is None else "checkpoint"):
+            params = _replica_params(cfg, checkpoint_dir, seed)
+        weights_s = time.perf_counter() - t0
         mesh = None
         if tensor_parallel > 1:
             from ray_tpu.parallel import MeshSpec
@@ -165,6 +174,7 @@ class _ContinuousLLMReplica:
             temperature=temperature, eos_id=eos_id, pad_id=pad_id,
             mesh=mesh, seed=seed,
             decode_chunk=decode_chunk).serve_forever()
+        self.engine.stats["weights_s"] = weights_s
 
     def __call__(self, prompt: Sequence[int],
                  max_new_tokens: Optional[int] = None) -> dict:
@@ -199,14 +209,22 @@ class _ContinuousLLMReplica:
         a stream still open (or an answer that was not streamed)."""
         return list(self.engine.request_log)[-int(last):]
 
+    def compile_log(self, last: int = 100) -> List[dict]:
+        """The ``last`` programs (of at most 256) this PROCESS asked the
+        backend for, oldest first: ``fun_name``, ``wall_s``, ``loaded``
+        (from the persistent cache, not compiled) and ``t_unix``. Which
+        program compiled again; the totals are in `engine_stats`
+        (``compile_requests`` and the five keys beside it)."""
+        from ray_tpu.utils.compile_cache import compile_log
+
+        return compile_log(last)
+
     def trace(self, seconds: float, log_dir: str) -> str:
         """Profile this replica for ``seconds`` (only the process that
         holds the chip can trace it): device operations, the engine's
         ``engine.*`` spans and the streams' ``serve.stream_wait`` on one
         clock. -> ``log_dir``, which holds
         ``plugins/profile/<time>/*.xplane.pb``."""
-        import time
-
         import jax
 
         jax.profiler.start_trace(log_dir)

@@ -3,7 +3,10 @@ ledger is complete, requests are stamped, the heartbeat tells a stall from
 an idle engine, the spans are on the profiler's clock, and `engine.stats`
 stays a flat dict of numbers (its readers difference every key). Since
 PR 40 a streamed request's ledger goes on from delivery to the pulls that
-take its tokens: every second of an open stream is `wait` or `held`."""
+take its tokens: every second of an open stream is `wait` or `held`.
+Since PR 58 the deploy is in the ledger too: the weights, the constructor
+and the warm-up are spans with a key each, and the process's compile
+ledger (`utils.compile_cache`) stands in every engine's `stats`."""
 
 import collections
 import glob
@@ -34,11 +37,15 @@ def model():
     return cfg, init_params(jax.random.key(0), cfg)
 
 
-def _engine(model, **kw):
+def _cold_engine(model, **kw):
     cfg, params = model
     kw = {"slots": 4, "max_prompt_len": 16, "max_new_tokens": 8,
           "decode_chunk": 2, **kw}
-    return InferenceEngine(params, cfg, **kw).warmup()
+    return InferenceEngine(params, cfg, **kw)
+
+
+def _engine(model, **kw):
+    return _cold_engine(model, **kw).warmup()
 
 
 def _drive(eng, reqs, steps=500):
@@ -265,6 +272,10 @@ def test_spans_are_on_the_profilers_clock(model, tmp_path):
             abs=2e-5 * len(named(span))), span
 
 
+COMPILE_KEYS = ("compile_requests", "compile_wait_s", "programs_loaded",
+                "programs_compiled", "cache_load_s", "trace_lower_s")
+
+
 def _flat_numbers(stats):
     assert all(type(v) in (int, float) for v in stats.values()), stats
     assert not any("." in k for k in stats)
@@ -275,9 +286,21 @@ def _flat_numbers(stats):
                          ids=["inline", "pipelined"])
 def test_stats_is_a_flat_dict_of_numbers_with_every_key_from_the_start(
         model, pipelined):
-    eng = _engine(model)
+    eng = _cold_engine(model)
     keys = _flat_numbers(eng.stats)
-    assert all(v == 0 for v in eng.stats.values())
+    # what construction sets: its own seconds, and the PROCESS's compile
+    # ledger as it stands (this session compiled long before this engine).
+    # `weights_s` is a replica's to write: 0 in an engine none built
+    assert set(COMPILE_KEYS) < keys
+    assert eng.stats["engine_init_s"] > 0
+    assert eng.stats["compile_requests"] > 0
+    assert all(v == 0 for k, v in eng.stats.items()
+               if k not in COMPILE_KEYS + ("engine_init_s",))
+    eng.warmup()    # sets its two keys and no other of the engine's own
+    assert eng.stats["warmup_s"] > 0 and eng.stats["warmup_programs"] > 0
+    assert all(v == 0 for k, v in eng.stats.items()
+               if k not in COMPILE_KEYS + (
+                   "engine_init_s", "warmup_s", "warmup_programs"))
     assert "cap_stalls" not in keys and not hasattr(eng, "_at_cap")
     if pipelined:
         eng.serve_forever()
@@ -319,6 +342,15 @@ def test_an_operators_way_in_through_the_replica(model, tmp_path):
         assert [r["rid"] for r in recs] == [1, 2]
         assert recs[-1]["tokens_out"] == 4 and recs[-1]["t_done"] > 0
         assert rep.engine_stats()["requests_done"] == 3
+        # the deploy, read the same way: the weights' seconds (the replica
+        # drew them), and the programs this process asked the backend for
+        st = rep.engine_stats()
+        assert st["weights_s"] > 0 and st["engine_init_s"] > 0
+        assert st["warmup_s"] == 0      # nobody warmed this replica up
+        log_ = rep.compile_log()
+        assert 0 < len(log_) <= 100 and len(rep.compile_log(last=3)) == 3
+        assert set(log_[-1]) == {"fun_name", "wall_s", "loaded", "t_unix"}
+        assert st["compile_requests"] >= len(log_)
         threading.Timer(0.05, lambda: rep(PROMPTS[3])).start()
         assert rep.trace(0.4, str(tmp_path)) == str(tmp_path)
     finally:
@@ -327,6 +359,81 @@ def test_an_operators_way_in_through_the_replica(model, tmp_path):
              for ev in evs}
     assert {"engine.park", "engine.fetch_idle", "engine.admit",
             "engine.decode_dispatch", "engine.deliver"} <= names
+
+
+def test_warmup_is_a_phase_of_the_deploy_and_no_stall(model, monkeypatch):
+    monkeypatch.setattr(engine_mod, "_SLOW_S", 0.0)
+    eng = _cold_engine(model, max_prompt_len=64)
+    assert eng._buckets == [16, 32, 64]
+    before = dict(eng.stats)
+    assert before["warmup_s"] == 0 and before["warmup_programs"] == 0
+    eng.warmup()
+    st = eng.stats
+    assert st["warmup_s"] > 0
+    # buckets x group sizes, and the decode chunk
+    assert st["warmup_programs"] == 3 * len(eng._GROUP_SIZES) + 1
+    assert st["engine_init_s"] == before["engine_init_s"] > 0
+    assert not eng.slow_events and st["slow_count"] == 0
+    # fewer group sizes where there are fewer slots than the largest
+    two = _cold_engine(model, slots=2).warmup()
+    assert two.stats["warmup_programs"] == 1 * 2 + 1
+
+
+def test_the_deploys_spans_are_on_the_profilers_clock(model, tmp_path):
+    from ray_tpu.serve.llm import _ContinuousLLMReplica
+
+    keep = jax.config.jax_persistent_cache_min_compile_time_secs
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        rep = _ContinuousLLMReplica(model[0], slots=2, max_prompt_len=16,
+                                    max_new_tokens=4)
+        rep.engine.warmup()
+    finally:
+        jax.profiler.stop_trace()
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", keep)
+        rep.engine.shutdown()
+    st = rep.engine_stats()
+    spans = {}
+    for prefix in ("engine.", "serve."):
+        for evs in _engine_events(str(tmp_path), prefix).values():
+            for name, s, e in evs:
+                spans.setdefault(name, []).append((s, e))
+    for span, key in (("serve.replica_weights", "weights_s"),
+                      ("engine.init", "engine_init_s"),
+                      ("engine.warmup", "warmup_s")):
+        assert len(spans[span]) == 1, span
+        (s, e), = spans[span]
+        assert (e - s) / 1e9 == pytest.approx(st[key], rel=0.10, abs=1e-4)
+    # one after the other, and a warm-up's programs inside it
+    assert spans["serve.replica_weights"][0][1] <= spans["engine.init"][0][0]
+    assert spans["engine.init"][0][1] <= spans["engine.warmup"][0][0]
+    programs = spans["engine.warmup_program"]
+    assert len(programs) == st["warmup_programs"] == 1 * 2 + 1
+    (w0, w1), = spans["engine.warmup"]
+    assert all(w0 <= s and e <= w1 for s, e in programs)
+
+
+def test_a_stall_that_was_a_compile_says_so(model, monkeypatch):
+    """No warm-up, and a shape no other test of this process runs: the
+    first admission asks the backend for its prefill program while the
+    request waits."""
+    monkeypatch.setattr(engine_mod, "_SLOW_S", 0.0)
+    eng = _cold_engine(model, slots=5, max_prompt_len=24, max_new_tokens=7)
+    asked = eng.stats["compile_requests"]
+    req = eng.submit(PROMPTS[1])
+    _drive(eng, [req])
+    admit = next(e for e in eng.slow_events if e["state"] == "admit")
+    assert admit["compiles"] >= 1
+    assert all(type(e["compiles"]) is int for e in eng.slow_events)
+    assert sum(e["compiles"] for e in eng.slow_events) \
+        <= eng.stats["compile_requests"] - asked
+    # the same requests again compile nothing: a stall there was no compile
+    eng.slow_events.clear()
+    _drive(eng, [eng.submit(PROMPTS[1])])
+    assert eng.slow_events
+    assert all(e["compiles"] == 0 for e in eng.slow_events)
 
 
 def test_a_lock_held_over_an_idle_engine_is_no_stall(model, monkeypatch):
